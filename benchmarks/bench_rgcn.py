@@ -15,6 +15,7 @@ import time
 import numpy as np
 
 from benchmarks.common import (
+    apply_smoke,
     base_parser,
     emit,
     init_backend,
@@ -76,14 +77,7 @@ def main():
 
 
 def _body(args):
-    init_backend(
-        retries=getattr(args, "backend_retries", 1),
-        delay=getattr(args, "backend_retry_delay", 15.0),
-    )
-    from benchmarks.common import _DEGRADED_REASON, apply_smoke
-
-    if _DEGRADED_REASON is not None:
-        args.smoke = True
+    init_backend(smoke=args.smoke)
     apply_smoke(args)
 
     import jax

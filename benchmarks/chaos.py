@@ -1,5 +1,5 @@
 """Chaos lane: FaultPlan drills over a tiny epoch — the resilience layer's
-evidence job (mega_session ``chaos`` stage, log-only).
+evidence job (log-only).
 
 Deterministic drills, each asserting the property the resilience
 layer guarantees (quiver_tpu/resilience/):
@@ -919,7 +919,7 @@ def main():
         args.nodes = min(args.nodes, 800)
         args.retry_steps = min(args.retry_steps, 4)
 
-    common.init_backend()
+    common.init_backend(smoke=args.smoke)
     topo, feat, labels = _build_graph(
         args.nodes, args.feature_dim, args.seed
     )

@@ -74,11 +74,6 @@ def _parser():
     p.add_argument("--smoke", action="store_true",
                    help="small budget/graph: a CI runner finishes in ~1 min")
     p.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
-    # accepted for common.run_guarded compatibility
-    p.add_argument("--backend-retries", type=int, default=0,
-                   help=argparse.SUPPRESS)
-    p.add_argument("--backend-retry-delay", type=float, default=5.0,
-                   help=argparse.SUPPRESS)
     return p
 
 
@@ -274,74 +269,71 @@ def main():
     # process so RLIMIT_AS (irreversible-downward) dies with the child
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-    def body():
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        flags = env.get("XLA_FLAGS", "")
-        if "host_platform_device_count" not in flags:
-            env["XLA_FLAGS"] = (flags + " " + _CHILD_XLA).strip()
-        env["PYTHONPATH"] = (
-            REPO + os.pathsep + env["PYTHONPATH"]
-            if env.get("PYTHONPATH") else REPO
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    flags = env.get("XLA_FLAGS", "")
+    if "host_platform_device_count" not in flags:
+        env["XLA_FLAGS"] = (flags + " " + _CHILD_XLA).strip()
+    env["PYTHONPATH"] = (
+        REPO + os.pathsep + env["PYTHONPATH"]
+        if env.get("PYTHONPATH") else REPO
+    )
+    argv = [sys.executable, "-m", "benchmarks.ooc_drill", "--child"]
+    for flag, val in (
+        ("--budget-mb", args.budget_mb),
+        ("--feature-dim", args.feature_dim),
+        ("--avg-degree", args.avg_degree),
+        ("--hot-frac", args.hot_frac),
+        ("--local-batch", args.local_batch),
+        ("--steps", args.steps),
+        ("--epochs", args.epochs),
+        ("--window-rows", args.window_rows),
+        ("--cache-windows", args.cache_windows),
+        ("--seed", args.seed),
+    ):
+        argv += [flag, str(val)]
+    common.log(f"spawning rlimit'd child: {' '.join(argv[1:])}")
+    r = subprocess.run(argv, capture_output=True, text=True,
+                       timeout=args.timeout, env=env, cwd=REPO)
+    sys.stderr.write(r.stderr or "")
+    rec = None
+    for line in (r.stdout or "").splitlines():
+        line = line.strip()
+        if line.startswith("{"):
+            try:
+                cand = json.loads(line)
+            except ValueError:
+                continue
+            if isinstance(cand, dict) and cand.get("ooc_drill"):
+                rec = cand
+    if r.returncode != 0 or rec is None:
+        tail = (r.stderr or r.stdout or "").strip()[-400:]
+        raise RuntimeError(
+            f"ooc drill child failed (rc={r.returncode}): {tail}"
         )
-        argv = [sys.executable, "-m", "benchmarks.ooc_drill", "--child"]
-        for flag, val in (
-            ("--budget-mb", args.budget_mb),
-            ("--feature-dim", args.feature_dim),
-            ("--avg-degree", args.avg_degree),
-            ("--hot-frac", args.hot_frac),
-            ("--local-batch", args.local_batch),
-            ("--steps", args.steps),
-            ("--epochs", args.epochs),
-            ("--window-rows", args.window_rows),
-            ("--cache-windows", args.cache_windows),
-            ("--seed", args.seed),
-        ):
-            argv += [flag, str(val)]
-        common.log(f"spawning rlimit'd child: {' '.join(argv[1:])}")
-        r = subprocess.run(argv, capture_output=True, text=True,
-                           timeout=args.timeout, env=env, cwd=REPO)
-        sys.stderr.write(r.stderr or "")
-        rec = None
-        for line in (r.stdout or "").splitlines():
-            line = line.strip()
-            if line.startswith("{"):
-                try:
-                    cand = json.loads(line)
-                except ValueError:
-                    continue
-                if isinstance(cand, dict) and cand.get("ooc_drill"):
-                    rec = cand
-        if r.returncode != 0 or rec is None:
-            tail = (r.stderr or r.stdout or "").strip()[-400:]
-            raise RuntimeError(
-                f"ooc drill child failed (rc={r.returncode}): {tail}"
-            )
-        common.set_record_context(
-            nodes=rec["nodes"], smoke=True if args.smoke else None
-        )
-        common.emit(
-            "ooc-epoch-time", rec["epoch_s"], "s", None,
-            store="pread",
-            graph_bytes=rec["graph_bytes"],
-            budget_bytes=rec["budget_bytes"],
-            graph_over_budget=rec["graph_over_budget"],
-            readahead_hits=rec["readahead_hits"],
-            page_reads=rec["page_reads"],
-            ooc_stage_wait_s=rec["stage_wait_s"],
-            recompiles_steady=rec["recompiles_steady"],
-            hot_rows=rec["hot_rows"],
-            steps=rec["steps"],
-            predicted_peak_bytes=rec.get("predicted_peak_bytes"),
-        )
-        common.log(
-            f"OOC drill OK: {rec['graph_over_budget']}x graph-over-budget, "
-            f"{rec['readahead_hits']} readahead hits, "
-            f"{rec['page_reads']} page reads, 0 steady recompiles"
-        )
-        return 0
-
-    return common.run_guarded(body, args)
+    common.set_record_context(
+        nodes=rec["nodes"], smoke=True if args.smoke else None
+    )
+    common.emit(
+        "ooc-epoch-time", rec["epoch_s"], "s", None,
+        store="pread",
+        graph_bytes=rec["graph_bytes"],
+        budget_bytes=rec["budget_bytes"],
+        graph_over_budget=rec["graph_over_budget"],
+        readahead_hits=rec["readahead_hits"],
+        page_reads=rec["page_reads"],
+        ooc_stage_wait_s=rec["stage_wait_s"],
+        recompiles_steady=rec["recompiles_steady"],
+        hot_rows=rec["hot_rows"],
+        steps=rec["steps"],
+        predicted_peak_bytes=rec.get("predicted_peak_bytes"),
+    )
+    common.log(
+        f"OOC drill OK: {rec['graph_over_budget']}x graph-over-budget, "
+        f"{rec['readahead_hits']} readahead hits, "
+        f"{rec['page_reads']} page reads, 0 steady recompiles"
+    )
+    return 0
 
 
 if __name__ == "__main__":

@@ -1,26 +1,22 @@
 """One-command TPU scoreboard: run every headline benchmark, write the
-results table (VERDICT r2 items 2-3).
+results table.
 
-Runs each benchmark as a supervised subprocess (same discipline as the
-repo-root ``bench.py``: hard timeout, JSON harvested from stdout, failures
-recorded instead of propagated) and writes:
+Runs each benchmark as its own child process — a chip belongs to one
+process at a time, so this parent never imports jax — with a hard timeout,
+JSON harvested from stdout and failures recorded instead of propagated,
+then writes under ``--out`` (default ``chiprun_out/scoreboard``, the
+directory a chip run brings back):
 
-* ``docs/TPU_RESULTS.md`` — the scoreboard table, every row stamped with
-  its platform, vs the reference's published numbers (BASELINE.md);
-* ``docs/tpu_results.json`` — the raw records;
-* ``BENCH_TRAJECTORY.jsonl`` (repo root) — one consolidated record per
-  round, appended, never rewritten: round-over-round movement of every
-  headline metric survives even when the per-round table is regenerated
-  whole. ``--backfill-trajectory`` reconstructs the early rounds from the
-  archived ``BENCH_r0*.json`` supervisor captures.
+* ``TPU_RESULTS.md`` — the scoreboard table, every row stamped with its
+  platform, vs the reference's published numbers (BASELINE.md);
+* ``tpu_results.json`` — the raw records.
 
     python -m benchmarks.scoreboard                 # full run
     python -m benchmarks.scoreboard --smoke         # small shapes
     python -m benchmarks.scoreboard --only sampler-hbm feature-replicate
-    python -m benchmarks.scoreboard --backfill-trajectory
 
-A row whose ``platform`` is not ``tpu`` means the chip was unreachable for
-that run; re-run when it frees up. The table is regenerated whole each time.
+A job without a chip fails (benchmarks exit non-zero off the TPU unless
+``--smoke``); the scoreboard exits non-zero when any job failed.
 """
 
 import argparse
@@ -32,17 +28,16 @@ import sys
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TRAJECTORY = os.path.join(REPO, "BENCH_TRAJECTORY.jsonl")
 
 # (key, module, args, baseline note)
 JOBS = [
-    # ordered: highest-evidence rows first, so a short chip window still
-    # lands the headline stream/scan numbers before the long-tail jobs
+    # ordered: highest-evidence rows first, so a run cut short still lands
+    # the headline stream/scan numbers before the long-tail jobs
     ("sampler-hbm", "benchmarks.bench_sampler",
      ["--mode", "HBM", "--stream", "128", "--dedup", "both"],
      "ref 34.29M SEPS (1-GPU UVA, Introduction_en.md:41); sort, dense-map "
      "AND scan dedup measured, fastest first (stage profile split into "
-     "its own job — one monolithic first job cost r4 a whole window)"),
+     "its own job)"),
     ("primitives", "benchmarks.microbench", [],
      "sort/scatter/gather/cummax Melem/s — decides which dedup strategy "
      "SHOULD win on this chip (scatter-serialization diagnosis), ~2 min"),
@@ -135,10 +130,8 @@ JOBS = [
      "UVA, which it never bounded or measured"),
     ("saint-node", "benchmarks.bench_saint", ["--sampler", "node"],
      "no reference baseline (SAINT never landed there)"),
-    ("validation", "benchmarks.tpu_validation", [],
-     "compiled-Pallas validity + head-to-heads"),
-    # last: single-chip mesh makes routed trivial on TPU; the 8-virtual-
-    # device CPU floor (scripts/cpu_floor.sh) is the multi-device evidence
+    # last: a single-chip mesh makes routed trivial; these want the
+    # four-chip host
     ("feature-shard-routed", "benchmarks.bench_feature",
      ["--policy", "shard", "--routed", "--stream", "32"],
      "owner-routed all_to_all hot gather over the mesh feature axis "
@@ -187,7 +180,7 @@ JOBS = [
      "fraction, fails on any finding or over-budget target"),
 ]
 
-TIMEOUT = float(os.environ.get("QUIVER_BENCH_TIMEOUT", 1800))
+TIMEOUT = 1800.0  # seconds per job
 
 
 def _harvest(stdout):
@@ -204,14 +197,16 @@ def _harvest(stdout):
     return recs
 
 
-def _run_once(module, extra, env_overrides, timeout_s):
+def run_job(module, extra, smoke, timeout_s):
+    """One child, one attempt. Returns (records, error | None, seconds)."""
     env = dict(os.environ)
-    env.update(env_overrides)
-    env["QUIVER_BENCH_SUPERVISED"] = "1"
     env["PYTHONPATH"] = (
         REPO + os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else REPO
     )
     argv = [sys.executable, "-m", module] + extra
+    if smoke:
+        argv.append("--smoke")
+    t0 = time.time()
     try:
         r = subprocess.run(argv, capture_output=True, text=True,
                            timeout=timeout_s, env=env, cwd=REPO)
@@ -219,146 +214,13 @@ def _run_once(module, extra, env_overrides, timeout_s):
         out = e.stdout or ""
         if isinstance(out, bytes):
             out = out.decode("utf-8", "replace")
-        # a hung multi-record job (tpu_validation) may already have emitted
-        # valid records — keep them
-        return _harvest(out), f"timeout>{timeout_s:.0f}s"
+        # a hung multi-record job may already have emitted valid records
+        return _harvest(out), f"timeout>{timeout_s:.0f}s", time.time() - t0
     recs = _harvest(r.stdout)
     err = None
     if not recs:
         err = (r.stderr or r.stdout).strip()[-400:] or f"rc={r.returncode}"
-    return recs, err
-
-
-def run_job(module, extra, smoke, timeout_s):
-    """Same discipline as the repo-root bench.py supervisor: children run
-    with QUIVER_BENCH_SUPERVISED=1 (fail fast, no self-healing), so THIS
-    function owns retry-on-error and the labeled CPU-smoke fallback."""
-    extra = extra + (["--smoke"] if smoke else [])
-    t0 = time.time()
-    recs, err = _run_once(module, extra, {}, timeout_s)
-    if not recs and not str(err).startswith("timeout"):
-        print(f"[scoreboard] retrying once after: {str(err)[:120]}",
-              file=sys.stderr, flush=True)
-        time.sleep(15)
-        recs, err = _run_once(module, extra, {}, timeout_s)
-    if not recs:
-        print("[scoreboard] falling back to labeled CPU smoke",
-              file=sys.stderr, flush=True)
-        fb = extra if "--smoke" in extra else extra + ["--smoke"]
-        recs, fb_err = _run_once(
-            module, fb,
-            {"JAX_PLATFORMS": "cpu",
-             "QUIVER_BENCH_DEGRADED": f"scoreboard fallback: {str(err)[:200]}"},
-            min(timeout_s, 600),
-        )
-        if recs:
-            err = None
-        else:
-            err = f"{err}; cpu fallback: {fb_err}"
     return recs, err, time.time() - t0
-
-
-def _headline(rec):
-    """Trajectory row for one benchmark record: the headline metric plus
-    just enough provenance to compare rounds (full detail stays in
-    tpu_results.json)."""
-    row = {
-        "metric": rec.get("metric"),
-        "value": rec.get("value"),
-        "unit": rec.get("unit", ""),
-        "platform": rec.get("platform", "?"),
-    }
-    if rec.get("vs_baseline") is not None:
-        row["vs_baseline"] = rec["vs_baseline"]
-    if rec.get("degraded"):
-        row["degraded"] = True
-    if rec.get("smoke"):
-        row["smoke"] = True
-    return row
-
-
-def _run_mode(rows):
-    """``tpu`` when any row is an undegraded full-scale chip number,
-    else ``cpu-smoke`` — the label the trajectory plots group by."""
-    for row in rows.values():
-        if (row.get("platform") == "tpu" and not row.get("degraded")
-                and not row.get("smoke")):
-            return "tpu"
-    return "cpu-smoke"
-
-
-def append_trajectory(entry, path=TRAJECTORY):
-    """Append one consolidated per-round record to the trajectory ledger.
-
-    Append-only on purpose: TPU_RESULTS.md and tpu_results.json are
-    regenerated whole each round, so they only ever show the latest
-    state; the ledger is the round-over-round history."""
-    with open(path, "a", encoding="utf-8") as fh:
-        fh.write(json.dumps(entry, sort_keys=True) + "\n")
-
-
-def trajectory_from_results(results, smoke, stamp):
-    rows = {}
-    for job in results:
-        recs = job.get("records") or []
-        if recs:
-            # first record of a job is its headline (bench modules emit
-            # the primary number first, attribution rows after)
-            rows[job["key"]] = _headline(recs[0])
-        else:
-            rows[job["key"]] = {"error": (job.get("error") or "failed")[:200]}
-    return {
-        "when": stamp,
-        "source": "scoreboard" + (" --smoke" if smoke else ""),
-        "mode": _run_mode(rows),
-        "rows": rows,
-    }
-
-
-def backfill_trajectory(path=TRAJECTORY):
-    """Reconstruct the early rounds from the archived ``BENCH_r0*.json``
-    supervisor captures and splice them in FRONT of any records already
-    in the ledger (which are newer by construction). Prior backfilled
-    round entries are replaced, not duplicated, so the command is
-    idempotent; scoreboard-appended entries are preserved."""
-    kept = []
-    if os.path.exists(path):
-        with open(path, encoding="utf-8") as fh:
-            for line in fh:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    d = json.loads(line)
-                except ValueError:
-                    continue
-                if d.get("source") != "bench.py":
-                    kept.append(d)
-    rounds = []
-    for name in sorted(os.listdir(REPO)):
-        if not (name.startswith("BENCH_r") and name.endswith(".json")):
-            continue
-        with open(os.path.join(REPO, name)) as fh:
-            cap = json.load(fh)
-        parsed = cap.get("parsed")
-        if parsed:
-            rows = {"sampler-hbm": _headline(parsed)}
-        else:
-            rows = {}
-        entry = {
-            "round": cap.get("n"),
-            "source": "bench.py",
-            "archive": name,
-            "mode": _run_mode(rows),
-            "rows": rows,
-        }
-        if not rows:
-            entry["error"] = f"rc={cap.get('rc')}: no parsed record"
-        rounds.append(entry)
-    with open(path, "w", encoding="utf-8") as fh:
-        for d in rounds + kept:
-            fh.write(json.dumps(d, sort_keys=True) + "\n")
-    return len(rounds), len(kept)
 
 
 def fmt_value(rec):
@@ -375,17 +237,9 @@ def main():
     p.add_argument("--smoke", action="store_true")
     p.add_argument("--only", nargs="*", default=None,
                    help="subset of job keys to run")
-    p.add_argument("--out", default=os.path.join(REPO, "docs"))
-    p.add_argument("--backfill-trajectory", action="store_true",
-                   help="rebuild the early BENCH_TRAJECTORY.jsonl rounds "
-                        "from the archived BENCH_r0*.json captures and exit")
+    p.add_argument("--out",
+                   default=os.path.join(REPO, "chiprun_out", "scoreboard"))
     args = p.parse_args()
-
-    if args.backfill_trajectory:
-        n_rounds, n_kept = backfill_trajectory()
-        print(f"[scoreboard] trajectory: {n_rounds} backfilled rounds + "
-              f"{n_kept} kept entries -> {TRAJECTORY}", file=sys.stderr)
-        return
 
     known = {key for key, *_ in JOBS}
     if args.only:
@@ -408,21 +262,14 @@ def main():
                         "error": err, "seconds": round(dt, 1)})
 
     write_outputs(results, args.out, args.smoke, merge=bool(args.only))
+    return 1 if any(not job["records"] for job in results) else 0
 
 
-def write_outputs(results, out, smoke, merge=False, trajectory_path=None):
+def write_outputs(results, out, smoke, merge=False):
     """Write ``tpu_results.json`` + ``TPU_RESULTS.md`` from job results.
 
     ``merge=True`` folds ``results`` into the existing json (keyed by job)
-    instead of replacing it — used by partial re-runs (``--only``) and by
-    the single-process chip-window runner (scripts/mega_session.py), which
-    writes after EVERY job so a mid-window kill loses nothing.
-
-    ``trajectory_path`` overrides where the consolidated round record is
-    appended (default: the repo-root ledger ``TRAJECTORY``). Tests MUST
-    pass a scratch path (or monkeypatch ``TRAJECTORY``) — the default
-    ledger is the authoritative round-over-round history and must only
-    ever receive real runs.
+    instead of replacing it — used by partial re-runs (``--only``).
     """
     os.makedirs(out, exist_ok=True)
     json_path = os.path.join(out, "tpu_results.json")
@@ -435,14 +282,14 @@ def write_outputs(results, out, smoke, merge=False, trajectory_path=None):
         except (ValueError, KeyError):
             prior = {}
         def _quality(job):
-            """Evidence rank of a job row: 2 full-scale TPU, 1 smoke/degraded
+            """Evidence rank of a job row: 2 full-scale TPU, 1 smoke
             TPU, 0 CPU/none. Higher-ranked prior rows must never be silently
             replaced by lower-ranked re-runs (a smoke rehearsal pointed at
             the same out dir would otherwise erase chip evidence)."""
             best = 0
             for rec in job.get("records") or []:
-                if rec.get("platform") == "tpu" and not rec.get("stale"):
-                    if rec.get("smoke") or rec.get("degraded"):
+                if rec.get("platform") == "tpu":
+                    if rec.get("smoke"):
                         best = max(best, 1)
                     else:
                         best = max(best, 2)
@@ -458,7 +305,7 @@ def write_outputs(results, out, smoke, merge=False, trajectory_path=None):
                 prior[job["key"]] = old
                 continue
             if old and _quality(old) > _quality(job):
-                # weaker evidence (smoke/degraded/CPU) must not displace a
+                # weaker evidence (smoke/CPU) must not displace a
                 # full-scale TPU row; keep the strong row and stash the
                 # newer weak one so nothing is lost either way
                 old = dict(old)
@@ -497,8 +344,6 @@ def write_outputs(results, out, smoke, merge=False, trajectory_path=None):
         for rec in job["records"]:
             vs = rec.get("vs_baseline")
             plat = rec.get("platform", "?")
-            if rec.get("degraded"):
-                plat += " (degraded)"
             if rec.get("smoke"):
                 # per-record stamp so merged tables can mix full-scale and
                 # smoke rows without the header mislabeling either
@@ -534,10 +379,8 @@ def write_outputs(results, out, smoke, merge=False, trajectory_path=None):
     ]
     with open(os.path.join(out, "TPU_RESULTS.md"), "w") as fh:
         fh.write("\n".join(lines))
-    append_trajectory(trajectory_from_results(results, smoke, stamp),
-                      path=trajectory_path or TRAJECTORY)
     print("\n".join(lines))
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

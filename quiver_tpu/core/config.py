@@ -30,8 +30,8 @@ def resolve_platform_strategy(env_var: str, choices, tpu_default: str,
     Several ops keep two bit-identical implementations whose cost model
     flips between backends (XLA serializes general scatters on TPU):
     dedup strategies, occurrence counts, chunked inference aggregation.
-    Each exposes an env var that FORCES a strategy during chip windows; a
-    typo'd force must raise, not silently measure the platform default.
+    Each exposes an env var that FORCES a strategy for a measurement run;
+    a typo'd force must raise, not silently measure the platform default.
     """
     import os
 

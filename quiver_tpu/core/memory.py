@@ -6,35 +6,38 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 __all__ = ["to_pinned_host"]
 
 
 def to_pinned_host(x: np.ndarray, mesh=None) -> tuple[jax.Array, bool]:
-    """Place an array in pinned host memory if the platform supports it.
+    """Place an array in pinned host memory. Returns (array, is_host).
 
     With ``mesh``, the host array is replicated across the mesh's devices
     (one physical copy per host) so it composes with mesh-sharded arrays.
-    Returns (array, is_host). Falls back to default placement with
-    is_host=False on platforms without a pinned_host memory space — callers
-    branch on the flag to pick direct vs staged gathers.
+    On a TPU the array must land in ``pinned_host`` — a "cold tier" that
+    quietly sits in HBM is an error, and a failed placement propagates.
+    Only the CPU backend, when it has no such memory space, gets the
+    default placement with ``is_host=False``; callers branch on the flag
+    to pick direct vs staged gathers.
     """
-    try:
-        if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            s = NamedSharding(mesh, PartitionSpec(), memory_kind="pinned_host")
-        else:
-            s = jax.sharding.SingleDeviceSharding(
-                jax.devices()[0], memory_kind="pinned_host"
-            )
-        arr = jax.device_put(np.asarray(x), s)
-        if getattr(arr.sharding, "memory_kind", None) == "pinned_host":
-            return arr, True
-    except Exception:
-        pass
-    if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        return jax.device_put(np.asarray(x), NamedSharding(mesh, PartitionSpec())), False
-    return jnp.asarray(x), False
+    device = jax.devices()[0] if mesh is None else mesh.devices.flat[0]
+    kind = "pinned_host"
+    if device.platform == "cpu" and kind not in {
+        m.kind for m in device.addressable_memories()
+    }:
+        if mesh is None:
+            return jnp.asarray(x), False
+        kind = None
+    sharding = (
+        SingleDeviceSharding(device, memory_kind=kind) if mesh is None
+        else NamedSharding(mesh, PartitionSpec(), memory_kind=kind)
+    )
+    arr = jax.device_put(np.asarray(x), sharding)
+    if kind is not None and arr.sharding.memory_kind != kind:
+        raise RuntimeError(
+            f"asked for {kind} memory on {device.platform}, got "
+            f"{arr.sharding.memory_kind!r}"
+        )
+    return arr, kind is not None

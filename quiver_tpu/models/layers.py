@@ -11,10 +11,10 @@ Two aggregation paths, identical results:
 * **dense** (``fanout`` set — every sampler-built Adj): the sampler's edge
   layout is regular (lane ``s*fanout + k`` targets seed ``s``), so
   aggregation is a masked ``(num_dst, fanout, F)`` reshape + axis-1
-  reduction — zero scatters. XLA serializes general scatters on TPU
-  (r3 link characterization, docs/TPU_MEASUREMENTS_R3.md), so on the
-  training path this is the difference between VPU-speed reductions and a
-  per-edge loop.
+  reduction — zero scatters. XLA is expected to serialize general
+  scatters on TPU (not yet measured on the chip: ROADMAP S2), which on
+  the training path would be the difference between VPU-speed reductions
+  and a per-edge loop.
 * **segment** (``fanout=None``): ``jax.ops.segment_sum`` with an overflow
   bucket for invalid lanes — kept for hand-built/irregular Adjs and as the
   differential-test oracle.

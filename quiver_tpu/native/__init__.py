@@ -1,16 +1,21 @@
 """ctypes loader for the native host runtime (quiver_host.cpp).
 
-Builds the shared library on first import (cached next to the source; no
-pybind11 in this image, so the C ABI + ctypes replaces the reference's
-torch-extension binding layer, srcs/cpp/src/quiver/torch/module.cpp).
-Falls back cleanly to ``available = False`` when no toolchain exists —
-callers keep their numpy paths, mirroring how the reference's CPU-only CI
-builds without CUDA (HAVE_CUDA gating, setup.py:13-16).
+Builds the shared library on first import (no pybind11 in this image, so
+the C ABI + ctypes replaces the reference's torch-extension binding layer,
+srcs/cpp/src/quiver/torch/module.cpp). The library's file name carries a
+hash of the source and the build flags, so a library built from other
+source — or one that rode along in a copied tree — is never loaded: a
+name that is not there is rebuilt. Falls back cleanly to
+``available = False`` when no toolchain exists — callers keep their numpy
+paths, mirroring how the reference's CPU-only CI builds without CUDA
+(HAVE_CUDA gating, setup.py:13-16).
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 
@@ -18,40 +23,53 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "quiver_host.cpp")
-_LIB = os.path.join(_DIR, "libquiver_host.so")
+# no -march=native: the library may be built on one machine and the tree
+# copied to another
+_FLAGS = ["-O3", "-fopenmp", "-shared", "-fPIC"]
 
 available = False
 _lib = None
 
 
-def _build() -> bool:
-    if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-        return True
+def _lib_path() -> str:
+    h = hashlib.sha256(" ".join(_FLAGS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_DIR, f"libquiver_host.{h.hexdigest()[:16]}.so")
+
+
+def _build() -> str | None:
+    """Path of the library for the current source, building it if its
+    name is absent; None without a working toolchain."""
+    lib = _lib_path()
+    if os.path.exists(lib):
+        return lib
     # compile to a temp path and atomically rename so concurrent importers
     # (one JAX process per TPU host on a shared FS) never dlopen a torn file
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
-    cmd = [
-        "g++", "-O3", "-march=native", "-fopenmp", "-shared", "-fPIC",
-        _SRC, "-o", tmp,
-    ]
+    tmp = f"{lib}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB)
-        return True
-    except Exception:
+        subprocess.run(["g++", *_FLAGS, _SRC, "-o", tmp], check=True,
+                       capture_output=True, timeout=120)
+        os.replace(tmp, lib)
+    except (OSError, subprocess.SubprocessError):
         try:
             os.unlink(tmp)
         except OSError:
             pass
-        return False
+        return None
+    for stale in glob.glob(os.path.join(_DIR, "libquiver_host*.so")):
+        if stale != lib:
+            os.unlink(stale)
+    return lib
 
 
 def _load():
     global _lib, available
-    if not _build():
+    path = _build()
+    if path is None:
         return
     try:
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(path)
 
         i64p = ctypes.POINTER(ctypes.c_int64)
         i32p = ctypes.POINTER(ctypes.c_int32)
@@ -70,7 +88,7 @@ def _load():
         lib.reindex_cpu.restype = ctypes.c_int64
         lib.quiver_host_num_threads.restype = ctypes.c_int
     except (OSError, AttributeError):
-        # torn/stale .so (e.g. built from older source, missing a symbol)
+        # a library that does not load or lacks a symbol
         return
     _lib = lib
     available = True

@@ -14,10 +14,11 @@ copy, and the select:
     the draw (stratified offsets + rotation for uniform/temporal, the raw
     ``(S, k)`` uniform block for weighted) — everything whose bits depend
     only on the key, keeping bit-parity with the XLA oracle provable.
- 2. The kernel DMAs ``indices[start : start+window]`` (and, as aligned
-    lanes, the ``cum_weights`` and ``eid`` windows when the variant needs
-    them) into VMEM — one bulk DMA per row per table, all rows of a tile
-    in flight at once.
+ 2. The kernel DMAs the lane-aligned window that covers
+    ``indices[start : start+window]`` (and the matching ``cum_weights``
+    and ``eid`` windows when the variant needs them) into VMEM — one bulk
+    DMA per row per table, all rows of a tile in flight at once
+    (``_fetch_windows`` has the memory-tile rules this obeys).
  3. Topology-dependent work happens on-chip against the VMEM window: the
     weighted inverse-CDF binary search walks the row's prefix-weight
     segment in VMEM (``_wselect_kernel`` — the WarpSampler walk without
@@ -57,22 +58,84 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..sample import rotate_offsets, stratified_offsets, temporal_window_counts
+from . import resolve_interpret
 
 __all__ = [
     "DEFAULT_WINDOW",
+    "MIN_EDGES",
     "fused_sample_layer",
     "fused_select_hop",
     "fused_weighted_hop",
 ]
 
-# default neighbor-window length; callers deciding between this kernel and
-# the XLA path compare edge_count against it (quiver_tpu/sampling/sampler.py)
-DEFAULT_WINDOW = 2048
+DEFAULT_WINDOW = 2048  # default neighbor-window length
 
 _I32MAX = 2**31 - 1
+_LANE = 128  # a DMA may only start on a lane-tile boundary
+
+# fewest edges a table may have at the default window: the aligned DMA
+# window is one lane tile longer than the draw window. Callers deciding
+# between this kernel and the XLA path compare edge_count against it
+# (quiver_tpu/sampling/sampler.py, sampling/dist.py)
+MIN_EDGES = DEFAULT_WINDOW + _LANE
 
 
-def _select_kernel(tile: int, window: int, k: int, n_tab: int,
+def _split(refs, *sizes):
+    """``refs`` cut into consecutive groups of the given sizes."""
+    groups, i = [], 0
+    for n in sizes:
+        groups.append(refs[i:i + n])
+        i += n
+    return groups
+
+
+def _fetch_windows(tile: int, wbuf: int, start_ref, tabs, stages, packed,
+                   sems):
+    """DMA one ``wbuf``-slot window per row per table and pack the rows of
+    the tile onto sublanes.
+
+    Mosaic only lets a DMA address whole memory tiles. So the tables ride
+    as ``(1, E)`` views (tiled ``(1, 128)``: a lane-aligned start is a
+    tile boundary), each row's window lands in its own ``(1, wbuf)`` slab
+    of a ``(tile, 1, wbuf)`` stage, and a VMEM copy moves slab ``j`` to
+    sublane ``j`` of the ``(tile, wbuf)`` buffer the select reads —
+    window slots on lanes, rows on sublanes, every vector op full-width.
+    """
+    i = pl.program_id(0)
+
+    def dma(t, j):
+        st = pl.multiple_of(start_ref[i * tile + j], _LANE)
+        return pltpu.make_async_copy(
+            tabs[t].at[:, pl.ds(st, wbuf)], stages[t].at[j], sems.at[t, j]
+        )
+
+    # fan out: every row-window DMA of this tile (all tables) in flight
+    for t in range(len(tabs)):
+        for j in range(tile):
+            dma(t, j).start()
+    for t in range(len(tabs)):
+        for j in range(tile):
+            dma(t, j).wait()
+    for t in range(len(tabs)):
+        for j in range(tile):
+            packed[t][pl.ds(j, 1), :] = stages[t][j]
+
+
+def _lane(x, lane_k, c: int):
+    """Column ``c`` of a ``(tile, k)`` block as ``(tile, 1)``: a masked
+    lane sum with one live term, so it is exact for ints and floats and
+    needs no lane-to-sublane relayout."""
+    return jnp.sum(jnp.where(lane_k == c, x, 0), axis=1, keepdims=True)
+
+
+def _pick(col, slot, window_vals):
+    """``window_vals[r, slot[r]]`` per row as ``(tile, 1)``: exact one-hot
+    masked sum over the window lanes."""
+    return jnp.sum(jnp.where(col == slot, window_vals, 0), axis=1,
+                   keepdims=True)
+
+
+def _select_kernel(tile: int, wbuf: int, k: int, n_tab: int,
                    start_ref, *refs):
     """Windowed gather-select over ``n_tab`` aligned int32 tables.
 
@@ -80,37 +143,26 @@ def _select_kernel(tile: int, window: int, k: int, n_tab: int,
     temporal / dist-owner select core; the eid lane is just a second table
     riding the same offsets.
     """
-    tabs = refs[:n_tab]
-    offs_ref = refs[n_tab]
-    outs = refs[n_tab + 1:2 * n_tab + 1]
-    bufs = refs[2 * n_tab + 1:3 * n_tab + 1]
-    sems = refs[3 * n_tab + 1]
-    i = pl.program_id(0)
+    tabs, (offs_ref,), outs, stages, packed, (sems,) = _split(
+        refs, n_tab, 1, n_tab, n_tab, n_tab, 1
+    )
+    _fetch_windows(tile, wbuf, start_ref, tabs, stages, packed, sems)
 
-    def dma(t, j):
-        return pltpu.make_async_copy(
-            tabs[t].at[pl.ds(start_ref[i * tile + j], window)],
-            bufs[t].at[j],
-            sems.at[t, j],
-        )
-
-    # fan out: every row-window DMA of this tile (all tables) in flight
+    offs = offs_ref[...]
+    lane_k = jax.lax.broadcasted_iota(jnp.int32, (tile, k), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tile, wbuf), 1)
+    acc = [jnp.zeros((tile, k), jnp.int32) for _ in range(n_tab)]
+    for c in range(k):
+        slot = _lane(offs, lane_k, c)
+        for t in range(n_tab):
+            acc[t] = jnp.where(
+                lane_k == c, _pick(col, slot, packed[t][...]), acc[t]
+            )
     for t in range(n_tab):
-        for j in range(tile):
-            dma(t, j).start()
-    for t in range(n_tab):
-        for j in range(tile):
-            dma(t, j).wait()
-
-    # exact integer select: out[j, c] = buf[j, offs[j, c]]
-    col = jax.lax.broadcasted_iota(jnp.int32, (tile, k, window), 2)
-    hit = col == offs_ref[:, :][:, :, None]
-    for t in range(n_tab):
-        vals = bufs[t][:, :].reshape(tile, 1, window)
-        outs[t][:, :] = jnp.sum(jnp.where(hit, vals, 0), axis=2)
+        outs[t][...] = acc[t]
 
 
-def _wselect_kernel(tile: int, window: int, k: int, iters: int,
+def _wselect_kernel(tile: int, wbuf: int, k: int, iters: int,
                     with_eid: bool, scale_u: bool, start_ref, *refs):
     """Weighted select: in-kernel inverse-CDF walk over the VMEM window.
 
@@ -121,77 +173,95 @@ def _wselect_kernel(tile: int, window: int, k: int, iters: int,
     values, same compares, same bits out. Emits the selected row-local
     offsets too (the eids-without-a-table lane is ``base + off`` in XLA).
     """
-    if with_eid:
-        (indices_ref, cw_ref, eid_ref, meta_ref, u_ref,
-         out_nbr, out_off, out_eid, ibuf, wbuf, ebuf, sems) = refs
-    else:
-        (indices_ref, cw_ref, meta_ref, u_ref,
-         out_nbr, out_off, ibuf, wbuf, sems) = refs
-        eid_ref = ebuf = out_eid = None
-    i = pl.program_id(0)
-    pairs = [(indices_ref, ibuf), (cw_ref, wbuf)]
-    if with_eid:
-        pairs.append((eid_ref, ebuf))
+    # tables indices, cum_weights[, eid]; outputs nbr, row_off[, eid]
+    n_tab = n_out = 3 if with_eid else 2
+    tabs, (meta_ref, u_ref), outs, stages, packed, (sems,) = _split(
+        refs, n_tab, 2, n_out, n_tab, n_tab, 1
+    )
+    _fetch_windows(tile, wbuf, start_ref, tabs, stages, packed, sems)
 
-    def dma(t, j):
-        src, dst = pairs[t]
-        return pltpu.make_async_copy(
-            src.at[pl.ds(start_ref[i * tile + j], window)],
-            dst.at[j],
-            sems.at[t, j],
-        )
-
-    for t in range(len(pairs)):
-        for j in range(tile):
-            dma(t, j).start()
-    for t in range(len(pairs)):
-        for j in range(tile):
-            dma(t, j).wait()
-
-    off0 = meta_ref[:, 0:1]  # (tile, 1) window offset of the row start
-    wl = meta_ref[:, 1:2]    # (tile, 1) row length (== deg; fits the window)
-    w = wbuf[:, :]
+    meta = meta_ref[...]
+    lane_2 = jax.lax.broadcasted_iota(jnp.int32, (tile, 2), 1)
+    off0 = _lane(meta, lane_2, 0)  # window offset of the row start
+    wl = _lane(meta, lane_2, 1)    # row length (== deg; fits the window)
+    w = packed[1][...]
+    col = jax.lax.broadcasted_iota(jnp.int32, (tile, wbuf), 1)
     # row weight total: the window copy of the row's LAST inclusive-prefix
     # entry — bitwise the oracle's staged_gather(cum_weights, base+deg-1)
-    col2 = jax.lax.broadcasted_iota(jnp.int32, (tile, window), 1)
-    endw = jnp.maximum(off0 + wl - 1, 0)
-    tot = jnp.sum(jnp.where(col2 == endw, w, 0.0), axis=1, keepdims=True)
+    tot = _pick(col, jnp.maximum(off0 + wl - 1, 0), w)
     tot = jnp.where(wl > 0, tot, 1.0)
-    u = u_ref[:, :]
-    if scale_u:
-        u = u * tot
-    # row-local inverse-CDF bisection (ops.sample._cdf_search shifted by
-    # start: (2*off0 + lo + hi) // 2 = off0 + (lo + hi) // 2, so every
-    # probe touches the same array element the global search would)
+    u = u_ref[...]
+    lane_k = jax.lax.broadcasted_iota(jnp.int32, (tile, k), 1)
     nonempty = (wl > 0).astype(jnp.int32)
-    lo = jnp.broadcast_to(off0, (tile, k))
-    hi = lo + (wl - 1) * nonempty
-    col3 = jax.lax.broadcasted_iota(jnp.int32, (tile, k, window), 2)
-    w3 = w.reshape(tile, 1, window)
-    for _ in range(iters):
-        mid = (lo + hi) // 2
-        # the min() is a safety clamp only: valid rows satisfy
-        # off0 + wlen <= window, so mid <= window-1 already
-        midc = jnp.minimum(mid * nonempty, window - 1)
-        pm = jnp.sum(jnp.where(col3 == midc[:, :, None], w3, 0.0), axis=2)
-        go = pm < u
-        lo = jnp.where(go, mid + 1, lo)
-        hi = jnp.where(go, hi, mid)
-    row_off = lo - off0
-    # take-all override (weighted_offsets / dist serve_wnbr): deg <= k
-    # rows keep CSR order — in-kernel so emitted offsets match XLA's
-    ii = jax.lax.broadcasted_iota(jnp.int32, (tile, k), 1)
-    row_off = jnp.where(
-        wl <= k, jnp.minimum(ii, jnp.maximum(wl - 1, 0)), row_off
+    acc = [jnp.zeros((tile, k), jnp.int32) for _ in range(n_out)]
+    for c in range(k):
+        uc = _lane(u, lane_k, c)
+        if scale_u:
+            uc = uc * tot
+        # row-local inverse-CDF bisection (ops.sample._cdf_search shifted
+        # by start: (2*off0 + lo + hi) // 2 = off0 + (lo + hi) // 2, so
+        # every probe touches the same array element the global search
+        # would)
+        lo = off0
+        hi = off0 + (wl - 1) * nonempty
+        for _ in range(iters):
+            mid = (lo + hi) // 2
+            # the min() is a safety clamp only: valid rows satisfy
+            # off0 + wlen <= wbuf, so mid <= wbuf-1 already
+            pm = _pick(col, jnp.minimum(mid * nonempty, wbuf - 1), w)
+            go = pm < uc
+            lo = jnp.where(go, mid + 1, lo)
+            hi = jnp.where(go, hi, mid)
+        # take-all override (weighted_offsets / dist serve_wnbr): deg <= k
+        # rows keep CSR order — in-kernel so emitted offsets match XLA's
+        row_off = jnp.where(
+            wl <= k, jnp.minimum(c, jnp.maximum(wl - 1, 0)), lo - off0
+        )
+        slot = off0 + row_off
+        picked = [_pick(col, slot, packed[0][...]), row_off]
+        if with_eid:
+            picked.append(_pick(col, slot, packed[2][...]))
+        for o in range(n_out):
+            acc[o] = jnp.where(lane_k == c, picked[o], acc[o])
+    for o in range(n_out):
+        outs[o][...] = acc[o]
+
+
+def _window_scratch(tile: int, wbuf: int, dtypes):
+    """Stage slabs, packed buffers and DMA semaphores for ``dtypes``
+    tables (the order ``_select_kernel``/``_wselect_kernel`` unpack)."""
+    return (
+        [pltpu.VMEM((tile, 1, wbuf), dt) for dt in dtypes]
+        + [pltpu.VMEM((tile, wbuf), dt) for dt in dtypes]
+        + [pltpu.SemaphoreType.DMA((len(dtypes), tile))]
     )
-    sel = off0 + row_off
-    hit = col3 == sel[:, :, None]
-    ivals = ibuf[:, :].reshape(tile, 1, window)
-    out_nbr[:, :] = jnp.sum(jnp.where(hit, ivals, 0), axis=2)
-    out_off[:, :] = row_off
-    if with_eid:
-        evals = ebuf[:, :].reshape(tile, 1, window)
-        out_eid[:, :] = jnp.sum(jnp.where(hit, evals, 0), axis=2)
+
+
+def _aligned_windows(E: int, start, window: int):
+    """Lane-aligned DMA starts covering ``[start, start + window)``.
+
+    Returns ``(wbuf, st, delta)``: every row DMAs ``[st, st + wbuf)`` with
+    ``st`` a multiple of 128, and a slot ``o`` of the caller's window is
+    slot ``o + delta`` of the fetched one. ``st + wbuf`` may pass ``E`` by
+    up to 127 slots; they lie inside the array's last memory tile (see
+    ``_as_rows``) and no offset ever selects them.
+    """
+    wbuf = window + _LANE
+    e_pad = -(-E // _LANE) * _LANE
+    st = jnp.clip((start // _LANE) * _LANE, 0, e_pad - wbuf)
+    return wbuf, st, start - st
+
+
+def _as_rows(table, interpret: bool):
+    """``(E,)`` table as the ``(1, E)`` view the window DMAs slice.
+
+    On the chip the view is free and its last tile is padded to 128 lanes
+    in HBM, which is what lets an aligned window run past ``E``. The
+    interpreter has no tiles, so there the padding is made explicit.
+    """
+    if interpret:
+        table = jnp.pad(table, (0, (-table.shape[0]) % _LANE))
+    return table.reshape(1, -1)
 
 
 @functools.partial(
@@ -200,6 +270,7 @@ def _wselect_kernel(tile: int, window: int, k: int, iters: int,
 def _run_select(tables, start, offs, tile, window, k, interpret):
     Sp = start.shape[0]
     n_tab = len(tables)
+    wbuf, st, delta = _aligned_windows(tables[0].shape[0], start, window)
     blk = pl.BlockSpec((tile, k), lambda i, *_: (i, 0),
                        memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -207,17 +278,14 @@ def _run_select(tables, start, offs, tile, window, k, interpret):
         grid=(Sp // tile,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_tab + [blk],
         out_specs=[blk] * n_tab,
-        scratch_shapes=(
-            [pltpu.VMEM((tile, window), jnp.int32)] * n_tab
-            + [pltpu.SemaphoreType.DMA((n_tab, tile))]
-        ),
+        scratch_shapes=_window_scratch(tile, wbuf, [jnp.int32] * n_tab),
     )
     outs = pl.pallas_call(
-        functools.partial(_select_kernel, tile, window, k, n_tab),
+        functools.partial(_select_kernel, tile, wbuf, k, n_tab),
         out_shape=[jax.ShapeDtypeStruct((Sp, k), jnp.int32)] * n_tab,
         grid_spec=grid_spec,
         interpret=interpret,
-    )(start, *tables, offs)
+    )(st, *[_as_rows(t, interpret) for t in tables], offs + delta[:, None])
     return tuple(outs)
 
 
@@ -229,46 +297,35 @@ def _run_wselect(indices, cum_weights, eid, start, meta, u, tile, window, k,
                  iters, scale_u, interpret):
     Sp = start.shape[0]
     with_eid = eid is not None
-    n_dma = 3 if with_eid else 2
+    wbuf, st, delta = _aligned_windows(indices.shape[0], start, window)
+    meta = meta.at[:, 0].add(delta)  # row start within the fetched window
     blk = pl.BlockSpec((tile, k), lambda i, *_: (i, 0),
                        memory_space=pltpu.VMEM)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    args = [indices, cum_weights] + ([eid] if with_eid else [])
-    in_specs = [any_spec] * len(args) + [
+    tables = [indices, cum_weights] + ([eid] if with_eid else [])
+    in_specs = [any_spec] * len(tables) + [
         pl.BlockSpec((tile, 2), lambda i, *_: (i, 0),
                      memory_space=pltpu.VMEM),
         blk,
     ]
-    n_out = 3 if with_eid else 2
-    scratch = [
-        pltpu.VMEM((tile, window), jnp.int32),
-        pltpu.VMEM((tile, window), cum_weights.dtype),
-    ]
-    if with_eid:
-        scratch.append(pltpu.VMEM((tile, window), jnp.int32))
-    scratch.append(pltpu.SemaphoreType.DMA((n_dma, tile)))
+    n_out = len(tables)  # nbr, row_off[, eid]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(Sp // tile,),
         in_specs=in_specs,
         out_specs=[blk] * n_out,
-        scratch_shapes=scratch,
+        scratch_shapes=_window_scratch(
+            tile, wbuf, [t.dtype for t in tables]),
     )
     outs = pl.pallas_call(
         functools.partial(
-            _wselect_kernel, tile, window, k, iters, with_eid, scale_u
+            _wselect_kernel, tile, wbuf, k, iters, with_eid, scale_u
         ),
         out_shape=[jax.ShapeDtypeStruct((Sp, k), jnp.int32)] * n_out,
         grid_spec=grid_spec,
         interpret=interpret,
-    )(start, *args, meta, u)
+    )(st, *[_as_rows(t, interpret) for t in tables], meta, u)
     return tuple(outs)
-
-
-def _default_interpret(interpret):
-    if interpret is None:
-        return jax.devices()[0].platform != "tpu"
-    return interpret
 
 
 def fused_select_hop(indices, start, offs, *, eid=None,
@@ -278,11 +335,12 @@ def fused_select_hop(indices, start, offs, *, eid=None,
     offs[r, c]]`` (plus an aligned ``eid`` lane when given).
 
     The dist owner-side select core. Contract: ``start`` int32 ``(S,)``
-    with ``start + window <= indices.shape[0]`` everywhere, ``offs`` int32
-    ``(S, k)`` in ``[0, window)``. Returns a tuple of ``(S, k)`` int32
+    with ``start + window <= indices.shape[0]`` everywhere,
+    ``indices.shape[0] >= window + 128``, ``offs`` int32 ``(S, k)`` in
+    ``[0, window)``. Returns a tuple of ``(S, k)`` int32
     arrays, one per table.
     """
-    interpret = _default_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     S, k = offs.shape
     pad = (-S) % tile
     if pad:
@@ -308,7 +366,7 @@ def fused_weighted_hop(indices, cum_weights, start, off0, wlen, u,
     is the selected row-local offset after the take-all override —
     bitwise ``ops.sample.weighted_offsets``.
     """
-    interpret = _default_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     S, k = u.shape
     meta = jnp.stack(
         [off0.astype(jnp.int32), wlen.astype(jnp.int32)], axis=1
@@ -332,7 +390,7 @@ def fused_sample_layer(topo, seeds, num_seeds, k: int, key, *,
     ``ops.sample.sample_layer`` (and bitwise equal wherever the draw span
     fits the window — see the module docstring's parity contract).
 
-    Requires an HBM-resident topology with ``edge_count >= window``
+    Requires an HBM-resident topology with ``edge_count >= window + 128``
     (callers fall back to the XLA path otherwise); the weighted walk
     additionally requires ``topo.max_degree <= window`` so every row's
     prefix segment is fully VMEM-resident.
@@ -341,10 +399,12 @@ def fused_sample_layer(topo, seeds, num_seeds, k: int, key, *,
         raise ValueError(f"fanout k must be >= 1, got {k}")
     if k > 46340:
         raise ValueError(f"fanout k must be <= 46340, got {k}")
-    interpret = _default_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     E = topo.indices.shape[0]
-    if E < window:
-        raise ValueError(f"edge_count {E} < window {window}; use the XLA path")
+    if E < window + _LANE:
+        raise ValueError(
+            f"edge_count {E} < window {window} + {_LANE}; use the XLA path"
+        )
     if E - window > _I32MAX:
         # window starts ride scalar-prefetch SMEM as int32; past 2^31 edges
         # they would wrap (the XLA path keeps indptr dtype and stays exact)

@@ -54,16 +54,16 @@ def resolve_dedup(dedup: str) -> str:
     The three strategies are bit-identical (tests/test_reindex.py); only
     their cost model differs per backend:
 
-    * **cpu** -> ``"map"`` — measured: the dense scatter-min map is 4-5x
-      the sort path at both smoke and full products scale
-      (docs/TPU_MEASUREMENTS_R3.md CPU-floor extras).
-    * **tpu** -> ``"scan"`` — the zero-scatter strategy, chosen because
-      XLA serializes general scatters on TPU while its sort runs at
-      ~1.8 ms/M elements (r3 link characterization); provisional until
-      the ``sampler-hbm --dedup both`` self-selection lands on hardware.
+    * **cpu** -> ``"map"`` — on XLA's CPU backend the dense scatter-min
+      map ran 4-5x the sort path at both smoke and full products scale.
+    * **tpu** -> ``"scan"`` — the zero-scatter strategy, chosen on the
+      expectation that XLA serializes general scatters on TPU. It runs on
+      a v5e (``chip_smoke.py``) but the three have not been timed against
+      each other there: provisional until ROADMAP S2 does (the two
+      2026-07-30 rows in ROADMAP's Speed-queue table used ``sort``).
 
     ``QUIVER_DEDUP=sort|map|scan`` overrides the ``"auto"`` resolution
-    ONLY (chip-window forcing): call sites passing an explicit strategy
+    ONLY: call sites passing an explicit strategy
     keep it — benchmark variant labels must match what actually ran — and
     the first such ignored force is logged so the mismatch is visible.
     Unknown names raise — a typo must not silently fall back to a

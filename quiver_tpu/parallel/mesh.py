@@ -32,23 +32,10 @@ FEATURE_AXIS = "feature"
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """Version-portable ``shard_map``.
-
-    jax >= 0.7 exposes ``jax.shard_map`` with the replication check named
-    ``check_vma``; older releases ship ``jax.experimental.shard_map.shard_map``
-    with the same check named ``check_rep``. The repo targets both: the
-    image's baked-in toolchain pins an older jax while dev boxes track
-    HEAD, and an AttributeError here takes down every mesh test.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(
+    """``jax.shard_map`` with the varying-axes check left at its default
+    unless ``check_vma`` says otherwise."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )
 

@@ -49,8 +49,7 @@ the world instead of leaving it intact:
 fully deterministic :class:`FaultPlan` that injects NaN rows into gathered
 features (in-program, step-indexed), transient exceptions into host
 sampler/feature lookups, and simulated preemption — reusable as a chaos
-lane by benchmarks (``benchmarks/chaos.py``, the mega_session ``chaos``
-stage).
+lane by benchmarks (``benchmarks/chaos.py``).
 """
 
 from .elastic import (

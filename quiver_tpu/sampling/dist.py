@@ -162,15 +162,16 @@ def dist_sample_layer(local_indptr, local_indices, rows_per_shard: int,
     if use_pallas:
         from ..ops.pallas.fused import (
             DEFAULT_WINDOW,
+            MIN_EDGES,
             fused_select_hop,
             fused_weighted_hop,
         )
 
-        if (E_local < DEFAULT_WINDOW
+        if (E_local < MIN_EDGES
                 or E_local > np.iinfo(np.int32).max
                 or k > DEFAULT_WINDOW):
             raise ValueError(
-                f"kernel='pallas' needs {DEFAULT_WINDOW} <= local edge "
+                f"kernel='pallas' needs {MIN_EDGES} <= local edge "
                 f"count <= int32 max and fanout <= {DEFAULT_WINDOW} (got "
                 f"E_local={E_local}, k={k}); DistGraphSageSampler gates "
                 f"this at compile time — use kernel='xla' here"
@@ -551,7 +552,7 @@ class DistGraphSageSampler(GraphSageSampler):
         n_topo = len(self._topo_operands())
         kernel = self.kernel  # resolved request (may run the election)
         if kernel == "pallas":
-            from ..ops.pallas.fused import DEFAULT_WINDOW
+            from ..ops.pallas.fused import DEFAULT_WINDOW, MIN_EDGES
 
             # compile-time eligibility for the fused owner-side kernel:
             # every shard's slice must host a full DMA window in int32
@@ -560,9 +561,9 @@ class DistGraphSageSampler(GraphSageSampler):
             E_local = int(self.topo.indices.shape[1])
             md = int(self.csr_topo.max_degree)
             bad = None
-            if E_local < DEFAULT_WINDOW:
+            if E_local < MIN_EDGES:
                 bad = (f"per-shard edge slices hold {E_local} edges, fewer "
-                       f"than the {DEFAULT_WINDOW}-edge DMA window")
+                       f"than the {MIN_EDGES}-edge DMA window")
             elif E_local > np.iinfo(np.int32).max:
                 bad = f"per-shard edge slices exceed int32 range ({E_local})"
             elif md > DEFAULT_WINDOW:

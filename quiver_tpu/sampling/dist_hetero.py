@@ -385,7 +385,7 @@ class DistHeteroSampler(HeteroGraphSampler):
         out_types, fc_slots = self._scal_layout(plans)
         pallas_rels = frozenset()
         if self.kernel == "pallas":  # resolved (may run the election)
-            from ..ops.pallas.fused import DEFAULT_WINDOW
+            from ..ops.pallas.fused import DEFAULT_WINDOW, MIN_EDGES
 
             # per-relation compile-time eligibility for the fused
             # owner-side kernel (same gates as the homogeneous sampler,
@@ -398,7 +398,7 @@ class DistHeteroSampler(HeteroGraphSampler):
             for et in rel_keys:
                 E_local = int(self.dev_topos.rels[et].indices.shape[1])
                 md = int(self.topo.relations[et].max_degree)
-                if (DEFAULT_WINDOW <= E_local <= np.iinfo(np.int32).max
+                if (MIN_EDGES <= E_local <= np.iinfo(np.int32).max
                         and md <= DEFAULT_WINDOW
                         and kmax.get(et, 0) <= DEFAULT_WINDOW):
                     ok.add(et)
@@ -411,7 +411,7 @@ class DistHeteroSampler(HeteroGraphSampler):
                     "relations %s: each needs a per-shard slice of at "
                     "least %d edges (int32 range) with max_degree and "
                     "fanout within the DMA window",
-                    sorted(degraded, key=str), DEFAULT_WINDOW,
+                    sorted(degraded, key=str), MIN_EDGES,
                 )
             pallas_rels = frozenset(ok)
 

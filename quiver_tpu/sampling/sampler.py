@@ -28,7 +28,7 @@ from ..core.topology import CSRTopo, DeviceTopology, VersionMismatchError
 from ..ops.election import KernelElection, validate_kernel_arg
 from ..ops.reindex import reindex_layer, resolve_dedup
 from ..ops.sample import sample_layer
-from ..utils.trace import get_logger, info_once, trace_scope
+from ..utils.trace import info_once, trace_scope
 
 __all__ = ["Adj", "GraphSageSampler", "SampleOutput"]
 
@@ -116,7 +116,11 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
     dedup = resolve_dedup(dedup)  # validates; maps "auto" per platform
     use_pallas = kernel == "pallas"
     if use_pallas:
-        from ..ops.pallas.fused import DEFAULT_WINDOW, fused_sample_layer
+        from ..ops.pallas.fused import (
+            DEFAULT_WINDOW,
+            MIN_EDGES,
+            fused_sample_layer,
+        )
 
         # trace-time eligibility for the fused kernel; every degrade is a
         # one-shot INFO (same info_once discipline as the other silent
@@ -130,7 +134,7 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
                 "HOST-staged placement falls back to the XLA sampler",
             )
             use_pallas = False
-        elif E < DEFAULT_WINDOW:
+        elif E < MIN_EDGES:
             # the kernel DMAs a full window per row; smaller graphs would
             # read past the edge array (trace-time constant)
             info_once(
@@ -138,7 +142,7 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
                 "graph has %d edges, fewer than the Pallas sampler's "
                 "%d-edge DMA window; kernel='pallas' falls back to the "
                 "XLA path for this topology",
-                E, DEFAULT_WINDOW,
+                E, MIN_EDGES,
             )
             use_pallas = False
         elif E - DEFAULT_WINDOW > np.iinfo(np.int32).max:
@@ -228,45 +232,25 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
 
 # -- kernel=auto election (the gather precedent, ops/election.py) ------------
 
-_PALLAS_SAMPLE_OK: bool | None = None
-
-
 def _pallas_sample_usable() -> bool:
-    """One-time differential smoke of the fused sampler (fail-safe for
-    auto): the compiled fused kernel must return BITWISE the XLA oracle's
-    output on a small synthetic graph before auto may elect pallas."""
-    global _PALLAS_SAMPLE_OK
-    if _PALLAS_SAMPLE_OK is None:
-        try:
-            from ..ops.pallas.fused import fused_sample_layer
+    """Differential smoke of the fused sampler at the shapes of the real
+    call — the default window, a fanout that is not a multiple of 8, a
+    seed block of training width: True when the compiled kernel returns
+    BITWISE the XLA oracle's output. A compile failure propagates."""
+    from ..ops.pallas.fused import fused_sample_layer
 
-            rng = np.random.default_rng(0)
-            ei = rng.integers(0, 64, size=(2, 512))
-            topo = CSRTopo(edge_index=ei).to_device()
-            seeds = jnp.asarray(rng.integers(0, 64, 16), jnp.int32)
-            key = jax.random.PRNGKey(0)
-            want = sample_layer(topo, seeds, jnp.int32(16), 4, key)
-            got = fused_sample_layer(topo, seeds, jnp.int32(16), 4, key,
-                                     window=256)
-            _PALLAS_SAMPLE_OK = all(
-                np.array_equal(np.asarray(jax.block_until_ready(g)),
-                               np.asarray(w))
-                for g, w in zip(got, want)
-            )
-            if not _PALLAS_SAMPLE_OK:
-                get_logger("sampler").warning(
-                    "pallas sample smoke diverged from the XLA oracle; "
-                    "kernel=auto degrades to xla"
-                )
-        except Exception as e:  # noqa: BLE001 — any compile failure degrades
-            get_logger("sampler").warning(
-                "pallas sample smoke failed (%s: %s); kernel=auto degrades "
-                "to xla",
-                type(e).__name__,
-                str(e)[:200],
-            )
-            _PALLAS_SAMPLE_OK = False
-    return _PALLAS_SAMPLE_OK
+    nodes, batch, k = 4096, 1024, 15
+    rng = np.random.default_rng(0)
+    ei = rng.integers(0, nodes, size=(2, 1 << 18))
+    topo = CSRTopo(edge_index=ei).to_device()
+    seeds = jnp.asarray(rng.integers(0, nodes, batch), jnp.int32)
+    key = jax.random.PRNGKey(0)
+    want = sample_layer(topo, seeds, jnp.int32(batch), k, key)
+    got = fused_sample_layer(topo, seeds, jnp.int32(batch), k, key)
+    return all(
+        np.array_equal(np.asarray(g), np.asarray(w))
+        for g, w in zip(got, want)
+    )
 
 
 def _measure_sample_eps(kernel: str, nodes: int = 4096, edges: int = 1 << 18,
@@ -333,8 +317,8 @@ def resolve_sample_kernel(kernel: str) -> str:
     ``"auto"`` on TPU elects by measured throughput between the fused
     Pallas megakernel and the XLA sampler via the shared
     ``ops.election.KernelElection`` machinery: a one-time bitwise
-    differential smoke gates Pallas (any divergence or compile failure
-    degrades auto to xla with one warning), then a fused-scan micro-bench
+    differential smoke gates Pallas (a divergence or a compile failure
+    raises — it never degrades to xla), then a fused-scan micro-bench
     picks the faster kernel. The election is cached per process and in the
     shared ``QUIVER_ELECTION_CACHE`` disk file (keyed by device kind), and
     ``QUIVER_SAMPLE_KERNEL=pallas|xla`` overrides it — pinned at first

@@ -36,8 +36,9 @@ Cache discipline (shared with the kernel-election cache, ops/election.py):
 The entries are pickles (the executable payload rides inside one), so
 the cache directory must be trusted — same threat model as the jit
 compilation cache. ``QUIVER_AOT_CACHE`` overrides the default location
-(beside ``QUIVER_ELECTION_CACHE``), resolved ONCE per process like every
-env knob on a potentially-traced path (env-before-first-use).
+(beside ``QUIVER_ELECTION_CACHE``, under the checkout), resolved ONCE per
+process like every env knob on a potentially-traced path
+(env-before-first-use).
 """
 
 from __future__ import annotations
@@ -56,7 +57,7 @@ from ..utils.trace import get_logger, warn_once
 
 __all__ = ["AOTExecutableCache", "program_fingerprint"]
 
-_BLOB_FORMAT = 1
+_BLOB_FORMAT = 2  # 2: entries carry the executable's device ids
 
 _AOT_CACHE_DIR: str | None = None
 
@@ -75,6 +76,18 @@ def _aot_cache_dir() -> str:
             ),
         )
     return _AOT_CACHE_DIR
+
+
+def _executable_device_ids(compiled) -> list[int]:
+    """Ids of the devices ``compiled`` runs on, from its shardings (the
+    serving ladder compiles one-device programs, so id order is the
+    device assignment)."""
+    import jax
+
+    shardings = jax.tree_util.tree_leaves(
+        (compiled.input_shardings, compiled.output_shardings)
+    )
+    return sorted({d.id for s in shardings for d in s.device_set})
 
 
 def program_fingerprint(components: dict) -> str:
@@ -151,8 +164,15 @@ class AOTExecutableCache:
                 deserialize_and_load,
             )
 
+            import jax
+
+            # load onto the devices the program was compiled for: the
+            # default is every device of the backend, and a one-device
+            # executable cannot take their shards
+            by_id = {d.id: d for d in jax.devices()}
             ex = deserialize_and_load(
-                blob["payload"], blob["in_tree"], blob["out_tree"]
+                blob["payload"], blob["in_tree"], blob["out_tree"],
+                execution_devices=[by_id[i] for i in blob["device_ids"]],
             )
         except Exception as e:  # noqa: BLE001 — a backend that refuses the
             # payload (driver/runtime skew the fingerprint can't see) must
@@ -189,6 +209,7 @@ class AOTExecutableCache:
                 "payload": payload,
                 "in_tree": in_tree,
                 "out_tree": out_tree,
+                "device_ids": _executable_device_ids(compiled),
             })
         except Exception as e:  # noqa: BLE001 — serialization support is
             # backend-dependent; its absence must not fail the serve path

@@ -121,12 +121,14 @@ def aval_bytes(aval, divisor: int = 1) -> int:
     return int(math.ceil(n * _itemsize(dt) / max(int(divisor), 1)))
 
 
-def _shard_div(names_entry, mesh) -> int:
-    """shard_map in/out_names entry ({dim: (axis, ...)}) -> the product
-    of partitioned mesh-axis sizes, i.e. the per-device byte divisor."""
+def _shard_div(spec, mesh) -> int:
+    """shard_map in/out_specs entry (a PartitionSpec) -> the product of
+    partitioned mesh-axis sizes, i.e. the per-device byte divisor."""
     div = 1
-    for axes in names_entry.values():
-        for ax in axes:
+    for axes in spec:
+        if axes is None:
+            continue
+        for ax in (axes,) if isinstance(axes, str) else axes:
             div *= int(mesh.shape[ax])
     return div
 
@@ -206,13 +208,13 @@ def arg_divisors(jaxpr) -> dict:
     (replicated operands) are absent — divisor 1."""
     return _names_divisors(
         jaxpr,
-        lambda eqn: (eqn.invars, eqn.params["in_names"],
+        lambda eqn: (eqn.invars, eqn.params["in_specs"],
                      eqn.params["mesh"]),
     )
 
 
 def out_divisors(jaxpr) -> dict:
-    """``{id(top_level_outvar): divisor}`` via shard_map ``out_names``,
+    """``{id(top_level_outvar): divisor}`` via shard_map ``out_specs``,
     propagated through pjit outvar positions."""
     j = _unwrap(jaxpr)
     divs: dict = {}
@@ -223,7 +225,7 @@ def out_divisors(jaxpr) -> dict:
         for eqn in jx.eqns:
             if eqn.primitive.name == "shard_map":
                 mesh = eqn.params["mesh"]
-                for v, nm in zip(eqn.outvars, eqn.params["out_names"]):
+                for v, nm in zip(eqn.outvars, eqn.params["out_specs"]):
                     key = lift.get(id(v))
                     if key is not None:
                         divs.setdefault(key, _shard_div(nm, mesh))
@@ -361,11 +363,11 @@ def _walk_peak(jaxpr, div_in=None) -> int:
             inner_peak = _walk_peak(inner, [1] * len(ij.invars))
             in_b = sum(
                 aval_bytes(v.aval, _shard_div(nm, mesh))
-                for v, nm in zip(eqn.invars, eqn.params["in_names"])
+                for v, nm in zip(eqn.invars, eqn.params["in_specs"])
                 if not hasattr(v, "val"))
             inner_extra = max(0, inner_peak - in_b)
             out_div = [_shard_div(nm, mesh)
-                       for nm in eqn.params["out_names"]]
+                       for nm in eqn.params["out_specs"]]
         else:
             pair_divs: dict = {}
             for sj_, opairs in _operand_pairs(eqn):

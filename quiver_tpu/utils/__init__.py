@@ -12,7 +12,7 @@ __all__ = [
     "get_logger",
     "start_trace",
     "stop_trace",
-    "honor_forced_platform",
+    "enable_compile_cache",
 ]
 
 _LAZY = {
@@ -29,7 +29,7 @@ _LAZY = {
     "get_logger": "trace",
     "start_trace": "trace",
     "stop_trace": "trace",
-    "honor_forced_platform": "backend",
+    "enable_compile_cache": "backend",
 }
 
 

@@ -18,7 +18,7 @@ def _f64_leak():
 
     # trace under x64 so the f64 actually lands in the jaxpr — the leak
     # an accidentally-enabled flag (or a numpy f64 operand) produces
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         return jax.jit(run).trace(jax.ShapeDtypeStruct((8,), jnp.float32))
 
 

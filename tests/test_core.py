@@ -171,3 +171,47 @@ def test_resolve_platform_strategy_edge_cases(monkeypatch):
     msg = str(ei.value)
     assert "QUIVER_TEST_STRAT" in msg and "scann" in msg
     assert "scan" in msg and "scatter" in msg
+
+
+def test_to_pinned_host_is_loud_off_cpu(monkeypatch):
+    """On a TPU the cold tier must land in pinned_host: a placement that
+    comes back in device memory raises instead of quietly filling HBM. The
+    CPU backend still degrades when it has no such memory space."""
+    import jax
+
+    from quiver_tpu.core import memory
+
+    x = np.arange(12, dtype=np.float32).reshape(3, 4)
+    real_put, real_dev = jax.device_put, jax.devices()[0]
+
+    class FakeTpu:
+        platform = "tpu"
+
+        def addressable_memories(self):
+            return []
+
+    def put_in_hbm(arr, sharding):  # the placement silently ignored
+        return real_put(arr, real_dev)
+
+    with monkeypatch.context() as m:
+        m.setattr(memory.jax, "devices", lambda: [FakeTpu()])
+        m.setattr(memory.jax, "device_put", put_in_hbm)
+        m.setattr(memory, "SingleDeviceSharding", lambda d, **kw: None)
+        with pytest.raises(RuntimeError, match="pinned_host"):
+            memory.to_pinned_host(x)
+
+    class CpuNoPinned:
+        platform = "cpu"
+
+        def addressable_memories(self):
+            return []
+
+    with monkeypatch.context() as m:
+        m.setattr(memory.jax, "devices", lambda: [CpuNoPinned()])
+        arr, is_host = memory.to_pinned_host(x)
+    assert not is_host
+    np.testing.assert_array_equal(np.asarray(arr), x)
+
+    arr, is_host = memory.to_pinned_host(x)  # the real backend
+    assert is_host == (arr.sharding.memory_kind == "pinned_host")
+    np.testing.assert_array_equal(np.asarray(arr), x)

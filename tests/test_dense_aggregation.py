@@ -2,8 +2,8 @@
 
 Every sampler-built Adj now carries its static ``fanout``, switching the
 model convs to dense masked (num_dst, fanout) reductions — zero scatters,
-because XLA serializes general scatters on TPU (the same diagnosis behind
-dedup="scan", docs/TPU_MEASUREMENTS_R3.md). These tests pin the invariant
+on the expectation that XLA serializes general scatters on TPU (the same
+reasoning as dedup="scan"; ROADMAP S2 measures it). These tests pin the invariant
 that the dense path is numerically the segment path: same Adj, same
 params, fanout set vs stripped, outputs must agree to float tolerance for
 all four homogeneous conv families plus the layer primitives.

@@ -163,8 +163,7 @@ def test_env_knobs_pinned_at_first_use(fresh_election, monkeypatch):
     """QUIVER_GATHER_KERNEL / QUIVER_ELECTION_CACHE resolve ONCE per
     process: flipping them after the first use is inert without a cache
     reset — the env-before-first-use contract graftlint's env-at-trace
-    rule enforces repo-wide (chip-window forcing must precede the first
-    gather)."""
+    rule enforces repo-wide (forcing must precede the first gather)."""
     monkeypatch.setenv("QUIVER_GATHER_KERNEL", "xla")
     assert F.GATHER_ELECTION.forced() == "xla"
     first_path = EL._election_cache_path()
@@ -183,13 +182,13 @@ def test_env_knobs_pinned_at_first_use(fresh_election, monkeypatch):
     assert F.GATHER_ELECTION.forced() == "pallas"
 
 
-def test_election_env_override_and_failsafes(fresh_election, monkeypatch):
-    # the sample election rides the same failsafe ladder as gather
+def test_election_env_override_and_loud_failures(fresh_election,
+                                                 monkeypatch):
     monkeypatch.setenv("QUIVER_SAMPLE_KERNEL", "xla")
     assert S.SAMPLE_ELECTION.elect() == "xla"
     assert S.SAMPLE_ELECTION.result["how"] == "env override"
 
-    # failed pallas smoke short-circuits to xla without measuring
+    # a diverging pallas smoke is an error, raised before any measuring
     S.SAMPLE_ELECTION.reset()
     monkeypatch.delenv("QUIVER_SAMPLE_KERNEL")
     monkeypatch.setattr(S, "_pallas_sample_usable", lambda: False)
@@ -198,19 +197,36 @@ def test_election_env_override_and_failsafes(fresh_election, monkeypatch):
         raise AssertionError("measured despite failed smoke")
 
     monkeypatch.setattr(S, "_measure_sample_eps", never)
-    assert S.SAMPLE_ELECTION.elect() == "xla"
-    assert S.SAMPLE_ELECTION.result["how"] == "pallas smoke failed"
+    with pytest.raises(RuntimeError, match="sample pallas smoke diverged"):
+        S.SAMPLE_ELECTION.elect()
+    assert S.SAMPLE_ELECTION.result is None
 
-    # a measurement crash degrades to xla instead of raising
-    S.SAMPLE_ELECTION.reset()
+    # a measurement crash propagates too: it is a Pallas call failing
     monkeypatch.setattr(S, "_pallas_sample_usable", lambda: True)
 
     def boom(k, **kw):
-        raise RuntimeError("chip went away")
+        raise RuntimeError("kernel fault")
 
     monkeypatch.setattr(S, "_measure_sample_eps", boom)
-    assert S.SAMPLE_ELECTION.elect() == "xla"
-    assert S.SAMPLE_ELECTION.result["how"] == "election failed"
+    with pytest.raises(RuntimeError, match="kernel fault"):
+        S.SAMPLE_ELECTION.elect()
+    assert S.SAMPLE_ELECTION.result is None
+
+
+def test_raising_smoke_propagates_from_auto_on_tpu(monkeypatch):
+    """On a (faked) TPU backend a Pallas sample smoke that raises — a
+    kernel the compiler refuses — propagates out of
+    resolve_sample_kernel("auto"); nothing degrades to xla."""
+    from quiver_tpu.ops.pallas import fused
+
+    def refuse(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(fused, "fused_sample_layer", refuse)
+    monkeypatch.setattr(S.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        S.resolve_sample_kernel("auto")
+    assert S.SAMPLE_ELECTION.result is None
 
 
 def test_resolve_passthrough_and_off_tpu(monkeypatch):

@@ -162,6 +162,7 @@ _SOURCES_EXEMPT = frozenset({
     "quiver_tpu/resilience/guard.py",
     "quiver_tpu/resilience/integrity.py",
     "quiver_tpu/serving/coalesce.py",
+    "quiver_tpu/utils/backend.py",
     "quiver_tpu/utils/checkpoint.py",
     "quiver_tpu/utils/reorder.py",
     "quiver_tpu/utils/trace.py",
