@@ -1,0 +1,173 @@
+"""Graph, features, labels, weights and the step feed, all from ``--seed``.
+
+The seed decides values and never a shape: ``nodes``, ``edges`` and the
+largest degree come from the configuration's file, so every seed drives the
+programs that the checkout's first run compiled. Everything is made on the
+host by one ``numpy.random.Generator`` in one thread: no BLAS, no sort over
+the edges, so the stage takes the same time in every run.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import mmap
+
+import numpy as np
+
+_CHUNK = 1 << 23  # edges drawn at a time into a buffer that is reused
+
+__all__ = ["Inputs", "degree_sequence", "make_inputs", "make_weights", "Feed"]
+
+
+@dataclasses.dataclass
+class Inputs:
+    indptr: np.ndarray    # (nodes + 1,) int64
+    indices: np.ndarray   # (edges,) int32
+    features: np.ndarray  # (nodes, feature_dim), the configuration's dtype
+    labels: np.ndarray    # (nodes,) int32
+
+
+def allocate(shape, dtype) -> np.ndarray:
+    """A host array whose pages are mapped before it is written.
+
+    A fresh gigabyte touched page by page costs seconds of page faults on a
+    virtual machine, and a different number of them from run to run;
+    ``MAP_POPULATE`` maps the whole array in one call."""
+    dtype = np.dtype(dtype)
+    size = int(np.prod(shape)) * dtype.itemsize
+    buf = mmap.mmap(-1, max(size, 1), flags=mmap.MAP_PRIVATE
+                    | mmap.MAP_ANONYMOUS | getattr(mmap, "MAP_POPULATE", 0))
+    return np.frombuffer(buf, dtype=dtype, count=int(np.prod(shape))).reshape(shape)
+
+
+def degree_sequence(rng, nodes: int, edges: int, alpha: float,
+                    max_degree: int) -> np.ndarray:
+    """Truncated power-law degrees that sum to ``edges`` exactly and whose
+    largest is ``max_degree`` exactly.
+
+    The draw is the one ``generate_pareto_graph`` makes (numpy's ``pareto``,
+    a Lomax tail of index ``alpha``, every node at least one edge); the
+    scale is then found by bisection so that the truncated, floored degrees
+    reach the published edge count, and the remainder of a few edges goes
+    one each to the first nodes that have room.
+    """
+    if not nodes <= edges <= nodes * max_degree:
+        raise ValueError(
+            f"{edges} edges cannot be spread over {nodes} nodes with degrees "
+            f"in [1, {max_degree}]"
+        )
+    tail = rng.pareto(alpha, nodes).astype(np.float32)
+    top = np.float32(max_degree - 1)
+
+    def degrees(scale):
+        return np.minimum(tail * np.float32(scale), top).astype(np.int32) + 1
+
+    def total(scale):
+        return int(degrees(scale).sum(dtype=np.int64))
+
+    lo, hi = 0.0, float(max_degree)
+    while total(hi) < edges:
+        hi *= 2.0
+    for _ in range(40):
+        mid = 0.5 * (lo + hi)
+        if total(mid) <= edges:
+            lo = mid
+        else:
+            hi = mid
+    deg = degrees(lo).astype(np.int64)
+    deg[np.argmax(tail)] = max_degree
+    rest = edges - int(deg.sum())
+    if rest > 0:
+        room = np.flatnonzero(deg < max_degree)[:rest]
+        deg[room] += 1
+    elif rest < 0:
+        room = np.flatnonzero((deg > 1) & (deg < max_degree))[:-rest]
+        deg[room] -= 1
+    if int(deg.sum()) != edges or int(deg.max()) != max_degree:
+        raise ValueError("degree sequence did not reach the configured sizes")
+    return deg
+
+
+def make_inputs(cfg: dict, seed: int) -> Inputs:
+    """The configuration's graph as a CSR, its feature table, and labels
+    that a GraphSAGE can learn, so that the loss falls."""
+    g = cfg["graph"]
+    nodes, edges = int(g["nodes"]), int(g["edges"])
+    rng = np.random.default_rng([int(seed), 1])
+    deg = degree_sequence(rng, nodes, edges, float(g["degree_alpha"]),
+                          int(g["max_degree"]))
+    indptr = np.zeros(nodes + 1, np.int64)
+    np.cumsum(deg, out=indptr[1:])
+    indices = allocate((edges,), np.int32)
+    for lo in range(0, edges, _CHUNK):
+        hi = min(lo + _CHUNK, edges)
+        indices[lo:hi] = rng.integers(0, nodes, size=hi - lo, dtype=np.int32)
+    if np.dtype(cfg["feature_dtype"]) != np.float32:
+        raise ValueError("the generator makes float32 features")
+    classes, width = int(cfg["classes"]), int(cfg["feature_dim"])
+    if classes > width:
+        raise ValueError("labels need at least `classes` feature columns")
+    # unit-variance uniform features, and on each node's label column a
+    # bump that a model can learn from the node's own row
+    feat = allocate((nodes, width), np.float32)
+    rng.random(out=feat, dtype=np.float32)
+    feat -= np.float32(0.5)
+    feat *= np.float32(12 ** 0.5)
+    labels = rng.integers(0, classes, size=nodes, dtype=np.int32)
+    feat[np.arange(nodes), labels] += np.float32(3.0)
+    return Inputs(indptr, indices, feat, labels)
+
+
+def layer_dims(cfg: dict) -> list[tuple[int, int]]:
+    """(in, out) width of each SAGE layer, input layer first."""
+    dims, d_in = [], int(cfg["feature_dim"])
+    for i in range(int(cfg["layers"])):
+        last = i == int(cfg["layers"]) - 1
+        d_out = int(cfg["classes"] if last else cfg["hidden"])
+        dims.append((d_in, d_out))
+        d_in = d_out
+    return dims
+
+
+def make_weights(cfg: dict, seed: int) -> list[dict]:
+    """Initial weights, one dict per layer (input layer first):
+    ``w_neigh`` and ``w_self`` of shape (in, out) drawn N(0, 1/in), ``b``
+    zero. The harness owns them: the program and the reference are both
+    handed these, neither makes its own."""
+    rng = np.random.default_rng([int(seed), 2])
+    layers = []
+    for d_in, d_out in layer_dims(cfg):
+        std = 1.0 / np.sqrt(d_in)
+        layers.append({
+            "w_neigh": (rng.standard_normal((d_in, d_out), dtype=np.float32)
+                        * np.float32(std)),
+            "b": np.zeros((d_out,), np.float32),
+            "w_self": (rng.standard_normal((d_in, d_out), dtype=np.float32)
+                       * np.float32(std)),
+        })
+    return layers
+
+
+class Feed:
+    """Step ``i``'s seed nodes and PRNG key: a permutation of the nodes cut
+    into global batches (an epoch that wraps), and raw ``uint32[2]`` keys.
+    Every step's rows differ from the step before."""
+
+    KEYS = 1 << 14
+
+    def __init__(self, nodes: int, global_batch: int, seed: int):
+        rng = np.random.default_rng([int(seed), 3])
+        self.order = rng.permutation(nodes).astype(np.int32)
+        self.global_batch = int(global_batch)
+        if self.global_batch > nodes:
+            raise ValueError("a global batch larger than the graph")
+        self.steps_per_epoch = nodes // self.global_batch
+        self.keys = rng.integers(0, 1 << 32, size=(self.KEYS, 2),
+                                 dtype=np.uint32)
+
+    def seeds(self, i: int) -> np.ndarray:
+        j = i % self.steps_per_epoch
+        return self.order[j * self.global_batch:(j + 1) * self.global_batch]
+
+    def key(self, i: int) -> np.ndarray:
+        return self.keys[i % self.KEYS]

@@ -1,0 +1,458 @@
+"""Tests of the benchmark itself, on the CPU at a tiny size.
+
+    python3 -m pytest chipbench/tests -q
+
+They show that the data files load and agree with ``BENCHMARK.json``, that
+no shape depends on the seed, that the counting functions and the trace
+reduction compute what they say, that the plain reference agrees with
+``DistributedTrainer.step``, and that the comparison fails what it has to
+fail: the control (the reference in bfloat16 in the program's place) and
+each fault a training cell can have, planted under a whole run.
+"""
+
+import argparse
+import glob
+import json
+import os
+import shutil
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from chipbench import check, inputs, readers, spec, work, xplane
+from chipbench import run as harness
+from chipbench.tests import tiny
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+BENCH = spec.load_benchmark()
+
+
+# -- the data files ----------------------------------------------------------
+
+def test_benchmark_names_units_and_files():
+    names = [e["name"] for k in ("configs", "workloads", "end_to_end",
+                                 "per_layer") for e in BENCH[k]]
+    names += [w["config"] for w in BENCH["workloads"]]
+    names += [w["traffic"] for w in BENCH["workloads"]]
+    names += [k for c in BENCH["configs"] for k in c["reduced"]]
+    assert all(spec.NAME.match(n) for n in names)
+    for group in ("end_to_end", "per_layer"):
+        for m in BENCH[group]:
+            assert spec.UNIT.match(m["unit"]), m
+            assert m["better"] in ("lower", "higher")
+        assert len({m["name"] for m in BENCH[group]}) == len(BENCH[group])
+    for w in BENCH["workloads"]:
+        assert w["name"] == f"{w['config']}.{w['traffic'].split('-', 1)[1]}"
+        assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
+    for c in BENCH["configs"]:
+        cfg = spec.load_config(c["name"])
+        assert c["file"] == f"chipbench/configs/{c['name']}.json"
+        assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
+        assert set(cfg["reduced"]) <= set(cfg["published"])
+    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= max(
+        1, len(BENCH["workloads"]) // 4)
+
+
+@pytest.mark.parametrize("kind", ["configs", "traffic", "metrics"])
+def test_every_data_file_loads_under_its_own_name(kind):
+    files = glob.glob(os.path.join(spec.HERE, kind, "*.json"))
+    assert files
+    for path in files:
+        with open(path) as f:
+            assert json.load(f)["name"] == os.path.basename(path)[:-5]
+
+
+def test_metric_files_agree_with_benchmark_json():
+    end_to_end = {m["name"] for m in BENCH["end_to_end"]}
+    cells = {w["name"] for w in BENCH["workloads"]}
+    for entry in BENCH["per_layer"]:
+        file = spec.load_metric(entry["name"])
+        assert file["reader"] in readers.READERS
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert file[key] == entry[key], (entry["name"], key)
+        assert file.get("workloads") == entry.get("workloads")
+        assert entry["moves"] in end_to_end
+        assert set(entry.get("workloads", [])) <= cells
+        if file["reader"] in ("roofline", "mfu"):
+            assert file["args"]["work"] in work.WORK
+            assert file["args"]["peak"] in spec.load_peaks("TPU v5 lite")
+    for w in BENCH["workloads"]:
+        assert spec.metrics_of(BENCH, w["name"], "per_layer")
+        assert len(spec.metrics_of(BENCH, w["name"], "end_to_end")) >= 2
+
+
+def test_unknown_device_kind_has_no_peaks():
+    with pytest.raises(KeyError):
+        spec.load_peaks("TPU v99")
+
+
+def test_a_cell_a_mix_a_metric_and_a_configuration_are_files_alone(
+        tmp_path, monkeypatch):
+    """What the README's worked examples add, added in a copy: nothing
+    that is there is edited, and the harness finds each by its name."""
+    root = tmp_path / "checkout"
+    shutil.copytree(spec.HERE, root / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    bench = json.loads(json.dumps(BENCH))
+    cfg = spec.load_config("reddit-sage")
+    cfg["name"], cfg["fanout"] = "reddit-sage-wide", [25, 15]
+    traffic = spec.load_traffic("train-hbm")
+    traffic["name"], traffic["warmup_steps"] = "train-hbm-long-warm", 5
+    metric = spec.load_metric("sample_device_ms")
+    metric.update(name="sample_hop1_device_ms",
+                  args={"pattern": r"sample_layer_1"},
+                  workloads=["reddit-sage-wide.hbm-long-warm"])
+    for kind, item in (("configs", cfg), ("traffic", traffic),
+                       ("metrics", metric)):
+        with open(root / "chipbench" / kind / f"{item['name']}.json", "w") as f:
+            json.dump(item, f)
+    bench["workloads"].append({
+        "name": "reddit-sage-wide.hbm-long-warm", "config": "reddit-sage-wide",
+        "traffic": "train-hbm-long-warm", "chips": 1, "why": "an example"})
+    bench["per_layer"].append({k: metric[k] for k in (
+        "name", "unit", "better", "source", "layer", "moves", "workloads")})
+    with open(root / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    monkeypatch.setattr(spec, "HERE", str(root / "chipbench"))
+    monkeypatch.setattr(spec, "ROOT", str(root))
+    loaded = spec.load_benchmark()
+    cell = spec.cell(loaded, "reddit-sage-wide.hbm-long-warm")
+    assert spec.load_config(cell["config"])["fanout"] == [25, 15]
+    assert spec.load_traffic(cell["traffic"])["warmup_steps"] == 5
+    mine = {m["name"] for m in spec.metrics_of(loaded, cell["name"], "per_layer")}
+    assert "sample_hop1_device_ms" in mine and "collective_exposed_ms" not in mine
+    assert "sample_hop1_device_ms" not in {
+        m["name"] for m in spec.metrics_of(loaded, "reddit-sage.hbm", "per_layer")}
+    trace = xplane.Trace([
+        xplane.Op("/device:TPU:0", "XLA Ops", "fusion.1", "a/sample_layer_1/x", 0, 40),
+        xplane.Op("/device:TPU:0", "XLA Ops", "fusion.2", "a/sample_layer_0/x", 50, 60),
+    ], [])
+    ctx = {"trace": trace, "steps": 2}
+    assert readers.read(spec.load_metric("sample_hop1_device_ms"), ctx) == \
+        pytest.approx(40 / 2 * 1e-6)
+
+
+# -- shapes from data, values from the seed ----------------------------------
+
+def test_two_seeds_give_the_same_shapes_and_other_values():
+    cfg = tiny.tiny_config("products-sage")
+    a, b = inputs.make_inputs(cfg, 3), inputs.make_inputs(cfg, 2**31 + 5)
+    for name in ("indptr", "indices", "features", "labels"):
+        x, y = getattr(a, name), getattr(b, name)
+        assert x.shape == y.shape and x.dtype == y.dtype
+        assert not np.array_equal(x, y)
+    g = cfg["graph"]
+    for data in (a, b):
+        deg = np.diff(data.indptr)
+        assert data.indptr[-1] == g["edges"] == data.indices.shape[0]
+        assert deg.max() == g["max_degree"] and deg.min() >= 1
+        assert data.features.shape == (g["nodes"], cfg["feature_dim"])
+    again = inputs.make_inputs(cfg, 3)
+    assert np.array_equal(a.indices, again.indices)
+    assert np.array_equal(a.features, again.features)
+    wa, wb = inputs.make_weights(cfg, 3), inputs.make_weights(cfg, 4)
+    assert [{k: v.shape for k, v in l.items()} for l in wa] == \
+        [{k: v.shape for k, v in l.items()} for l in wb]
+    fa, fb = inputs.Feed(3000, 64, 3), inputs.Feed(3000, 64, 4)
+    assert fa.seeds(0).shape == fb.seeds(0).shape == (64,)
+    assert fa.key(0).shape == (2,) and fa.key(0).dtype == np.uint32
+    assert not np.array_equal(fa.seeds(0), fa.seeds(1))
+
+
+# -- the counting functions --------------------------------------------------
+
+def test_work_counts_against_hand_worked_values():
+    # two hops, seeds outward: 4 seeds x fanout 3, then 10 targets x fanout 2
+    counts = {
+        "hops": [
+            {"fanout": 3, "targets": 4, "edges": 11, "unique": 10},
+            {"fanout": 2, "targets": 10, "edges": 18, "unique": 21},
+        ],
+        "gathered_rows": 21, "feature_dim": 8, "feature_itemsize": 4,
+        "layer_dims": [(8, 16), (16, 5)],
+    }
+    assert work.sample_bytes(counts) == 4 * (8 + 24) + 10 * (8 + 16)
+    assert work.reindex_bytes(counts) == \
+        4 * (2 * 16 + 10) + 4 * (2 * 30 + 21)
+    assert work.gather_bytes(counts) == 21 * (2 * 32 + 4)
+    input_layer = 2 * 10 * 8 * 16   # one matmul over hop 1's 10 targets
+    output_layer = 2 * 4 * 16 * 5   # one matmul over the 4 seeds
+    assert work.step_flops(counts) == (
+        4 * input_layer + 2 * 18 * 8            # fwd + weight grads + mean
+        + 6 * output_layer + 2 * 11 * 16)       # and the input gradients
+
+
+# -- the trace reduction -----------------------------------------------------
+
+def synthetic_trace():
+    ops = [
+        # a while that spans two body ops, then a gap, then a free op
+        xplane.Op("/device:TPU:0", "XLA Ops", "while.1", "jit(f)/reindex_layer_0/while", 100, 200),
+        xplane.Op("/device:TPU:0", "XLA Ops", "fusion.1", "jit(f)/reindex_layer_0/while/body/sort", 110, 150),
+        xplane.Op("/device:TPU:0", "XLA Ops", "fusion.2", "jit(f)/sample_layer_0/gather", 160, 190),
+        xplane.Op("/device:TPU:0", "XLA Ops", "copy.3", "", 300, 350),
+        xplane.Op("/device:TPU:0", "XLA Ops", "all-reduce.4", "jit(f)/pmean", 350, 400),
+    ]
+    spans = [xplane.Span("chipbench.window", 0, 500),
+             xplane.Span("chipbench.step", 0, 120),
+             xplane.Span("chipbench.loss_read", 120, 480)]
+    return xplane.Trace(ops, spans)
+
+
+def test_trace_arithmetic_on_known_intervals():
+    t = synthetic_trace()
+    assert t.window_s() == pytest.approx(500e-9)
+    assert t.busy_s() == pytest.approx((100 + 100) * 1e-9)
+    assert t.scope_s(r"reindex_layer_\d+") == pytest.approx((30 + 40) * 1e-9)
+    assert t.scope_s(r"sample_layer_\d+") == pytest.approx(30e-9)
+    claimed = [r"reindex_layer_\d+", r"sample_layer_\d+"]
+    assert t.unclaimed_s(claimed) == pytest.approx((50 + 50) * 1e-9)
+    assert t.scope_s("reindex") + t.scope_s("sample") + t.unclaimed_s(claimed) \
+        == pytest.approx(t.busy_s())
+    assert t.collective_exposed_s() == pytest.approx(50e-9)
+    assert dict(t.idle_gaps()) == pytest.approx({
+        "chipbench.step": 100e-9, "chipbench.loss_read": 180e-9,
+        "outside any span": 20e-9})
+    ctx = {"trace": t, "steps": 2}
+    assert readers.idle_share(ctx) == pytest.approx(60.0)
+    assert readers.device_time_by_scope(ctx, "nothing_here") is None
+
+
+def _message(*fields) -> bytes:
+    """A protobuf message of length-delimited fields: (number, bytes)."""
+    out = b""
+    for number, payload in fields:
+        size, head = len(payload), b""
+        while True:
+            head += bytes([(size & 0x7F) | (0x80 if size > 0x7F else 0)])
+            size >>= 7
+            if not size:
+                break
+        out += bytes([(number << 3) | 2]) + head + payload
+    return out
+
+
+def test_scope_paths_are_read_from_the_hlo_proto_in_the_trace():
+    def instruction(name, path):
+        return _message((1, name), (2, b"fusion"),
+                        (7, _message((1, b"gather"), (2, path))))
+
+    module = _message((1, b"jit_body"), (3, _message(
+        (1, b"main"),
+        (2, instruction(b"fusion.7", b"jit(body)/sample_layer_0/gather")),
+        (2, instruction(b"fusion.8", b"jit(body)/" + b"x" * 300)),
+        (2, _message((1, b"copy.1"))))))
+    stat = _message((6, _message((1, module))))
+    plane = _message((2, b"/host:metadata"),
+                     (4, _message((2, _message((2, b"jit_body"), (5, stat))))))
+    other = _message((2, b"/device:TPU:0"),
+                     (4, _message((2, _message((5, stat))))))
+    assert xplane.hlo_scope_paths(_message((1, other), (1, plane))) == {
+        "fusion.7": "jit(body)/sample_layer_0/gather",
+        "fusion.8": "jit(body)/" + "x" * 300}
+    assert xplane.hlo_scope_paths(_message((1, other))) == {}
+
+
+def test_recorded_trace_reduces_to_the_recorded_numbers():
+    """A trace recorded on the chip (a few steps of reddit-sage.hbm, cut to
+    its ops and spans) and the numbers read off it when it was recorded."""
+    with open(os.path.join(DATA, "recorded.expected.json")) as f:
+        want = json.load(f)
+    t = xplane.load_json(os.path.join(DATA, "recorded.json.gz"))
+    assert t.devices == want["devices"]
+    assert t.window_s() == pytest.approx(want["window_s"])
+    assert t.busy_s() == pytest.approx(want["busy_s"])
+    assert 0 < t.busy_s() <= t.window_s()
+    claims = want["claims"]
+    by_scope = {p: t.scope_s(p) for p in claims}
+    assert by_scope == pytest.approx(want["by_scope"])
+    assert all(v > 0 for v in by_scope.values())
+    assert t.unclaimed_s(claims) == pytest.approx(want["unclaimed_s"])
+    # self times: every op counted once, so the parts make up the whole
+    assert sum(by_scope.values()) + t.unclaimed_s(claims) == pytest.approx(
+        t.time_by(lambda op: True))
+    assert t.time_by(lambda op: True) <= t.busy_s() * 1.0001
+
+
+# -- a whole run, the chip left out ------------------------------------------
+
+@pytest.fixture()
+def small(monkeypatch):
+    monkeypatch.setattr(spec, "load_config", tiny.tiny_config)
+    monkeypatch.setattr(spec, "load_peaks",
+                        lambda kind: {"bf16_tflops": 1.0, "hbm_gbps": 1.0})
+
+
+def drive(workload: str, seed: int = 7, trace: int = 0) -> dict:
+    import jax
+
+    chips = spec.cell(BENCH, workload)["chips"]
+    args = argparse.Namespace(workload=workload, seed=seed, seconds=0.5,
+                              trace=trace)
+    return harness.run(args, jax.devices()[:chips], harness.CompileMeter(),
+                       time.perf_counter(), {})
+
+
+@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
+def test_reference_agrees_with_the_trainer_step(small, workload, capfd):
+    result = drive(workload, seed=2**31 + 9)
+    assert result["correct"] is True, result["compared"]
+    assert list(result)[-1] == "compared"
+    assert result["attempted"] >= 2 and result["failed"] == 0
+    wanted = {m["name"] for m in spec.metrics_of(BENCH, workload, "end_to_end")}
+    assert set(result["metrics"]) == wanted
+    for name, row in result["compared"].items():
+        assert row["limit"] is not None and row["value"] <= row["limit"], name
+    assert "compared loss_gap_1 = " in capfd.readouterr().err
+
+
+def test_traced_run_reports_only_what_it_could_read(small):
+    result = drive("reddit-sage.hbm", trace=1)
+    assert result["correct"] is True
+    # the CPU backend's trace has no device plane: host spans, counters and
+    # stages are read, every device reader returns nothing and is left out
+    assert {"setup_inputs_s", "setup_place_s", "compile_s", "cache_misses",
+            "host_dispatch_ms"} <= set(result["metrics"])
+    assert not {"sample_roofline", "step_mfu", "sample_device_ms",
+                "device_idle_share"} & set(result["metrics"])
+    assert result["metrics"]["cache_misses"]["value"] >= 0
+
+
+def broken_step(kind):
+    from chipbench.adapter import Program
+
+    real = Program.step
+
+    def unchanged(self, seeds, key):
+        params, opt_state = self.params, self.opt_state
+        loss = real(self, seeds, key)
+        self.params, self.opt_state = params, opt_state
+        return loss
+
+    def half_batch(self, seeds, key):
+        return real(self, seeds[:len(seeds) // 2], key)
+
+    return {"unchanged": unchanged, "half_batch": half_batch}[kind]
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
+def test_a_broken_step_comes_out_not_correct(small, monkeypatch, fault):
+    from chipbench.adapter import Program
+
+    monkeypatch.setattr(Program, "step", broken_step(fault))
+    result = drive("reddit-sage.hbm")
+    assert result["correct"] is False
+    failed = {n for n, r in result["compared"].items()
+              if r["value"] > r["limit"]}
+    assert failed and "block_faults" not in failed
+
+
+def test_the_exchange_between_chips_left_out_comes_out_not_correct(
+        small, monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax.lax, "pmean", lambda x, axis_name: x)
+    result = drive("products-sage.clique2x2")
+    assert result["correct"] is False
+
+
+def test_an_altered_block_comes_out_not_correct(small, monkeypatch):
+    """A sampled neighbour altered where it is produced: the block check
+    finds an edge that the graph does not have."""
+    from chipbench.adapter import Program
+
+    real = Program.blocks
+
+    def altered(self, seeds, key):
+        blocks = real(self, seeds, key)
+        n_id = blocks[0].n_id.copy()
+        last = int((n_id >= 0).sum()) - 1
+        n_id[last] = (n_id[last] + 1) % 3000
+        blocks[0].n_id = n_id
+        return blocks
+
+    monkeypatch.setattr(Program, "blocks", altered)
+    result = drive("reddit-sage.hbm")
+    assert result["correct"] is False
+    assert result["compared"]["block_faults"]["value"] > 0
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_the_control_in_bfloat16_comes_out_not_correct(seed):
+    """The reference, computed in bfloat16 and put in the program's place,
+    fails at least one number; computed in float32 it passes all."""
+    import jax.numpy as jnp
+
+    from chipbench.reference import sage
+
+    cfg = tiny.tiny_config("reddit-sage")
+    data = inputs.make_inputs(cfg, seed)
+    weights0 = inputs.make_weights(cfg, seed)
+    feed = inputs.Feed(cfg["graph"]["nodes"], cfg["batch"], seed)
+    rng = np.random.default_rng(seed)
+    steps = [[host_block(data, feed.seeds(i), cfg["fanout"], rng)]
+             for i in range(3)]
+    feats, labels = jnp.asarray(data.features), jnp.asarray(data.labels)
+    ref = sage.train(weights0, feats, labels, steps, cfg["optimizer"])
+    for dtype, passes in ((jnp.float32, True), (jnp.bfloat16, False)):
+        side = sage.train(weights0, feats, labels, steps, cfg["optimizer"],
+                          param_dtype=dtype, compute_dtype=dtype)
+        values = check.numbers(side[0], side[1], side[2], *ref, weights0)
+        ok, table = check.verdict(values, tiny.LIMITS)
+        assert ok is passes, table
+
+
+def host_block(data, seeds, fanout, rng):
+    """A sampled block made with numpy alone, for tests that need no
+    program: ``min(deg, k)`` neighbours of every target."""
+    from chipbench.reference.sage import Block
+
+    n_id = list(dict.fromkeys(int(s) for s in seeds))
+    local = {n: i for i, n in enumerate(n_id)}
+    layers, targets = [], len(n_id)
+    for k in fanout:
+        src, dst = [], []
+        for t in range(targets):
+            row = data.indices[data.indptr[n_id[t]]:data.indptr[n_id[t] + 1]]
+            for n in rng.choice(row, size=min(len(row), k), replace=False):
+                if int(n) not in local:
+                    local[int(n)] = len(n_id)
+                    n_id.append(int(n))
+                src.append(local[int(n)])
+                dst.append(t)
+        layers.append((np.asarray(src, np.int32), np.asarray(dst, np.int32),
+                       targets))
+        targets = len(n_id)
+    return Block(np.asarray(n_id, np.int32), layers[::-1], len(seeds))
+
+
+def test_host_block_passes_the_block_check_and_a_bent_one_does_not():
+    from chipbench.reference import graph
+
+    cfg = tiny.tiny_config("reddit-sage")
+    data = inputs.make_inputs(cfg, 5)
+    seeds = inputs.Feed(3000, 64, 5).seeds(0)
+    rng = np.random.default_rng(5)
+    block = host_block(data, seeds, cfg["fanout"], rng)
+    assert sum(graph.block_faults(data.indptr, data.indices, seeds, block,
+                                  cfg["fanout"], rng).values()) == 0
+    src, dst, n = block.layers[0]
+    block.layers[0] = (src[1:], dst[1:], n)  # one sampled edge dropped
+    assert graph.block_faults(data.indptr, data.indices, seeds, block,
+                              cfg["fanout"], rng)["wrong_counts"] == 1
+
+
+# -- off the chip ------------------------------------------------------------
+
+def test_run_exits_non_zero_off_the_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    done = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload",
+         "reddit-sage.hbm", "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=spec.ROOT, env=env, capture_output=True, text=True, timeout=120)
+    assert done.returncode != 0
+    assert "needs 1 TPU chip" in done.stderr
+    assert not done.stdout.strip().startswith("{")
