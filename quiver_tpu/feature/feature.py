@@ -244,10 +244,16 @@ class KernelChoice:
         return resolved
 
 
+@trace_scope("feature_gather")
 def tiered_lookup(n_id, feature_order, hot_rows: int, hot_gather, cold_gather,
                   rep_rows: int = 0, rep_gather=None, hot_miss_id: int = 0,
                   with_hits: bool = False):
-    """Shared tier-merge used by Feature and ShardedFeature.
+    """Shared tier-merge of every feature store, under ``feature_gather``.
+
+    The one place that opens that scope, for ``Feature``, ``ShardedFeature``,
+    ``MmapFeatureStore`` and the fused step alike; each tier's gather runs
+    under ``tier_rep`` / ``tier_hot`` / ``tier_cold`` below it, with
+    whatever its callable does (dequant, psum, routing, host staging).
 
     Three contiguous tiers in the translated (reordered) row space:
 
@@ -277,25 +283,27 @@ def tiered_lookup(n_id, feature_order, hot_rows: int, hot_gather, cold_gather,
         ids = feature_order[ids]
     hot_end = rep_rows + hot_rows
     have_rep = rep_gather is not None and rep_rows > 0
-    # (mask, gather, row offset into the tier's table, other-tier miss id);
-    # masks partition the valid id range, in tier order
+    # (scope, mask, gather, row offset into the tier's table, other-tier
+    # miss id); masks partition the valid id range, in tier order
     tiers = []
     if have_rep:
-        tiers.append((ids < rep_rows, rep_gather, 0, 0))
+        tiers.append(("tier_rep", ids < rep_rows, rep_gather, 0, 0))
     if hot_gather is not None:
         m = ids < hot_end
         if have_rep:
             m = m & (ids >= rep_rows)
-        tiers.append((m, hot_gather, rep_rows, hot_miss_id))
+        tiers.append(("tier_hot", m, hot_gather, rep_rows, hot_miss_id))
     if cold_gather is not None:
-        tiers.append((ids >= hot_end, cold_gather, hot_end, 0))
+        tiers.append(("tier_cold", ids >= hot_end, cold_gather, hot_end, 0))
     if len(tiers) == 1:
-        _, gather, off, _ = tiers[0]
-        out = gather(ids - off if off else ids)
+        scope, _, gather, off, _ = tiers[0]
+        with trace_scope(scope):
+            out = gather(ids - off if off else ids)
     else:
         out = None
-        for mask, gather, off, miss in tiers:
-            part = gather(jnp.where(mask, ids - off, miss))
+        for scope, mask, gather, off, miss in tiers:
+            with trace_scope(scope):
+                part = gather(jnp.where(mask, ids - off, miss))
             out = part if out is None else jnp.where(mask[:, None], part, out)
     out = jnp.where(valid[:, None], out, 0)
     if not with_hits:
@@ -470,10 +478,9 @@ class Feature(KernelChoice):
         _, hot_gather, cold_gather = wrap_dequant_gathers(
             self.scale, self.hot_rows, hot_gather, cold_gather
         )
-        with trace_scope("feature_gather"):
-            return tiered_lookup(
-                n_id, self.feature_order, self.hot_rows, hot_gather, cold_gather
-            )
+        return tiered_lookup(
+            n_id, self.feature_order, self.hot_rows, hot_gather, cold_gather
+        )
 
     def size(self, dim: int) -> int:
         return self.shape[dim]

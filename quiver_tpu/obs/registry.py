@@ -48,6 +48,9 @@ __all__ = [
     "ROUTED_OVERFLOW",
     "TIER_HITS",
     "SAMPLE_OVERFLOW",
+    "SAMPLE_EDGES",
+    "SAMPLE_FRONTIER",
+    "SAMPLE_FRONTIER_OVERFLOW",
     "HETERO_SAMPLE_OVERFLOW",
     "GUARD_SKIPPED",
     "GUARD_NONFINITE",
@@ -87,6 +90,14 @@ __all__ = [
 ROUTED_OVERFLOW = "feature.routed_overflow"
 TIER_HITS = "feature.tier_hits"
 SAMPLE_OVERFLOW = "sample.hop_overflow"
+# what the fused step's sampler counts in-program, mesh totals per step:
+# valid sampled edges per hop, distinct nodes found per hop BEFORE the
+# frontier cap (both seeds-outward, like sample.hop_overflow), and the
+# uniques dropped for exceeding ``frontier_caps`` (> 0 means the caps are
+# too small and the step trained on a truncated block)
+SAMPLE_EDGES = "sample.edges"
+SAMPLE_FRONTIER = "sample.frontier"
+SAMPLE_FRONTIER_OVERFLOW = "sample.frontier_overflow"
 # per-(hop, edge-type) routed-overflow lanes of the distributed hetero
 # sampler (flat vector in the sampler's static slot order; relations
 # sharing a destination type share that hop's route plan, so they report

@@ -325,11 +325,10 @@ class MmapFeatureStore(KernelChoice):
         _, hot_gather, cold_gather = wrap_dequant_gathers(
             self.scale, self.hot_rows, hot_gather, cold_gather
         )
-        with trace_scope("feature_gather"):
-            return tiered_lookup(
-                n_id, self.feature_order, self.hot_rows, hot_gather,
-                cold_gather,
-            )
+        return tiered_lookup(
+            n_id, self.feature_order, self.hot_rows, hot_gather,
+            cold_gather,
+        )
 
     def trace_lookup(self, batch: int):
         """AOT-trace the device-side tier merge one staged batch runs —

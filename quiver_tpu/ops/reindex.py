@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
+from ..utils.trace import info_once, trace_scope
+
 __all__ = [
     "masked_unique",
     "reindex_layer",
@@ -76,8 +78,6 @@ def resolve_dedup(dedup: str) -> str:
     if dedup in DEDUP_STRATEGIES:
         forced = _forced_dedup_env()
         if forced and forced != dedup:
-            from ..utils.trace import info_once
-
             info_once(
                 f"dedup-env-ignored-{dedup}",
                 "QUIVER_DEDUP=%s ignored for explicit dedup=%r (the env "
@@ -186,6 +186,47 @@ def masked_unique(ids, valid, size: int, num_forced: int = 0,
     T = ids.shape[0]
     pos = jnp.arange(T, dtype=jnp.int32)
 
+    # the three phases are scopes of every strategy, so that a device
+    # trace splits the reindex the same way whichever one ran
+    with trace_scope("dedup"):
+        rep_pos = _first_occurrence(ids, valid, pos, node_bound, scatter_free)
+
+    with trace_scope("compact"):
+        forced = (pos < num_forced) & valid
+        is_rep = (valid & (rep_pos == pos)) | forced
+        rank = jnp.cumsum(is_rep.astype(jnp.int32)) - 1  # first-occurrence rank
+        num_unique = jnp.sum(is_rep.astype(jnp.int32))
+
+        if scatter_free and node_bound is None:
+            # compaction WITHOUT a sort or scatter: ``rank`` is
+            # non-decreasing (a cumsum), and the r-th rep's position is the
+            # first index whose rank reaches r — a vectorized binary search.
+            # The (size,) write is a contiguous slice update.
+            m = min(size, T)
+            comp_pos = jnp.searchsorted(
+                rank, jnp.arange(m, dtype=rank.dtype), side="left"
+            )
+            packed = jnp.where(
+                jnp.arange(m) < num_unique,
+                ids[jnp.clip(comp_pos, 0, T - 1)], -1
+            ).astype(ids.dtype)
+            uniq = jnp.full(size, -1, ids.dtype).at[:m].set(packed)
+        else:
+            uniq = (
+                jnp.full(size, -1, ids.dtype)
+                .at[jnp.where(is_rep & (rank < size), rank, size)]
+                .set(ids, mode="drop")
+            )
+    with trace_scope("relabel"):
+        local = rank[rep_pos]
+        local = jnp.where(valid & (local < size), local, -1)
+    return uniq, num_unique, local
+
+
+def _first_occurrence(ids, valid, pos, node_bound, scatter_free):
+    """(T,) position of the first occurrence of each lane's id — the
+    ``dedup`` phase of :func:`masked_unique`, one branch per strategy."""
+    T = ids.shape[0]
     if node_bound is not None:
         safe = jnp.where(valid, ids, 0)
         first_pos = (
@@ -193,73 +234,41 @@ def masked_unique(ids, valid, size: int, num_forced: int = 0,
             .at[safe]
             .min(jnp.where(valid, pos, T), mode="drop")
         )
-        rep_pos = first_pos[safe]
-    else:
-        # shared sorted view: stable value sort, run starts (sentinel run
-        # excluded); positions within a run ascend, so a run's first sorted
-        # element IS the value's first occurrence
-        sent = jnp.iinfo(ids.dtype).max
-        vals = jnp.where(valid, ids, sent)
-        order = jnp.argsort(vals, stable=True)
-        sv = vals[order]
-        pv = pos[order]
-        first = jnp.concatenate(
-            [jnp.ones(1, bool), sv[1:] != sv[:-1]]
-        ) & (sv != sent)
+        return first_pos[safe]
+    # shared sorted view: stable value sort, run starts (sentinel run
+    # excluded); positions within a run ascend, so a run's first sorted
+    # element IS the value's first occurrence
+    sent = jnp.iinfo(ids.dtype).max
+    vals = jnp.where(valid, ids, sent)
+    order = jnp.argsort(vals, stable=True)
+    sv = vals[order]
+    pv = pos[order]
+    first = jnp.concatenate(
+        [jnp.ones(1, bool), sv[1:] != sv[:-1]]
+    ) & (sv != sent)
 
-        if scatter_free:
-            # sorted-view index of the current run's first element: a
-            # running max over first-markers (the scatter-free
-            # run-representative)
-            idx_first = lax.cummax(
-                jnp.where(first, jnp.arange(T, dtype=jnp.int32), -1)
-            )
-            rep_pos_sorted = jnp.where(
-                idx_first >= 0, pv[jnp.clip(idx_first, 0)], T
-            )
-            # back to original positions via the inverse permutation, built
-            # by sorting the permutation instead of scattering into it
-            rep_pos = rep_pos_sorted[inverse_permutation_gather(order)]
-        else:
-            run_id = jnp.cumsum(first.astype(jnp.int32)) - 1
-            # representative position scattered per run
-            by_run = (
-                jnp.zeros(T, jnp.int32)
-                .at[jnp.where(first, run_id, T)]
-                .set(pv, mode="drop")
-            )
-            rep_pos_sorted = by_run[jnp.clip(run_id, 0)]
-            # back to original positions
-            rep_pos = jnp.zeros(T, jnp.int32).at[order].set(rep_pos_sorted)
-
-    forced = (pos < num_forced) & valid
-    is_rep = (valid & (rep_pos == pos)) | forced
-    rank = jnp.cumsum(is_rep.astype(jnp.int32)) - 1  # first-occurrence rank
-    num_unique = jnp.sum(is_rep.astype(jnp.int32))
-
-    if scatter_free and node_bound is None:
-        # compaction WITHOUT a sort or scatter: ``rank`` is non-decreasing
-        # (a cumsum), and the r-th rep's position is the first index whose
-        # rank reaches r — a vectorized binary search. The (size,) write is
-        # a contiguous slice update.
-        m = min(size, T)
-        comp_pos = jnp.searchsorted(
-            rank, jnp.arange(m, dtype=rank.dtype), side="left"
+    if scatter_free:
+        # sorted-view index of the current run's first element: a running
+        # max over first-markers (the scatter-free run-representative)
+        idx_first = lax.cummax(
+            jnp.where(first, jnp.arange(T, dtype=jnp.int32), -1)
         )
-        packed = jnp.where(
-            jnp.arange(m) < num_unique,
-            ids[jnp.clip(comp_pos, 0, T - 1)], -1
-        ).astype(ids.dtype)
-        uniq = jnp.full(size, -1, ids.dtype).at[:m].set(packed)
-    else:
-        uniq = (
-            jnp.full(size, -1, ids.dtype)
-            .at[jnp.where(is_rep & (rank < size), rank, size)]
-            .set(ids, mode="drop")
+        rep_pos_sorted = jnp.where(
+            idx_first >= 0, pv[jnp.clip(idx_first, 0)], T
         )
-    local = rank[rep_pos]
-    local = jnp.where(valid & (local < size), local, -1)
-    return uniq, num_unique, local
+        # back to original positions via the inverse permutation, built by
+        # sorting the permutation instead of scattering into it
+        return rep_pos_sorted[inverse_permutation_gather(order)]
+    run_id = jnp.cumsum(first.astype(jnp.int32)) - 1
+    # representative position scattered per run
+    by_run = (
+        jnp.zeros(T, jnp.int32)
+        .at[jnp.where(first, run_id, T)]
+        .set(pv, mode="drop")
+    )
+    rep_pos_sorted = by_run[jnp.clip(run_id, 0)]
+    # back to original positions
+    return jnp.zeros(T, jnp.int32).at[order].set(rep_pos_sorted)
 
 
 def reindex_layer(seeds, num_seeds, neighbors, frontier_cap: int,
@@ -288,16 +297,18 @@ def reindex_layer(seeds, num_seeds, neighbors, frontier_cap: int,
       overflow: scalar count of uniques dropped for exceeding frontier_cap.
     """
     S, K = neighbors.shape
-    ids = jnp.concatenate([seeds, neighbors.reshape(-1)])
-    seed_valid = (jnp.arange(S) < num_seeds) & (seeds >= 0)
-    nbr_valid = neighbors.reshape(-1) >= 0
-    valid = jnp.concatenate([seed_valid, nbr_valid])
+    with trace_scope("dedup"):
+        ids = jnp.concatenate([seeds, neighbors.reshape(-1)])
+        seed_valid = (jnp.arange(S) < num_seeds) & (seeds >= 0)
+        nbr_valid = neighbors.reshape(-1) >= 0
+        valid = jnp.concatenate([seed_valid, nbr_valid])
 
     uniq, num_unique, local = masked_unique(
         ids, valid, frontier_cap, num_forced=S, node_bound=node_bound,
         scatter_free=scatter_free,
     )
-    col_local = local[S:].reshape(S, K)
-    num_frontier = jnp.minimum(num_unique, frontier_cap)
-    overflow = jnp.maximum(num_unique - frontier_cap, 0)
+    with trace_scope("relabel"):
+        col_local = local[S:].reshape(S, K)
+        num_frontier = jnp.minimum(num_unique, frontier_cap)
+        overflow = jnp.maximum(num_unique - frontier_cap, 0)
     return uniq, num_frontier, col_local, overflow

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.reindex import inverse_permutation_gather
+from ..utils.trace import trace_scope
 
 __all__ = ["BucketRoute"]
 
@@ -68,6 +69,7 @@ class BucketRoute:
       metric: tape counter name; defaults to ``obs.ROUTED_OVERFLOW``.
     """
 
+    @trace_scope("route_plan")
     def __init__(self, ids, valid, owner, *, axis: str, num_shards: int,
                  cap: int | None = None, tape=None, metric: str | None = None):
         F = int(num_shards)
@@ -133,6 +135,7 @@ class BucketRoute:
 
     # -- internals ----------------------------------------------------------
 
+    @trace_scope("route_plan")
     def _bucketize(self, sorted_vals, fill):
         """(L, ...) sorted per-lane values -> (F, cap, ...) send buckets:
         the first ``cap`` lanes per destination, ``fill`` elsewhere."""
@@ -144,6 +147,7 @@ class BucketRoute:
         live = live.reshape(live.shape + (1,) * (vals.ndim - 2))
         return jnp.where(live, vals, fill)
 
+    @trace_scope("route_exchange")
     def _a2a(self, x):
         """Exchange (F, cap, ...) buckets: bucket f goes to shard f; the
         result's leading axis indexes the SENDING shard."""
@@ -159,6 +163,57 @@ class BucketRoute:
         live = jnp.arange(self.ov_budget, dtype=jnp.int32) < self._ov_local
         live = live.reshape(live.shape + (1,) * (take.ndim - 1))
         return jnp.where(live, take, fill)
+
+    @trace_scope("route_fallback")
+    def _answer_overflow(self, serve, sorted_payload, main):
+        """``main`` with the lanes past their bucket's capacity answered
+        through the cond-gated psum fallback."""
+        F = self.num_shards
+        L_ov = self.ov_budget
+        ov_ids = self._compact_overflow(self._sorted_ids, fill=-1)
+        ov_payload = (
+            None if sorted_payload is None
+            else self._compact_overflow(sorted_payload, fill=0)
+        )
+        trailing = main.shape[1:]
+        dtype = main.dtype
+        my = jax.lax.axis_index(self.axis)
+
+        def _fallback(args):
+            # psum fallback: everyone sees everyone's overflow requests
+            # (cheap — id/payload lanes, no answers), each shard
+            # contributes the answers it owns, the psum hands every
+            # member the full result and it keeps its own slice
+            ids_, pay_ = args
+            allov = jax.lax.all_gather(
+                ids_, self.axis, tiled=False
+            ).reshape(F, L_ov)
+            if pay_ is None:
+                part = serve(allov.reshape(-1))
+            else:
+                allpay = jax.lax.all_gather(
+                    pay_, self.axis, tiled=False
+                ).reshape((F, L_ov) + pay_.shape[1:])
+                part = serve(
+                    allov.reshape(-1),
+                    allpay.reshape((F * L_ov,) + pay_.shape[1:]),
+                )
+            part = part.reshape((F, L_ov) + trailing)
+            return jax.lax.psum(part, self.axis)[my]
+
+        def _no_overflow(args):
+            return jnp.zeros((L_ov,) + trailing, dtype)
+
+        ov_rows = jax.lax.cond(
+            self.overflow > 0, _fallback, _no_overflow,
+            (ov_ids, ov_payload),
+        )
+        mask = self._ov_mask.reshape(
+            self._ov_mask.shape + (1,) * (main.ndim - 1)
+        )
+        return jnp.where(
+            mask, ov_rows[jnp.clip(self._ov_rank, 0, L_ov - 1)], main
+        )
 
     # -- API ----------------------------------------------------------------
 
@@ -180,6 +235,7 @@ class BucketRoute:
                 self._bucketize(self._sorted_ids, fill=-1)
             )
         recv_ids = self._recv_ids
+        sorted_payload = None
         if payload is not None:
             sorted_payload = payload[self._order]
             recv_payload = self._a2a(self._bucketize(sorted_payload, fill=0))
@@ -196,51 +252,7 @@ class BucketRoute:
         if self.ov_budget == 0:
             answered = main
         else:
-            L_ov = self.ov_budget
-            ov_ids = self._compact_overflow(self._sorted_ids, fill=-1)
-            ov_payload = (
-                None if payload is None
-                else self._compact_overflow(sorted_payload, fill=0)
-            )
-            trailing = main.shape[1:]
-            dtype = main.dtype
-            my = jax.lax.axis_index(self.axis)
-
-            def _fallback(args):
-                # psum fallback: everyone sees everyone's overflow requests
-                # (cheap — id/payload lanes, no answers), each shard
-                # contributes the answers it owns, the psum hands every
-                # member the full result and it keeps its own slice
-                ids_, pay_ = args
-                allov = jax.lax.all_gather(
-                    ids_, self.axis, tiled=False
-                ).reshape(F, L_ov)
-                if pay_ is None:
-                    part = serve(allov.reshape(-1))
-                else:
-                    allpay = jax.lax.all_gather(
-                        pay_, self.axis, tiled=False
-                    ).reshape((F, L_ov) + pay_.shape[1:])
-                    part = serve(
-                        allov.reshape(-1),
-                        allpay.reshape((F * L_ov,) + pay_.shape[1:]),
-                    )
-                part = part.reshape((F, L_ov) + trailing)
-                return jax.lax.psum(part, self.axis)[my]
-
-            def _no_overflow(args):
-                return jnp.zeros((L_ov,) + trailing, dtype)
-
-            ov_rows = jax.lax.cond(
-                self.overflow > 0, _fallback, _no_overflow,
-                (ov_ids, ov_payload),
-            )
-            mask = self._ov_mask.reshape(
-                self._ov_mask.shape + (1,) * (main.ndim - 1)
-            )
-            answered = jnp.where(
-                mask, ov_rows[jnp.clip(self._ov_rank, 0, L_ov - 1)], main
-            )
+            answered = self._answer_overflow(serve, sorted_payload, main)
 
         out = answered[inverse_permutation_gather(self._order)]
         vmask = self._valid.reshape(self._valid.shape + (1,) * (out.ndim - 1))

@@ -27,7 +27,8 @@ feature > 1 (docs/Introduction.md "Cost of redundant sampling").
 
 from __future__ import annotations
 
-from functools import partial
+import operator
+from functools import partial, reduce
 from typing import Sequence
 
 import numpy as np
@@ -47,6 +48,9 @@ from ..obs.registry import (
     GUARD_SKIPPED,
     PIPELINE_REISSUES,
     ROUTED_OVERFLOW,
+    SAMPLE_EDGES,
+    SAMPLE_FRONTIER,
+    SAMPLE_FRONTIER_OVERFLOW,
     SAMPLE_OVERFLOW,
     TIER_HITS,
     TRAIN_OVERLAP_EFFICIENCY,
@@ -57,7 +61,7 @@ from ..obs.tracing import Tracer
 from ..resilience.elastic import validate_resume_meta, worker_ordered_mean
 from ..resilience.faults import Preemption
 from ..resilience.guard import guard_verdict, guarded_update
-from ..utils.trace import info_once
+from ..utils.trace import info_once, trace_scope
 from ..parallel.mesh import DATA_AXIS, FEATURE_AXIS, shard_map
 from ..parallel.pipeline import PipelinedBatch, Prefetcher
 from ..parallel.train import cross_entropy_on_seeds
@@ -254,6 +258,10 @@ class DistributedTrainer:
         #     fallback lanes (int32 (num_layers,), seeds-outward;
         #     (steps, num_layers) after epoch_scan; zeros for replicated
         #     topologies)
+        # The sampler's own counts have no ``last_*`` view; read them with
+        # ``trainer.metrics.value(name)``: sample.edges, sample.frontier
+        # (int32 (num_layers,), seeds-outward) and
+        # sample.frontier_overflow (scalar), on every topology.
         # collect_metrics=False disables collection at the PROGRAM level:
         # the compiled step carries zero metric values/collectives and the
         # loss trajectory is bit-identical (tests/test_obs.py differential).
@@ -274,6 +282,22 @@ class DistributedTrainer:
             unit="lanes",
             doc="per-hop fallback-served lanes of the topo-sharded "
                 "sampler (seeds-outward)",
+        )
+        hops = (len(tuple(sampler.sizes)),)
+        self.metrics.counter(
+            SAMPLE_EDGES, shape=hops, unit="edges",
+            doc="mesh-total valid sampled edges per hop (seeds-outward: "
+                "index 0 is the seeds' own hop)",
+        )
+        self.metrics.counter(
+            SAMPLE_FRONTIER, shape=hops, unit="nodes",
+            doc="mesh-total distinct nodes found per hop before the "
+                "frontier cap (seeds-outward; each worker's count summed)",
+        )
+        self.metrics.counter(
+            SAMPLE_FRONTIER_OVERFLOW, unit="nodes",
+            doc="mesh-total uniques dropped for exceeding frontier_caps, "
+                "all hops; > 0 means the step trained on truncated blocks",
         )
         # resilience (resilience/): nonfinite_guard=True compiles the
         # non-finite step guard into the step body — a NaN/Inf loss or
@@ -792,10 +816,11 @@ class DistributedTrainer:
                 rep_rows=rep_rows, rep_gather=rep_g,
                 hot_miss_id=-1 if sharded else 0, with_hits=True,
             )
-            heat = (
-                row_heat_histogram(n_id, order, node_count, heat_bins)
-                if heat_on else None
-            )
+            heat = None
+            if heat_on:
+                with trace_scope("step_metrics"):
+                    heat = row_heat_histogram(
+                        n_id, order, node_count, heat_bins)
             return x, ov_box[0], hits, heat
 
         elastic = self.elastic
@@ -811,8 +836,9 @@ class DistributedTrainer:
             # always drew — so an issued batch is bitwise the serial one
             # no matter where in the schedule it runs (the prologue, the
             # skewed scan body, or a checkpoint-chunk re-issue).
-            sample_key = jax.random.split(key)[0]
-            num_seeds = jnp.sum((seeds >= 0).astype(jnp.int32))
+            with trace_scope("step_keys"):
+                sample_key = jax.random.split(key)[0]
+                num_seeds = jnp.sum((seeds >= 0).astype(jnp.int32))
             if topo_sharded:
                 # sharded-topology sampling: per-hop owner routing over the
                 # feature axis, SAME routing budget (routed_alpha) as the
@@ -820,7 +846,8 @@ class DistributedTrainer:
                 from ..sampling.dist import dist_multilayer_sample
 
                 indptr_blk, indices_blk = topo
-                n_id, _, adjs, _, _, _, hop_ovs = dist_multilayer_sample(
+                (n_id, _, adjs, frontier_ov, edges, frontier,
+                 hop_ovs) = dist_multilayer_sample(
                     indptr_blk[0], indices_blk[0], rows_per_shard, seeds,
                     num_seeds, sample_key, sizes, caps,
                     axis=FEATURE_AXIS, num_shards=mesh.shape[FEATURE_AXIS],
@@ -829,22 +856,36 @@ class DistributedTrainer:
                 )
                 sample_ov = jnp.stack(hop_ovs)  # feature-group totals
             else:
-                n_id, _, adjs, _, _, _ = multilayer_sample(
-                    topo, seeds, num_seeds, sample_key, sizes, caps,
-                    weighted=sampler.weighted, kernel=sampler.kernel,
-                    dedup=sampler.dedup,
+                n_id, _, adjs, frontier_ov, edges, frontier = (
+                    multilayer_sample(
+                        topo, seeds, num_seeds, sample_key, sizes, caps,
+                        weighted=sampler.weighted, kernel=sampler.kernel,
+                        dedup=sampler.dedup,
+                    )
                 )
                 sample_ov = jnp.zeros((len(sizes),), jnp.int32)
             x, routed_ov, tier_hits, heat = gather_features(parts, n_id)
-            return (n_id, x, adjs, num_seeds, routed_ov, tier_hits,
-                    sample_ov, heat)
+            # the block's local telemetry, one pytree: what feed_issue
+            # hands to the tape (blocks of one device are summed leaf by
+            # leaf first). The sampler's per-hop tuples come deepest hop
+            # first; the metrics are seeds-outward like sample_ov.
+            with trace_scope("step_metrics"):
+                tele = dict(
+                    routed_ov=routed_ov, tier_hits=tier_hits,
+                    sample_ov=sample_ov, heat=heat,
+                    edges=jnp.stack(edges[::-1]),
+                    frontier=jnp.stack(frontier[::-1]),
+                    frontier_ov=frontier_ov,
+                )
+            return n_id, x, adjs, num_seeds, tele
 
         def train_block(params, n_id, x, adjs, num_seeds, labels, key,
                         inject):
             # the COMPUTE half: fault injection, label/mask prep, loss +
             # grad. Draws the dropout stream — the second split of the
             # same block key issue_block split its sampling stream from.
-            dropout_key = jax.random.split(key)[1]
+            with trace_scope("step_keys"):
+                dropout_key = jax.random.split(key)[1]
             if inject_rows:
                 # FaultPlan NaN injection: poison the leading rows of the
                 # gathered block on planned steps (inject is the per-step
@@ -860,15 +901,19 @@ class DistributedTrainer:
                 rows = min(inject_rows, int(x.shape[0]))
                 poison = jnp.full((rows, x.shape[1]), jnp.nan, x.dtype)
                 x = x.at[:rows].set(jnp.where(inject, poison, x[:rows]))
-            lab = labels[jnp.clip(n_id[:S], 0)]
-            mask = jnp.arange(S) < num_seeds
+            with trace_scope("seed_loss"):
+                lab = labels[jnp.clip(n_id[:S], 0)]
+                mask = jnp.arange(S) < num_seeds
 
             def loss_fn(p):
                 logits = model.apply(
                     {"params": p}, x, adjs, train=True,
                     rngs={"dropout": dropout_key}
                 )
-                return cross_entropy_on_seeds(logits[:S], lab, mask)
+                # opened inside the differentiated function: its paths
+                # read jvp(seed_loss) and transpose(jvp(seed_loss))
+                with trace_scope("seed_loss"):
+                    return cross_entropy_on_seeds(logits[:S], lab, mask)
 
             return jax.value_and_grad(loss_fn)(params)
 
@@ -876,12 +921,12 @@ class DistributedTrainer:
             # one logical seed block = the two halves composed in place
             # (the serial schedule; pipeline_depth=1 runs the same halves
             # as separate programs with a one-step skew between them)
-            (n_id, x, adjs, num_seeds, routed_ov, tier_hits, sample_ov,
-             heat) = issue_block(topo, parts, seeds, key)
+            n_id, x, adjs, num_seeds, tele = issue_block(
+                topo, parts, seeds, key)
             loss, grads = train_block(
                 params, n_id, x, adjs, num_seeds, labels, key, inject
             )
-            return loss, grads, routed_ov, tier_hits, sample_ov, heat
+            return loss, grads, tele
 
         # the step program's metric names, split by producing half: the
         # issue half owns the sample/gather telemetry, the train half the
@@ -890,106 +935,128 @@ class DistributedTrainer:
         # train.pipeline_reissues never enter the program), the pipelined
         # halves finalize their own subset so the merged per-step dict is
         # disjoint instead of zero-filled entries clobbering real values.
-        issue_names = (ROUTED_OVERFLOW, TIER_HITS, SAMPLE_OVERFLOW) + (
-            (FEATURE_ROW_HEAT,) if heat_on else ()
-        )
+        issue_names = (
+            ROUTED_OVERFLOW, TIER_HITS, SAMPLE_OVERFLOW, SAMPLE_EDGES,
+            SAMPLE_FRONTIER, SAMPLE_FRONTIER_OVERFLOW,
+        ) + ((FEATURE_ROW_HEAT,) if heat_on else ())
         train_names = (GUARD_SKIPPED, GUARD_NONFINITE) if guard else ()
         program_names = issue_names + train_names
+        axes = (DATA_AXIS, FEATURE_AXIS)
+        # lanes that are distinct per device under "all" (a mesh-wide psum
+        # is the batch total) are processed redundantly by the feature-
+        # group members under "data": summing those too would overcount
+        # each lane F times
+        lane_axes = axes if routed else DATA_AXIS
 
-        def body(params, opt_state, topo, parts, seeds, labels, key, inject):
-            # distinct key per seed-block worker; under "data" sharding the
-            # feature-axis members share the key (identical redundant
-            # sampling)
-            widx = jax.lax.axis_index(DATA_AXIS)
-            if routed:
-                widx = widx * mesh.shape[FEATURE_AXIS] + jax.lax.axis_index(
-                    FEATURE_AXIS
-                )
-            axes = (DATA_AXIS, FEATURE_AXIS)
-            if not elastic:
-                (loss, grads, routed_ov, tier_hits, sample_ov,
-                 heat) = one_block(
-                    params, topo, parts, seeds, labels,
-                    jax.random.fold_in(key, widx), inject
-                )
-                if guard:
-                    # verdict BEFORE the pmean (it spreads one worker's NaN
-                    # mesh-wide); psum'd over both axes so every chip agrees
-                    ok, local_bad = guard_verdict(loss, grads, axes)
-                grads = jax.lax.pmean(grads, axes)
-                loss = jax.lax.pmean(loss, axes)
-            else:
-                # elastic mode: this device runs ``bpd`` logical seed
-                # blocks sequentially (every device runs the same
-                # per-block program, so the per-block collectives stay
-                # uniform and deadlock-free), each keyed on its LOGICAL
-                # worker index — at bpd=1 the keys equal the non-elastic
-                # fold exactly. The mean then reduces in fixed logical-
-                # worker order (all_gather is device-major, blocks-minor
-                # = worker order), making loss/grads bitwise independent
-                # of how many devices the workers map onto: the seam
-                # resume(mesh=) relies on.
-                blocks = seeds.reshape(bpd, -1)
-                outs = [
-                    one_block(
-                        params, topo, parts, blocks[b], labels,
-                        jax.random.fold_in(key, widx * bpd + b), inject
-                    )
-                    for b in range(bpd)
-                ]
-                losses = jnp.stack([o[0] for o in outs])
-                grads_blocks = jax.tree_util.tree_map(
-                    lambda *g: jnp.stack(g), *[o[1] for o in outs]
-                )
-                routed_ov = sum(o[2] for o in outs)
-                tier_hits = sum(o[3] for o in outs)
-                sample_ov = sum(o[4] for o in outs)
-                heat = sum(o[5] for o in outs) if heat_on else None
-                if guard:
-                    # stacked per-block values: one verdict for the whole
-                    # step, still counted before any cross-worker mean
-                    ok, local_bad = guard_verdict(losses, grads_blocks, axes)
-                grads = worker_ordered_mean(grads_blocks, axes, workers)
-                loss = worker_ordered_mean(losses, axes, workers)
+        def block_key(key, b):
+            # distinct key per LOGICAL seed-block worker; under "data"
+            # sharding the feature-axis members share the key (identical
+            # redundant sampling). At bpd=1 the fold equals the non-elastic
+            # one exactly.
+            with trace_scope("step_keys"):
+                widx = jax.lax.axis_index(DATA_AXIS)
+                if routed:
+                    widx = widx * mesh.shape[FEATURE_AXIS] + (
+                        jax.lax.axis_index(FEATURE_AXIS))
+                return jax.random.fold_in(key, widx * bpd + b)
+
+        def sum_blocks(teles):
+            # one device's blocks, summed leaf by leaf (heat may be None)
+            return jax.tree_util.tree_map(
+                lambda *v: reduce(operator.add, v), *teles)
+
+        def feed_issue(tape, tele):
             # graftscope: the step's telemetry rides ONE metrics pytree.
             # Each metric declares its own mesh reduction (applied once by
             # tape.finalize): the routed overflow and per-hop sample
             # overflow are feature-psum'd inside the route already, so the
-            # data-axis psum makes them mesh-wide totals; tier hits under
-            # "all" are distinct lanes per device (mesh-wide psum = batch
-            # total) while under "data" the feature-group members process
-            # the SAME lanes redundantly — summing them too would overcount
-            # each lane F times. With collect_metrics=False the tape feeds
-            # nothing and the program carries zero metric collectives.
-            tape = metrics.tape()
-            tape.add(ROUTED_OVERFLOW, routed_ov, psum=DATA_AXIS)
-            tape.set(TIER_HITS, tier_hits,
-                     psum=axes if routed else DATA_AXIS)
+            # data-axis psum makes them mesh-wide totals; tier hits and the
+            # sampler's counts reduce over ``lane_axes``. With
+            # collect_metrics=False the tape feeds nothing and the program
+            # carries zero metric collectives.
+            tape.add(ROUTED_OVERFLOW, tele["routed_ov"], psum=DATA_AXIS)
+            tape.set(TIER_HITS, tele["tier_hits"], psum=lane_axes)
             if heat_on:
-                # same reduction discipline as tier_hits: distinct lanes
-                # per device under "all", redundant under "data"
-                tape.set(FEATURE_ROW_HEAT, heat,
-                         psum=axes if routed else DATA_AXIS)
+                tape.set(FEATURE_ROW_HEAT, tele["heat"], psum=lane_axes)
             if topo_sharded:
-                tape.add(SAMPLE_OVERFLOW, sample_ov, psum=DATA_AXIS)
+                tape.add(SAMPLE_OVERFLOW, tele["sample_ov"], psum=DATA_AXIS)
+            tape.add(SAMPLE_EDGES, tele["edges"], psum=lane_axes)
+            tape.add(SAMPLE_FRONTIER, tele["frontier"], psum=lane_axes)
+            tape.add(SAMPLE_FRONTIER_OVERFLOW, tele["frontier_ov"],
+                     psum=lane_axes)
+
+        def allreduce_update(params, opt_state, blocks):
+            """The shared tail of the serial body and the train half:
+            verdict, cross-worker mean, optimizer update. ``blocks`` holds
+            the ``(loss, grads)`` of each of this device's seed blocks (one
+            outside elastic mode). Returns the guard's ``(ok, local_bad)``
+            last (None without the guard)."""
+            verdict = None
+            with trace_scope("grad_allreduce"):
+                if not elastic:
+                    (losses, grads_blocks), = blocks
+                else:
+                    losses = jnp.stack([loss for loss, _ in blocks])
+                    grads_blocks = jax.tree_util.tree_map(
+                        lambda *g: jnp.stack(g), *[g for _, g in blocks]
+                    )
+                if guard:
+                    # verdict BEFORE the mean (it spreads one worker's NaN
+                    # mesh-wide); psum'd over both axes so every chip
+                    # agrees. Stacked per-block values in elastic mode: one
+                    # verdict for the whole step.
+                    verdict = guard_verdict(losses, grads_blocks, axes)
+                if not elastic:
+                    grads = jax.lax.pmean(grads_blocks, axes)
+                    loss = jax.lax.pmean(losses, axes)
+                else:
+                    # fixed logical-worker order (all_gather is device-
+                    # major, blocks-minor = worker order): loss/grads are
+                    # bitwise independent of how many devices the workers
+                    # map onto — the seam resume(mesh=) relies on
+                    grads = worker_ordered_mean(grads_blocks, axes, workers)
+                    loss = worker_ordered_mean(losses, axes, workers)
+            with trace_scope("optax_update"):
+                if guard:
+                    params, opt_state = guarded_update(
+                        tx, grads, opt_state, params, verdict[0]
+                    )
+                else:
+                    updates, opt_state = tx.update(grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
+            return params, opt_state, loss, verdict
+
+        def feed_guard(tape, verdict):
             if guard:
-                # local_bad counts this worker's non-finite values; under
-                # "data" sharding the feature-group members recompute the
-                # SAME grads, so summing them too would overcount F times
-                # (same discipline as tier_hits). The skip flag is already
+                # local_bad counts this worker's non-finite values
+                # (``lane_axes``: under "data" the feature-group members
+                # recompute the SAME grads). The skip flag is already
                 # mesh-agreed (psum'd verdict) — no further reduction.
-                tape.add(GUARD_NONFINITE, local_bad,
-                         psum=axes if routed else DATA_AXIS)
+                ok, local_bad = verdict
+                tape.add(GUARD_NONFINITE, local_bad, psum=lane_axes)
                 tape.add(GUARD_SKIPPED, (~ok).astype(jnp.int32))
-                params, opt_state = guarded_update(
-                    tx, grads, opt_state, params, ok
+
+        def body(params, opt_state, topo, parts, seeds, labels, key, inject):
+            # elastic mode: this device runs ``bpd`` logical seed blocks
+            # sequentially (every device runs the same per-block program,
+            # so the per-block collectives stay uniform and deadlock-free),
+            # each keyed on its LOGICAL worker index
+            blocks = seeds.reshape(bpd, -1) if elastic else [seeds]
+            outs = [
+                one_block(
+                    params, topo, parts, blocks[b], labels,
+                    block_key(key, b), inject
                 )
-            else:
-                updates, opt_state = tx.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-            return params, opt_state, loss, tape.finalize(
-                names=program_names
-            )
+                for b in range(bpd)
+            ]
+            params, opt_state, loss, verdict = allreduce_update(
+                params, opt_state, [o[:2] for o in outs])
+            with trace_scope("step_metrics"):
+                tape = metrics.tape()
+                feed_issue(tape, sum_blocks([o[2] for o in outs]))
+                feed_guard(tape, verdict)
+                mtree = tape.finalize(names=program_names)
+            return params, opt_state, loss, mtree
 
         hot_spec = P(FEATURE_AXIS, None) if sharded else P()
         parts_spec = (P(), hot_spec, P(), P(), P())
@@ -1028,18 +1095,9 @@ class DistributedTrainer:
         # makes the pipelined trajectory bitwise identical.
 
         def issue_body(topo, parts, seeds, key):
-            widx = jax.lax.axis_index(DATA_AXIS)
-            if routed:
-                widx = widx * mesh.shape[FEATURE_AXIS] + jax.lax.axis_index(
-                    FEATURE_AXIS
-                )
-            axes = (DATA_AXIS, FEATURE_AXIS)
             blocks = seeds.reshape(bpd, -1)
             outs = [
-                issue_block(
-                    topo, parts, blocks[b],
-                    jax.random.fold_in(key, widx * bpd + b)
-                )
+                issue_block(topo, parts, blocks[b], block_key(key, b))
                 for b in range(bpd)
             ]
             n_id = jnp.stack([o[0] for o in outs])
@@ -1051,78 +1109,33 @@ class DistributedTrainer:
                 lambda *leaves: jnp.stack(leaves), *[o[2] for o in outs]
             ))
             num_seeds = jnp.stack([o[3] for o in outs])
-            routed_ov = sum(o[4] for o in outs)
-            tier_hits = sum(o[5] for o in outs)
-            sample_ov = sum(o[6] for o in outs)
-            heat = sum(o[7] for o in outs) if heat_on else None
             # identical feeds (and psum axes) to the serial body — the
             # issue half owns the batch's telemetry so a carried batch's
             # metrics stay attributed to the step that SAMPLED it
-            tape = metrics.tape()
-            tape.add(ROUTED_OVERFLOW, routed_ov, psum=DATA_AXIS)
-            tape.set(TIER_HITS, tier_hits,
-                     psum=axes if routed else DATA_AXIS)
-            if heat_on:
-                tape.set(FEATURE_ROW_HEAT, heat,
-                         psum=axes if routed else DATA_AXIS)
-            if topo_sharded:
-                tape.add(SAMPLE_OVERFLOW, sample_ov, psum=DATA_AXIS)
-            return PipelinedBatch(
-                n_id, x, adjs, num_seeds,
-                tape.finalize(names=issue_names),
-            )
+            with trace_scope("step_metrics"):
+                tape = metrics.tape()
+                feed_issue(tape, sum_blocks([o[4] for o in outs]))
+                mtree = tape.finalize(names=issue_names)
+            return PipelinedBatch(n_id, x, adjs, num_seeds, mtree)
 
         def train_body(params, opt_state, batch, labels, key, inject):
-            widx = jax.lax.axis_index(DATA_AXIS)
-            if routed:
-                widx = widx * mesh.shape[FEATURE_AXIS] + jax.lax.axis_index(
-                    FEATURE_AXIS
-                )
-            axes = (DATA_AXIS, FEATURE_AXIS)
-
             def block(b):
                 adjs_b = jax.tree_util.tree_map(
                     lambda leaf: leaf[b], batch.adjs
                 )
                 return train_block(
                     params, batch.n_id[b], batch.x[b], adjs_b,
-                    batch.num_seeds[b], labels,
-                    jax.random.fold_in(key, widx * bpd + b), inject,
+                    batch.num_seeds[b], labels, block_key(key, b), inject,
                 )
 
-            # mirror the serial body's reduction exactly: scalar verdict +
-            # plain pmean outside elastic mode, stacked verdict + fixed
-            # logical-worker-order mean inside it
-            if not elastic:
-                loss, grads = block(0)
-                if guard:
-                    ok, local_bad = guard_verdict(loss, grads, axes)
-                grads = jax.lax.pmean(grads, axes)
-                loss = jax.lax.pmean(loss, axes)
-            else:
-                outs = [block(b) for b in range(bpd)]
-                losses = jnp.stack([o[0] for o in outs])
-                grads_blocks = jax.tree_util.tree_map(
-                    lambda *g: jnp.stack(g), *[o[1] for o in outs]
-                )
-                if guard:
-                    ok, local_bad = guard_verdict(losses, grads_blocks, axes)
-                grads = worker_ordered_mean(grads_blocks, axes, workers)
-                loss = worker_ordered_mean(losses, axes, workers)
-            tape = metrics.tape()
-            if guard:
-                tape.add(GUARD_NONFINITE, local_bad,
-                         psum=axes if routed else DATA_AXIS)
-                tape.add(GUARD_SKIPPED, (~ok).astype(jnp.int32))
-                params, opt_state = guarded_update(
-                    tx, grads, opt_state, params, ok
-                )
-            else:
-                updates, opt_state = tx.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-            return params, opt_state, loss, tape.finalize(
-                names=train_names
-            )
+            # the serial body's reduction exactly
+            params, opt_state, loss, verdict = allreduce_update(
+                params, opt_state, [block(b) for b in range(bpd)])
+            with trace_scope("step_metrics"):
+                tape = metrics.tape()
+                feed_guard(tape, verdict)
+                mtree = tape.finalize(names=train_names)
+            return params, opt_state, loss, mtree
 
         # the batch rides device-resident: every array keeps its producing
         # worker's shard (the same placement the seed blocks arrive with),
@@ -1241,11 +1254,19 @@ class DistributedTrainer:
         ``last_tier_hits`` the mesh-total per-tier feature-hit vector
         (int32 (3,), [replicated, sharded, cold]), and
         ``last_sample_overflow`` the topo-sharded sampler's per-hop
-        fallback lane counts (int32 (num_layers,), seeds-outward; zeros
-        for replicated topologies). Persistent overflow means
-        ``routed_alpha`` is too small for the id skew — pass
-        ``auto_alpha=True`` (the shared tuner grows it between batches)
-        or grow it yourself between epochs.
+        routed-fallback lane counts (int32 (num_layers,), seeds-outward;
+        a replicated topology routes nothing, so zeros there). Persistent
+        overflow means ``routed_alpha`` is too small for the id skew —
+        pass ``auto_alpha=True`` (the shared tuner grows it between
+        batches) or grow it yourself between epochs.
+
+        What the sampler counted rides the same registry on every
+        topology: ``metrics.value("sample.edges")`` and
+        ``("sample.frontier")`` (int32 (num_layers,), seeds-outward: valid
+        edges, and distinct nodes found before the cap) and
+        ``("sample.frontier_overflow")`` (scalar). A non-zero overflow
+        means ``frontier_caps`` are too small: the step dropped that many
+        nodes, and their edges, from its blocks.
 
         A ShardedFeature built with ``auto_split=True`` consumes the hit
         vector here: the eager tuner moves its replicated/sharded boundary

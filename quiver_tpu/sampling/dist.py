@@ -341,10 +341,10 @@ def dist_multilayer_sample(local_indptr, local_indices, rows_per_shard: int,
     cur, cur_n = seeds, num_seeds
     total_overflow = jnp.zeros((), jnp.int32)
     for l, k in enumerate(sizes):
-        key, sub = jax.random.split(key)
         S = cur.shape[0]
         cap = routed_sample_cap(S, num_shards, routed_alpha)
         with trace_scope(f"dist_sample_layer_{l}"):
+            key, sub = jax.random.split(key)
             nbr, counts, hop_ov = dist_sample_layer(
                 local_indptr, local_indices, rows_per_shard, cur, cur_n, k,
                 sub, axis=axis, num_shards=num_shards, cap=cap,
@@ -359,15 +359,17 @@ def dist_multilayer_sample(local_indptr, local_indices, rows_per_shard: int,
                 cur, cur_n, nbr, caps[l], node_bound=node_bound,
                 scatter_free=(dedup == "scan"),
             )
-        row = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None], (S, k))
-        row = jnp.where(col >= 0, row, -1)
-        edge_index = jnp.stack([col.reshape(-1), row.reshape(-1)])
+            with trace_scope("assemble"):
+                row = jnp.broadcast_to(
+                    jnp.arange(S, dtype=jnp.int32)[:, None], (S, k))
+                row = jnp.where(col >= 0, row, -1)
+                edge_index = jnp.stack([col.reshape(-1), row.reshape(-1)])
+                del counts
+                edge_counts.append(jnp.sum((col >= 0).astype(jnp.int32)))
+                frontier_counts.append(n_frontier + overflow)
+                total_overflow = total_overflow + overflow
         adjs.append(Adj(edge_index, None, (caps[l], S), fanout=k))
-        del counts
-        edge_counts.append(jnp.sum((col >= 0).astype(jnp.int32)))
-        frontier_counts.append(n_frontier + overflow)
         cur, cur_n = frontier, n_frontier
-        total_overflow = total_overflow + overflow
     return (cur, cur_n, adjs[::-1], total_overflow,
             tuple(edge_counts[::-1]), tuple(frontier_counts[::-1]),
             tuple(hop_overflows))

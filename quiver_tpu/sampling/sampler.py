@@ -169,7 +169,6 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
     cur, cur_n = seeds, num_seeds
     total_overflow = jnp.zeros((), jnp.int32)
     for l, k in enumerate(sizes):
-        key, sub = jax.random.split(key)
         eids = None
         if use_pallas and k > DEFAULT_WINDOW:
             info_once(
@@ -178,6 +177,7 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
                 "falls back to the XLA sampler", k, DEFAULT_WINDOW,
             )
         with trace_scope(f"sample_layer_{l}"):
+            key, sub = jax.random.split(key)
             if use_pallas and k <= DEFAULT_WINDOW:
                 if with_eid:
                     nbr, counts, eids = fused_sample_layer(
@@ -207,25 +207,29 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
                 cur, cur_n, nbr, caps[l], node_bound=node_bound,
                 scatter_free=(dedup == "scan"),
             )
-        S = cur.shape[0]
-        row = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None], (S, k))
-        row = jnp.where(col >= 0, row, -1)
-        edge_index = jnp.stack([col.reshape(-1), row.reshape(-1)])
-        if eids is not None:
-            # re-mask with col: neighbors dropped by frontier-cap overflow
-            # must not leak their edge ids
-            eids = jnp.where(col >= 0, eids, -1).reshape(-1)
+            with trace_scope("assemble"):
+                S = cur.shape[0]
+                row = jnp.broadcast_to(
+                    jnp.arange(S, dtype=jnp.int32)[:, None], (S, k))
+                row = jnp.where(col >= 0, row, -1)
+                edge_index = jnp.stack([col.reshape(-1), row.reshape(-1)])
+                if eids is not None:
+                    # re-mask with col: neighbors dropped by frontier-cap
+                    # overflow must not leak their edge ids
+                    eids = jnp.where(col >= 0, eids, -1).reshape(-1)
+                # per-layer tallies in-program: the fused step's counters,
+                # benchmarks and the auto-cap planner read scalars instead
+                # of reducing (2, E_cap) arrays on the host path. Tallied
+                # POST-reindex (col >= 0), so overflow-dropped neighbors
+                # are excluded — edge_counts[i] always equals the valid
+                # edges actually present in adjs[i] (BASELINE.md honesty
+                # rule)
+                del counts
+                edge_counts.append(jnp.sum((col >= 0).astype(jnp.int32)))
+                frontier_counts.append(n_frontier + overflow)
+                total_overflow = total_overflow + overflow
         adjs.append(Adj(edge_index, eids, (caps[l], S), fanout=k))
-        # per-layer tallies in-program: benchmarks and the auto-cap planner
-        # read scalars instead of reducing (2, E_cap) arrays on the host
-        # path. Tallied POST-reindex (col >= 0), so overflow-dropped
-        # neighbors are excluded — edge_counts[i] always equals the valid
-        # edges actually present in adjs[i] (BASELINE.md honesty rule)
-        del counts
-        edge_counts.append(jnp.sum((col >= 0).astype(jnp.int32)))
-        frontier_counts.append(n_frontier + overflow)
         cur, cur_n = frontier, n_frontier
-        total_overflow = total_overflow + overflow
     return (cur, cur_n, adjs[::-1], total_overflow, tuple(edge_counts[::-1]),
             tuple(frontier_counts[::-1]))
 

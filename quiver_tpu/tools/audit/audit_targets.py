@@ -194,10 +194,12 @@ def _trace_step(trainer, params, opt, labels):
 _EPOCH_COMM = dict(feature_shards=2, local_len=2 * 8 * 3 * 2, feature_dim=8)
 
 # the tiny step's metric reductions beyond the training math: the
-# feature.routed_overflow scalar psum over "data" and the
-# feature.tier_hits (3,) psum over ("data", "feature") — update alongside
-# obs/registry.py when a new per-step metric collective lands
-_EXPECTED_METRIC_REDUCTIONS = 2
+# feature.routed_overflow scalar psum over "data", and over
+# ("data", "feature") the feature.tier_hits (3,) psum and the three
+# sampler counts (sample.edges, sample.frontier,
+# sample.frontier_overflow) — update alongside obs/registry.py when a new
+# per-step metric collective lands
+_EXPECTED_METRIC_REDUCTIONS = 5
 
 
 # -- targets ------------------------------------------------------------------

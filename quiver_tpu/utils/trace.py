@@ -2,11 +2,15 @@
 
 Capability parity with the reference's observability layer (SURVEY §5):
 
-- ``TRACE_SCOPE`` macros (torch-quiver trace.hpp:6-14) — compiled to no-ops
-  unless ``QUIVER_ENABLE_TRACE`` is set — become :func:`trace_scope`, which
-  annotates both the host timeline (``jax.profiler.TraceAnnotation``) and the
-  XLA program (``jax.named_scope``) and is a no-op unless tracing is enabled
-  via the same ``QUIVER_ENABLE_TRACE`` env var or :func:`enable_trace`.
+- ``TRACE_SCOPE`` macros (torch-quiver trace.hpp:6-14) become
+  :func:`trace_scope`. Its device half, the scope name in the XLA program
+  (``jax.named_scope``), is ALWAYS written: names are op metadata, cost
+  nothing on the device, and JAX's persistent compile cache leaves metadata
+  out of its key, so names behind a switch would be lost whenever a process
+  with the switch off filled the cache first. Its host half, the slice on the host
+  profiler timeline (``jax.profiler.TraceAnnotation``), is the part the
+  reference's ``QUIVER_ENABLE_TRACE`` switch (or :func:`enable_trace`)
+  turns on. ``docs/Introduction.md`` has the scope tree of the fused step.
 - the RAII wall-clock ``timer`` (timer.hpp:7-28) becomes :class:`Timer`.
 - the ad-hoc ``"LOG>>>"`` prints (feature.py:109-111, shard_tensor.py:69-71)
   become a real structured logger under the ``quiver_tpu`` namespace.
@@ -48,13 +52,15 @@ def trace_enabled() -> bool:
         return _enabled
     # env is only the initial default — enable_trace()/disable_trace() are
     # the live switches, and an in-trace read only gates the trace-time
-    # profiler annotation (no runtime behavior depends on it)
+    # host annotation (nothing of the compiled program depends on it)
     # graftlint: disable=env-at-trace -- initial default; enable_trace() is the live switch
     return os.environ.get(_TRACE_ENV, "0") not in ("", "0", "false", "False")
 
 
 def enable_trace() -> None:
-    """Turn trace scopes on for this process (overrides the env var)."""
+    """Turn on :func:`trace_scope`'s host annotations (overrides the env).
+
+    Scope names in compiled programs do not depend on it."""
     global _enabled
     _enabled = True
 
@@ -66,17 +72,19 @@ def disable_trace() -> None:
 
 @contextlib.contextmanager
 def trace_scope(name: str):
-    """Annotate a region on the host profiler timeline and in the jaxpr.
+    """Name a region in the XLA program, and on the host profiler timeline.
 
-    No-op (zero overhead beyond one branch) unless tracing is enabled,
-    mirroring the reference's compile-time-gated TRACE_SCOPE. Usable around
-    both eager host code (shows up as a TraceAnnotation slice) and traced
-    code (names the XLA ops for the device timeline).
+    The ``jax.named_scope`` is entered unconditionally: every op traced
+    inside carries ``name`` in its scope path, whether or not tracing is
+    enabled, so a device trace of any process reads the same names. Only
+    the host half (a ``TraceAnnotation`` slice around eager host code) is
+    behind :func:`trace_enabled`, mirroring the reference's compile-time-
+    gated TRACE_SCOPE.
     """
-    if not trace_enabled():
-        yield
-        return
-    with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
+    with contextlib.ExitStack() as stack:
+        stack.enter_context(jax.named_scope(name))
+        if trace_enabled():
+            stack.enter_context(jax.profiler.TraceAnnotation(name))
         yield
 
 

@@ -1,0 +1,294 @@
+"""The fused step names every phase it runs and counts its own work.
+
+Scope names are an interface (PERF.md section 3 lists them, the per-layer
+metric files under ``chipbench/metrics/`` read them), so they are tested
+where they are written: in the ``op_name`` metadata of the compiled step, at
+a tiny size, with tracing DISABLED. The paths are read from the compiled
+module because XLA's inliner is what joins a nested jit's names to its call
+site (``jit(body)/reindex_layer_0/compact/jit(searchsorted)/...``): the
+same text a device trace shows.
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+import optax
+
+import quiver_tpu as quiver
+from chipbench import spec
+from quiver_tpu.models.sage import GraphSAGE
+from quiver_tpu.ops.reindex import reindex_layer
+from quiver_tpu.parallel.mesh import make_mesh
+from quiver_tpu.parallel.trainer import DistributedTrainer
+from quiver_tpu.utils import trace
+from quiver_tpu.utils.graphgen import generate_pareto_graph
+
+BATCH = 8
+BENCH = spec.load_benchmark()
+
+# the tiny programs stand for the benchmark's cells: same hops, same mesh,
+# same feature store and seed sharding
+CELLS = {
+    "reddit-sage.hbm": dict(fanout=[3, 2], caps=[32, 64]),
+    "products-sage.hbm": dict(fanout=[3, 2, 2], caps=[32, 64, 128]),
+    "products-sage.clique2x2": dict(
+        fanout=[3, 2, 2], caps=[32, 64, 128], data=2, feature=2),
+}
+
+# (b): what may sit outside every scope. Parameters, tuples, copies and
+# constants (a broadcast or iota of one included) are data movement the
+# compiler arranges; an instruction with no op_name at all is one the
+# compiler made itself (a reduce-window tree, a simplified multiply); a
+# name that does not start with ``jit(`` is an argument's or a reduction
+# region's own (``parts[1]``, ``reduce_sum``); ``broadcast.N`` is how the
+# SPMD partitioner names a constant it spread over the shards.
+TRIVIAL = {"parameter", "constant", "tuple", "get-tuple-element", "copy",
+           "bitcast", "broadcast", "iota"}
+PARTITIONER_CONSTANT = re.compile(r"^broadcast\.\d+$")
+
+TOP_LEVEL = re.compile(
+    r"^(sample_layer_\d+|reindex_layer_\d+|feature_gather"
+    r"|jvp\(GraphSAGE\)|transpose\(jvp\(GraphSAGE\)\)"
+    r"|seed_loss|jvp\(seed_loss\)|transpose\(jvp\(seed_loss\)\)"
+    r"|step_keys|grad_allreduce|optax_update|step_metrics)$")
+
+# (c): the top-level scope each new metric's pattern may reach into
+NEW_METRICS = {
+    "reindex_dedup_device_ms": r"reindex_layer_\d+",
+    "reindex_compact_device_ms": r"reindex_layer_\d+",
+    "reindex_relabel_device_ms": r"reindex_layer_\d+",
+    "reindex_hop0_device_ms": r"reindex_layer_0",
+    "reindex_hop1_device_ms": r"reindex_layer_1",
+    "reindex_hop2_device_ms": r"reindex_layer_2",
+    "gather_hot_device_ms": r"feature_gather",
+    "gather_route_device_ms": r"feature_gather",
+    "gather_exchange_device_ms": r"feature_gather",
+    "allreduce_device_ms": r"grad_allreduce",
+    "forward_device_ms": r"jvp\(GraphSAGE\)",
+    "backward_device_ms": r"transpose\(jvp\(GraphSAGE\)\)",
+    "optimizer_device_ms": r"optax_update",
+}
+
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%\S+ = .*?\s?([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_PREFIX = re.compile(r"^jit\(\w+\)/(shard_map/)?")
+
+
+@pytest.fixture(autouse=True)
+def _tracing_disabled():
+    trace.disable_trace()
+    yield
+    trace._enabled = None
+
+
+def build(fanout, caps, data=1, feature=1, dedup="scan", **kwargs):
+    ei = generate_pareto_graph(300, 6.0, seed=0)
+    topo = quiver.CSRTopo(edge_index=ei)
+    sampler = quiver.GraphSageSampler(
+        topo, list(fanout), frontier_caps=list(caps), kernel="xla",
+        dedup=dedup)
+    mesh = make_mesh(data=data, feature=feature,
+                     devices=jax.devices()[:data * feature])
+    rows = np.random.default_rng(0).normal(
+        size=(topo.node_count, 8)).astype(np.float32)
+    if feature > 1:
+        store = quiver.ShardedFeature(
+            mesh, device_cache_size=rows.nbytes // feature, csr_topo=topo,
+            kernel="xla")
+    else:
+        store = quiver.Feature(
+            device_cache_size=rows.nbytes, csr_topo=topo, kernel="xla")
+    trainer = DistributedTrainer(
+        mesh, sampler, store.from_cpu_tensor(rows),
+        GraphSAGE(hidden=8, num_classes=4, num_layers=len(fanout),
+                  dropout=0.0),
+        optax.adam(1e-2), local_batch=BATCH,
+        seed_sharding="all" if feature > 1 else "data", **kwargs)
+    params, opt_state = trainer.init(jax.random.PRNGKey(0))
+    labels = jnp.asarray(
+        np.random.default_rng(1).integers(0, 4, topo.node_count), jnp.int32)
+    return trainer, params, opt_state, labels
+
+
+def step_instructions(trainer, params, opt_state, labels):
+    """(opcode, scope path below ``jit(body)/[shard_map/]``) of every
+    instruction of the compiled step that carries a ``jit(...)`` name."""
+    seeds = jnp.asarray(trainer.shard_seeds(np.arange(trainer.global_batch)))
+    text = trainer._step.lower(
+        params, opt_state, trainer.topo, trainer._feature_parts(), seeds,
+        labels, jax.random.PRNGKey(1), np.asarray(False),
+    ).compile().as_text()
+    out = []
+    for line in text.splitlines():
+        inst, name = _INSTRUCTION.match(line), _OP_NAME.search(line)
+        if inst and name and name.group(1).startswith("jit("):
+            out.append((inst.group(1), _PREFIX.sub("", name.group(1))))
+    assert out
+    return out
+
+
+@pytest.fixture(scope="module")
+def programs():
+    """The compiled step of each cell's tiny stand-in, lowered once."""
+    trace.disable_trace()
+    return {cell: step_instructions(*build(**shape))
+            for cell, shape in CELLS.items()}
+
+
+def paths_of(instructions):
+    return [path for _, path in instructions]
+
+
+def has(paths, pattern):
+    rx = re.compile(pattern)
+    return any(rx.search(p) for p in paths)
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_every_scope_of_the_tree_is_in_the_untraced_program(programs, cell):
+    assert not trace.trace_enabled()
+    paths = paths_of(programs[cell])
+    hops = len(CELLS[cell]["fanout"])
+    wanted = [f"^sample_layer_{l}/" for l in range(hops)]
+    for l in range(hops):
+        wanted += [f"^reindex_layer_{l}/(.*/)?{phase}(/|$)"
+                   for phase in ("dedup", "compact", "relabel", "assemble")]
+    wanted += [r"^feature_gather/", r"^feature_gather/tier_hot/",
+               r"^jvp\(GraphSAGE\)/", r"^transpose\(jvp\(GraphSAGE\)\)/",
+               r"^(jvp\()?seed_loss", r"^step_keys/", r"^grad_allreduce/",
+               r"^optax_update/", r"^step_metrics/"]
+    if cell.endswith("clique2x2"):
+        wanted += [r"^feature_gather/tier_hot/route_plan/",
+                   r"^feature_gather/tier_hot/route_exchange/"]
+    missing = [w for w in wanted if not has(paths, w)]
+    assert not missing, missing
+    assert not has(paths, "feature_gather/feature_gather")
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_every_instruction_lies_under_one_top_level_scope(programs, cell):
+    stray = sorted({(op, path) for op, path in programs[cell]
+                    if op not in TRIVIAL
+                    and not PARTITIONER_CONSTANT.match(path)
+                    and not TOP_LEVEL.match(path.split("/")[0])})
+    assert not stray, stray[:20]
+
+
+@pytest.mark.parametrize("metric", list(NEW_METRICS))
+def test_new_metric_patterns_read_their_scope_and_nothing_else(
+        programs, metric):
+    file = spec.load_metric(metric)
+    assert file["reader"] == "device_time_by_scope"
+    rx = re.compile(file["args"]["pattern"])
+    inside = re.compile("^(" + NEW_METRICS[metric] + ")$")
+    cells = file.get("workloads") or [w["name"] for w in BENCH["workloads"]]
+    for cell in cells:
+        # the reader searches the whole path, prefix included
+        matched = [p for p in paths_of(programs[cell])
+                   if rx.search("jit(body)/" + p)]
+        assert matched, (metric, cell)
+        outside = [p for p in matched if not inside.match(p.split("/")[0])]
+        assert not outside, (metric, cell, outside[:5])
+
+
+def test_accepted_patterns_still_claim_the_gather_and_the_optimizer(programs):
+    gather = re.compile(spec.load_metric("gather_device_ms")["args"]["pattern"])
+    model = re.compile(spec.load_metric("model_device_ms")["args"]["pattern"])
+    clique = programs["products-sage.clique2x2"]
+    for cell, instructions in programs.items():
+        rows = [p for op, p in instructions
+                if op in ("gather", "fusion") and "tier_hot" in p
+                and p.endswith("/gather")]
+        assert rows and all(gather.search(p) for p in rows), cell
+        update = [p for _, p in instructions if p.startswith("optax_update")]
+        assert update and all(model.search(p) for p in update), cell
+        # the loss's label gather is the model's no more the gather's
+        assert not any(gather.search(p) for _, p in instructions
+                       if "seed_loss" in p), cell
+    exchanged = [p for op, p in clique if op == "all-to-all"]
+    assert exchanged and all(gather.search(p) for p in exchanged)
+
+
+def test_the_overflow_fallback_has_its_scope():
+    """``route_fallback`` exists only where a bucket can overflow: with
+    two shards the default budget (alpha 2) makes full-length buckets."""
+    paths = paths_of(step_instructions(*build(
+        **CELLS["products-sage.clique2x2"], routed_alpha=1.0)))
+    assert has(paths, r"^feature_gather/tier_hot/route_fallback/")
+    rx = re.compile(
+        spec.load_metric("gather_route_device_ms")["args"]["pattern"])
+    assert any(rx.search(p) for p in paths if "route_fallback" in p)
+
+
+@pytest.mark.parametrize("dedup", ["sort", "map", "scan"])
+def test_every_dedup_strategy_has_the_three_phases(dedup):
+    def run(seeds, nbr):
+        with trace.trace_scope("reindex_layer_0"):
+            return reindex_layer(
+                seeds, jnp.int32(6), nbr, 16,
+                node_bound=40 if dedup == "map" else None,
+                scatter_free=dedup == "scan")
+
+    text = jax.jit(run).lower(
+        jnp.arange(8, dtype=jnp.int32),
+        jnp.arange(24, dtype=jnp.int32).reshape(8, 3) % 11,
+    ).compile().as_text()
+    paths = _OP_NAME.findall(text)
+    for phase in ("dedup", "compact", "relabel"):
+        assert has(paths, rf"reindex_layer_0/{phase}/"), (dedup, phase)
+
+
+# -- the step keeps the sampler's counts --------------------------------------
+
+
+def block_counts(trainer, seeds, key):
+    """Per-hop valid edges and unclipped frontier sizes, seeds outward, of
+    the block ``step(seeds, key)`` draws for its one worker."""
+    padded = np.full(BATCH, -1, np.int32)
+    padded[:len(seeds)] = seeds
+    sample_key = jax.random.split(jax.random.fold_in(jnp.asarray(key), 0))[0]
+    _, _, adjs, overflow, _, frontier = trainer.sampler.sample_padded(
+        trainer.sampler.topo, jnp.asarray(padded), jnp.int32(len(seeds)),
+        sample_key)
+    edges = [int((np.asarray(a.edge_index)[0] >= 0).sum()) for a in adjs]
+    return edges[::-1], [int(f) for f in frontier][::-1], int(overflow)
+
+
+def test_sample_edges_are_the_valid_edges_of_the_blocks():
+    trainer, params, opt_state, labels = build([3, 2], [32, 64])
+    key = np.asarray(jax.random.PRNGKey(5))
+    seeds = np.arange(BATCH)
+    trainer.step(params, opt_state, seeds, labels, key)
+    edges, frontier, overflow = block_counts(trainer, seeds, key)
+    value = trainer.metrics.value
+    assert np.asarray(value("sample.edges")).tolist() == edges
+    assert np.asarray(value("sample.frontier")).tolist() == frontier
+    assert int(value("sample.frontier_overflow")) == overflow == 0
+    assert "sample.edges" in trainer.metrics_report()
+
+
+def test_caps_too_small_are_reported_on_a_replicated_topology():
+    caps = [10, 12]
+    trainer, params, opt_state, labels = build([3, 2], caps)
+    trainer.step(params, opt_state, np.arange(BATCH), labels,
+                 np.asarray(jax.random.PRNGKey(5)))
+    frontier = np.asarray(trainer.metrics.value("sample.frontier"))
+    dropped = int(trainer.metrics.value("sample.frontier_overflow"))
+    assert dropped > 0
+    assert (frontier > np.asarray(caps)).any()
+    assert dropped == int(np.maximum(frontier - np.asarray(caps), 0).sum())
+
+
+def test_epoch_scan_stacks_the_counts_per_step():
+    trainer, params, opt_state, labels = build([3, 2], [32, 64])
+    seed_mat = trainer.pack_epoch(np.arange(3 * BATCH), seed=0)
+    trainer.epoch_scan(params, opt_state, seed_mat, labels,
+                       jax.random.PRNGKey(2))
+    assert np.shape(trainer.metrics.value("sample.edges")) == (3, 2)
+    assert np.shape(trainer.metrics.value("sample.frontier")) == (3, 2)
+    assert np.shape(trainer.metrics.value("sample.frontier_overflow")) == (3,)
+    assert (np.asarray(trainer.metrics.value("sample.edges")) > 0).all()

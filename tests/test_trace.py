@@ -19,8 +19,13 @@ from quiver_tpu.utils import debug, trace
 
 @pytest.fixture(autouse=True)
 def _reset_trace_state():
+    # the log tests below switch the package logger's propagation: put it
+    # back, or every later log assertion in this process reads nothing
+    logger = logging.getLogger("quiver_tpu")
+    propagate = logger.propagate
     yield
     trace._enabled = None  # restore env-var-driven default
+    logger.propagate = propagate
 
 
 def test_trace_scope_disabled_is_noop(monkeypatch):
