@@ -58,11 +58,13 @@ def resolve_dedup(dedup: str) -> str:
 
     * **cpu** -> ``"map"`` — on XLA's CPU backend the dense scatter-min
       map ran 4-5x the sort path at both smoke and full products scale.
-    * **tpu** -> ``"scan"`` — the zero-scatter strategy, chosen on the
-      expectation that XLA serializes general scatters on TPU. It runs on
-      a v5e (``chip_smoke.py``) but the three have not been timed against
-      each other there: provisional until ROADMAP S2 does (the two
-      2026-07-30 rows in ROADMAP's Speed-queue table used ``sort``).
+    * **tpu** -> ``"scan"`` — the zero-scatter strategy. What a v5e has
+      timed so far (PERF.md, PR 26) is its compaction: at ogbn-products'
+      deepest hop (852,480 lanes) the payload-carrying sort it uses costs
+      0.9 ms and the scatter of the other two strategies 3.9 ms, so a
+      scatter with unique indices is NOT serialized there, only slower.
+      The three whole strategies have not been run against each other on
+      a cell: provisional until ROADMAP S9 does.
 
     ``QUIVER_DEDUP=sort|map|scan`` overrides the ``"auto"`` resolution
     ONLY: call sites passing an explicit strategy
@@ -110,9 +112,8 @@ def inverse_permutation(p):
 
 def inverse_permutation_gather(p):
     """The zero-scatter sibling of :func:`inverse_permutation`: argsort of
-    a permutation IS its inverse. Costs a sort instead of a scatter — the
-    right trade on backends where XLA serializes scatters (shared by the
-    dedup scan strategy and the routed feature gather)."""
+    a permutation IS its inverse. Costs a sort instead of a scatter
+    (shared by the dedup scan strategy and the routed feature gather)."""
     return jnp.argsort(p).astype(jnp.int32)
 
 
@@ -167,14 +168,16 @@ def masked_unique(ids, valid, size: int, num_forced: int = 0,
         pass topo.node_count; neighbor ids are CSR entries < node_count by
         construction).
       scatter_free: use the ZERO-SCATTER strategy (``dedup="scan"``): two
-        sorts + a cumulative max + a binary-search compaction + gathers, no
-        ``.at[].set/min`` anywhere — the other two strategies compact their
-        output with a scatter. Rationale: the round-3 link characterization
-        measured TPU sort at ~1.8 ms/M elements while the reindex stage ran
-        tens of ms — XLA scatters with non-trivial index patterns can
-        serialize on TPU, so a strategy whose only data movement is sorts,
-        scans, and gathers is the natural third candidate. Same contract;
-        pick by measurement (ignored when ``node_bound`` is given).
+        sorts + a cumulative max + gathers find the first occurrences, and
+        ONE more sort packs them: keyed by position on the representatives
+        and by ``T`` elsewhere, it carries the ids along, so the frontier
+        comes out in first-occurrence order with no ``.at[].set/min``
+        anywhere — the other two strategies compact with a scatter. On a
+        v5e at ogbn-products' deepest hop (T = 852,480, ``size`` 672,384)
+        that sort costs 0.9 ms, the scatter 3.9 ms, and the binary search
+        per output slot it replaced (twenty rounds of dependent gathers)
+        88 ms (PERF.md, PR 26). Same contract; pick by measurement
+        (ignored when ``node_bound`` is given).
 
     Returns:
       uniq: (size,) unique ids in first-occurrence order, -1 padded.
@@ -198,18 +201,16 @@ def masked_unique(ids, valid, size: int, num_forced: int = 0,
         num_unique = jnp.sum(is_rep.astype(jnp.int32))
 
         if scatter_free and node_bound is None:
-            # compaction WITHOUT a sort or scatter: ``rank`` is
-            # non-decreasing (a cumsum), and the r-th rep's position is the
-            # first index whose rank reaches r — a vectorized binary search.
-            # The (size,) write is a contiguous slice update.
+            # compaction as ONE sort that carries the ids: a rep's key is
+            # its position (distinct, ascending in first-occurrence order),
+            # every other lane's is T, so the reps come out packed in front
+            # and the rest is masked. No gather, no scatter, no loop; the
+            # (size,) write is a contiguous slice update.
             m = min(size, T)
-            comp_pos = jnp.searchsorted(
-                rank, jnp.arange(m, dtype=rank.dtype), side="left"
+            _, packed = lax.sort(
+                (jnp.where(is_rep, pos, T), ids), num_keys=1, is_stable=False
             )
-            packed = jnp.where(
-                jnp.arange(m) < num_unique,
-                ids[jnp.clip(comp_pos, 0, T - 1)], -1
-            ).astype(ids.dtype)
+            packed = jnp.where(jnp.arange(m) < num_unique, packed[:m], -1)
             uniq = jnp.full(size, -1, ids.dtype).at[:m].set(packed)
         else:
             uniq = (

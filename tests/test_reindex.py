@@ -119,6 +119,78 @@ def test_masked_unique_scan_all_invalid_and_oversize():
     assert int(n) == 2 and list(np.asarray(local)) == [0, 0, 1]
 
 
+def _masked_unique_oracle(ids, valid, size, forced):
+    """Plain-Python contract of masked_unique: forced lanes take a slot
+    each, every lane is labelled by its value's FIRST slot."""
+    uniq, slot, local = [], {}, []
+    for p, (v, ok) in enumerate(zip(ids, valid)):
+        if not ok:
+            local.append(-1)
+            continue
+        if v not in slot:
+            slot[v] = len(uniq)
+            uniq.append(v)
+        elif p < forced:
+            uniq.append(v)
+        local.append(slot[v] if slot[v] < size else -1)
+    return (uniq + [-1] * size)[:size], len(uniq), local
+
+
+# the cases the binary search handled implicitly: (ids, valid, size, forced)
+_COMPACT_CASES = {
+    "all_invalid": ([5, 5, 2, 9], [0, 0, 0, 0], 3, 2),
+    "size_over_T": ([5, 3, 5, 7], [1, 1, 1, 1], 9, 1),
+    "overflow": ([4, 1, 4, 2, 8, 1, 6, 3], [1, 1, 1, 1, 1, 1, 1, 1], 3, 2),
+    "forced_duplicates": ([7, 7, 3, 3, 7, 5, -1, 3],
+                          [1, 1, 1, 1, 1, 1, 0, 1], 8, 3),
+    "single_lane": ([6], [1], 2, 1),
+    "all_duplicates_of_lane_0": ([4, 4, 4, 4, 4, 4], [1, 1, 1, 1, 1, 1], 4, 1),
+}
+
+
+@pytest.mark.parametrize("fn", ["masked_unique", "reindex_layer"])
+@pytest.mark.parametrize("case", list(_COMPACT_CASES))
+def test_scan_compaction_edge_cases(case, fn):
+    """The sort compaction of dedup="scan" against dedup="sort", the
+    plain-Python contract and ops/cpu_ref.py."""
+    ids, valid, size, forced = _COMPACT_CASES[case]
+    ids = np.asarray(ids, np.int32)
+    valid = np.asarray(valid, bool)
+    want_uniq, want_n, want_local = _masked_unique_oracle(
+        ids.tolist(), valid.tolist(), size, forced)
+    lanes = np.where(valid, ids, -1)
+    # the hash-map reference dedups its seeds, so it speaks for the cases
+    # whose forced lanes hold no duplicate
+    seed_lanes = lanes[:forced][lanes[:forced] >= 0]
+    if len(set(seed_lanes.tolist())) == len(seed_lanes):
+        ref_frontier, ref_col = reindex_layer_ref(
+            lanes[:forced], lanes[forced:][None, :])
+        assert ref_frontier.tolist()[:size] == want_uniq[:want_n][:size]
+        ref_col = np.where(ref_col < size, ref_col, -1)
+        assert ref_col[0].tolist() == want_local[forced:]
+    if fn == "masked_unique":
+        args = (jnp.asarray(ids), jnp.asarray(valid), size)
+        scan = masked_unique(*args, num_forced=forced, scatter_free=True)
+        sort = masked_unique(*args, num_forced=forced)
+        want = (want_uniq, want_n, want_local)
+    else:
+        # seeds = the forced lanes (a valid prefix), one neighbour row each
+        k = -(-(len(ids) - forced) // forced)
+        nbr = np.full(forced * k, -1, np.int32)
+        nbr[:len(ids) - forced] = lanes[forced:]
+        args = (jnp.asarray(lanes[:forced]), jnp.int32(valid[:forced].sum()),
+                jnp.asarray(nbr.reshape(forced, k)), size)
+        scan = reindex_layer(*args, scatter_free=True)
+        sort = reindex_layer(*args)
+        col = np.full(forced * k, -1)
+        col[:len(ids) - forced] = want_local[forced:]
+        want = (want_uniq, min(want_n, size), col.reshape(forced, k),
+                max(want_n - size, 0))
+    for got, other, expect in zip(scan, sort, want):
+        assert np.array_equal(np.asarray(got), np.asarray(other)), case
+        assert np.array_equal(np.asarray(got), np.asarray(expect)), case
+
+
 def test_sampler_dedup_alternatives_match_sort():
     """End-to-end: GraphSageSampler(dedup='map'|'scan') reproduces
     dedup='sort' exactly (same seed, same key path)."""

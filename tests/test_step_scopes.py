@@ -5,8 +5,8 @@ metric files under ``chipbench/metrics/`` read them), so they are tested
 where they are written: in the ``op_name`` metadata of the compiled step, at
 a tiny size, with tracing DISABLED. The paths are read from the compiled
 module because XLA's inliner is what joins a nested jit's names to its call
-site (``jit(body)/reindex_layer_0/compact/jit(searchsorted)/...``): the
-same text a device trace shows.
+site (``jit(body)/reindex_layer_0/dedup/jit(argsort)/...``): the same
+text a device trace shows.
 """
 
 import re
@@ -167,6 +167,23 @@ def test_every_scope_of_the_tree_is_in_the_untraced_program(programs, cell):
     missing = [w for w in wanted if not has(paths, w)]
     assert not missing, missing
     assert not has(paths, "feature_gather/feature_gather")
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_the_compaction_has_no_loop_and_keeps_its_scope(programs, cell):
+    """``dedup="scan"`` packs the frontier with one sort: no ``while`` (the
+    binary search it replaced) under ``compact``, and a ``compact`` path
+    at every hop still, so that ``reindex_compact_device_ms`` keeps
+    reading."""
+    compact = re.compile(r"^reindex_layer_(\d+)/compact(/|$)")
+    inside = [(op, path, int(m.group(1)))
+              for op, path in programs[cell] if (m := compact.match(path))]
+    loops = [path for op, path, _ in inside
+             if op == "while" or "searchsorted" in path or "while" in path]
+    assert not loops, loops[:5]
+    hops = set(range(len(CELLS[cell]["fanout"])))
+    assert {hop for _, _, hop in inside} == hops
+    assert {hop for op, _, hop in inside if op == "sort"} == hops
 
 
 @pytest.mark.parametrize("cell", list(CELLS))
