@@ -1,6 +1,8 @@
 """The system under test, built and driven as a user builds and drives it.
 
-This is the one module of the benchmark that imports ``quiver_tpu``. It
+This module and the model files it finds by the configuration's ``model``
+key (``models/<name>.py``: the flax module, the weights' tree, the scope of
+its ops) are the only ones of the benchmark that import ``quiver_tpu``. It
 calls the program's public constructors with the arguments the
 configuration and the traffic mix give (explicit ``kernel``, ``dedup`` and
 ``frontier_caps``: nothing timed chooses the code, no election, no probe
@@ -14,7 +16,8 @@ import gc
 
 import numpy as np
 
-from .reference.sage import Block
+from . import spec
+from .reference.graph import Block
 
 __all__ = ["Program", "enable_compile_cache"]
 
@@ -36,28 +39,6 @@ def enable_compile_cache() -> str:
     return cache_dir
 
 
-def _to_program_tree(weights: list) -> dict:
-    """The harness's per-layer weights in GraphSAGE's flax tree."""
-    return {
-        f"conv{i}": {
-            "lin_l": {"kernel": w["w_neigh"], "bias": w["b"]},
-            "lin_r": {"kernel": w["w_self"]},
-        }
-        for i, w in enumerate(weights)
-    }
-
-
-def _from_program_tree(tree, layers: int) -> list:
-    return [
-        {
-            "w_neigh": np.asarray(tree[f"conv{i}"]["lin_l"]["kernel"]),
-            "b": np.asarray(tree[f"conv{i}"]["lin_l"]["bias"]),
-            "w_self": np.asarray(tree[f"conv{i}"]["lin_r"]["kernel"]),
-        }
-        for i in range(layers)
-    ]
-
-
 class Program:
     """Topology, sampler, feature store, model and trainer of one cell."""
 
@@ -68,7 +49,6 @@ class Program:
         from jax.sharding import NamedSharding, PartitionSpec
 
         import quiver_tpu
-        from quiver_tpu.models.sage import GraphSAGE
         from quiver_tpu.parallel.mesh import make_mesh
         from quiver_tpu.parallel.trainer import DistributedTrainer
 
@@ -107,10 +87,8 @@ class Program:
         else:
             raise ValueError(f"no feature store {placement['store']!r}")
         self.feature = store.from_cpu_tensor(data.features)
-        model = GraphSAGE(
-            hidden=int(cfg["hidden"]), num_classes=int(cfg["classes"]),
-            num_layers=self.layers, dropout=float(cfg["dropout"]),
-        )
+        self.model_file = spec.load_model(cfg["model"], "models")
+        model = self.model_file.build(cfg)
         opt = cfg["optimizer"]
         if opt["name"] != "adam":
             raise ValueError(f"no optimizer {opt['name']!r}")
@@ -121,7 +99,7 @@ class Program:
             seed_sharding=traffic["seed_sharding"],
         )
         replicated = NamedSharding(self.mesh, PartitionSpec())
-        params = _to_program_tree(weights0)
+        params = self.model_file.to_program_tree(weights0)
         # placed as step() returns them, so that step compiles once
         self.params, self.opt_state = jax.device_put(
             (params, tx.init(params)), replicated)
@@ -140,11 +118,11 @@ class Program:
         return self.trainer.last_tier_hits
 
     def params_host(self) -> list:
-        return _from_program_tree(self.params, self.layers)
+        return self.model_file.from_program_tree(self.params, self.layers)
 
     def first_moment_host(self) -> list:
         """Adam's first moment in the reference's naming."""
-        return _from_program_tree(self.opt_state[0].mu, self.layers)
+        return self.model_file.from_program_tree(self.opt_state[0].mu, self.layers)
 
     def worker_seeds(self, seeds: np.ndarray, workers: int = None) -> list:
         """The seed block each worker gets of a global batch."""

@@ -18,7 +18,8 @@ import sys
 
 import numpy as np
 
-from .reference import graph, sage
+from . import spec
+from .reference import graph
 
 __all__ = ["Observed", "compare", "readings", "verdict", "report"]
 
@@ -34,7 +35,7 @@ class Observed:
     losses: list          # one float per followed step
     first_moment: list    # Adam's m after step 1, the reference's naming
     params: list          # parameters after the last followed step
-    blocks: list          # per step, one sage.Block per worker
+    blocks: list          # per step, one graph.Block per worker
     seeds: list           # per step, per worker, the seed nodes as fed
 
 
@@ -49,19 +50,20 @@ def delta(after, before):
              for k in a} for a, b in zip(after, before)]
 
 
-def numbers(obs_losses, obs_grads, obs_params, ref_losses, ref_grads,
+def numbers(model, obs_losses, obs_grads, obs_params, ref_losses, ref_grads,
             ref_params, weights0) -> dict:
-    """The compared numbers of one side against the reference."""
+    """The compared numbers of one side against the reference; ``model``
+    is the plain side of the configuration's model."""
     out = {}
     for i, (a, b) in enumerate(zip(obs_losses, ref_losses)):
         out[f"loss_gap_{i + 1}"] = abs(a - b) / abs(b)
-    g_ref = sage.leaf_norms(ref_grads)
-    out["grad_gap"] = worst_leaf_gap(sage.leaf_norms(obs_grads), g_ref)
+    g_ref = model.leaf_norms(ref_grads)
+    out["grad_gap"] = worst_leaf_gap(model.leaf_norms(obs_grads), g_ref)
     floor = float(np.median(list(g_ref.values())))
     alive = [n for n, v in g_ref.items() if v >= DEAD_LEAF * floor]
     out["update_gap"] = worst_leaf_gap(
-        sage.leaf_norms(delta(obs_params, weights0)),
-        sage.leaf_norms(delta(ref_params, weights0)), alive)
+        model.leaf_norms(delta(obs_params, weights0)),
+        model.leaf_norms(delta(ref_params, weights0)), alive)
     return out
 
 
@@ -81,12 +83,14 @@ def compare(cfg: dict, data, weights0, obs: Observed, seed: int) -> dict:
     features = jnp.asarray(data.features)
     labels = jnp.asarray(data.labels)
     opt = cfg["optimizer"]
-    ref = sage.train(weights0, features, labels, obs.blocks, opt)
+    model = spec.load_model(cfg["model"], "reference")
+    ref = model.train(weights0, features, labels, obs.blocks, opt)
     b1 = opt["b1"]
     obs_grads = [{k: np.asarray(v, np.float64) / (1 - b1)
                   for k, v in layer.items()} for layer in obs.first_moment]
     out = {"block_faults": float(sum(faults.values()))}
-    out.update(numbers(obs.losses, obs_grads, obs.params, *ref, weights0))
+    out.update(numbers(model, obs.losses, obs_grads, obs.params, *ref,
+                       weights0))
     return {"numbers": out, "block_detail": faults,
             "losses": {"program": obs.losses, "reference": ref[0]}}
 
@@ -95,26 +99,28 @@ def readings(cfg: dict, features, labels, weights0, blocks) -> dict:
     """What a limit's upper end is set from, each put in the program's
     place and compared with the reference as the program is: the control
     (the reference in the next lower precision; ``control_mixed`` keeps
-    float32 parameters and optimizer, the recipe of ``GraphSAGE(dtype=
-    "bfloat16")``) and the faults a training cell can have."""
+    float32 parameters and optimizer, the recipe of the program's model
+    built with ``dtype="bfloat16"``) and the faults a training cell can
+    have."""
     import jax.numpy as jnp
 
     opt = cfg["optimizer"]
-    ref = sage.train(weights0, features, labels, blocks, opt)
+    model = spec.load_model(cfg["model"], "reference")
+    ref = model.train(weights0, features, labels, blocks, opt)
     lower = jnp.dtype(cfg["precision"]["control"])
     sides = {
-        "control": sage.train(weights0, features, labels, blocks, opt,
-                              param_dtype=lower, compute_dtype=lower),
-        "control_mixed": sage.train(weights0, features, labels, blocks, opt,
-                                    compute_dtype=lower),
-        "fault_half_batch": sage.train(
+        "control": model.train(weights0, features, labels, blocks, opt,
+                               param_dtype=lower, compute_dtype=lower),
+        "control_mixed": model.train(weights0, features, labels, blocks, opt,
+                                     compute_dtype=lower),
+        "fault_half_batch": model.train(
             weights0, features, labels, blocks, opt,
             seed_mask=np.arange(blocks[0][0].num_seeds) % 2 == 0),
     }
     if len(blocks[0]) > 1:
-        sides["fault_no_exchange"] = sage.train(
+        sides["fault_no_exchange"] = model.train(
             weights0, features, labels, blocks, opt, workers=[0])
-    out = {name: numbers(side[0], side[1], side[2], *ref, weights0)
+    out = {name: numbers(model, *side, *ref, weights0)
            for name, side in sides.items()}
     # a step that returns its state unchanged reads 1 by this measure
     out["fault_state_unchanged"] = {"update_gap": 1.0}

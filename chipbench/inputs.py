@@ -14,9 +14,12 @@ import mmap
 
 import numpy as np
 
+from . import spec
+
 _CHUNK = 1 << 23  # edges drawn at a time into a buffer that is reused
 
-__all__ = ["Inputs", "degree_sequence", "make_inputs", "make_weights", "Feed"]
+__all__ = ["Inputs", "degree_sequence", "draw_endpoints", "make_inputs",
+           "make_weights", "Feed"]
 
 
 @dataclasses.dataclass
@@ -88,9 +91,46 @@ def degree_sequence(rng, nodes: int, edges: int, alpha: float,
     return deg
 
 
+def draw_endpoints(rng, law: str, indptr: np.ndarray,
+                   indices: np.ndarray) -> None:
+    """Fill ``indices`` with every edge's endpoint, by the configuration's
+    ``graph.endpoints``.
+
+    ``"uniform"``: any node, with equal chance. A node is then reached
+    with chance 1/N whatever its degree, so ordering rows by degree orders
+    them by nothing the traffic follows, and a cache of any share of the
+    rows hits that share.
+
+    ``"degree"``: node ``v`` with chance ``deg(v) / edges``, the owner of a
+    uniformly drawn edge slot, ``deg`` being the out-degrees just drawn.
+    In-degree then follows out-degree as on a symmetrised graph, and hubs
+    are sampled in proportion to their degree. The owners of all slots are
+    one more ``int32[edges]`` on the host while the edges are drawn (472
+    MiB at ogbn-products' 123.7 M edges), and the draw is a random read of
+    that table for every edge: there the inputs stage takes 6.7 s against
+    3.0 s under ``"uniform"`` on the chip's host (PERF.md section 6, PR 27).
+    """
+    nodes, edges = indptr.shape[0] - 1, indices.shape[0]
+    if law == "uniform":
+        high, owner = nodes, None
+    elif law == "degree":
+        # every node has an edge, so its first slot is its own: mark the
+        # first slots of nodes 1.. and sum
+        high, owner = edges, np.zeros(edges, np.int32)
+        owner[indptr[1:-1]] = 1
+        np.cumsum(owner, dtype=np.int32, out=owner)
+    else:
+        raise ValueError(
+            f"`graph.endpoints` is {law!r}: \"uniform\" or \"degree\"")
+    for lo in range(0, edges, _CHUNK):
+        hi = min(lo + _CHUNK, edges)
+        drawn = rng.integers(0, high, size=hi - lo, dtype=np.int32)
+        indices[lo:hi] = drawn if owner is None else owner[drawn]
+
+
 def make_inputs(cfg: dict, seed: int) -> Inputs:
     """The configuration's graph as a CSR, its feature table, and labels
-    that a GraphSAGE can learn, so that the loss falls."""
+    that the model can learn, so that the loss falls."""
     g = cfg["graph"]
     nodes, edges = int(g["nodes"]), int(g["edges"])
     rng = np.random.default_rng([int(seed), 1])
@@ -99,53 +139,31 @@ def make_inputs(cfg: dict, seed: int) -> Inputs:
     indptr = np.zeros(nodes + 1, np.int64)
     np.cumsum(deg, out=indptr[1:])
     indices = allocate((edges,), np.int32)
-    for lo in range(0, edges, _CHUNK):
-        hi = min(lo + _CHUNK, edges)
-        indices[lo:hi] = rng.integers(0, nodes, size=hi - lo, dtype=np.int32)
+    draw_endpoints(rng, g["endpoints"], indptr, indices)
     if np.dtype(cfg["feature_dtype"]) != np.float32:
         raise ValueError("the generator makes float32 features")
     classes, width = int(cfg["classes"]), int(cfg["feature_dim"])
-    if classes > width:
-        raise ValueError("labels need at least `classes` feature columns")
     # unit-variance uniform features, and on each node's label column a
-    # bump that a model can learn from the node's own row
+    # bump that a model can learn from the node's own row; where there are
+    # more classes than columns, classes `width` apart share a column
     feat = allocate((nodes, width), np.float32)
     rng.random(out=feat, dtype=np.float32)
     feat -= np.float32(0.5)
     feat *= np.float32(12 ** 0.5)
     labels = rng.integers(0, classes, size=nodes, dtype=np.int32)
-    feat[np.arange(nodes), labels] += np.float32(3.0)
+    column = labels if classes <= width else labels % width
+    feat[np.arange(nodes), column] += np.float32(3.0)
     return Inputs(indptr, indices, feat, labels)
 
 
-def layer_dims(cfg: dict) -> list[tuple[int, int]]:
-    """(in, out) width of each SAGE layer, input layer first."""
-    dims, d_in = [], int(cfg["feature_dim"])
-    for i in range(int(cfg["layers"])):
-        last = i == int(cfg["layers"]) - 1
-        d_out = int(cfg["classes"] if last else cfg["hidden"])
-        dims.append((d_in, d_out))
-        d_in = d_out
-    return dims
-
-
 def make_weights(cfg: dict, seed: int) -> list[dict]:
-    """Initial weights, one dict per layer (input layer first):
-    ``w_neigh`` and ``w_self`` of shape (in, out) drawn N(0, 1/in), ``b``
-    zero. The harness owns them: the program and the reference are both
-    handed these, neither makes its own."""
+    """Initial weights, one dict of leaves per layer (input layer first),
+    drawn by the plain side of the configuration's model
+    (``reference/<model>.py``) from the seed. The harness owns them: the
+    program and the reference are both handed these, neither makes its
+    own."""
     rng = np.random.default_rng([int(seed), 2])
-    layers = []
-    for d_in, d_out in layer_dims(cfg):
-        std = 1.0 / np.sqrt(d_in)
-        layers.append({
-            "w_neigh": (rng.standard_normal((d_in, d_out), dtype=np.float32)
-                        * np.float32(std)),
-            "b": np.zeros((d_out,), np.float32),
-            "w_self": (rng.standard_normal((d_in, d_out), dtype=np.float32)
-                       * np.float32(std)),
-        })
-    return layers
+    return spec.load_model(cfg["model"], "reference").make_weights(cfg, rng)
 
 
 class Feed:

@@ -4,11 +4,12 @@
 
 Draws the configuration's graph for several seeds, samples a few batches
 with a plain numpy sampler (``min(deg, k)`` distinct neighbours per target,
-as GraphSAGE samples), and prints the largest number of distinct nodes each
-hop reached, with the sampler's own margin (1.25) rounded up to 128: the
-numbers a configuration file pins under ``frontier_caps``. It runs no
-program and measures no time; any sampler that draws ``min(deg, k)``
-neighbours uniformly reaches frontiers of the same size.
+as the program's neighbour sampler draws), and prints the largest number of
+distinct nodes each hop reached, with the sampler's own margin (1.25)
+rounded up to 128: the numbers a configuration file pins under
+``frontier_caps``. It runs no program and measures no time; any sampler
+that draws ``min(deg, k)`` neighbours uniformly reaches frontiers of the
+same size.
 """
 
 from __future__ import annotations
@@ -22,10 +23,10 @@ from . import inputs, spec
 MARGIN = 1.25  # GraphSageSampler's auto_margin
 
 
-def frontier_sizes(indptr, indices, seeds, fanouts, rng) -> list[int]:
-    """Distinct nodes after each hop, seeds included."""
+def frontiers(indptr, indices, seeds, fanouts, rng) -> list[np.ndarray]:
+    """The distinct nodes after each hop, seeds included."""
     frontier = np.unique(seeds)
-    sizes = []
+    out = []
     for k in fanouts:
         base = indptr[frontier]
         deg = indptr[frontier + 1] - base
@@ -38,8 +39,13 @@ def frontier_sizes(indptr, indices, seeds, fanouts, rng) -> list[int]:
         keep = j < np.minimum(deg, k)[:, None]
         nbr = indices[(base[:, None] + off)[keep]]
         frontier = np.unique(np.concatenate([frontier, nbr]))
-        sizes.append(int(frontier.shape[0]))
-    return sizes
+        out.append(frontier)
+    return out
+
+
+def frontier_sizes(indptr, indices, seeds, fanouts, rng) -> list[int]:
+    return [int(f.shape[0]) for f in frontiers(indptr, indices, seeds,
+                                               fanouts, rng)]
 
 
 def main() -> None:

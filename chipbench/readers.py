@@ -9,8 +9,10 @@ of a roofline or of a peak is never reported as 0.
 ``ctx`` is what one traced run collected: ``trace`` (an ``xplane.Trace``),
 ``steps`` (steps completed in the traced window), ``spans`` (host span name
 -> list of seconds), ``counters``, ``stages``, ``work`` (the counts that
-``work.py`` reads), ``peaks``, ``chips``, ``workers`` and ``claims`` (the patterns of
-every ``device_time_by_scope`` metric of the cell).
+``work.py`` reads), ``peaks``, ``chips``, ``workers``, ``model_scope`` (what
+fills ``{model_scope}`` in a pattern: the scope the cell's model file
+declares) and ``claims`` (the filled patterns of every
+``device_time_by_scope`` metric of the cell).
 """
 
 from __future__ import annotations

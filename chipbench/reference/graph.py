@@ -1,16 +1,33 @@
-"""What a sampled block has to satisfy, checked against the harness's own
-copy of the graph in numpy: GraphSAGE's sampler draws ``min(deg, k)``
-neighbours of every target from that target's row, and the block's local
-ids are consistent. Which neighbours were drawn is the sampler's choice; a
-block that passes here is a sample the reference accepts as its input."""
+"""The sampler's contract, which every model's reference takes as its
+input: what a sampled block is, and what it has to satisfy, checked against
+the harness's own copy of the graph in numpy. The neighbour sampler draws
+``min(deg, k)`` neighbours of every target from that target's row, and the
+block's local ids are consistent. Which neighbours were drawn is the
+sampler's choice; a block that passes here is a sample a reference accepts
+as its input."""
 
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 
-__all__ = ["block_faults"]
+__all__ = ["Block", "block_faults"]
 
 ROWS_CHECKED = 512  # targets per hop whose every edge is looked up in the CSR
+
+
+@dataclasses.dataclass
+class Block:
+    """One sampled mini-batch of one worker: ``n_id`` maps local ids to
+    nodes (-1 pads), seeds first; ``layers`` holds, input layer first,
+    ``(src, dst, n_dst)``: an edge list in local ids (``src`` -1 on a lane
+    that holds no edge) and how many local ids are targets."""
+
+    n_id: np.ndarray
+    layers: list
+    num_seeds: int
+    overflow: int = 0  # lanes the sampler clipped at a frontier cap
 
 
 def block_faults(indptr, indices, seeds, block, fanout, rng) -> dict:

@@ -10,32 +10,69 @@ layout, no batching tricks: an edge list and a segment sum.
 ``param_dtype`` and ``compute_dtype`` select the precision. The reference
 runs float32 throughout with ``highest`` matmul precision; the control runs
 the same code in bfloat16.
+
+What the harness calls of a model's plain side: ``layer_dims``,
+``make_weights``, ``train``, ``leaf_norms`` and ``step_flops``.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["Block", "loss_and_grads", "adam_init", "adam_update", "train",
-           "leaf_norms"]
+from .graph import Block
+
+__all__ = ["layer_dims", "make_weights", "step_flops", "loss_and_grads",
+           "adam_init", "adam_update", "train", "leaf_norms"]
 
 
-@dataclasses.dataclass
-class Block:
-    """One sampled mini-batch of one worker: ``n_id`` maps local ids to
-    nodes (-1 pads), seeds first; ``layers`` holds, input layer first,
-    ``(src, dst, n_dst)``: an edge list in local ids (``src`` -1 on a lane
-    that holds no edge) and how many local ids are targets."""
+def layer_dims(cfg: dict) -> list[tuple[int, int]]:
+    """(in, out) width of each SAGE layer, input layer first."""
+    dims, d_in = [], int(cfg["feature_dim"])
+    for i in range(int(cfg["layers"])):
+        last = i == int(cfg["layers"]) - 1
+        d_out = int(cfg["classes"] if last else cfg["hidden"])
+        dims.append((d_in, d_out))
+        d_in = d_out
+    return dims
 
-    n_id: np.ndarray
-    layers: list
-    num_seeds: int
-    overflow: int = 0  # lanes the sampler clipped at a frontier cap
+
+def make_weights(cfg: dict, rng: np.random.Generator) -> list[dict]:
+    """Initial weights, one dict per layer (input layer first):
+    ``w_neigh`` and ``w_self`` of shape (in, out) drawn N(0, 1/in), ``b``
+    zero."""
+    layers = []
+    for d_in, d_out in layer_dims(cfg):
+        std = 1.0 / np.sqrt(d_in)
+        layers.append({
+            "w_neigh": (rng.standard_normal((d_in, d_out), dtype=np.float32)
+                        * np.float32(std)),
+            "b": np.zeros((d_out,), np.float32),
+            "w_self": (rng.standard_normal((d_in, d_out), dtype=np.float32)
+                       * np.float32(std)),
+        })
+    return layers
+
+
+def step_flops(counts: dict) -> float:
+    """Forward and backward of the SAGE layers over the valid rows, nothing
+    recomputed (``counts`` as ``work.py`` describes them). Layer ``i``
+    (input layer first) has the targets of hop ``L-1-i``: two matmuls
+    forward (neighbour mean and self), their two weight gradients, and
+    their two input gradients except at the input layer, whose inputs are
+    data; the mean adds one flop per edge and feature each way."""
+    hops = counts["hops"][::-1]  # input layer first
+    total = 0.0
+    for i, ((d_in, d_out), hop) in enumerate(zip(counts["layer_dims"], hops)):
+        matmul = 2.0 * hop["targets"] * d_in * d_out
+        total += 2 * matmul                    # forward
+        total += 2 * matmul                    # weight gradients
+        total += 2 * matmul if i else 0.0      # input gradients
+        total += 2.0 * hop["edges"] * d_in     # mean, forward and backward
+    return total
 
 
 def forward(weights, x, layers, compute_dtype):

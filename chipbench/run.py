@@ -150,7 +150,9 @@ def work_counts(cfg: dict, blocks: list, gathered_rows: float) -> dict:
         "gathered_rows": gathered_rows,
         "feature_dim": int(cfg["feature_dim"]),
         "feature_itemsize": int(np.dtype(cfg["feature_dtype"]).itemsize),
-        "layer_dims": inputs.layer_dims(cfg),
+        "model": cfg["model"],
+        "layer_dims": spec.load_model(
+            cfg["model"], "reference").layer_dims(cfg),
     }
 
 
@@ -187,7 +189,8 @@ def traced(bench: dict, workload: str, tdir: str, device: dict,
         shutil.copy(files[0], os.environ["CHIPBENCH_KEEP_TRACE"])
     shutil.rmtree(tdir, ignore_errors=True)
     wanted = spec.metrics_of(bench, workload, "per_layer")
-    files_of = {m["name"]: spec.load_metric(m["name"]) for m in wanted}
+    files_of = {m["name"]: spec.load_metric(m["name"], ctx["model_scope"])
+                for m in wanted}
     ctx = dict(ctx, trace=trace, claims=[
         f["args"]["pattern"] for f in files_of.values()
         if f["reader"] == "device_time_by_scope"])
@@ -324,7 +327,8 @@ def run(args, devices, compiled: CompileMeter, setup_start: float,
             {"steps": steps, "spans": spans.seconds, "counters": counters,
              "stages": stages, "work": work_counts(cfg, obs.blocks, rows),
              "peaks": spec.load_peaks(dev.device_kind),
-             "chips": len(devices), "workers": program.workers}))
+             "chips": len(devices), "workers": program.workers,
+             "model_scope": program.model_file.SCOPE}))
     result["device"] = device
     result["compared"] = table
     check.report(table)

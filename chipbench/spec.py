@@ -1,12 +1,15 @@
 """The benchmark's data files, found by the names in ``BENCHMARK.json``.
 
 A cell names a configuration and a traffic mix; a per-layer metric names
-itself. Each is one file under this directory, so a later PR adds a cell, a
-mix or a metric by adding files and entries and edits nothing that is here.
+itself; a configuration names its model. Each is one file under this
+directory (a model two: the program's side and the plain reference), so a
+later PR adds a cell, a mix, a metric or a model by adding files and entries
+and edits nothing that is here.
 """
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import re
@@ -15,6 +18,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+MODEL_SIDES = ("models", "reference")
+MODEL_SCOPE = "{model_scope}"  # what a `device_time_by_scope` pattern may hold
 
 
 def _load(kind: str, name: str) -> dict:
@@ -31,15 +36,59 @@ def load_benchmark() -> dict:
 
 
 def load_config(name: str) -> dict:
-    return _load("configs", name)
+    """The configuration's file. Its model and its graph's endpoint law
+    have no default: a file that lacks either key, or names a model that
+    has no files, is an error here, before anything is built."""
+    cfg = _load("configs", name)
+    if "model" not in cfg:
+        raise KeyError(
+            f"configuration {name!r} has no `model`; name a file of "
+            "chipbench/models/ and chipbench/reference/, there is no default")
+    if "endpoints" not in cfg.get("graph", {}):
+        raise KeyError(
+            f"configuration {name!r} has no `graph.endpoints`; give the law "
+            "its edges' endpoints follow (chipbench/inputs.py), there is no "
+            "default")
+    model = cfg["model"]
+    for side in MODEL_SIDES:
+        if not (NAME.match(model) and os.path.isfile(
+                os.path.join(HERE, side, model + ".py"))):
+            raise FileNotFoundError(
+                f"`model` {model!r} of configuration {name!r} has no file "
+                f"chipbench/{side}/{model}.py")
+    return cfg
+
+
+def load_model(name: str, side: str):
+    """The module of that name: ``side`` is ``models`` (the program's side:
+    the flax module, the weights' tree, ``SCOPE``) or ``reference`` (the
+    plain side: ``layer_dims``, ``make_weights``, ``train``, ``leaf_norms``,
+    ``step_flops``)."""
+    if not NAME.match(name) or side not in MODEL_SIDES:
+        raise ValueError(f"not a model the benchmark allows: {side}/{name!r}")
+    return importlib.import_module(f"{__package__}.{side}.{name}")
 
 
 def load_traffic(name: str) -> dict:
     return _load("traffic", name)
 
 
-def load_metric(name: str) -> dict:
-    return _load("metrics", name)
+def load_metric(name: str, model_scope: str | None = None) -> dict:
+    """The metric's file, ``{model_scope}`` in its pattern filled with the
+    scope that the cell's model file declares (``models/<name>.py``,
+    ``SCOPE``). Asked outside any cell, the pattern reads as it does over
+    all cells: filled with the scope of every model that a configuration
+    of ``BENCHMARK.json`` names."""
+    metric = _load("metrics", name)
+    args = metric.get("args", {})
+    if MODEL_SCOPE in args.get("pattern", ""):
+        scopes = [model_scope] if model_scope is not None else sorted({
+            load_model(load_config(c["name"])["model"], "models").SCOPE
+            for c in load_benchmark()["configs"]})
+        fill = "|".join(map(re.escape, scopes))
+        args["pattern"] = args["pattern"].replace(
+            MODEL_SCOPE, fill if len(scopes) == 1 else f"(?:{fill})")
+    return metric
 
 
 def load_peaks(device_kind: str) -> dict:

@@ -12,6 +12,7 @@ each fault a training cell can have, planted under a whole run.
 
 import argparse
 import glob
+import hashlib
 import json
 import os
 import shutil
@@ -89,28 +90,41 @@ def test_unknown_device_kind_has_no_peaks():
         spec.load_peaks("TPU v99")
 
 
-def test_a_cell_a_mix_a_metric_and_a_configuration_are_files_alone(
-        tmp_path, monkeypatch):
+ADDED = "reddit-sage-wide.hbm-long-warm"
+
+
+@pytest.fixture()
+def added(tmp_path, monkeypatch):
     """What the README's worked examples add, added in a copy: nothing
     that is there is edited, and the harness finds each by its name."""
+    import chipbench.models
+    import chipbench.reference
+
     root = tmp_path / "checkout"
     shutil.copytree(spec.HERE, root / "chipbench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     bench = json.loads(json.dumps(BENCH))
+    for side in spec.MODEL_SIDES:
+        shutil.copy(root / "chipbench" / side / "sage.py",
+                    root / "chipbench" / side / "sage2.py")
     cfg = spec.load_config("reddit-sage")
     cfg["name"], cfg["fanout"] = "reddit-sage-wide", [25, 15]
+    cfg["model"], cfg["graph"]["endpoints"] = "sage2", "degree"
     traffic = spec.load_traffic("train-hbm")
     traffic["name"], traffic["warmup_steps"] = "train-hbm-long-warm", 5
     metric = spec.load_metric("sample_device_ms")
     metric.update(name="sample_hop1_device_ms",
-                  args={"pattern": r"sample_layer_1"},
-                  workloads=["reddit-sage-wide.hbm-long-warm"])
+                  args={"pattern": r"sample_layer_1"}, workloads=[ADDED])
     for kind, item in (("configs", cfg), ("traffic", traffic),
                        ("metrics", metric)):
         with open(root / "chipbench" / kind / f"{item['name']}.json", "w") as f:
             json.dump(item, f)
+    bench["configs"].append({
+        "name": "reddit-sage-wide", "source": cfg["source"],
+        "file": "chipbench/configs/reddit-sage-wide.json",
+        "reduced": cfg["reduced"], "why": "an example"})
     bench["workloads"].append({
-        "name": "reddit-sage-wide.hbm-long-warm", "config": "reddit-sage-wide",
+        "name": ADDED, "config": "reddit-sage-wide",
         "traffic": "train-hbm-long-warm", "chips": 1, "why": "an example"})
     bench["per_layer"].append({k: metric[k] for k in (
         "name", "unit", "better", "source", "layer", "moves", "workloads")})
@@ -118,14 +132,24 @@ def test_a_cell_a_mix_a_metric_and_a_configuration_are_files_alone(
         json.dump(bench, f)
     monkeypatch.setattr(spec, "HERE", str(root / "chipbench"))
     monkeypatch.setattr(spec, "ROOT", str(root))
-    loaded = spec.load_benchmark()
-    cell = spec.cell(loaded, "reddit-sage-wide.hbm-long-warm")
+    # the copy's model files are found as a checkout's own are
+    for package in (chipbench.models, chipbench.reference):
+        side = package.__name__.rsplit(".", 1)[1]
+        monkeypatch.setattr(package, "__path__", list(package.__path__)
+                            + [str(root / "chipbench" / side)])
+    yield spec.load_benchmark()
+    for side in spec.MODEL_SIDES:
+        sys.modules.pop(f"chipbench.{side}.sage2", None)
+
+
+def test_a_cell_a_mix_a_metric_and_a_configuration_are_files_alone(added):
+    cell = spec.cell(added, ADDED)
     assert spec.load_config(cell["config"])["fanout"] == [25, 15]
     assert spec.load_traffic(cell["traffic"])["warmup_steps"] == 5
-    mine = {m["name"] for m in spec.metrics_of(loaded, cell["name"], "per_layer")}
+    mine = {m["name"] for m in spec.metrics_of(added, cell["name"], "per_layer")}
     assert "sample_hop1_device_ms" in mine and "collective_exposed_ms" not in mine
     assert "sample_hop1_device_ms" not in {
-        m["name"] for m in spec.metrics_of(loaded, "reddit-sage.hbm", "per_layer")}
+        m["name"] for m in spec.metrics_of(added, "reddit-sage.hbm", "per_layer")}
     trace = xplane.Trace([
         xplane.Op("/device:TPU:0", "XLA Ops", "fusion.1", "a/sample_layer_1/x", 0, 40),
         xplane.Op("/device:TPU:0", "XLA Ops", "fusion.2", "a/sample_layer_0/x", 50, 60),
@@ -133,6 +157,76 @@ def test_a_cell_a_mix_a_metric_and_a_configuration_are_files_alone(
     ctx = {"trace": trace, "steps": 2}
     assert readers.read(spec.load_metric("sample_hop1_device_ms"), ctx) == \
         pytest.approx(40 / 2 * 1e-6)
+
+
+def test_a_model_is_two_files_and_a_configuration_names_it(added, monkeypatch):
+    """``models/sage2.py``, ``reference/sage2.py`` and a configuration
+    that names them, on a graph whose endpoints follow the degrees: the
+    whole run, the chip left out, comes out correct through those files."""
+    import jax
+
+    monkeypatch.setattr(spec, "load_config", tiny.tiny_config)
+    args = argparse.Namespace(workload=ADDED, seed=11, seconds=0.5, trace=0)
+    result = harness.run(args, jax.devices()[:1], harness.CompileMeter(),
+                         time.perf_counter(), {})
+    assert result["correct"] is True, result["compared"]
+    for name, row in result["compared"].items():
+        assert row["limit"] is not None and row["value"] <= row["limit"], name
+    for side in spec.MODEL_SIDES:
+        module = sys.modules[f"chipbench.{side}.sage2"]
+        assert module.__file__.startswith(spec.HERE)
+    cfg = spec.load_config("reddit-sage-wide")
+    assert (cfg["model"], cfg["graph"]["endpoints"]) == ("sage2", "degree")
+
+
+@pytest.mark.parametrize("broken, named", [
+    (lambda cfg: cfg.pop("model"), "`model`"),
+    (lambda cfg: cfg["graph"].pop("endpoints"), "`graph.endpoints`"),
+    (lambda cfg: cfg.update(model="gat"), "models/gat.py"),
+    (lambda cfg: cfg["graph"].update(endpoints="zipf"), "`graph.endpoints`"),
+])
+def test_a_configuration_names_its_model_and_its_endpoint_law(
+        added, broken, named):
+    """Neither key has a default: a file without one, or with a name that
+    nothing answers to, fails with the key's name before anything is built."""
+    cfg = spec.load_config("reddit-sage-wide")
+    broken(cfg)
+    with open(os.path.join(spec.HERE, "configs", "reddit-sage-wide.json"),
+              "w") as f:
+        json.dump(cfg, f)
+    with pytest.raises((KeyError, FileNotFoundError, ValueError)) as raised:
+        inputs.make_inputs(tiny.tiny_config("reddit-sage-wide"), 1)
+    assert named in str(raised.value)
+
+
+@pytest.mark.parametrize("metric, filled", [
+    ("forward_device_ms", r"/jvp\(GraphSAGE\)"),
+    ("backward_device_ms", r"transpose\(jvp\(GraphSAGE\)\)"),
+    ("model_device_ms", r"GraphSAGE|adam|optax|apply_updates|scale_by"),
+])
+def test_model_scope_in_a_pattern_is_filled_from_the_model_file(metric, filled):
+    """The patterns name no class: the cell's model file declares the scope
+    its ops carry, which is the flax module's class name. Outside a cell
+    (``tests/test_step_scopes.py`` holds the patterns to the compiled step
+    so) the pattern is filled with every model's scope of the benchmark."""
+    cfg = spec.load_config("products-sage")
+    model = spec.load_model(cfg["model"], "models")
+    assert type(model.build(cfg)).__name__ == model.SCOPE
+    with open(os.path.join(spec.HERE, "metrics", metric + ".json")) as f:
+        assert spec.MODEL_SCOPE in json.load(f)["args"]["pattern"]
+    assert spec.load_metric(metric, model.SCOPE)["args"]["pattern"] == filled
+    assert spec.load_metric(metric)["args"]["pattern"] == filled
+    assert spec.load_metric(metric, "GAT")["args"]["pattern"] == \
+        filled.replace("GraphSAGE", "GAT")
+
+
+def test_two_models_fill_a_pattern_with_both_scopes(added, monkeypatch):
+    second = spec.load_model("sage2", "models")
+    monkeypatch.setattr(second, "SCOPE", "Graph.SAGE2")
+    assert spec.load_metric("forward_device_ms")["args"]["pattern"] == \
+        r"/jvp\((?:Graph\.SAGE2|GraphSAGE)\)"
+    assert spec.load_metric("forward_device_ms", second.SCOPE)[
+        "args"]["pattern"] == r"/jvp\(Graph\.SAGE2\)"
 
 
 # -- shapes from data, values from the seed ----------------------------------
@@ -162,6 +256,141 @@ def test_two_seeds_give_the_same_shapes_and_other_values():
     assert not np.array_equal(fa.seeds(0), fa.seeds(1))
 
 
+# digests of the parent's arrays (commit c9b5f11, before the endpoint law and
+# the model became data): `"uniform"` and `"model": "sage"` are that draw,
+# byte for byte
+GOLDEN = {
+    ("products-sage", 3): {
+        "indptr": "8db897232b37d72bfeb05ac0409d1125a11243c46b71f526cc053961b957b578",
+        "indices": "2929502b4d50bf449731128c435c57520371206a110aa8d831e410775d07e207",
+        "features": "d731f3d6ef5e6ab7960db52bf57236b3e6c8fb077f4cb67c57ae3ad53496fd9b",
+        "labels": "2319b06686ec5b1697c2e72e2613accb30e1b3a60c855f558536ae5bbd850404",
+        "weights": "dba5571f14cd6e8b1ee0687394099c958b2eb3159743045792ac8373e3245907",
+        "feed": "6bb71d629eb463131975c985fb1c2c1d0383e31057bc6ccbfe46591f9cab9973"},
+    ("products-sage", 2**31 + 5): {
+        "indptr": "e7f03a3b5ff8de69752fdfe260e8d6f34fe2aad74fbcdde825815a50d4ba0321",
+        "indices": "ac6299385047ee5be6d0d6c17a8cda00016a749a8cc77bbff33d436672be15c1",
+        "features": "0946eedac5e21437e266085858ba93aaff401b0c29451acb2622ad8fce248116",
+        "labels": "05aa8d6680de14f463295c0a8cb540e7391b7d590635664c13d0357047fbb68e",
+        "weights": "01d372591b2f50b8c33d3fb77d0b28689f29e58a2ccb93cf7ac33037970b5635",
+        "feed": "462e13ef8e24dc25b102441334b23daa09036c1b02f987009229754b46129b28"},
+    ("reddit-sage", 3): {
+        "indptr": "8db897232b37d72bfeb05ac0409d1125a11243c46b71f526cc053961b957b578",
+        "indices": "2929502b4d50bf449731128c435c57520371206a110aa8d831e410775d07e207",
+        "features": "ea49cd4ab7c3c4e293e06ae5b95bcafd3a775914ce96563e2929ad41ed2848d4",
+        "labels": "9674b9f79e11f8cc01bcf0e8c2015ffee515111bd09cf7d04f2bbd5790efe5cd",
+        "weights": "6b9b51ff80f9da6589c1bdfd0a818031dcf2f611d2d28ee7f46d0f92509be803",
+        "feed": "6bb71d629eb463131975c985fb1c2c1d0383e31057bc6ccbfe46591f9cab9973"},
+    ("reddit-sage", 2**31 + 5): {
+        "indptr": "e7f03a3b5ff8de69752fdfe260e8d6f34fe2aad74fbcdde825815a50d4ba0321",
+        "indices": "ac6299385047ee5be6d0d6c17a8cda00016a749a8cc77bbff33d436672be15c1",
+        "features": "793a2b75cf56932a203385fc910141afae1bf176a26abc81f4bff242f2216ad8",
+        "labels": "5cd46ee5ffb42eecbac05273b78e07e457f8e05a52b108af096db4c05a732d30",
+        "weights": "d36a3d5f370f50481fea168e55fa99d52942bb5fa119f6a8b786185d3cce811f",
+        "feed": "462e13ef8e24dc25b102441334b23daa09036c1b02f987009229754b46129b28"},
+}
+
+
+def sha256(*arrays) -> str:
+    return hashlib.sha256(b"".join(
+        np.ascontiguousarray(a).tobytes() for a in arrays)).hexdigest()
+
+
+@pytest.mark.parametrize("config, seed", list(GOLDEN))
+def test_inputs_are_the_bytes_they_were(config, seed):
+    cfg = tiny.tiny_config(config)
+    data = inputs.make_inputs(cfg, seed)
+    weights = inputs.make_weights(cfg, seed)
+    feed = inputs.Feed(cfg["graph"]["nodes"], cfg["batch"], seed)
+    got = {name: sha256(getattr(data, name))
+           for name in ("indptr", "indices", "features", "labels")}
+    got["weights"] = sha256(*(layer[leaf] for layer in weights
+                              for leaf in sorted(layer)))
+    got["feed"] = sha256(feed.seeds(0), feed.seeds(1), feed.key(0), feed.key(1))
+    assert got == GOLDEN[config, seed]
+
+
+def test_more_classes_than_feature_columns_share_the_bumps():
+    """ogbn-papers100M has 172 classes and 128-d rows: the label's bump
+    goes to column ``label % width``, and every label is still drawn."""
+    cfg = tiny.tiny_config("products-sage")
+    cfg.update(classes=172, feature_dim=128)
+    data = inputs.make_inputs(cfg, 3)
+    assert data.labels.max() == 171 and data.features.shape[1] == 128
+    bumped = data.features[np.arange(3000), data.labels % 128]
+    assert bumped.mean() > 2.5 and abs(data.features.mean() - 3 / 128) < 0.02
+    assert len(inputs.make_weights(cfg, 3)[-1]["b"]) == 172
+
+
+# -- the endpoint law ---------------------------------------------------------
+
+@pytest.fixture(scope="module", params=["degree", "uniform"])
+def law_graph(request):
+    """ogbn-products' degree law at 200 k nodes, under each endpoint law."""
+    cfg = spec.load_config("products-sage")
+    mean = cfg["graph"]["edges"] / cfg["graph"]["nodes"]
+    cfg["graph"].update(nodes=200_000, edges=int(200_000 * mean),
+                        endpoints=request.param)
+    return request.param, cfg, inputs.make_inputs(cfg, 7)
+
+
+def test_endpoints_follow_the_law(law_graph):
+    """The share of endpoints on the top fifth of the nodes by degree is
+    those nodes' share of the edge slots under ``"degree"``, a fifth under
+    ``"uniform"``; to sampling error."""
+    law, cfg, data = law_graph
+    nodes, edges = cfg["graph"]["nodes"], cfg["graph"]["edges"]
+    deg = np.diff(data.indptr)
+    top = np.zeros(nodes, bool)
+    top[np.argsort(-deg, kind="stable")[:nodes // 5]] = True
+    slots = deg[top].sum() / edges
+    assert 0.6 < slots < 0.75
+    want = slots if law == "degree" else 0.2
+    share = top[data.indices].mean()
+    assert abs(share - want) < 5 * (want * (1 - want) / edges) ** 0.5
+    if law == "degree":
+        received = np.bincount(data.indices, minlength=nodes)
+        assert np.corrcoef(received, deg)[0, 1] > 0.99
+
+
+def test_a_cache_of_a_fifth_of_the_rows_hits_by_the_law(law_graph):
+    """Through the program's own placement and tier merge: the hot fifth
+    by degree holds over half of a batch's distinct rows when endpoints
+    follow the degrees, and a fifth of them when they do not. Two hops of a
+    batch of 256 reach a ninth of this graph's nodes; a frontier that
+    covers more of the graph runs out of hubs (1,024 seeds reach 30 % of
+    the nodes, and the hot share is 0.49)."""
+    import jax.numpy as jnp
+    import quiver_tpu
+    from quiver_tpu.feature.feature import tiered_lookup
+
+    from chipbench import plan_caps
+
+    law, cfg, data = law_graph
+    nodes, width = data.features.shape
+    topo = quiver_tpu.CSRTopo(indptr=data.indptr, indices=data.indices)
+    feature = quiver_tpu.Feature(
+        device_cache_size=nodes // 5 * width * 4, csr_topo=topo,
+        kernel="xla").from_cpu_tensor(data.features)
+    assert feature.hot_rows == nodes // 5
+    feed = inputs.Feed(nodes, 256, 7)
+    n_id = plan_caps.frontiers(data.indptr, data.indices, feed.seeds(0),
+                               cfg["fanout"][:2], np.random.default_rng(7))[-1]
+    hot, cold = jnp.asarray(feature.hot), jnp.asarray(np.asarray(feature.cold))
+    rows, hits = tiered_lookup(
+        jnp.asarray(n_id, jnp.int32), feature.feature_order, feature.hot_rows,
+        lambda ids: hot[ids], lambda ids: cold[ids], with_hits=True)
+    assert np.array_equal(np.asarray(rows), data.features[n_id])
+    rep, in_hot, in_cold = (int(h) for h in hits)
+    assert rep == 0 and in_hot + in_cold == n_id.shape[0]
+    share = in_hot / n_id.shape[0]
+    print(f"{law}: {n_id.shape[0]} distinct rows, hot share {share:.3f}")
+    if law == "degree":
+        assert share > 0.5
+    else:
+        assert abs(share - 0.2) < 0.02
+
+
 # -- the counting functions --------------------------------------------------
 
 def test_work_counts_against_hand_worked_values():
@@ -172,7 +401,7 @@ def test_work_counts_against_hand_worked_values():
             {"fanout": 2, "targets": 10, "edges": 18, "unique": 21},
         ],
         "gathered_rows": 21, "feature_dim": 8, "feature_itemsize": 4,
-        "layer_dims": [(8, 16), (16, 5)],
+        "model": "sage", "layer_dims": [(8, 16), (16, 5)],
     }
     assert work.sample_bytes(counts) == 4 * (8 + 24) + 10 * (8 + 16)
     assert work.reindex_bytes(counts) == \
@@ -386,9 +615,8 @@ def test_the_control_in_bfloat16_comes_out_not_correct(seed):
     fails at least one number; computed in float32 it passes all."""
     import jax.numpy as jnp
 
-    from chipbench.reference import sage
-
     cfg = tiny.tiny_config("reddit-sage")
+    sage = spec.load_model(cfg["model"], "reference")
     data = inputs.make_inputs(cfg, seed)
     weights0 = inputs.make_weights(cfg, seed)
     feed = inputs.Feed(cfg["graph"]["nodes"], cfg["batch"], seed)
@@ -400,7 +628,7 @@ def test_the_control_in_bfloat16_comes_out_not_correct(seed):
     for dtype, passes in ((jnp.float32, True), (jnp.bfloat16, False)):
         side = sage.train(weights0, feats, labels, steps, cfg["optimizer"],
                           param_dtype=dtype, compute_dtype=dtype)
-        values = check.numbers(side[0], side[1], side[2], *ref, weights0)
+        values = check.numbers(sage, *side, *ref, weights0)
         ok, table = check.verdict(values, tiny.LIMITS)
         assert ok is passes, table
 
@@ -408,7 +636,7 @@ def test_the_control_in_bfloat16_comes_out_not_correct(seed):
 def host_block(data, seeds, fanout, rng):
     """A sampled block made with numpy alone, for tests that need no
     program: ``min(deg, k)`` neighbours of every target."""
-    from chipbench.reference.sage import Block
+    from chipbench.reference.graph import Block
 
     n_id = list(dict.fromkeys(int(s) for s in seeds))
     local = {n: i for i, n in enumerate(n_id)}

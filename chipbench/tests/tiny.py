@@ -11,8 +11,8 @@ LIMITS = {"block_faults": 0, "block_overflow": 0, "nonfinite_losses": 0,
 
 def shrink(cfg: dict) -> dict:
     cfg = dict(cfg)
-    cfg["graph"] = {"nodes": 3000, "edges": 60000, "degree_alpha": 2.0,
-                    "max_degree": 400}
+    cfg["graph"] = dict(cfg["graph"], nodes=3000, edges=60000,
+                        degree_alpha=2.0, max_degree=400)
     cfg["batch"] = 64
     cfg["frontier_caps"] = [3072] * len(cfg["fanout"])
     cfg["limits"] = dict(LIMITS)

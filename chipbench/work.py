@@ -13,10 +13,14 @@ low; it cannot pass 100 % unless the time leaves out part of the work.
   ``unique`` (distinct nodes of the frontier after the hop, ``U``);
 * ``gathered_rows``: valid feature rows gathered; ``feature_dim``,
   ``feature_itemsize``;
-* ``layer_dims``: ``(in, out)`` of each SAGE layer, input layer first.
+* ``model`` and ``layer_dims``: the configuration's model and the
+  ``(in, out)`` of each of its layers, input layer first; the step's
+  operations are the model's to count (``reference/<model>.py``).
 """
 
 from __future__ import annotations
+
+from . import spec
 
 __all__ = ["sample_bytes", "reindex_bytes", "gather_bytes", "step_flops",
            "WORK"]
@@ -44,21 +48,9 @@ def gather_bytes(counts: dict) -> float:
 
 
 def step_flops(counts: dict) -> float:
-    """Forward and backward of the SAGE layers over the valid rows, nothing
-    recomputed. Layer ``i`` (input layer first) has the targets of hop
-    ``L-1-i``: two matmuls forward (neighbour mean and self), their two
-    weight gradients, and their two input gradients except at the input
-    layer, whose inputs are data; the mean adds one flop per edge and
-    feature each way."""
-    hops = counts["hops"][::-1]  # input layer first
-    total = 0.0
-    for i, ((d_in, d_out), hop) in enumerate(zip(counts["layer_dims"], hops)):
-        matmul = 2.0 * hop["targets"] * d_in * d_out
-        total += 2 * matmul                    # forward
-        total += 2 * matmul                    # weight gradients
-        total += 2 * matmul if i else 0.0      # input gradients
-        total += 2.0 * hop["edges"] * d_in     # mean, forward and backward
-    return total
+    """Forward and backward of the model over the valid rows, nothing
+    recomputed, as the model's plain side counts them."""
+    return spec.load_model(counts["model"], "reference").step_flops(counts)
 
 
 WORK = {
