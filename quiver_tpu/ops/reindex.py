@@ -58,13 +58,14 @@ def resolve_dedup(dedup: str) -> str:
 
     * **cpu** -> ``"map"`` — on XLA's CPU backend the dense scatter-min
       map ran 4-5x the sort path at both smoke and full products scale.
-    * **tpu** -> ``"scan"`` — the zero-scatter strategy. What a v5e has
-      timed so far (PERF.md, PR 26) is its compaction: at ogbn-products'
-      deepest hop (852,480 lanes) the payload-carrying sort it uses costs
-      0.9 ms and the scatter of the other two strategies 3.9 ms, so a
-      scatter with unique indices is NOT serialized there, only slower.
-      The three whole strategies have not been run against each other on
-      a cell: provisional until ROADMAP S9 does.
+    * **tpu** -> ``"scan"`` — the zero-scatter, zero-gather strategy:
+      every value travels as the payload of a sort. On a v5e at
+      ogbn-products' deepest hop (852,480 lanes) a whole ``masked_unique``
+      costs 4.8 ms under ``"scan"``, 24.6 under ``"sort"`` and 21.3 under
+      ``"map"`` (one probe, PERF.md, PR 29): there a T-lane 4-byte gather
+      costs 6.4 ms, a scatter with unique indices 3.9 (PR 26) and a
+      payload-carrying sort 1.0 - 1.5. Only ``"scan"`` has run in a cell;
+      ROADMAP S9 / D1 settle what becomes of the other two.
 
     ``QUIVER_DEDUP=sort|map|scan`` overrides the ``"auto"`` resolution
     ONLY: call sites passing an explicit strategy
@@ -112,8 +113,8 @@ def inverse_permutation(p):
 
 def inverse_permutation_gather(p):
     """The zero-scatter sibling of :func:`inverse_permutation`: argsort of
-    a permutation IS its inverse. Costs a sort instead of a scatter
-    (shared by the dedup scan strategy and the routed feature gather)."""
+    a permutation IS its inverse. Costs a sort instead of a scatter (the
+    routed feature gather un-buckets through it)."""
     return jnp.argsort(p).astype(jnp.int32)
 
 
@@ -167,17 +168,12 @@ def masked_unique(ids, valid, size: int, num_forced: int = 0,
         node_bound from the id space that produced ``ids`` (the samplers
         pass topo.node_count; neighbor ids are CSR entries < node_count by
         construction).
-      scatter_free: use the ZERO-SCATTER strategy (``dedup="scan"``): two
-        sorts + a cumulative max + gathers find the first occurrences, and
-        ONE more sort packs them: keyed by position on the representatives
-        and by ``T`` elsewhere, it carries the ids along, so the frontier
-        comes out in first-occurrence order with no ``.at[].set/min``
-        anywhere — the other two strategies compact with a scatter. On a
-        v5e at ogbn-products' deepest hop (T = 852,480, ``size`` 672,384)
-        that sort costs 0.9 ms, the scatter 3.9 ms, and the binary search
-        per output slot it replaced (twenty rounds of dependent gathers)
-        88 ms (PERF.md, PR 26). Same contract; pick by measurement
-        (ignored when ``node_bound`` is given).
+      scatter_free: use the ZERO-SCATTER strategy (``dedup="scan"``), which
+        is zero-gather too: every value travels as the payload of a sort
+        (:func:`_unique_by_sorts`), where the other two strategies gather
+        through the sort order and compact with a scatter. Same contract;
+        pick by measurement (:func:`resolve_dedup` has a v5e's; ignored
+        when ``node_bound`` is given).
 
     Returns:
       uniq: (size,) unique ids in first-occurrence order, -1 padded.
@@ -188,45 +184,48 @@ def masked_unique(ids, valid, size: int, num_forced: int = 0,
     """
     T = ids.shape[0]
     pos = jnp.arange(T, dtype=jnp.int32)
+    if scatter_free and node_bound is None:
+        return _unique_by_sorts(ids, valid, pos, size, num_forced)
 
     # the three phases are scopes of every strategy, so that a device
     # trace splits the reindex the same way whichever one ran
     with trace_scope("dedup"):
-        rep_pos = _first_occurrence(ids, valid, pos, node_bound, scatter_free)
+        rep_pos = _first_occurrence(ids, valid, pos, node_bound)
 
     with trace_scope("compact"):
         forced = (pos < num_forced) & valid
         is_rep = (valid & (rep_pos == pos)) | forced
         rank = jnp.cumsum(is_rep.astype(jnp.int32)) - 1  # first-occurrence rank
         num_unique = jnp.sum(is_rep.astype(jnp.int32))
-
-        if scatter_free and node_bound is None:
-            # compaction as ONE sort that carries the ids: a rep's key is
-            # its position (distinct, ascending in first-occurrence order),
-            # every other lane's is T, so the reps come out packed in front
-            # and the rest is masked. No gather, no scatter, no loop; the
-            # (size,) write is a contiguous slice update.
-            m = min(size, T)
-            _, packed = lax.sort(
-                (jnp.where(is_rep, pos, T), ids), num_keys=1, is_stable=False
-            )
-            packed = jnp.where(jnp.arange(m) < num_unique, packed[:m], -1)
-            uniq = jnp.full(size, -1, ids.dtype).at[:m].set(packed)
-        else:
-            uniq = (
-                jnp.full(size, -1, ids.dtype)
-                .at[jnp.where(is_rep & (rank < size), rank, size)]
-                .set(ids, mode="drop")
-            )
+        uniq = (
+            jnp.full(size, -1, ids.dtype)
+            .at[jnp.where(is_rep & (rank < size), rank, size)]
+            .set(ids, mode="drop")
+        )
     with trace_scope("relabel"):
         local = rank[rep_pos]
         local = jnp.where(valid & (local < size), local, -1)
     return uniq, num_unique, local
 
 
-def _first_occurrence(ids, valid, pos, node_bound, scatter_free):
+def _sorted_view(ids, valid, pos):
+    """Value sort that carries the positions (``pv``: the sort order
+    itself) and its run starts, sentinel run excluded. The position is the
+    second key, so positions within a run ascend: a run's first sorted
+    element IS the value's first occurrence."""
+    sent = jnp.iinfo(ids.dtype).max
+    sv, pv = lax.sort(
+        (jnp.where(valid, ids, sent), pos), num_keys=2, is_stable=False
+    )
+    live = sv != sent
+    first = jnp.concatenate([jnp.ones(1, bool), sv[1:] != sv[:-1]]) & live
+    return sv, pv, first, live
+
+
+def _first_occurrence(ids, valid, pos, node_bound):
     """(T,) position of the first occurrence of each lane's id — the
-    ``dedup`` phase of :func:`masked_unique`, one branch per strategy."""
+    ``dedup`` phase of the ``"map"`` (``node_bound``) and ``"sort"``
+    strategies of :func:`masked_unique`."""
     T = ids.shape[0]
     if node_bound is not None:
         safe = jnp.where(valid, ids, 0)
@@ -236,30 +235,7 @@ def _first_occurrence(ids, valid, pos, node_bound, scatter_free):
             .min(jnp.where(valid, pos, T), mode="drop")
         )
         return first_pos[safe]
-    # shared sorted view: stable value sort, run starts (sentinel run
-    # excluded); positions within a run ascend, so a run's first sorted
-    # element IS the value's first occurrence
-    sent = jnp.iinfo(ids.dtype).max
-    vals = jnp.where(valid, ids, sent)
-    order = jnp.argsort(vals, stable=True)
-    sv = vals[order]
-    pv = pos[order]
-    first = jnp.concatenate(
-        [jnp.ones(1, bool), sv[1:] != sv[:-1]]
-    ) & (sv != sent)
-
-    if scatter_free:
-        # sorted-view index of the current run's first element: a running
-        # max over first-markers (the scatter-free run-representative)
-        idx_first = lax.cummax(
-            jnp.where(first, jnp.arange(T, dtype=jnp.int32), -1)
-        )
-        rep_pos_sorted = jnp.where(
-            idx_first >= 0, pv[jnp.clip(idx_first, 0)], T
-        )
-        # back to original positions via the inverse permutation, built by
-        # sorting the permutation instead of scattering into it
-        return rep_pos_sorted[inverse_permutation_gather(order)]
+    _, pv, first, _ = _sorted_view(ids, valid, pos)
     run_id = jnp.cumsum(first.astype(jnp.int32)) - 1
     # representative position scattered per run
     by_run = (
@@ -269,7 +245,62 @@ def _first_occurrence(ids, valid, pos, node_bound, scatter_free):
     )
     rep_pos_sorted = by_run[jnp.clip(run_id, 0)]
     # back to original positions
-    return jnp.zeros(T, jnp.int32).at[order].set(rep_pos_sorted)
+    return jnp.zeros(T, jnp.int32).at[pv].set(rep_pos_sorted)
+
+
+def _spread_bits(T: int, size: int) -> tuple[int, int]:
+    """``(bits, passes)`` of :func:`_unique_by_sorts`'s run broadcast. A
+    pass packs a lane index (< T) above ``bits`` bits of a local id (<=
+    ``size`` and < T) into a non-negative int32: ``bits`` is what 31
+    leaves, ``passes`` the chunks a local id needs — static in the shapes
+    (1 or 2 at every hop of the benchmark's cells)."""
+    bits = 31 - (T - 1).bit_length()
+    if bits < 1:
+        raise ValueError(f"masked_unique: {T} lanes leave no bit to carry")
+    return bits, max(1, -(-min(size, T - 1).bit_length() // bits))
+
+
+def _unique_by_sorts(ids, valid, pos, size, num_forced):
+    """:func:`masked_unique` for ``dedup="scan"``: four sorts, no scatter
+    and no T-lane gather — what would travel through ``x[perm]`` is a
+    sort's payload, and a run's first lane reaches the whole run as a
+    running max."""
+    T = ids.shape[0]
+    with trace_scope("dedup"):
+        sv, pv, first, live = _sorted_view(ids, valid, pos)
+        is_rep = (first | (pv < num_forced)) & live
+        num_unique = jnp.sum(is_rep.astype(jnp.int32))
+    with trace_scope("compact"):
+        # ONE sort packs the reps in front in first-occurrence order (a
+        # rep's key is its position, every other lane's lies above them
+        # all; all distinct) and carries the ids and each one's sorted-view
+        # lane: a rep's output index IS its local id. The (size,) write is
+        # a contiguous slice update.
+        m = min(size, T)
+        _, packed, lane = lax.sort(
+            (jnp.where(is_rep, pv, T + pv), sv, pos), num_keys=1,
+            is_stable=False,
+        )
+        packed = jnp.where(pos[:m] < num_unique, packed[:m], -1)
+        uniq = jnp.full(size, -1, ids.dtype).at[:m].set(packed)
+    with trace_scope("relabel"):
+        # local ids back at their sorted-view lanes (``lane`` is a
+        # permutation); clipped, overflow stays overflow in fewer bits
+        _, local = lax.sort((lane, pos), num_keys=1, is_stable=False)
+        local = jnp.minimum(local, size)
+        # spread each run's first over the run: running max of (lane index
+        # | chunk of the id), the lane index rising along the view
+        bits, passes = _spread_bits(T, size)
+        mask = (1 << bits) - 1
+        spread = jnp.zeros(T, jnp.int32)
+        for shift in range(0, bits * passes, bits):
+            word = (pos << bits) | ((local >> shift) & mask)
+            word = lax.cummax(jnp.where(first, word, 0))
+            spread = spread | ((word & mask) << shift)
+        # back to lane order: ``pv`` is a permutation too
+        _, local = lax.sort((pv, spread), num_keys=1, is_stable=False)
+        local = jnp.where(valid & (local < size), local, -1)
+    return uniq, num_unique, local
 
 
 def reindex_layer(seeds, num_seeds, neighbors, frontier_cap: int,
@@ -287,7 +318,7 @@ def reindex_layer(seeds, num_seeds, neighbors, frontier_cap: int,
       frontier_cap: static capacity of the output frontier.
       node_bound: optional static id upper bound enabling the sort-free
         scatter-min dedup (see masked_unique).
-      scatter_free: the zero-scatter sort/scan/gather strategy
+      scatter_free: the zero-scatter, zero-gather payload-sort strategy
         (see masked_unique; ignored when node_bound is given).
 
     Returns:

@@ -8,7 +8,8 @@ import pytest
 import numpy as np
 import jax.numpy as jnp
 
-from quiver_tpu.ops.reindex import masked_unique, reindex_layer
+from quiver_tpu.ops.reindex import (
+    _spread_bits, masked_unique, reindex_layer)
 from quiver_tpu.ops.cpu_ref import reindex_layer_ref
 
 
@@ -136,7 +137,22 @@ def _masked_unique_oracle(ids, valid, size, forced):
     return (uniq + [-1] * size)[:size], len(uniq), local
 
 
-# the cases the binary search handled implicitly: (ids, valid, size, forced)
+def _random_case(t, forced, size, bound, seed):
+    """Random lanes over ``bound`` ids, a fifth invalid past the forced
+    prefix (whose lanes are distinct: ops/cpu_ref.py then speaks too)."""
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, bound, t)
+    ids[:forced] = rng.choice(bound, forced, replace=False)
+    valid = rng.random(t) < 0.8
+    valid[:forced] = True
+    return ids, valid, size, forced
+
+
+_TOP = np.iinfo(np.int32).max - 1
+
+# (ids, valid, size, forced), or a function that makes them; the number of
+# broadcast passes of dedup="scan" follows from (T, size) alone, so the
+# shapes below force 1, 2 and 3 of them
 _COMPACT_CASES = {
     "all_invalid": ([5, 5, 2, 9], [0, 0, 0, 0], 3, 2),
     "size_over_T": ([5, 3, 5, 7], [1, 1, 1, 1], 9, 1),
@@ -145,19 +161,36 @@ _COMPACT_CASES = {
                           [1, 1, 1, 1, 1, 1, 0, 1], 8, 3),
     "single_lane": ([6], [1], 2, 1),
     "all_duplicates_of_lane_0": ([4, 4, 4, 4, 4, 4], [1, 1, 1, 1, 1, 1], 4, 1),
+    "every_lane_one_value_some_invalid": ([9] * 7, [1, 0, 1, 1, 0, 1, 1], 7, 2),
+    "ids_at_the_top": ([_TOP, 5, _TOP, _TOP - 1, 5, 0, _TOP - 1, _TOP],
+                       [1, 1, 1, 1, 1, 1, 0, 1], 8, 1),
+    "overflow_into_the_forced": ([3, 1, 2, 1, 3, 4], [1, 1, 1, 1, 1, 1], 2, 3),
+    "T_one_below_a_power_of_two": lambda: _random_case(63, 5, 40, 30, 63),
+    "T_a_power_of_two": lambda: _random_case(64, 5, 64, 30, 64),
+    "T_one_above_a_power_of_two": lambda: _random_case(65, 5, 70, 30, 65),
+    "one_pass": lambda: _random_case(5_000, 16, 3_000, 4_000, 1),
+    "two_passes": lambda: _random_case(70_000, 64, 40_000, 90_000, 2),
+    "three_passes": lambda: _random_case(
+        1_600_000, 512, 1_200_000, 6_000_000, 3),
 }
+_PASSES = {"one_pass": 1, "two_passes": 2, "three_passes": 3}
 
 
 @pytest.mark.parametrize("fn", ["masked_unique", "reindex_layer"])
 @pytest.mark.parametrize("case", list(_COMPACT_CASES))
 def test_scan_compaction_edge_cases(case, fn):
-    """The sort compaction of dedup="scan" against dedup="sort", the
-    plain-Python contract and ops/cpu_ref.py."""
-    ids, valid, size, forced = _COMPACT_CASES[case]
+    """The payload sorts of dedup="scan" against dedup="sort", dedup="map",
+    the plain-Python contract and ops/cpu_ref.py."""
+    made = _COMPACT_CASES[case]
+    ids, valid, size, forced = made() if callable(made) else made
     ids = np.asarray(ids, np.int32)
     valid = np.asarray(valid, bool)
     want_uniq, want_n, want_local = _masked_unique_oracle(
         ids.tolist(), valid.tolist(), size, forced)
+    if case in _PASSES:  # that many passes, and the last carries something
+        bits, passes = _spread_bits(len(ids), size)
+        assert passes == _PASSES[case]
+        assert max(want_local) >> (bits * (passes - 1)) > 0
     lanes = np.where(valid, ids, -1)
     # the hash-map reference dedups its seeds, so it speaks for the cases
     # whose forced lanes hold no duplicate
@@ -168,10 +201,14 @@ def test_scan_compaction_edge_cases(case, fn):
         assert ref_frontier.tolist()[:size] == want_uniq[:want_n][:size]
         ref_col = np.where(ref_col < size, ref_col, -1)
         assert ref_col[0].tolist() == want_local[forced:]
+    # the dense map of dedup="map" is as long as its id space
+    bound = int(ids.max()) + 1
+    others = [{}] + ([{"node_bound": bound}] if bound < 1 << 24 else [])
     if fn == "masked_unique":
         args = (jnp.asarray(ids), jnp.asarray(valid), size)
         scan = masked_unique(*args, num_forced=forced, scatter_free=True)
-        sort = masked_unique(*args, num_forced=forced)
+        others = [masked_unique(*args, num_forced=forced, **kw)
+                  for kw in others]
         want = (want_uniq, want_n, want_local)
     else:
         # seeds = the forced lanes (a valid prefix), one neighbour row each
@@ -181,14 +218,40 @@ def test_scan_compaction_edge_cases(case, fn):
         args = (jnp.asarray(lanes[:forced]), jnp.int32(valid[:forced].sum()),
                 jnp.asarray(nbr.reshape(forced, k)), size)
         scan = reindex_layer(*args, scatter_free=True)
-        sort = reindex_layer(*args)
+        others = [reindex_layer(*args, **kw) for kw in others]
         col = np.full(forced * k, -1)
         col[:len(ids) - forced] = want_local[forced:]
         want = (want_uniq, min(want_n, size), col.reshape(forced, k),
                 max(want_n - size, 0))
-    for got, other, expect in zip(scan, sort, want):
-        assert np.array_equal(np.asarray(got), np.asarray(other)), case
+    for other in others:
+        for got, theirs in zip(scan, other):
+            assert np.array_equal(np.asarray(got), np.asarray(theirs)), case
+    for got, expect in zip(scan, want):
         assert np.array_equal(np.asarray(got), np.asarray(expect)), case
+
+
+@pytest.mark.parametrize("T", [1, 2, 3, 63, 64, 65, 16_384, 178_816, 852_480,
+                               26_624, 332_288, 1 << 20, (1 << 20) + 1,
+                               1 << 29, 1 << 30])
+def test_the_packed_word_never_passes_31_bits(T):
+    """(bits, passes) of the run broadcast: the largest lane index shifted
+    over a full chunk stays a non-negative int32, and the chunks together
+    hold every local id up to ``size`` (and no more than T - 1)."""
+    for size in (0, 1, T // 2, T - 1, T, T + 1, 4 * T):
+        bits, passes = _spread_bits(T, size)
+        assert bits >= 1 and passes >= 1
+        assert ((T - 1) << bits) | ((1 << bits) - 1) < 1 << 31
+        assert min(size, T - 1) < 1 << (bits * passes)
+        assert passes == 1 or min(size, T - 1) >= 1 << (bits * (passes - 1))
+    with pytest.raises(ValueError, match="lanes"):
+        _spread_bits((1 << 30) + 1, 8)
+
+
+def test_the_cells_hops_take_one_or_two_passes():
+    """The pinned shapes of the benchmark's configurations (T, cap)."""
+    hops = {(16_384, 16_256): 1, (178_816, 142_080): 2, (852_480, 672_384): 2,
+            (26_624, 30_208): 1, (332_288, 195_328): 2}
+    assert {k: _spread_bits(*k)[1] for k in hops} == hops
 
 
 def test_sampler_dedup_alternatives_match_sort():

@@ -187,6 +187,25 @@ def test_the_compaction_has_no_loop_and_keeps_its_scope(programs, cell):
 
 
 @pytest.mark.parametrize("cell", list(CELLS))
+def test_the_scan_reindex_sorts_in_every_phase_and_never_gathers(
+        programs, cell):
+    """``dedup="scan"`` carries every value as the payload of a sort: a
+    ``sort`` under each of the three phases at every hop, and no gather
+    (an op, or a fusion named for its gather) under ``dedup`` or
+    ``relabel``, where the T-lane gathers of the device trace were."""
+    phase = re.compile(r"^reindex_layer_(\d+)/(?:.*/)?(dedup|compact|relabel)/")
+    inside = [(op, path, int(m.group(1)), m.group(2))
+              for op, path in programs[cell] if (m := phase.match(path))]
+    gathers = [path for op, path, _, name in inside if name != "compact"
+               and (op == "gather" or path.endswith("/gather"))]
+    assert not gathers, gathers[:5]
+    hops = range(len(CELLS[cell]["fanout"]))
+    assert {(hop, name) for op, _, hop, name in inside if op == "sort"} == {
+        (hop, name) for hop in hops
+        for name in ("dedup", "compact", "relabel")}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
 def test_every_instruction_lies_under_one_top_level_scope(programs, cell):
     stray = sorted({(op, path) for op, path in programs[cell]
                     if op not in TRIVIAL
