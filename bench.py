@@ -12,7 +12,7 @@ reference's 34.29M 1-GPU UVA SEPS (docs/Introduction_en.md:41).
 
 import sys
 
-HEADLINE_ARGS = ["--stream", "128", "--dedup", "both"]
+HEADLINE_ARGS = ["--stream", "128"]
 
 
 def main() -> int:

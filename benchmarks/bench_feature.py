@@ -38,12 +38,6 @@ def main():
     p.add_argument("--policy", default="replicate", choices=["replicate", "shard"])
     p.add_argument("--gather-batch", type=int, default=65536)
     p.add_argument(
-        "--kernel",
-        default="auto",
-        choices=["auto", "pallas", "xla"],
-        help="hot-tier gather kernel (auto = pallas on TPU, xla elsewhere)",
-    )
-    p.add_argument(
         "--routed", action="store_true",
         help="shard policy: owner-routed all_to_all hot-tier gather (ids "
         "sharded over every mesh axis) instead of the psum flavor — the "
@@ -152,14 +146,14 @@ def _body(args):
                                csr_topo=topo, dtype=dtype)
         log(f"raw feature dir written in {time.time()-t0:.1f}s: {raw_dir}")
         store = MmapFeatureStore(
-            raw_dir, kernel=args.kernel, access=args.store,
+            raw_dir, access=args.store,
             window_rows=args.ooc_window,
             cache_windows=args.ooc_cache_windows,
         )
     elif args.policy == "replicate":
         store = Feature(
-            device_cache_size=budget, csr_topo=topo, kernel=args.kernel,
-            dtype=dtype, replicate_budget=args.replicate_budget,
+            device_cache_size=budget, csr_topo=topo, dtype=dtype,
+            replicate_budget=args.replicate_budget,
         ).from_cpu_tensor(feat)
     else:
         mesh = make_mesh(feature=len(jax.devices()))
@@ -167,7 +161,6 @@ def _body(args):
             mesh,
             device_cache_size=budget // len(jax.devices()),
             csr_topo=topo,
-            kernel=args.kernel,
             dtype=dtype,
             routed_alpha=args.routed_alpha or 2.0,
             replicate_budget=args.replicate_budget,
@@ -252,7 +245,6 @@ def _body(args):
         "GB/s",
         BASELINE_GBPS,
         policy=args.policy,
-        kernel=store.kernel,
         dtype=args.dtype,
         cache_ratio=round(store.cache_ratio, 3),
         gather_batch=args.gather_batch,
@@ -518,7 +510,6 @@ def _stream_gbps(args, store, batches, stored_itemsize, row_overhead,
         "GB/s",
         BASELINE_GBPS,
         policy=args.policy,
-        kernel=store.kernel,
         dtype=args.dtype,
         cache_ratio=round(store.cache_ratio, 3),
         gather_batch=args.gather_batch,

@@ -38,23 +38,11 @@ def main():
         "--kernel",
         default="xla",
         choices=["xla", "pallas", "fused", "auto"],
-        help="sampling kernel: exact XLA stratified sampler, the fused "
-        "Pallas megakernel ('pallas' and 'fused' are the same engine — "
-        "one windowed-DMA kernel behind every variant, weighted and "
-        "sharded included; 'fused' names the scoreboard lane), or 'auto' "
-        "(measured election, QUIVER_SAMPLE_KERNEL overrides)",
-    )
-    p.add_argument(
-        "--dedup",
-        default="sort",
-        choices=["sort", "map", "scan", "both"],
-        help="reindex dedup strategy: stable-sort run-scan, the sort-free "
-        "dense-map scatter-min (reference hash-table analogue), or the "
-        "zero-scatter sort/cummax/gather 'scan'. 'both' (stream mode) "
-        "measures ALL strategies in one process — sharing the device "
-        "topology and the planned caps — and emits the faster stream "
-        "record FIRST, so the headline self-selects the winning strategy "
-        "on whatever backend it runs on",
+        help="sampling kernel: exact XLA stratified sampler ('auto' means "
+        "the same) or the fused Pallas megakernel ('pallas' and 'fused' "
+        "are the same engine — one windowed-DMA kernel behind every "
+        "variant, weighted and sharded included; 'fused' names the "
+        "scoreboard lane)",
     )
     p.add_argument(
         "--weighted", action="store_true",
@@ -189,17 +177,8 @@ def _stage_profile(args, sampler, topo, reps: int = 30):
                 )
             )
         (nbr, counts), t_sample = timed(f_sample, sampler.topo, cur, cur_n, sub)
-        # honor the sampler's dedup strategy (same node_bound rule as
-        # multilayer_sample) so stage attribution matches the headline
-        nb_bound = (
-            int(sampler.topo.indptr.shape[0]) - 1
-            if sampler.dedup == "map" else None
-        )
         f_reindex = jax.jit(
-            lambda c, n, nb, fc=caps[l]: reindex_layer(
-                c, n, nb, fc, node_bound=nb_bound,
-                scatter_free=(sampler.dedup == "scan"),
-            )
+            lambda c, n, nb, fc=caps[l]: reindex_layer(c, n, nb, fc)
         )
         (frontier, n_frontier, _, _), t_reindex = timed(
             f_reindex, cur, cur_n, nbr
@@ -222,7 +201,6 @@ def _stage_profile(args, sampler, topo, reps: int = 30):
             None,
             layer=l,
             stage="reindex",
-            dedup=sampler.dedup,
             frontier_cap=int(caps[l]),
         )
         cur, cur_n = frontier, n_frontier
@@ -237,85 +215,43 @@ def _stream_seps(args, sampler, topo, reps: int = 3):
     reshape/stack assembly is dead code. Timed wall includes the seed
     matrix H2D and the scalar readback. Valid edges only (BASELINE.md
     honesty rule).
-
-    ``--dedup both``: extra samplers measure the dense-map and zero-scatter
-    scan strategies in the same process (sharing the device topology and
-    the already-planned caps); records are emitted fastest-first so the
-    first SEPS record — the headline — is the winner on this backend.
     """
-    from quiver_tpu import GraphSageSampler
-
     cap = sampler._seed_capacity  # _body always sets seed_capacity=batch
-
-    candidates = [(sampler.dedup, sampler)]
-    if args.dedup == "both":
-        for dedup in ("map", "scan"):
-            other = GraphSageSampler(
-                topo, args.fanout, mode=args.mode, seed_capacity=cap,
-                seed=args.seed, kernel=sampler.kernel, dedup=dedup,
-                weighted=sampler.weighted,
-                frontier_caps=(
-                    tuple(sampler._frontier_caps)
-                    if sampler._frontier_caps is not None else None
-                ),
-                device_topo=sampler.topo,
-            )
-            candidates.append((dedup, other))
-
-    results = []
-    for dedup, s in candidates:
-        # identical seed stream per candidate (a fresh rng from the same
-        # seed): the winner must be decided by strategy, not draw variance
-        rng = np.random.default_rng(args.seed + 13)
-        try:
-            res = stream_seps(s, topo.node_count, cap, args.stream, rng, reps)
-        except Exception as e:  # noqa: BLE001 — one candidate must not
-            # discard the other's measurement
-            log(f"stream candidate dedup={dedup} failed: "
-                f"{type(e).__name__}: {str(e)[:200]}")
-            continue
-        if res is not None:
-            results.append((res[0], dedup, res))
-    winner = None
-    for seps, dedup, (_, oflo, stream) in sorted(results, reverse=True):
-        # roofline sanity: how far from the chip's HBM ceiling this number
-        # is, not just how far from a 2021 GPU's (VERDICT r3 item 2)
-        extra = {}
-        try:
-            s_cand = next(s for d, s in candidates if d == dedup)
-            rl = sampler_roofline(s_cand, args.batch, dedup)
-            if rl is not None:
-                extra = {
-                    "roofline_ceiling_seps": round(rl[1]),
-                    "roofline_frac": round(seps / rl[1], 3),
-                    "roofline_model": "hbm-traffic lower bound "
-                    f"({rl[0] / 1e6:.0f} MB/batch @ "
-                    f"{hbm_bandwidth_gbps():g} GB/s)",
-                }
-        except Exception as e:  # noqa: BLE001 — analytics must not cost a record
-            log(f"roofline estimate failed: {type(e).__name__}: {str(e)[:120]}")
-        emit(
-            "sampled-edges/sec/chip",
-            seps,
-            "SEPS",
-            BASELINE_UVA_SEPS,
-            mode=args.mode,
-            kernel=args.kernel,
-            fanout=args.fanout,
-            batch=args.batch,
-            caps=args.caps,
-            dedup=dedup,
-            weighted=getattr(args, "weighted", False),
-            dispatch="stream",
-            stream_batches=stream,
-            overflow=oflo,
-            **extra,
-        )
-        if winner is None:
-            winner = dedup
-    # the stage profile should attribute the HEADLINE strategy
-    return next(
-        (s for d, s in candidates if d == winner), sampler
+    rng = np.random.default_rng(args.seed + 13)
+    res = stream_seps(sampler, topo.node_count, cap, args.stream, rng, reps)
+    if res is None:
+        return
+    seps, oflo, stream = res
+    # roofline sanity: how far from the chip's HBM ceiling this number
+    # is, not just how far from a 2021 GPU's (VERDICT r3 item 2)
+    extra = {}
+    try:
+        rl = sampler_roofline(sampler, args.batch)
+        if rl is not None:
+            extra = {
+                "roofline_ceiling_seps": round(rl[1]),
+                "roofline_frac": round(seps / rl[1], 3),
+                "roofline_model": "hbm-traffic lower bound "
+                f"({rl[0] / 1e6:.0f} MB/batch @ "
+                f"{hbm_bandwidth_gbps():g} GB/s)",
+            }
+    except Exception as e:  # noqa: BLE001 — analytics must not cost a record
+        log(f"roofline estimate failed: {type(e).__name__}: {str(e)[:120]}")
+    emit(
+        "sampled-edges/sec/chip",
+        seps,
+        "SEPS",
+        BASELINE_UVA_SEPS,
+        mode=args.mode,
+        kernel=args.kernel,
+        fanout=args.fanout,
+        batch=args.batch,
+        caps=args.caps,
+        weighted=getattr(args, "weighted", False),
+        dispatch="stream",
+        stream_batches=stream,
+        overflow=oflo,
+        **extra,
     )
 
 
@@ -380,10 +316,6 @@ def _body_sharded(args):
     if args.stream:
         log("WARNING: --stream is not supported with --topo-sharding mesh; "
             "measuring the per-call dispatch loop only")
-    dedup = "sort" if args.dedup == "both" else args.dedup
-    if args.dedup == "both":
-        log("WARNING: --dedup both is a stream-mode comparison; "
-            "--topo-sharding mesh measures dedup=sort only")
 
     topo = build_graph(args)
     if args.weighted:
@@ -397,7 +329,7 @@ def _body_sharded(args):
     mesh = make_mesh(data=1, feature=F)
     alpha = args.routed_alpha or None
     sampler = GraphSageSampler(
-        topo, args.fanout, mode="HBM", seed=args.seed, dedup=dedup,
+        topo, args.fanout, mode="HBM", seed=args.seed,
         kernel="pallas" if args.kernel == "fused" else args.kernel,
         topo_sharding="mesh", mesh=mesh, routed_alpha=alpha,
         weighted=args.weighted,
@@ -446,7 +378,6 @@ def _body_sharded(args):
         fanout=args.fanout,
         batch=args.batch,
         caps=args.caps,
-        dedup=dedup,
         dispatch="percall",
         weighted=args.weighted,
         mesh_devices=W,
@@ -477,10 +408,9 @@ def _body(args):
             np.random.default_rng(args.seed + 5).normal(size=topo.edge_count)
         ).astype(np.float32)
         topo.set_edge_weight(w)
-    base_dedup = "sort" if args.dedup == "both" else args.dedup
     sampler = GraphSageSampler(
         topo, args.fanout, mode=args.mode, seed_capacity=args.batch,
-        seed=args.seed, dedup=base_dedup,
+        seed=args.seed,
         kernel="pallas" if args.kernel == "fused" else args.kernel,
         weighted=args.weighted,
         frontier_caps="auto" if args.caps == "auto" else None,
@@ -510,17 +440,13 @@ def _body(args):
         log(f"WARNING: {recompiles_steady} steady-state recompile(s) — "
             "the sampler program must be compiled once per shape")
 
-    stage_sampler = sampler
-    if args.dedup == "both" and not args.stream:
-        log("WARNING: --dedup both only compares under --stream; this run "
-            "measures dedup=sort per-call only")
     if args.stream:
         # stream headline FIRST (the first SEPS record is the headline),
         # per-call after as the dispatch=percall record.
         # Guarded: a stream failure must not discard the per-call number
         # already in hand (same discipline as _stage_profile below)
         try:
-            stage_sampler = _stream_seps(args, sampler, topo) or sampler
+            _stream_seps(args, sampler, topo)
         except Exception as e:  # noqa: BLE001
             log(f"stream measure failed (per-call record stands): "
                 f"{type(e).__name__}: {str(e)[:200]}")
@@ -535,7 +461,6 @@ def _body(args):
         fanout=args.fanout,
         batch=args.batch,
         caps=args.caps,
-        dedup=base_dedup,
         weighted=args.weighted,
         dispatch="percall",
         recompiles_steady=recompiles_steady,
@@ -546,7 +471,7 @@ def _body(args):
         # not take the run down (each stage is a fresh compile, each a
         # fresh chance at a transient backend error)
         try:
-            _stage_profile(args, stage_sampler, topo)
+            _stage_profile(args, sampler, topo)
         except Exception as e:  # noqa: BLE001
             log(f"stage profile failed (headline unaffected): "
                 f"{type(e).__name__}: {str(e)[:200]}")

@@ -33,7 +33,7 @@ def stream_seps(sampler, node_count: int, batch: int, stream: int, rng,
                 reps: int = 3):
     """Shared fused-stream SEPS measurement: ONE compiled program scans
     ``stream`` seed batches (in-program valid-edge tallies, one scalar
-    readback). Used by bench_sampler's --stream headline and sweep_sampler.
+    readback). Used by bench_sampler's --stream headline.
 
     Returns (median SEPS, last overflow, stream actually used), or None
     when even a single batch's worst-case edge count would wrap the int32
@@ -121,17 +121,16 @@ def hbm_bandwidth_gbps() -> float | None:
     return None
 
 
-def sampler_roofline(sampler, batch: int, dedup: str):
+def sampler_roofline(sampler, batch: int):
     """Coarse HBM-traffic lower bound for ONE seed batch through the fused
     sampler — the denominator for "how far from the chip's ceiling is this
     SEPS number" (VERDICT r3 item 2), not a precise model.
 
     Traffic counted per layer (worst-case frontiers = the static caps):
     sample: 2 indptr gathers (base/deg) + the random CSR indices gather +
-    the neighbor write; reindex: map dedup = map memset + random scatter +
-    random gather + compacted write, sort dedup = ~log2(T) passes over
-    (value, position) pairs. Every RANDOM 4-byte access is charged a full
-    32-byte HBM granule — a pure-byte count would put the ceiling ~8x too
+    the neighbor write; reindex: four payload sorts, each ~log2(T) passes
+    over (key, payload) pairs, and the compacted write. Every RANDOM
+    4-byte access is charged a full 32-byte HBM granule — a pure-byte count would put the ceiling ~8x too
     high for gather-dominated programs. Returns (bytes_per_batch,
     ceiling_seps) or None when bandwidth is unknown.
     """
@@ -144,7 +143,6 @@ def sampler_roofline(sampler, batch: int, dedup: str):
     _, caps = sampler._compiled(batch)
     ins = (batch,) + tuple(caps[:-1])
     ptr_b = max(sampler.topo.indptr.dtype.itemsize, GRANULE)
-    n_bound = sampler.csr_topo.node_count
     total = 0
     worst_edges = 0
     for l, (S, k) in enumerate(zip(ins, sampler.sizes)):
@@ -154,17 +152,8 @@ def sampler_roofline(sampler, batch: int, dedup: str):
         total += S * ptr_b + S * k * GRANULE + S * k * 4  # reads + write
         worst_edges += S * k
         T = S * k + S
-        if dedup == "map":
-            # sequential memset + random scatter + random gather + write
-            total += n_bound * 4 + 2 * T * GRANULE + caps[l] * 4
-        elif dedup == "scan":
-            # two sorts + scans + a binary-search compaction: pure bytes
-            # for the sorts, a granule per search probe
-            total += 2 * int(math.log2(max(T, 2))) * T * 8
-            total += int(math.log2(max(T, 2))) * caps[l] * GRANULE + caps[l] * 4
-        else:
-            # sort passes stream sequentially: pure bytes
-            total += int(math.log2(max(T, 2))) * T * 8 + caps[l] * 4
+        # sort passes stream sequentially: pure bytes
+        total += 4 * int(math.log2(max(T, 2))) * T * 8 + caps[l] * 4
     ceiling = worst_edges / (total / (bw * 1e9))
     return total, ceiling
 

@@ -34,13 +34,9 @@ JOBS = [
     # ordered: highest-evidence rows first, so a run cut short still lands
     # the headline stream/scan numbers before the long-tail jobs
     ("sampler-hbm", "benchmarks.bench_sampler",
-     ["--mode", "HBM", "--stream", "128", "--dedup", "both"],
-     "ref 34.29M SEPS (1-GPU UVA, Introduction_en.md:41); sort, dense-map "
-     "AND scan dedup measured, fastest first (stage profile split into "
-     "its own job)"),
-    ("primitives", "benchmarks.microbench", [],
-     "sort/scatter/gather/cummax Melem/s — decides which dedup strategy "
-     "SHOULD win on this chip (scatter-serialization diagnosis), ~2 min"),
+     ["--mode", "HBM", "--stream", "128"],
+     "ref 34.29M SEPS (1-GPU UVA, Introduction_en.md:41); stage profile "
+     "split into its own job"),
     ("feature-replicate", "benchmarks.bench_feature",
      ["--policy", "replicate", "--stream", "32"],
      "ref 14.82 GB/s (1 GPU, 20% cache, Introduction_en.md:95)"),
@@ -69,12 +65,9 @@ JOBS = [
      "table attributes the sample-stage share vs the XLA sampler-weighted "
      "row and recompiles_steady must stay 0"),
     ("sampler-weighted", "benchmarks.bench_sampler",
-     ["--mode", "HBM", "--weighted", "--stream", "128", "--dedup", "both"],
+     ["--mode", "HBM", "--weighted", "--stream", "128"],
      "weight-proportional draws — the path the reference never shipped "
      "reachable (quiver.cu.hpp:240-272)"),
-    ("feature-replicate-xla", "benchmarks.bench_feature",
-     ["--policy", "replicate", "--kernel", "xla", "--stream", "32"],
-     "XLA-gather control for the kernel=auto row"),
     ("feature-bf16", "benchmarks.bench_feature",
      ["--policy", "replicate", "--dtype", "bf16", "--stream", "32"],
      "bf16 rows: 2x rows/s at equal GB/s, 2x cache rows per budget"),
@@ -99,7 +92,7 @@ JOBS = [
      "beyond-HBM FUSED: HOST topology + 50% cold tier through one "
      "compiled epoch program (r4; ref papers100M UVA path equivalent)"),
     ("sampler-stages", "benchmarks.bench_sampler",
-     ["--mode", "HBM", "--stages", "--dedup", "both", "--iters", "8"],
+     ["--mode", "HBM", "--stages", "--iters", "8"],
      "per-layer sample/reindex stage attribution for the headline row"),
     ("rgcn", "benchmarks.bench_rgcn", ["--stream", "16"],
      "no reference baseline (hetero is beyond-parity)"),
@@ -354,8 +347,8 @@ def write_outputs(results, out, smoke, merge=False):
             extras = {k: v for k, v in rec.items()
                       if k in ("kernel", "mode", "policy", "caps", "sampler",
                                "layer", "stage", "dispatch", "stream_batches",
-                               "dedup", "roofline_frac", "ceiling_gbps",
-                               "topo_mode", "cache_ratio", "elected",
+                               "roofline_frac", "ceiling_gbps",
+                               "topo_mode", "cache_ratio",
                                "model", "prng", "hit_rep", "hit_cold",
                                "effective_lanes_per_hop", "topo_sharding",
                                "topo_shrink", "comm_reduction",

@@ -28,8 +28,9 @@ def resolve_platform_strategy(env_var: str, choices, tpu_default: str,
     """Shared env-override-then-platform-default resolver.
 
     Several ops keep two bit-identical implementations whose cost model
-    flips between backends (XLA serializes general scatters on TPU):
-    dedup strategies, occurrence counts, chunked inference aggregation.
+    may flip between backends (on a v5e a scatter costs 4.4x a payload
+    sort of the same lanes: PERF.md, PR 26): occurrence counts, chunked
+    inference aggregation. Neither pair has a chip number (ROADMAP D4).
     Each exposes an env var that FORCES a strategy for a measurement run;
     a typo'd force must raise, not silently measure the platform default.
     """

@@ -39,12 +39,11 @@ import jax.numpy as jnp
 from ..core.config import CachePolicy, parse_size_bytes
 from ..core.memory import to_pinned_host
 from ..core.topology import CSRTopo
-from ..ops.election import KernelElection, validate_kernel_arg
 from ..ops.sample import staged_gather
 from ..utils.reorder import reorder_by_degree
 from ..utils.trace import get_logger, info_once, trace_scope
 
-__all__ = ["Feature", "HeteroFeature", "tiered_lookup", "resolve_gather_kernel"]
+__all__ = ["Feature", "HeteroFeature", "tiered_lookup"]
 
 
 def _parse_storage_dtype(dtype):
@@ -119,129 +118,18 @@ def wrap_dequant_gathers(scale, hot_rows: int, hot_gather, cold_gather,
     return rep_gather, hot_gather, cold_gather
 
 
-def validate_gather_kernel(kernel: str) -> str:
-    """Eager argument check only — MUST NOT touch the JAX backend (object
-    construction must stay cheap and never initialize/lock backend choice)."""
-    return validate_kernel_arg(kernel)
-
-
-def resolve_gather_kernel(kernel: str) -> str:
-    """Resolve the hot-tier gather kernel choice. Touches the backend, so
-    callers defer this to first use (never the constructor).
-
-    ``"auto"`` on TPU ELECTS BY MEASURED THROUGHPUT between the Pallas
-    row-DMA kernel (ops/pallas/gather.py — the ``quiver_tensor_gather``
-    analogue, shard_tensor.cu.hpp:16-58) and the stock XLA take, via the
-    shared ``ops.election.KernelElection`` machinery: a correctness smoke
-    gates Pallas (a compile failure or wrong rows raise — never a degrade
-    to xla), then a 2-candidate fused-scan micro-bench picks the faster
-    kernel — "it
-    compiled and returned right rows" is not evidence it is fast (VERDICT
-    r3 item 4). The election is cached per process and on disk (the shared
-    ``QUIVER_ELECTION_CACHE`` file, keyed by device kind), and
-    ``QUIVER_GATHER_KERNEL=pallas|xla`` overrides it. Off-TPU auto is xla
-    (the Pallas CPU path is correct but slow). An explicit
-    ``kernel="pallas"`` bypasses everything (fail loudly on request).
-    Env-before-first-use: both knobs (the force and the cache path) are
-    resolved ONCE per process at the first auto resolution — set them
-    before the first gather; flipping them afterwards is inert
-    (tests/test_kernel_election.py pins this).
-    """
-    return GATHER_ELECTION.resolve_request(kernel)
-
-
-def _pallas_gather_usable() -> bool:
-    """Compiled smoke of the Pallas gather at the shape of the real call —
-    100-float rows (not a lane multiple) and an id count that is not a
-    tile multiple: True when it returns exactly ``table[ids]``. A compile
-    failure propagates."""
-    from ..ops.pallas.gather import gather_rows
-
-    rng = np.random.default_rng(0)
-    table = jnp.asarray(rng.standard_normal((4096, 100)), jnp.float32)
-    ids = jnp.asarray(rng.integers(0, 4096, 1000), jnp.int32)
-    return bool(jnp.array_equal(gather_rows(table, ids), table[ids]))
-
-
-def _measure_gather_gbps(kernel: str, rows: int = 65536, dim: int = 128,
-                         batch: int = 8192, reps: int = 16) -> float:
-    """Median GB/s of one gather kernel over a fused id-scan.
-
-    Dispatch-clean by construction (a per-call loop measures dispatch and
-    the host sync as much as the kernel): ONE program scans ``reps`` distinct
-    id batches — distinct so XLA cannot hoist the gather out of the scan —
-    with a checksum carry keeping every gathered column live, and one
-    scalar readback ends the clock.
-    """
-    import time
-
-    from jax import lax
-
-    table = jnp.arange(rows * dim, dtype=jnp.float32).reshape(rows, dim)
-    ids_mat = jax.random.randint(
-        jax.random.PRNGKey(0), (reps, batch), 0, rows, dtype=jnp.int32
-    )
-    gather = _hot_gather_fn(table, kernel)
-
-    @jax.jit
-    def run(ids_all):
-        def step(carry, ids):
-            return carry + jnp.sum(gather(ids)), None
-        total, _ = lax.scan(step, jnp.float32(0), ids_all)
-        return total
-
-    jax.block_until_ready(run(ids_mat))  # compile
-    times = []
-    for _ in range(3):
-        t0 = time.time()
-        jax.block_until_ready(run(ids_mat))
-        times.append(time.time() - t0)
-    nbytes = reps * batch * dim * 4
-    return nbytes / sorted(times)[1] / 1e9
-
-
-# GB/s election between the Pallas row-DMA gather and the XLA take. The
-# rev is bumped when either gather kernel's implementation changes: the
-# disk cache is keyed on rev + jax version + device kind, so a kernel or
-# toolchain change forces re-election instead of trusting stale numbers.
-# The smoke/measure callables defer module-global lookup so tests can
-# monkeypatch feature._pallas_gather_usable / _measure_gather_gbps.
-GATHER_ELECTION = KernelElection(
-    "gather", env_var="QUIVER_GATHER_KERNEL", rev=1,
-    smoke=lambda: _pallas_gather_usable(),  # noqa: PLW0108 — late binding
-    measure=lambda kernel: _measure_gather_gbps(kernel),
-    unit="GB/s", log_child="feature",
-)
-
-
-def _hot_gather_fn(table, kernel: str):
-    """(ids) -> rows gather over the HBM-resident hot tier."""
+def validate_gather_kernel(kernel: str) -> None:
+    """The stores' ``kernel=`` keyword: ``"auto"`` and ``"xla"`` are the one
+    gather there is (``table[ids]``). Kept because the benchmark passes it
+    by name (ROADMAP D14). Touches no backend."""
     if kernel == "pallas":
-        from ..ops.pallas.gather import gather_rows
-
-        return lambda ids: gather_rows(table, ids.astype(jnp.int32))
-    return lambda ids: table[ids]
-
-
-class KernelChoice:
-    """Lazy, retrace-stable gather-kernel choice for the feature stores.
-
-    ``self._kernel`` holds the constructor request verbatim (it rides in
-    pytree aux_data, so it must NEVER change — mutating it after a jit call
-    would silently invalidate the jit cache and force a retrace); the
-    resolved choice is cached separately. Resolution touches the backend,
-    so it happens on first use, never in a constructor.
-    """
-
-    _kernel: str
-
-    @property
-    def kernel(self) -> str:
-        resolved = getattr(self, "_kernel_resolved", None)
-        if resolved is None:
-            resolved = resolve_gather_kernel(self._kernel)
-            self._kernel_resolved = resolved
-        return resolved
+        raise ValueError(
+            "kernel='pallas' was removed: the Pallas row gather lost to "
+            "XLA's on the v5e, 12.5 against 35.1 GB/s and 10.2 against "
+            "34.6 (CHANGES.md, PR 21); pass 'auto' or 'xla'"
+        )
+    if kernel not in ("auto", "xla"):
+        raise ValueError(f"kernel must be 'auto' or 'xla', got {kernel!r}")
 
 
 @trace_scope("feature_gather")
@@ -317,7 +205,7 @@ def tiered_lookup(n_id, feature_order, hot_rows: int, hot_gather, cold_gather,
 
 
 @jax.tree_util.register_pytree_node_class
-class Feature(KernelChoice):
+class Feature:
     """Tiered node-feature table with jit-compatible lookup.
 
     Args mirror the reference's constructor (feature.py:29-44):
@@ -377,7 +265,7 @@ class Feature(KernelChoice):
         self.cache_policy = CachePolicy.parse(cache_policy)
         self.csr_topo = csr_topo
         self.hot_shuffle_seed = hot_shuffle_seed
-        self._kernel = validate_gather_kernel(kernel)
+        validate_gather_kernel(kernel)
         # storage dtype override: "bfloat16" halves the byte budget per row
         # (so ~2x rows fit the same HBM cache and every gather moves half
         # the bytes) — the TPU-first answer to the reference's hardcoded
@@ -469,7 +357,7 @@ class Feature(KernelChoice):
 
         Jit-composable; invalid lanes return zero rows.
         """
-        hot_gather = None if self.hot is None else _hot_gather_fn(self.hot, self.kernel)
+        hot_gather = None if self.hot is None else lambda ids: self.hot[ids]
         cold_gather = (
             None
             if self.cold is None
@@ -503,7 +391,6 @@ class Feature(KernelChoice):
             self.dtype,
             self._cold_is_host,
             self.hot_shuffle_seed,
-            self._kernel,
             self.storage_dtype,
             self.replicate_budget,
         )
@@ -523,7 +410,6 @@ class Feature(KernelChoice):
             obj.dtype,
             obj._cold_is_host,
             obj.hot_shuffle_seed,
-            obj._kernel,
             obj.storage_dtype,
             obj.replicate_budget,
         ) = aux

@@ -45,8 +45,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.config import CachePolicy, parse_size_bytes
 from .feature import (
-    KernelChoice,
-    _hot_gather_fn,
     _parse_storage_dtype,
     quantize_rows_int8,
     tiered_lookup,
@@ -65,7 +63,7 @@ from ..utils.reorder import reorder_by_degree
 __all__ = ["ShardedTensor", "ShardedFeature"]
 
 
-class ShardedTensor(KernelChoice):
+class ShardedTensor:
     """2-D table row-sharded over the mesh's feature axis.
 
     Rows are padded to a multiple of the axis size; shard d owns rows
@@ -74,12 +72,11 @@ class ShardedTensor(KernelChoice):
     (shard_tensor.py:55-76).
     """
 
-    def __init__(self, mesh: Mesh, axis: str = FEATURE_AXIS, kernel: str = "auto",
+    def __init__(self, mesh: Mesh, axis: str = FEATURE_AXIS,
                  routed_alpha: float = 2.0):
         self.mesh = mesh
         self.axis = axis
         self.num_shards = mesh.shape[axis]
-        self._kernel = validate_gather_kernel(kernel)
         # capped-bucket routed gather: per-destination bucket capacity
         # ceil(routed_alpha * L / F). alpha=2 leaves 2x headroom over a
         # uniform owner distribution — degree-ordered hot rows concentrate
@@ -146,7 +143,7 @@ class ShardedTensor(KernelChoice):
         owner = ids // self.rows_per_shard
         mine = owner == my
         local_idx = jnp.where(mine, ids - my * self.rows_per_shard, 0)
-        rows = _hot_gather_fn(local_table, self.kernel)(local_idx)
+        rows = local_table[local_idx]
         return jnp.where(mine[:, None], rows, 0)
 
     def routed_cap(self, length: int, alpha: float | None = None) -> int:
@@ -249,7 +246,6 @@ class ShardedTensor(KernelChoice):
         # the two all_to_all hops, and the cond-gated psum fallback
         my = jax.lax.axis_index(self.axis)
         rps = self.rows_per_shard
-        gather_rows = _hot_gather_fn(local_table, self.kernel)
 
         def serve(req_ids):
             # ownership-masked local gather: zero for dead (-1) lanes and
@@ -257,7 +253,7 @@ class ShardedTensor(KernelChoice):
             # harmless on the main hop (routing guarantees ownership there)
             mine = (req_ids >= 0) & (req_ids // rps == my)
             lidx = jnp.where(mine, req_ids - my * rps, 0)
-            rows = gather_rows(lidx)
+            rows = local_table[lidx]
             return jnp.where(mine[:, None], rows, 0)
 
         route = BucketRoute(
@@ -396,7 +392,7 @@ class ShardedTensor(KernelChoice):
         return self.gather(ids)
 
 
-class ShardedFeature(KernelChoice):
+class ShardedFeature:
     """Feature store with a three-tier memory hierarchy over the mesh:
 
     * **L0 replicated super-hot** (``replicate_budget`` bytes/device): the
@@ -440,7 +436,7 @@ class ShardedFeature(KernelChoice):
     ):
         self.mesh = mesh
         self.axis = axis
-        self._kernel = validate_gather_kernel(kernel)
+        validate_gather_kernel(kernel)
         if routed_alpha <= 0:
             raise ValueError(f"routed_alpha must be > 0, got {routed_alpha}")
         self.routed_alpha = float(routed_alpha)
@@ -548,8 +544,7 @@ class ShardedFeature(KernelChoice):
             self.rep = None
         if total - rep_rows > 0:
             self.hot = ShardedTensor(
-                self.mesh, self.axis, kernel=self._kernel,
-                routed_alpha=self.routed_alpha,
+                self.mesh, self.axis, routed_alpha=self.routed_alpha,
             ).from_cpu_tensor(region[rep_rows:])
         else:
             self.hot = None
@@ -1126,8 +1121,7 @@ class ShardedFeature(KernelChoice):
         if self.auto_split or self._controller is not None:
             self._maybe_auto_split()
         rep_gather = (
-            None if self.rep is None
-            else _hot_gather_fn(self.rep, self.kernel)
+            None if self.rep is None else lambda ids: self.rep[ids]
         )
         hot_gather = (
             None if self.hot is None

@@ -79,8 +79,8 @@ def _accumulate_chunk(acc, x_all, indptr, indices, e0, chunk: int,
 )
 def _accumulate_chunk_scan(acc, x_all, indptr, indices, e0, chunk: int,
                            span: int, host: bool):
-    """Zero-scatter chunk aggregation (the TPU path, where XLA serializes
-    general scatters — same diagnosis as ops.reindex dedup="scan").
+    """Zero-scatter chunk aggregation (the TPU default; against the
+    scatter sibling it has no chip number, ROADMAP D4).
 
     CSR edge order makes each chunk's destinations a sorted run over a
     CONTIGUOUS row window, so the segmented sum is exact dense algebra:
@@ -140,7 +140,7 @@ def _chunk_row_span(indptr_host, chunk: int) -> int:
 
 def _use_scan_agg() -> bool:
     """Platform-resolved chunk-aggregation strategy with env override
-    (``QUIVER_INFER_AGG=scan|scatter``), mirroring resolve_dedup."""
+    (``QUIVER_INFER_AGG=scan|scatter``)."""
     from ..core.config import resolve_platform_strategy
 
     return resolve_platform_strategy(

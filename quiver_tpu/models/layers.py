@@ -11,10 +11,10 @@ Two aggregation paths, identical results:
 * **dense** (``fanout`` set — every sampler-built Adj): the sampler's edge
   layout is regular (lane ``s*fanout + k`` targets seed ``s``), so
   aggregation is a masked ``(num_dst, fanout, F)`` reshape + axis-1
-  reduction — zero scatters. XLA is expected to serialize general
-  scatters on TPU (not yet measured on the chip: ROADMAP S2), which on
-  the training path would be the difference between VPU-speed reductions
-  and a per-edge loop.
+  reduction — zero scatters. A scatter is not serialized on a v5e but
+  costs 4.6 ns a lane, 4.4x a payload sort of the same lanes (one
+  unique-index scatter of 852,480 lanes: PERF.md, PR 26); the segment
+  path's own cost has not been measured on the chip.
 * **segment** (``fanout=None``): ``jax.ops.segment_sum`` with an overflow
   bucket for invalid lanes — kept for hand-built/irregular Adjs and as the
   differential-test oracle.
@@ -117,8 +117,9 @@ def zero_scatter_counts(ids, valid, n: int, dtype=jnp.float32):
     """Occurrence count of each value in [0, n) among ``ids[valid]`` —
     a histogram with no scatter: sort (invalid lanes to the sentinel n),
     then bucket edges via one vectorized binary search. The zero-scatter
-    analogue of ``segment_sum(ones, ids)`` for backends where XLA
-    serializes scatters (same rationale as ops.reindex dedup="scan")."""
+    analogue of ``segment_sum(ones, ids)`` for backends where a scatter
+    costs more than a sort (a v5e: 4.4x, PERF.md, PR 26; this pair itself
+    has no chip number, ROADMAP D4)."""
     sv = jnp.sort(jnp.where(valid, ids, n))
     edges = jnp.searchsorted(sv, jnp.arange(n + 1, dtype=ids.dtype))
     return (edges[1:] - edges[:-1]).astype(dtype)
@@ -126,7 +127,7 @@ def zero_scatter_counts(ids, valid, n: int, dtype=jnp.float32):
 
 def occurrence_counts(ids, valid, n: int, dtype=jnp.float32):
     """Histogram of ``ids[valid]`` over [0, n), strategy picked per
-    platform (the counts-shaped sibling of ops.reindex.resolve_dedup):
+    platform (``core.config.resolve_platform_strategy``):
     zero-scatter sort+searchsorted on TPU, one scalar scatter-add
     elsewhere. ``QUIVER_COUNTS=scan|scatter`` overrides — resolved once
     per process at op construction (:func:`resolve_counts_strategy`), so
@@ -168,8 +169,8 @@ def segment_mean_aggregate(messages, dst, valid, num_dst: int,
 
         # the gate failed on SHAPE: fanout promised the dense layout but
         # E != num_dst*fanout, so this aggregation silently reverts to the
-        # segment-scatter path (XLA serializes scatters on TPU) — make the
-        # perf regression visible (ADVICE layers.py:93)
+        # segment-scatter path — make the perf regression visible (ADVICE
+        # layers.py:93)
         info_once(
             f"dense-gate-fallback-{messages.shape[0]}-{num_dst}-{fanout}",
             "Adj.fanout=%d set but E=%d != num_dst*fanout=%d; falling back "

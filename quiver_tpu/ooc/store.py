@@ -51,12 +51,9 @@ import jax.numpy as jnp
 
 from ..core.config import parse_size_bytes
 from ..feature.feature import (
-    KernelChoice,
-    _hot_gather_fn,
     _parse_storage_dtype,
     quantize_rows_int8,
     tiered_lookup,
-    validate_gather_kernel,
     wrap_dequant_gathers,
 )
 from ..utils.reorder import reorder_by_degree
@@ -70,12 +67,11 @@ _ACCESS_MODES = ("mmap", "pread")
 _ROWS_KIND = "quiver-ooc-feature-rows"
 
 
-class MmapFeatureStore(KernelChoice):
+class MmapFeatureStore:
     """Open a :meth:`write`-prepared raw feature directory for lookups.
 
     Args:
       path: raw-format directory written by :meth:`write`.
-      kernel: hot-tier gather kernel ("auto" elects, like Feature).
       access: "mmap" (np.memmap window slices) or "pread"
         (positioned reads, zero file mappings — the rlimit-drill mode).
       window_rows: rows per disk read (the readahead granularity).
@@ -88,9 +84,8 @@ class MmapFeatureStore(KernelChoice):
         ``ooc.*`` counters and stages.
     """
 
-    def __init__(self, path: str, kernel: str = "auto",
-                 access: str = "mmap", window_rows: int = 1024,
-                 cache_windows: int = 32, host_cache_rows: int = 0,
+    def __init__(self, path: str, access: str = "mmap",
+                 window_rows: int = 1024, cache_windows: int = 32, host_cache_rows: int = 0,
                  retries: int = 0, backoff: float = 0.05,
                  backoff_cap: float = 2.0, jitter: float = 0.5,
                  retry_seed: int = 0, metrics=None, timeline=None):
@@ -99,7 +94,6 @@ class MmapFeatureStore(KernelChoice):
                 f"access must be one of {_ACCESS_MODES}, got {access!r}"
             )
         self.path = str(path)
-        self._kernel = validate_gather_kernel(kernel)
         self.access = access
         self.metrics = metrics
         self.timeline = timeline
@@ -318,10 +312,7 @@ class MmapFeatureStore(KernelChoice):
             # block IS the gather's result (the dequant wrapper still
             # consumes the traced ids for its scale lookup)
             cold_gather = lambda ids: block  # noqa: E731
-        hot_gather = (
-            None if self.hot is None
-            else _hot_gather_fn(self.hot, self.kernel)
-        )
+        hot_gather = None if self.hot is None else lambda ids: self.hot[ids]
         _, hot_gather, cold_gather = wrap_dequant_gathers(
             self.scale, self.hot_rows, hot_gather, cold_gather
         )
@@ -351,8 +342,7 @@ class MmapFeatureStore(KernelChoice):
                 block = staged[0]
                 cold_gather = lambda ids: block  # noqa: E731
             hot_gather = (
-                None if self.hot is None
-                else _hot_gather_fn(self.hot, self.kernel)
+                None if self.hot is None else lambda ids: self.hot[ids]
             )
             _, hot_gather, cold_gather = wrap_dequant_gathers(
                 self.scale, self.hot_rows, hot_gather, cold_gather

@@ -851,8 +851,7 @@ class DistributedTrainer:
                     indptr_blk[0], indices_blk[0], rows_per_shard, seeds,
                     num_seeds, sample_key, sizes, caps,
                     axis=FEATURE_AXIS, num_shards=mesh.shape[FEATURE_AXIS],
-                    routed_alpha=routed_alpha, dedup=sampler.dedup,
-                    node_count=node_count,
+                    routed_alpha=routed_alpha,
                 )
                 sample_ov = jnp.stack(hop_ovs)  # feature-group totals
             else:
@@ -860,7 +859,6 @@ class DistributedTrainer:
                     multilayer_sample(
                         topo, seeds, num_seeds, sample_key, sizes, caps,
                         weighted=sampler.weighted, kernel=sampler.kernel,
-                        dedup=sampler.dedup,
                     )
                 )
                 sample_ov = jnp.zeros((len(sizes),), jnp.int32)

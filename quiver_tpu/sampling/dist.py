@@ -42,7 +42,7 @@ ranges in place of plain degrees (one int32 exchange with trailing dim
 2, +``F*cap`` lanes over uniform).
 
 Bit-parity contract: for the same seed block, PRNG key, fanouts, frontier
-caps, and dedup strategy, every per-worker ``SampleOutput`` (n_id, adjs)
+caps, every per-worker ``SampleOutput`` (n_id, adjs)
 is bit-identical to the replicated ``GraphSageSampler``'s on that block
 with key ``fold_in(key, worker_index)`` — capping and routing change which
 wires the bits cross, never the bits.
@@ -60,7 +60,7 @@ from ..core.config import SampleMode
 from ..core.sharded_topology import ShardedTopology
 from ..core.topology import CSRTopo
 from ..obs.registry import SAMPLE_OVERFLOW, MetricsRegistry
-from ..ops.reindex import reindex_layer, resolve_dedup
+from ..ops.reindex import reindex_layer
 from ..ops.sample import rotate_offsets, stratified_offsets
 from ..parallel.mesh import FEATURE_AXIS, shard_map
 from ..parallel.routing import BucketRoute
@@ -320,7 +320,6 @@ def dist_sample_layer(local_indptr, local_indices, rows_per_shard: int,
 def dist_multilayer_sample(local_indptr, local_indices, rows_per_shard: int,
                            seeds, num_seeds, key, sizes, caps, *, axis: str,
                            num_shards: int, routed_alpha: float | None = 2.0,
-                           dedup: str = "sort", node_count: int | None = None,
                            weighted: bool = False, local_cum_weights=None,
                            time_window=None, local_edge_time=None,
                            search_iters: int = 0, kernel: str = "xla"):
@@ -333,7 +332,6 @@ def dist_multilayer_sample(local_indptr, local_indices, rows_per_shard: int,
     (axis-group totals, seeds-outward order) — the ``last_sample_overflow``
     telemetry source.
     """
-    dedup = resolve_dedup(dedup)
     adjs = []
     edge_counts = []
     frontier_counts = []
@@ -354,10 +352,8 @@ def dist_multilayer_sample(local_indptr, local_indices, rows_per_shard: int,
             )
         hop_overflows.append(hop_ov)
         with trace_scope(f"reindex_layer_{l}"):
-            node_bound = node_count if dedup == "map" else None
             frontier, n_frontier, col, overflow = reindex_layer(
-                cur, cur_n, nbr, caps[l], node_bound=node_bound,
-                scatter_free=(dedup == "scan"),
+                cur, cur_n, nbr, caps[l]
             )
             with trace_scope("assemble"):
                 row = jnp.broadcast_to(
@@ -390,8 +386,7 @@ class DistGraphSageSampler(GraphSageSampler):
     draws — see ``dist_sample_layer``) and ``time_window`` (owner-answered
     in-window slot ranges) biased draws, each bit-identical to its
     replicated counterpart. The ``kernel`` knob matches the replicated
-    sampler too: with "pallas" (or an auto election landing there) the
-    owner-side gathers and weighted CDF walks run on the fused Pallas
+    sampler too: with "pallas" the owner-side gathers and weighted CDF walks run on the fused Pallas
     engine — bits on the wire unchanged — degrading per compile to xla
     (one INFO) when a shard's slice cannot host the window DMA.
     Constraints vs the replicated sampler: HBM mode and no ``with_eid``
@@ -541,9 +536,8 @@ class DistGraphSageSampler(GraphSageSampler):
             return self._compiled_cache[cache_key]
         mesh, axis = self.mesh, self.axis
         F = mesh.shape[axis]
-        sizes, dedup = self.sizes, self.dedup
+        sizes = self.sizes
         alpha = self.routed_alpha
-        n = self.csr_topo.node_count
         rps = self.topo.rows_per_shard
         ids_axes = tuple(mesh.axis_names)
         other_axes = tuple(a for a in mesh.axis_names if a != axis)
@@ -552,7 +546,7 @@ class DistGraphSageSampler(GraphSageSampler):
         time_window = self.time_window
         iters = self.topo.search_iters
         n_topo = len(self._topo_operands())
-        kernel = self.kernel  # resolved request (may run the election)
+        kernel = self.kernel
         if kernel == "pallas":
             from ..ops.pallas.fused import DEFAULT_WINDOW, MIN_EDGES
 
@@ -595,7 +589,6 @@ class DistGraphSageSampler(GraphSageSampler):
              hop_ovs) = dist_multilayer_sample(
                 topo_blks[0][0], topo_blks[1][0], rps, seeds, num_seeds, key,
                 sizes, caps, axis=axis, num_shards=F, routed_alpha=alpha,
-                dedup=dedup, node_count=n,
                 weighted=weighted, local_cum_weights=cum_blk,
                 time_window=time_window, local_edge_time=time_blk,
                 search_iters=iters, kernel=kernel,

@@ -19,8 +19,8 @@ adds ``F*cap_t`` (degrees back) + ``F*cap_t*k`` (offsets out) +
 more ``F*cap_t`` f32 hop (row weight totals back; its offsets-out hop
 carries the f32 uniform block instead of int32 offsets).
 
-Bit-parity contract: for the same seed block, fanouts, caps, and dedup
-strategy, every per-worker output is bit-identical to the replicated
+Bit-parity contract: for the same seed block, fanouts and caps,
+every per-worker output is bit-identical to the replicated
 ``HeteroGraphSampler``'s on that block with key ``fold_in(base_key,
 worker_index)`` — the per-relation key schedule (one split per active
 relation, plan order) and the per-type dedup are byte-for-byte the
@@ -44,14 +44,13 @@ from ..core.config import SampleMode
 from ..core.hetero import HeteroCSRTopo
 from ..core.hetero_sharded import HeteroShardedTopology
 from ..obs.registry import HETERO_SAMPLE_OVERFLOW, MetricsRegistry
-from ..ops.election import validate_kernel_arg
 from ..ops.reindex import masked_unique
 from ..parallel.mesh import FEATURE_AXIS, shard_map
 from ..parallel.routing import BucketRoute
 from ..utils.trace import info_once, trace_scope
 from .dist import _worker_index, dist_sample_layer, routed_sample_cap
 from .hetero import HeteroGraphSampler, HeteroLayer, HeteroSampleOutput
-from .sampler import Adj, _round_up, resolve_sample_kernel
+from .sampler import Adj, _round_up, settle_sample_kernel
 
 __all__ = ["DistHeteroSampler", "dist_hetero_multilayer_sample"]
 
@@ -61,8 +60,7 @@ def dist_hetero_multilayer_sample(rel_blocks, seeds, num_seeds, key,
                                   num_shards: int, rows_per_shard: dict,
                                   routed_alpha: float | None = 2.0,
                                   weighted_rels=frozenset(),
-                                  search_iters=None, node_bounds=None,
-                                  scatter_free: bool = False,
+                                  search_iters=None,
                                   pallas_rels=frozenset()):
     """The per-device distributed hetero loop (call inside ``shard_map``).
 
@@ -155,9 +153,7 @@ def dist_hetero_multilayer_sample(rel_blocks, seeds, num_seeds, key,
             ids = jnp.concatenate(blocks)
             valid = jnp.concatenate(valids)
             uniq, num_u, local = masked_unique(
-                ids, valid, cap, num_forced=n_prev,
-                node_bound=None if node_bounds is None else node_bounds[t],
-                scatter_free=scatter_free,
+                ids, valid, cap, num_forced=n_prev
             )
             new_frontier[t] = uniq
             new_counts[t] = jnp.minimum(num_u, cap)
@@ -203,9 +199,9 @@ class DistHeteroSampler(HeteroGraphSampler):
     Extra args over the replicated sampler: ``mesh`` (required), the
     ``routed_alpha`` capped-bucket budget (``cap = ceil(alpha * S / F)``
     lanes per destination per hop; ``None`` = uncapped), ``axis`` (the
-    mesh axis the partitions live on), and ``kernel``
-    ("auto"|"pallas"|"xla" — with pallas, eligible relations' owner-side
-    hops run on the fused Pallas engine, per-relation compile-time gating
+    mesh axis the partitions live on), and ``kernel`` ("xla", which "auto"
+    means, or "pallas" — with pallas, eligible relations' owner-side hops
+    run on the fused Pallas engine, per-relation compile-time gating
     with one INFO per degrade; bits on the wire unchanged). Constraints:
     HBM mode and no ``with_eid`` (the sharded relation slices do not
     carry eid — that path stays on the replicated sampler).
@@ -221,14 +217,12 @@ class DistHeteroSampler(HeteroGraphSampler):
                  seed_capacity: int | None = None,
                  frontier_caps: str | None = None, seed: int = 0,
                  auto_margin: float = 1.25, weighted=False,
-                 with_eid: bool = False, dedup: str = "auto", *,
+                 with_eid: bool = False, *,
                  mesh=None, routed_alpha: float | None = 2.0,
                  axis: str = FEATURE_AXIS, kernel: str = "auto"):
         if mesh is None:
             raise ValueError("DistHeteroSampler requires mesh=")
-        # the request rides verbatim; resolution (which may run the
-        # measured election) happens at first compile via the property
-        self._kernel = validate_kernel_arg(str(kernel))
+        self.kernel = settle_sample_kernel(str(kernel))
         if with_eid:
             raise ValueError(
                 "with_eid over a sharded topology is not supported; the "
@@ -254,7 +248,7 @@ class DistHeteroSampler(HeteroGraphSampler):
             topo, sizes, input_type, mode=mode,
             seed_capacity=seed_capacity, frontier_caps=frontier_caps,
             seed=seed, auto_margin=auto_margin, weighted=weighted,
-            with_eid=with_eid, dedup=dedup,
+            with_eid=with_eid,
         )
         # static (hop, edge_type) telemetry slot order — the active sets
         # depend only on schema reachability, never on cap values, so any
@@ -282,16 +276,6 @@ class DistHeteroSampler(HeteroGraphSampler):
             self.mesh, self.topo, axis=self.axis,
             weighted_rels=self.weighted_rels,
         )
-
-    @property
-    def kernel(self) -> str:
-        """The resolved sampler kernel ("pallas"|"xla") — same lazy
-        election contract as ``GraphSageSampler.kernel``."""
-        resolved = getattr(self, "_kernel_resolved", None)
-        if resolved is None:
-            resolved = resolve_sample_kernel(self._kernel)
-            self._kernel_resolved = resolved
-        return resolved
 
     @property
     def overflow_slots(self) -> tuple:
@@ -376,15 +360,10 @@ class DistHeteroSampler(HeteroGraphSampler):
         }
         alpha = self.routed_alpha
         input_type = self.input_type
-        node_bounds = (
-            {t: int(n) for t, n in self.topo.num_nodes.items()}
-            if self.dedup == "map" else None
-        )
-        scatter_free = self.dedup == "scan"
         n_topo = len(self._topo_operands())
         out_types, fc_slots = self._scal_layout(plans)
         pallas_rels = frozenset()
-        if self.kernel == "pallas":  # resolved (may run the election)
+        if self.kernel == "pallas":
             from ..ops.pallas.fused import DEFAULT_WINDOW, MIN_EDGES
 
             # per-relation compile-time eligibility for the fused
@@ -433,8 +412,7 @@ class DistHeteroSampler(HeteroGraphSampler):
                 rel_blocks, seeds, num_seeds, key, input_type, plans,
                 axis=axis, num_shards=F, rows_per_shard=rps,
                 routed_alpha=alpha, weighted_rels=weighted_rels,
-                search_iters=iters, node_bounds=node_bounds,
-                scatter_free=scatter_free, pallas_rels=pallas_rels,
+                search_iters=iters, pallas_rels=pallas_rels,
             )
             # per-worker scalar row in the _scal_layout order
             scal = jnp.stack(

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from ..core.config import SampleMode
 from ..core.hetero import HeteroCSRTopo
-from ..ops.reindex import masked_unique, resolve_dedup
+from ..ops.reindex import masked_unique
 from ..ops.sample import sample_layer
 from .sampler import Adj, _round_up
 
@@ -113,8 +113,7 @@ def _normalize_sizes(sizes, topo: HeteroCSRTopo):
 
 def hetero_multilayer_sample(dev_topos, seeds, num_seeds, key, input_type,
                              layer_plans, weighted_rels=frozenset(),
-                             with_eid: bool = False, node_bounds=None,
-                             scatter_free: bool = False):
+                             with_eid: bool = False):
     """The jit-composable hetero sampling loop.
 
     ``layer_plans`` is a static tuple of per-hop plans, each
@@ -125,10 +124,6 @@ def hetero_multilayer_sample(dev_topos, seeds, num_seeds, key, input_type,
     ``with_eid`` threads per-edge global edge ids into every Adj — the
     homogeneous contract (multilayer_sample, sampler.py) extended to typed
     relations: ids are COO positions within each relation's own edge list.
-    ``node_bounds`` (static {type: node_count} or None) switches the
-    per-type dedup to the sort-free dense-map scatter-min, matching the
-    homogeneous ``dedup='map'`` option; ``scatter_free`` selects the
-    zero-scatter scan strategy (homogeneous ``dedup='scan'``).
     Returns (frontier dict, counts dict, layers deepest-first, overflow).
     """
     frontier = {input_type: seeds}
@@ -178,9 +173,7 @@ def hetero_multilayer_sample(dev_topos, seeds, num_seeds, key, input_type,
             ids = jnp.concatenate(blocks)
             valid = jnp.concatenate(valids)
             uniq, num_u, local = masked_unique(
-                ids, valid, cap, num_forced=n_prev,
-                node_bound=None if node_bounds is None else node_bounds[t],
-                scatter_free=scatter_free,
+                ids, valid, cap, num_forced=n_prev
             )
             new_frontier[t] = uniq
             new_counts[t] = jnp.minimum(num_u, cap)
@@ -243,12 +236,6 @@ class HeteroGraphSampler:
       with_eid: populate every ``Adj.e_id`` with relation-local global edge
         ids (COO positions) — the homogeneous sampler's contract
         (sage_sampler.py:100-109 parity) extended to typed graphs.
-      dedup: per-type frontier first-occurrence strategy — "sort" (stable
-        sort + run scan), "map" (sort-free scatter-min into a dense
-        per-type position map), or "scan" (zero-scatter sorts + cummax +
-        gathers). Identical results. Default "auto" picks per platform
-        (ops.reindex.resolve_dedup). Mirrors the homogeneous
-        GraphSageSampler option.
     """
 
     def __init__(self, topo: HeteroCSRTopo, sizes: Sequence,
@@ -256,10 +243,9 @@ class HeteroGraphSampler:
                  seed_capacity: int | None = None,
                  frontier_caps: str | None = None, seed: int = 0,
                  auto_margin: float = 1.25, weighted=False,
-                 with_eid: bool = False, dedup: str = "auto"):
+                 with_eid: bool = False):
         if input_type not in topo.num_nodes:
             raise ValueError(f"unknown input_type {input_type!r}")
-        self.dedup = resolve_dedup(str(dedup))  # validates; "auto" -> platform
         self.topo = topo
         self.input_type = input_type
         self.sizes = _normalize_sizes(sizes, topo)
@@ -381,18 +367,12 @@ class HeteroGraphSampler:
         input_type = self.input_type
         weighted_rels = self.weighted_rels
         with_eid = self.with_eid
-        node_bounds = (
-            {t: int(n) for t, n in self.topo.num_nodes.items()}
-            if self.dedup == "map" else None
-        )
-        scatter_free = self.dedup == "scan"
 
         @jax.jit
         def run(dev_topos, seeds, num_seeds, key):
             return hetero_multilayer_sample(
                 dev_topos, seeds, num_seeds, key, input_type, plans,
                 weighted_rels=weighted_rels, with_eid=with_eid,
-                node_bounds=node_bounds, scatter_free=scatter_free,
             )
 
         self._compiled_cache[cache_key] = run

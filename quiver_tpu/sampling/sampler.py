@@ -25,8 +25,7 @@ import jax.numpy as jnp
 
 from ..core.config import SampleMode
 from ..core.topology import CSRTopo, DeviceTopology, VersionMismatchError
-from ..ops.election import KernelElection, validate_kernel_arg
-from ..ops.reindex import reindex_layer, resolve_dedup
+from ..ops.reindex import reindex_layer
 from ..ops.sample import sample_layer
 from ..utils.trace import info_once, trace_scope
 
@@ -47,7 +46,7 @@ class Adj:
     it asserts the REGULAR edge layout — lane ``s*fanout + k`` targets seed
     ``s`` (or is invalid), so ``E_cap == size[1] * fanout``. Models use it
     to aggregate with dense (num_dst, fanout) reductions instead of
-    segment scatters, which XLA serializes on TPU.
+    segment scatters.
     """
 
     def __init__(self, edge_index, e_id, size: tuple[int, int],
@@ -94,8 +93,7 @@ def _round_up(x: int, m: int) -> int:
 
 
 def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
-                      kernel="xla", with_eid=False, dedup="sort",
-                      time_window=None):
+                      kernel="xla", with_eid=False, time_window=None):
     """The multi-layer sample+reindex loop (jit- and shard_map-composable).
 
     One trace covers all layers — the fused analogue of the reference's
@@ -111,9 +109,6 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
     Returns (n_id, n_count, adjs deepest-first, overflow, per-layer edge
     counts, per-layer unclipped frontier counts).
     """
-    if kernel == "auto":
-        kernel = resolve_sample_kernel(kernel)
-    dedup = resolve_dedup(dedup)  # validates; maps "auto" per platform
     use_pallas = kernel == "pallas"
     if use_pallas:
         from ..ops.pallas.fused import (
@@ -196,16 +191,8 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
                                            weighted=weighted,
                                            time_window=time_window)
         with trace_scope(f"reindex_layer_{l}"):
-            # dedup="map": sort-free scatter-min dedup over a dense
-            # (node_count,) position map — the reference's hash-table
-            # analogue (reindex.cu.hpp:120-139); node count is static
-            # from the indptr shape
-            node_bound = (
-                int(topo.indptr.shape[0]) - 1 if dedup == "map" else None
-            )
             frontier, n_frontier, col, overflow = reindex_layer(
-                cur, cur_n, nbr, caps[l], node_bound=node_bound,
-                scatter_free=(dedup == "scan"),
+                cur, cur_n, nbr, caps[l]
             )
             with trace_scope("assemble"):
                 S = cur.shape[0]
@@ -234,104 +221,14 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
             tuple(frontier_counts[::-1]))
 
 
-# -- kernel=auto election (the gather precedent, ops/election.py) ------------
-
-def _pallas_sample_usable() -> bool:
-    """Differential smoke of the fused sampler at the shapes of the real
-    call — the default window, a fanout that is not a multiple of 8, a
-    seed block of training width: True when the compiled kernel returns
-    BITWISE the XLA oracle's output. A compile failure propagates."""
-    from ..ops.pallas.fused import fused_sample_layer
-
-    nodes, batch, k = 4096, 1024, 15
-    rng = np.random.default_rng(0)
-    ei = rng.integers(0, nodes, size=(2, 1 << 18))
-    topo = CSRTopo(edge_index=ei).to_device()
-    seeds = jnp.asarray(rng.integers(0, nodes, batch), jnp.int32)
-    key = jax.random.PRNGKey(0)
-    want = sample_layer(topo, seeds, jnp.int32(batch), k, key)
-    got = fused_sample_layer(topo, seeds, jnp.int32(batch), k, key)
-    return all(
-        np.array_equal(np.asarray(g), np.asarray(w))
-        for g, w in zip(got, want)
-    )
-
-
-def _measure_sample_eps(kernel: str, nodes: int = 4096, edges: int = 1 << 18,
-                        batch: int = 1024, k: int = 8, reps: int = 8) -> float:
-    """Median sampled edges/s of one hop kernel over a fused seed-scan.
-
-    Dispatch-clean by construction (the gather election's lesson): ONE
-    program scans ``reps`` distinct seed batches — distinct keys so XLA
-    cannot hoist the draw out of the scan — with a count-sum carry keeping
-    every hop live, and one scalar readback ends the clock.
-    """
-    import time
-
-    from jax import lax
-
-    rng = np.random.default_rng(0)
-    ei = rng.integers(0, nodes, size=(2, edges))
-    topo = CSRTopo(edge_index=ei).to_device()
-    seeds_mat = jax.random.randint(
-        jax.random.PRNGKey(0), (reps, batch), 0, nodes, dtype=jnp.int32
-    )
-    if kernel == "pallas":
-        from ..ops.pallas.fused import fused_sample_layer as hop
-    else:
-        hop = sample_layer
-    key0 = jax.random.PRNGKey(1)
-
-    @jax.jit
-    def run(seeds_all):
-        def step(carry, seeds):
-            kcar, tot = carry
-            kcar, sub = jax.random.split(kcar)
-            _nbr, counts = hop(topo, seeds, jnp.int32(batch), k, sub)
-            return (kcar, tot + jnp.sum(counts)), None
-        (_, total), _ = lax.scan(step, (key0, jnp.int32(0)), seeds_all)
-        return total
-
-    jax.block_until_ready(run(seeds_mat))  # compile
-    times = []
-    for _ in range(3):
-        t0 = time.time()
-        jax.block_until_ready(run(seeds_mat))
-        times.append(time.time() - t0)
-    return reps * batch * k / sorted(times)[1]
-
-
-# edges/s election between the fused Pallas megakernel (ops/pallas/fused.py)
-# and the XLA stratified sampler — which stays forever as the bitwise
-# differential oracle. The rev bumps when either sampler's implementation
-# changes (same cache-invalidation discipline as feature.GATHER_ELECTION).
-# smoke/measure defer module-global lookup so tests can monkeypatch them.
-SAMPLE_ELECTION = KernelElection(
-    "sample", env_var="QUIVER_SAMPLE_KERNEL", rev=1,
-    smoke=lambda: _pallas_sample_usable(),  # noqa: PLW0108 — late binding
-    measure=lambda kernel: _measure_sample_eps(kernel),
-    unit="edges/s", log_child="sampler",
-)
-
-
-def resolve_sample_kernel(kernel: str) -> str:
-    """Resolve the sampler kernel choice. Touches the backend, so callers
-    defer this to first use (never the constructor).
-
-    ``"auto"`` on TPU elects by measured throughput between the fused
-    Pallas megakernel and the XLA sampler via the shared
-    ``ops.election.KernelElection`` machinery: a one-time bitwise
-    differential smoke gates Pallas (a divergence or a compile failure
-    raises — it never degrades to xla), then a fused-scan micro-bench
-    picks the faster kernel. The election is cached per process and in the
-    shared ``QUIVER_ELECTION_CACHE`` disk file (keyed by device kind), and
-    ``QUIVER_SAMPLE_KERNEL=pallas|xla`` overrides it — pinned at first
-    use, same env-before-first-trace contract as the gather knob
-    (tests/test_kernel_election.py). Off-TPU auto is xla (the interpret
-    path is correct but slow). An explicit ``kernel="pallas"`` bypasses
-    everything (fail loudly on request).
-    """
-    return SAMPLE_ELECTION.resolve_request(kernel)
+def settle_sample_kernel(kernel: str) -> str:
+    """The sampler kernel a constructor's ``kernel=`` names: ``"auto"`` is
+    ``"xla"`` on every backend (the only sampler that is exact on rows
+    above the fused kernel's 2,048-neighbour window); ``"pallas"`` is taken
+    by name only. Touches no backend."""
+    if kernel not in ("auto", "pallas", "xla"):
+        raise ValueError(f"kernel must be auto|pallas|xla, got {kernel!r}")
+    return "xla" if kernel == "auto" else kernel
 
 
 class GraphSageSampler:
@@ -358,28 +255,22 @@ class GraphSageSampler:
         edges never appear). Requires ``csr_topo.set_edge_time()``, HBM
         mode, kernel="xla", and is mutually exclusive with ``weighted``.
       auto_margin: headroom factor for "auto" caps (>= 1).
-      kernel: "auto" (default — measured election, ``resolve_sample_kernel``),
-        "xla" (exact stratified sampler), or "pallas" (the fused per-hop
-        megakernel, ops/pallas/fused.py — HBM mode; every variant:
-        uniform, weighted, temporal, with_eid — bitwise equal to the XLA
-        oracle for rows with deg <= window, see the kernel's parity
-        contract). ``QUIVER_SAMPLE_KERNEL`` overrides "auto" (pinned at
-        first use). Ineligible topologies (graphs smaller than the DMA
-        window, HOST placements, weighted graphs whose max_degree exceeds
-        the window) degrade per hop to the XLA path with a one-shot INFO.
+      kernel: "xla" (exact stratified sampler; "auto", the default, means
+        the same on every backend) or "pallas" (the fused per-hop
+        megakernel, ops/pallas/fused.py, by name only — HBM mode; every
+        variant: uniform, weighted, temporal, with_eid — bitwise equal to
+        the XLA oracle for rows with deg <= window, see the kernel's
+        parity contract). Ineligible topologies (graphs smaller than the
+        DMA window, HOST placements, weighted graphs whose max_degree
+        exceeds the window) degrade per hop to the XLA path with a
+        one-shot INFO.
       with_eid: populate ``Adj.e_id`` with per-edge global edge ids
         (reference sage_sampler.py:100-109) — COO positions when the
         topology tracks ``eid``, CSR slots otherwise.
-      dedup: reindex first-occurrence strategy — "sort" (stable sort +
-        run scan), "map" (sort-free scatter-min into a dense (node_count,)
-        position map, the reference hash-table analogue,
-        reindex.cu.hpp:120-139), or "scan" (zero-scatter: sorts +
-        cumulative max + gathers only — for backends where XLA scatter
-        serializes). Identical results. Default "auto" picks per platform
-        (ops.reindex.resolve_dedup: cpu->map measured, tpu->scan).
-        ``QUIVER_DEDUP`` overrides the "auto" resolution ONLY — an
-        explicit strategy here keeps what it names (the ignored force is
-        logged once; see resolve_dedup).
+      dedup: "auto" or "scan", one meaning: the reindex has one
+        algorithm (ops.reindex.masked_unique). Kept because the benchmark
+        passes it by name (ROADMAP D14); "sort" and "map" were removed
+        and raise.
       device_topo: advanced — reuse an existing DeviceTopology (built with
         compatible to_device flags) instead of uploading a fresh copy;
         lets many sampler configurations share one device-resident graph.
@@ -454,11 +345,15 @@ class GraphSageSampler:
                     "pick one biased draw per sampler"
                 )
         self.time_window = time_window
-        # the request rides verbatim; resolution (which may run the
-        # measured election) happens at first use via the kernel property
-        self._kernel = validate_kernel_arg(str(kernel))
-        self.dedup = resolve_dedup(str(dedup))  # validates; "auto" -> platform
-        if self._kernel == "pallas":
+        self.kernel = settle_sample_kernel(str(kernel))
+        if dedup not in ("auto", "scan"):
+            raise ValueError(
+                f"dedup must be 'auto' or 'scan', got {dedup!r}" + (
+                    ": 'sort' and 'map' were removed, on a v5e both cost "
+                    "4-5x the one algorithm left (PERF.md, PR 29)"
+                    if dedup in ("sort", "map") else "")
+            )
+        if self.kernel == "pallas":
             # an explicit pallas request fails loudly on the one capability
             # the fused kernel cannot provide: the HBM-resident CSR it DMAs
             # from. Every sampler VARIANT (weighted/temporal/with_eid) now
@@ -521,18 +416,6 @@ class GraphSageSampler:
         # on (seed_cap, caps), and an unbounded per-instance dict would pin
         # every superseded program (and its captured constants) forever
         self._compiled_cache = OrderedDict()
-
-    @property
-    def kernel(self) -> str:
-        """The resolved sampler kernel ("pallas"|"xla"). ``_kernel`` holds
-        the constructor request verbatim; resolution (which may run the
-        measured election) is cached at first use — never the constructor
-        (same lazy contract as feature.KernelChoice)."""
-        resolved = getattr(self, "_kernel_resolved", None)
-        if resolved is None:
-            resolved = resolve_sample_kernel(self._kernel)
-            self._kernel_resolved = resolved
-        return resolved
 
     def _init_topo(self, device_topo):
         """Build (or adopt) the device-resident topology. The mesh-sharded
@@ -640,14 +523,13 @@ class GraphSageSampler:
         weighted = self.weighted
         kernel = self.kernel
         with_eid = self.with_eid
-        dedup = self.dedup
         time_window = self.time_window
 
         @jax.jit
         def run(topo, seeds, num_seeds, key):
             return multilayer_sample(topo, seeds, num_seeds, key, sizes, caps,
                                      weighted=weighted, kernel=kernel,
-                                     with_eid=with_eid, dedup=dedup,
+                                     with_eid=with_eid,
                                      time_window=time_window)
 
         self._compiled_cache[cache_key] = (run, caps)

@@ -24,7 +24,7 @@ six graftscope timeline stages, and lands the ``serve.*`` counters on a
 
 Scale-out rides two more pieces: :class:`AOTExecutableCache` persists
 every compiled ladder program (serialized backend executable, fingerprint
--keyed, shared disk cache beside ``QUIVER_ELECTION_CACHE``) so a replica
+-keyed, shared disk cache under ``QUIVER_AOT_CACHE``) so a replica
 — even in a fresh process — warms by *deserializing* instead of
 compiling; :class:`ServingFleet` runs N replicas over one shared
 store/controller/cache with least-depth routing and fleet-level

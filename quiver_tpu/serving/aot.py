@@ -13,12 +13,12 @@ StableHLO and recompile on load — only the compiled-executable form
 replays with ZERO compiles), and this module persists those bytes in a
 shared disk cache so a new replica deserializes instead of compiling.
 
-Cache discipline (shared with the kernel-election cache, ops/election.py):
+Cache discipline:
 
 * **Keying** — a :func:`program_fingerprint` over everything the
   compiled program closed over: the graftaudit-style target id
   (``serve.sample``/``serve.forward``), bucket size, ladder geometry
-  (fanouts, lane caps), sampler config (kernel, dedup, weighted), the
+  (fanouts, lane caps), sampler config (kernel, weighted), the
   CSR's committed ``version`` *and* the topology leaf avals (a streaming
   commit that changes edge counts changes traced shapes), the
   model/param treedef + avals, feature dtype/width, and the toolchain
@@ -26,19 +26,17 @@ Cache discipline (shared with the kernel-election cache, ops/election.py):
   backend artifacts). Any mismatch is a miss: fall back to
   compile-and-publish, never to a wrong executable.
 * **Tolerant load** — a corrupt/truncated/unpicklable entry degrades to
-  a miss with ONE warning per process
-  (:func:`~quiver_tpu.ops.election.tolerant_cache_read`); the subsequent
-  compile republishes over the bad file.
+  a miss with ONE warning per process (:func:`tolerant_cache_read`); the
+  subsequent compile republishes over the bad file.
 * **Atomic publish** — temp file + fsync + ``os.replace``
-  (:func:`~quiver_tpu.ops.election.atomic_publish_bytes`), so replicas
-  warming concurrently from the same directory never read a torn blob.
+  (:func:`atomic_publish_bytes`), so replicas warming concurrently from
+  the same directory never read a torn blob.
 
 The entries are pickles (the executable payload rides inside one), so
 the cache directory must be trusted — same threat model as the jit
 compilation cache. ``QUIVER_AOT_CACHE`` overrides the default location
-(beside ``QUIVER_ELECTION_CACHE``, under the checkout), resolved ONCE per
-process like every env knob on a potentially-traced path
-(env-before-first-use).
+(``<checkout>/.quiver_cache/aot_executables``), resolved ONCE per process
+like every env knob on a potentially-traced path (env-before-first-use).
 """
 
 from __future__ import annotations
@@ -48,11 +46,7 @@ import json
 import os
 import pickle
 
-from ..ops.election import (
-    _election_cache_path,
-    atomic_publish_bytes,
-    tolerant_cache_read,
-)
+from ..utils.backend import CHECKOUT
 from ..utils.trace import get_logger, warn_once
 
 __all__ = ["AOTExecutableCache", "program_fingerprint"]
@@ -64,18 +58,69 @@ _AOT_CACHE_DIR: str | None = None
 
 def _aot_cache_dir() -> str:
     """Default cache directory (``QUIVER_AOT_CACHE``), resolved ONCE per
-    process — beside the kernel-election cache so one knob
-    (``QUIVER_ELECTION_CACHE``) relocates the whole persisted-decision
-    family. Tests reset ``_AOT_CACHE_DIR`` to re-resolve."""
+    process. Tests reset ``_AOT_CACHE_DIR`` to re-resolve."""
     global _AOT_CACHE_DIR
     if _AOT_CACHE_DIR is None:
         _AOT_CACHE_DIR = os.environ.get(
             "QUIVER_AOT_CACHE",
-            os.path.join(
-                os.path.dirname(_election_cache_path()), "aot_executables"
-            ),
+            os.path.join(CHECKOUT, ".quiver_cache", "aot_executables"),
         )
     return _AOT_CACHE_DIR
+
+
+# The cache is an *optimization*: a hit skips a recompilation. It must be
+# fail-safe in both directions — a corrupt/truncated/unreadable file
+# degrades to a miss with ONE process-wide warning (never a raise on the
+# serve path), and a publish is atomic (readers of the shared directory
+# never observe a half-written blob, even with several replicas warming
+# concurrently).
+
+def tolerant_cache_read(path: str):
+    """Fail-safe read of one cache entry: the unpickled blob or ``None``.
+
+    A missing file is a silent miss; anything else (truncation, garbage
+    bytes, a permission error, an unpickle that chokes) is a miss plus ONE
+    warning per (process, path) — the caller recompiles and republishes
+    over the bad file, so the warning self-heals.
+    """
+    try:
+        with open(path, "rb") as f:
+            return pickle.load(f)
+    except FileNotFoundError:
+        return None
+    except Exception as e:  # noqa: BLE001 — any corruption degrades to a
+        # recompile; a cache must never be the thing that takes serving down
+        warn_once(
+            f"cache-unreadable:{path}",
+            "AOT-executable cache %s unreadable (%s: %s); ignoring it — "
+            "recompiling and republishing over it", path, type(e).__name__,
+            str(e)[:200], child="serving.aot",
+        )
+        return None
+
+
+def atomic_publish_bytes(path: str, data: bytes) -> None:
+    """Atomically publish ``data`` at ``path`` (write temp + fsync +
+    ``os.replace``): concurrent readers — other serving replicas warming
+    from the same cache — see either the old blob or the new one, never a
+    torn write. Raises ``OSError`` on failure; callers that treat the
+    cache as optional catch it."""
+    parent = os.path.dirname(path)
+    if parent:
+        os.makedirs(parent, exist_ok=True)
+    tmp_path = f"{path}.tmp.{os.getpid()}"
+    try:
+        with open(tmp_path, "wb") as f:
+            f.write(data)
+            f.flush()
+            os.fsync(f.fileno())
+        os.replace(tmp_path, path)
+    except OSError:
+        try:
+            os.unlink(tmp_path)
+        except OSError:
+            pass
+        raise
 
 
 def _executable_device_ids(compiled) -> list[int]:
@@ -137,9 +182,7 @@ class AOTExecutableCache:
         republish) is correct for all of them. Never raises.
         """
         path = self.entry_path(fingerprint)
-        blob = tolerant_cache_read(
-            path, pickle.load, what="AOT-executable", child="serving.aot"
-        )
+        blob = tolerant_cache_read(path)
         if blob is None:
             self.misses += 1
             if os.path.exists(path):

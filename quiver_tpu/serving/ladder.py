@@ -44,7 +44,7 @@ class ServeLadder:
 
     Args:
       sampler: a *replicated* :class:`GraphSageSampler` — the ladder
-        replays its device topology, fanouts, dedup and kernel choices.
+        replays its device topology, fanouts and kernel choice.
         The mesh-sharded sampler is rejected: its per-hop collectives
         assume trainer-scale frontiers, not single-seed lanes (serve
         against a replicated topology; a mesh-sharded *feature* store is
@@ -121,7 +121,7 @@ class ServeLadder:
         n_id, _n_count, adjs, overflow, _ec, _fc = multilayer_sample(
             topo, seed[None] if seed.ndim == 0 else seed, nvalid, key,
             self.sizes, self.lane_caps, weighted=s.weighted, kernel=s.kernel,
-            with_eid=False, dedup=s.dedup,
+            with_eid=False,
         )
         return n_id, tuple(a.edge_index for a in adjs), overflow
 
@@ -214,7 +214,6 @@ class ServeLadder:
             "sizes": list(self.sizes),
             "lane_caps": list(self.lane_caps),
             "kernel": s.kernel,
-            "dedup": bool(s.dedup),
             "weighted": bool(s.weighted),
             "csr_version": int(getattr(s.csr_topo, "version", 0)),
             "topo_avals": self._avals(s.topo),

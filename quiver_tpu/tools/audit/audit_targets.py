@@ -273,7 +273,7 @@ def _sample_hop():
     ei = rng.integers(0, 120, size=(2, 900)).astype(np.int64)
     topo = CSRTopo(edge_index=ei)
     sampler = GraphSageSampler(
-        topo, [3, 2], seed=7, seed_capacity=16, dedup="sort",
+        topo, [3, 2], seed=7, seed_capacity=16,
         topo_sharding="mesh", mesh=mesh,
     )
     run, _caps = sampler._compiled(16)
@@ -390,12 +390,9 @@ def _metrics_off():
     "fused sample megakernel family, interpret-mode lowering in ONE "
     "traced program: the uniform+eid hop over a host-numpy CSRTopo "
     "closure (regression: host indptr indexing broke this trace "
-    "entirely), the weighted inverse-CDF hop, and the Pallas row gather "
-    "(the QUIVER_{SAMPLE,GATHER}_KERNEL=pallas election paths)",
+    "entirely) and the weighted inverse-CDF hop (kernel='pallas')",
     sources=("quiver_tpu/ops/pallas/fused.py",
-             "quiver_tpu/ops/pallas/sample.py",
-             "quiver_tpu/ops/pallas/gather.py",
-             "quiver_tpu/ops/election.py"),
+             "quiver_tpu/ops/pallas/sample.py"),
     meta={"hbm_budget": 64 * 1024},
     # the CSR topology rides the closure as trace constants — bounded at
     # ~10KB here, and the production path passes topology as operands
@@ -408,7 +405,6 @@ def _pallas_fused():
 
     from ...core.topology import CSRTopo
     from ...ops.pallas.fused import fused_sample_layer
-    from ...ops.pallas.gather import gather_rows
 
     rng = np.random.default_rng(0)
     ei = np.stack([rng.integers(0, 64, 900), rng.integers(0, 64, 900)])
@@ -417,17 +413,15 @@ def _pallas_fused():
     wtopo = topo.to_device(with_weights=True)
     seeds = jax.ShapeDtypeStruct((16,), np.int32)
     key = jax.ShapeDtypeStruct((2,), np.uint32)
-    tbl = jax.ShapeDtypeStruct((64, 8), np.float32)
-    ids = jax.ShapeDtypeStruct((16,), np.int32)
 
-    def program(s, k, t, i):
+    def program(s, k):
         uni = fused_sample_layer(topo, s, 16, 4, k, with_eid=True,
                                  window=128, interpret=True)
         wei = fused_sample_layer(wtopo, s, 16, 4, k, weighted=True,
                                  window=128, interpret=True)
-        return uni, wei, gather_rows(t, i, interpret=True)
+        return uni, wei
 
-    return jax.jit(program).trace(seeds, key, tbl, ids)
+    return jax.jit(program).trace(seeds, key)
 
 
 def _ladder():

@@ -16,8 +16,8 @@ statically needs a conservative call-graph walk:
    attribute calls (``self.routed_gather(...)``) resolve by terminal name
    against every named function in the analyzed file set — conservative:
    homonyms all get marked. Class instantiation marks ``__init__``;
-   property *access* from traced code marks the property body (that is how
-   ``KernelChoice.kernel`` runs at trace time); local functions/lambdas
+   property *access* from traced code marks the property body (a
+   property read inside a jitted body runs at trace time); local functions/lambdas
    passed as arguments or returned from traced code are marked (closure
    callbacks like ``BucketRoute.exchange``'s ``serve``).
 3. **Barriers**: a *resolve-once* function — ``global X`` + an

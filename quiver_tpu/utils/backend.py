@@ -14,7 +14,7 @@ import os
 __all__ = ["CHECKOUT", "enable_compile_cache"]
 
 # the checkout this package was imported from; the caches that decide
-# start-up cost (compile, kernel election, serving AOT) default under it
+# start-up cost (compile, serving AOT) default under it
 CHECKOUT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )

@@ -192,7 +192,7 @@ def warn_once(key: str, msg: str, *args, child: str | None = None) -> None:
     """Log ``msg`` at WARNING level exactly once per process per ``key``.
 
     The fail-safe-degradation companion to :func:`info_once`: shared
-    on-disk caches (kernel elections, AOT serving executables) treat any
+    on-disk caches (the AOT serving executables) treat any
     corrupt/truncated file as a miss and recompute — that degradation
     must reach the operator ONCE, not once per lookup on a hot path.
     """

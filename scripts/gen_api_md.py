@@ -67,10 +67,9 @@ SECTIONS = [
      "quiver-ooc — out-of-core disk tier: raw mmap-native format, "
      "disk-backed feature store, async window staging"),
     ("quiver_tpu.ops.sample", "Sampling ops (XLA)"),
-    ("quiver_tpu.ops.reindex", "Dedup/reindex strategies"),
+    ("quiver_tpu.ops.reindex", "Dedup/reindex"),
     ("quiver_tpu.models.layers", "Message-passing primitives"),
     ("quiver_tpu.ops.pallas.sample", "Pallas windowed sampler"),
-    ("quiver_tpu.ops.pallas.gather", "Pallas row gather"),
     ("quiver_tpu.utils.reorder", "Degree-based feature reorder"),
     ("quiver_tpu.utils.checkpoint",
      "Atomic manifest checkpointing (integrity-verified)"),

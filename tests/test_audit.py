@@ -189,7 +189,7 @@ def test_changed_scoping_and_target_selection():
     assert "serve_fleet_forward" in select_targets(
         changed={"quiver_tpu/serving/aot.py"})
     assert "pallas_fused_interp" in select_targets(
-        changed={"quiver_tpu/ops/election.py"})
+        changed={"quiver_tpu/ops/pallas/fused.py"})
     # editing the auditor itself re-audits everything
     assert set(select_targets(
         changed={"quiver_tpu/tools/audit/rules.py"})) == set(REGISTRY)

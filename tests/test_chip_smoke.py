@@ -39,7 +39,7 @@ def test_stages_at_tiny_size(capsys):
     chip_smoke.stage_trainer(
         TINY, "trainer", make_mesh(1), sampler, feature, labels, meter
     )
-    chip_smoke.stage_kernels(TINY, sampler, feature, out, meter)
+    chip_smoke.stage_kernels(TINY, sampler, out, meter)
     # the multi-device stage on the virtual mesh: placement and the
     # no-implicit-transfer guard are real there, memory statistics are not
     chip_smoke.stage_multichip(TINY, topo, feat, labels, sampler, meter)

@@ -133,7 +133,7 @@ def test_csr_save_load_minimal(tmp_path):
 
 def test_resolve_platform_strategy_edge_cases(monkeypatch):
     """The shared env-override resolver behind every strategy knob
-    (QUIVER_COUNTS/QUIVER_DEDUP/QUIVER_INFER_AGG...): graftlint's
+    (QUIVER_COUNTS, QUIVER_INFER_AGG): graftlint's
     env-at-trace rule points users at this helper, so its contract is
     pinned here — empty/whitespace fall through to the platform default,
     values are case/whitespace-normalized, and a typo'd FORCE raises with

@@ -1,12 +1,12 @@
 """Dense (fanout) vs segment aggregation parity.
 
 Every sampler-built Adj now carries its static ``fanout``, switching the
-model convs to dense masked (num_dst, fanout) reductions — zero scatters,
-on the expectation that XLA serializes general scatters on TPU (the same
-reasoning as dedup="scan"; ROADMAP S2 measures it). These tests pin the invariant
-that the dense path is numerically the segment path: same Adj, same
-params, fanout set vs stripped, outputs must agree to float tolerance for
-all four homogeneous conv families plus the layer primitives.
+model convs to dense masked (num_dst, fanout) reductions — zero scatters
+(on a v5e a scatter costs 4.4x a sort of the same lanes: PERF.md, PR 26).
+These tests pin the invariant that the dense path is numerically the
+segment path: same Adj, same params, fanout set vs stripped, outputs must
+agree to float tolerance for all four homogeneous conv families plus the
+layer primitives.
 """
 
 import numpy as np

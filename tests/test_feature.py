@@ -102,28 +102,44 @@ def test_feature_delete_frees_buffers():
         _ = np.asarray(hot)  # buffer really gone
 
 
-def test_pallas_kernel_switch_matches_xla():
-    """VERDICT r1 item 2: the Pallas gather must be reachable through the
-    Feature store, not just as a dangling unit-tested kernel. Differential
-    oracle: kernel="pallas" (interpret mode on CPU) == kernel="xla" == dense
-    take, including the mixed hot/cold tier split and -1 lanes."""
+def _store(kind, t, **kw):
+    row_bytes = t.shape[1] * 4
+    if kind == "Feature":
+        return Feature(device_cache_size=100 * row_bytes, **kw
+                       ).from_cpu_tensor(t)
+    from quiver_tpu.feature.shard import ShardedFeature
+    from quiver_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(n_devices=2, data=1, feature=2)
+    return ShardedFeature(mesh, device_cache_size=50 * row_bytes, **kw
+                          ).from_cpu_tensor(t)
+
+
+@pytest.mark.parametrize("kind", ["Feature", "ShardedFeature"])
+def test_kernel_auto_and_xla_are_one_path(kind):
+    """The keyword names no second gather: the default, "auto" and "xla"
+    trace to the same program, and it is the dense take (mixed hot/cold
+    tier split and -1 lanes included)."""
     t = _table(n=300, f=16, seed=3)
-    row_bytes = 16 * 4
     ids = jnp.asarray(
         np.concatenate([np.random.default_rng(4).integers(0, 300, 60), [-1, -1]])
     )
-    fx = Feature(device_cache_size=100 * row_bytes, kernel="xla").from_cpu_tensor(t)
-    fp = Feature(device_cache_size=100 * row_bytes, kernel="pallas").from_cpu_tensor(t)
-    assert fx.kernel == "xla" and fp.kernel == "pallas"
-    ox, op = np.asarray(fx[ids]), np.asarray(fp[ids])
-    assert np.allclose(ox, op)
-    assert np.allclose(op[:60], t[np.asarray(ids)[:60]])
-    assert np.all(op[60:] == 0)
+    stores = [_store(kind, t), _store(kind, t, kernel="auto"),
+              _store(kind, t, kernel="xla")]
+    programs = {str(jax.make_jaxpr(lambda i, f=f: f[i])(ids)) for f in stores}
+    assert len(programs) == 1
+    out = np.asarray(stores[0][ids])
+    assert np.array_equal(out[:60], t[np.asarray(ids)[:60]])
+    assert np.all(out[60:] == 0)
+    assert not hasattr(stores[0], "kernel")
 
 
-def test_kernel_auto_resolves_off_tpu():
-    f = Feature(device_cache_size="1G", kernel="auto")
-    assert f.kernel == "xla"  # CPU test mesh — pallas only auto-selected on TPU
+@pytest.mark.parametrize("kind", ["Feature", "ShardedFeature"])
+def test_kernel_pallas_is_refused(kind):
+    with pytest.raises(ValueError, match="removed"):
+        _store(kind, _table(), kernel="pallas")
+    with pytest.raises(ValueError, match="kernel"):
+        _store(kind, _table(), kernel="cuda")
 
 
 def test_bf16_storage_doubles_cache_rows_and_stays_close():
@@ -212,28 +228,3 @@ def test_int8_zero_rows_exact():
     q = Feature(device_cache_size="1G", dtype="int8").from_cpu_tensor(t)
     out = np.asarray(q[jnp.asarray([7])])
     assert np.all(out == 0)
-
-
-def test_kernel_auto_is_loud_when_pallas_broken(monkeypatch):
-    """On a TPU a Pallas gather the compiler refuses is an error: the
-    smoke's exception propagates out of kernel="auto" instead of degrading
-    to xla behind one warning."""
-    from quiver_tpu.feature import feature as feature_mod
-    from quiver_tpu.ops.pallas import gather as gather_mod
-
-    def boom(*a, **k):
-        raise RuntimeError("simulated Mosaic compile failure")
-
-    monkeypatch.setattr(gather_mod, "gather_rows", boom)
-    monkeypatch.setattr(feature_mod.jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("QUIVER_GATHER_KERNEL", raising=False)
-    feature_mod.GATHER_ELECTION.reset()
-    try:
-        with pytest.raises(RuntimeError, match="simulated Mosaic"):
-            feature_mod.resolve_gather_kernel("auto")
-        assert feature_mod.GATHER_ELECTION.result is None  # nothing decided
-        # an explicit request bypasses the election either way
-        assert feature_mod.resolve_gather_kernel("pallas") == "pallas"
-        assert feature_mod.resolve_gather_kernel("xla") == "xla"
-    finally:
-        feature_mod.GATHER_ELECTION.reset()

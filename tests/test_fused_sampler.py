@@ -90,16 +90,15 @@ def test_hop_bitwise_full_batch_and_wide_fanout():
     _assert_hop_bitwise(wdev, k=17, num=64, cap=64, weighted=True)
 
 
-# -- sampler-level parity across dedup modes --------------------------------
+# -- sampler-level parity ---------------------------------------------------
 
 
-@pytest.mark.parametrize("dedup", ["sort", "map", "scan"])
-def test_sampler_parity_across_dedup_modes(dedup):
+def test_sampler_parity_across_kernels():
     """Full GraphSageSampler outputs (n_id, every layer's edge_index and
     e_id) are bitwise identical between kernel='pallas' and 'xla' — the
-    reindex stage downstream sees identical draws, whatever the dedup."""
+    reindex stage downstream sees identical draws."""
     t = _topo()
-    kw = dict(seed=5, seed_capacity=64, dedup=dedup, with_eid=True)
+    kw = dict(seed=5, seed_capacity=64, with_eid=True)
     sp = GraphSageSampler(t, [5, 3], kernel="pallas", **kw)
     sx = GraphSageSampler(t, [5, 3], kernel="xla", **kw)
     seeds = np.random.default_rng(2).integers(0, t.node_count, 60)
@@ -118,7 +117,7 @@ def test_sampler_parity_across_dedup_modes(dedup):
 
 def _dist_pair(topo, sizes, F=2, **kw):
     mesh = make_mesh(n_devices=F, data=1, feature=F)
-    mk = dict(seed=7, seed_capacity=32, dedup="sort",
+    mk = dict(seed=7, seed_capacity=32,
               topo_sharding="mesh", mesh=mesh, **kw)
     return (GraphSageSampler(topo, sizes, kernel="pallas", **mk),
             GraphSageSampler(topo, sizes, kernel="xla", **mk))

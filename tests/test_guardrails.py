@@ -1,6 +1,6 @@
-"""Guardrail satellites (ISSUE 1): QUIVER_CHECK layout assertion, honest
-QUIVER_DEDUP contract, inert-parity-arg signals, and the DataParallelTrainer
-auto-cap pinning that removes the mid-epoch _stack raise."""
+"""Guardrail satellites (ISSUE 1): QUIVER_CHECK layout assertion, the list
+of environment names the package reads, inert-parity-arg signals, and the
+DataParallelTrainer auto-cap pinning that removes the mid-epoch _stack raise."""
 
 import logging
 
@@ -83,23 +83,24 @@ def test_dense_gate_shape_fallback_logged(caplog):
     assert any("segment-scatter" in r.message for r in caplog.records)
 
 
-# -- QUIVER_DEDUP honesty (ADVICE reindex.py:31) ---------------------------
+# -- the environment the package reads --------------------------------------
 
-def test_dedup_env_applies_to_auto_only_and_logs(monkeypatch, caplog):
-    from quiver_tpu.ops import reindex as R
+def test_the_environment_names_the_package_reads():
+    """Every ``QUIVER_*`` name in ``quiver_tpu/`` is in the one list in
+    ``docs/Introduction.md`` and the list names nothing else: a new knob
+    (or a removed one) shows up in a diff of that list."""
+    import pathlib
+    import re
 
-    # the force is read once per process (env-before-first-use); reset the
-    # caches so this test's env value is the one resolved
-    monkeypatch.setattr(R, "_forced_dedup", None)
-    monkeypatch.setattr(R, "_auto_dedup", None)
-    monkeypatch.setenv("QUIVER_DEDUP", "scan")
-    assert R.resolve_dedup("auto") == "scan"  # env wins for auto
-    with caplog.at_level(logging.INFO, logger="quiver_tpu"):
-        assert R.resolve_dedup("sort") == "sort"  # explicit wins over env
-    assert any("QUIVER_DEDUP" in r.message and "ignored" in r.message
-               for r in caplog.records)
-    monkeypatch.setattr(R, "_forced_dedup", None)
-    monkeypatch.setattr(R, "_auto_dedup", None)  # leave no pin
+    root = pathlib.Path(__file__).resolve().parent.parent
+    name = re.compile(r"QUIVER_[A-Z0-9_]+")
+    read = set()
+    for path in (root / "quiver_tpu").rglob("*.py"):
+        read.update(name.findall(path.read_text()))
+    doc = (root / "docs" / "Introduction.md").read_text()
+    section = doc.split("## Environment variables the package reads", 1)[1]
+    listed = set(name.findall(section.split("\n## ", 1)[0]))
+    assert read == listed, (sorted(read - listed), sorted(listed - read))
 
 
 # -- inert parity-arg signals (VERDICT r5 weak #7) -------------------------

@@ -83,7 +83,13 @@ class TestBackendGate:
 
     def test_bench_py_gates_before_any_metric(self, monkeypatch, capsys):
         import bench
+        from quiver_tpu.utils import backend
 
+        # run_guarded turns the persistent compile cache on for the whole
+        # process before the gate: left on, every later compile of this
+        # worker goes through <checkout>/.jax_cache (what broke
+        # tests/test_serving_fleet.py under six workers until PR 30)
+        monkeypatch.setattr(backend, "enable_compile_cache", lambda: "")
         monkeypatch.setattr(sys, "argv", ["bench.py", "--nodes", "2000"])
         with pytest.raises(SystemExit) as exc:
             bench.main()
@@ -258,30 +264,15 @@ def _bench_records(*argv):
     return recs, r
 
 
-def test_microbench_emits_all_primitives():
-    """The primitive microbench must produce one record per building block
-    (the dedup diagnosis depends on all six being present)."""
-    recs, r = _bench_records("benchmarks.microbench", "--smoke")
-    ops = {x["op"] for x in recs if x["metric"] == "primitive-Melem/s"}
-    assert ops == {"sort", "argsort-pair", "gather", "scatter-set",
-                   "scatter-min", "cummax"}, r.stderr[-400:]
-    assert all(x["value"] > 0 for x in recs)
-
-
 @pytest.mark.slow
-def test_dedup_both_emits_fastest_stream_first():
-    """--dedup both must emit its stream records fastest-first (the first
-    SEPS record is the headline), with all three strategies present and the
-    per-call record last.
+def test_stream_record_comes_before_percall():
+    """--stream emits its one stream record first (the first SEPS record is
+    the headline) and the per-call record last.
 
-    slow: a full bench-harness subprocess — compiles three dedup variants
-    end-to-end (~35 s); the emit-ordering logic it pins is host-side and
-    changes rarely."""
+    slow: a full bench-harness subprocess, compiled end to end."""
     recs, r = _bench_records("benchmarks.bench_sampler", "--smoke",
-                             "--stream", "2", "--dedup", "both")
-    streams = [x for x in recs if x.get("dispatch") == "stream"]
-    assert len(streams) == 3, r.stdout + r.stderr[-500:]
-    assert {x["dedup"] for x in streams} == {"sort", "map", "scan"}
-    vals = [x["value"] for x in streams]
-    assert vals == sorted(vals, reverse=True)
-    assert recs[-1]["dispatch"] == "percall"
+                             "--stream", "2")
+    seps = [x for x in recs if x["metric"] == "sampled-edges/sec/chip"]
+    assert [x["dispatch"] for x in seps] == ["stream", "percall"], (
+        r.stdout + r.stderr[-500:])
+    assert all(x["value"] > 0 and "dedup" not in x for x in seps)

@@ -90,35 +90,6 @@ def test_hetero_sampler_contract():
     }
 
 
-@pytest.mark.slow  # 15s hetero 3-way dedup differential
-def test_hetero_dedup_alternatives_match_sort():
-    """dedup='map' and dedup='scan' must reproduce dedup='sort' exactly
-    across every node type's frontier and every relation's edge_index
-    (same seed path)."""
-    topo, edges, _ = _toy_schema(seed=9)
-    seeds = np.arange(24)
-    outs = {}
-    for dedup in ("sort", "map", "scan"):
-        s = HeteroGraphSampler(topo, [3, 2], input_type="paper", seed=4,
-                               dedup=dedup)
-        outs[dedup] = s.sample(seeds)
-    a = outs["sort"]
-    for other in ("map", "scan"):
-        b = outs[other]
-        assert set(a.n_id) == set(b.n_id)
-        for t in a.n_id:
-            assert np.array_equal(
-                np.asarray(a.n_id[t]), np.asarray(b.n_id[t])
-            ), (other, t)
-        for la, lb in zip(a.adjs, b.adjs):
-            assert set(la.adjs) == set(lb.adjs)
-            for et in la.adjs:
-                assert np.array_equal(
-                    np.asarray(la.adjs[et].edge_index),
-                    np.asarray(lb.adjs[et].edge_index),
-                ), (other, et)
-
-
 def test_hetero_sampled_edges_are_real():
     topo, edges, _ = _toy_schema(seed=3)
     sampler = HeteroGraphSampler(topo, [4, 3], input_type="paper", seed=1)

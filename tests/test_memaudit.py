@@ -239,7 +239,7 @@ def test_builder_import_closure_covered_by_sources():
     assert not stale, f"exempt modules now in sources: {sorted(stale)}"
     # the PR 16-18 subsystems are load-bearing sources, never exemptions
     required = {
-        "quiver_tpu/ops/election.py", "quiver_tpu/serving/aot.py",
+        "quiver_tpu/serving/aot.py",
         "quiver_tpu/serving/fleet.py", "quiver_tpu/ooc/store.py",
         "quiver_tpu/ooc/format.py", "quiver_tpu/ooc/stager.py",
     }

@@ -1,8 +1,8 @@
 """Pallas kernel tests (interpret mode on CPU; compiled on real TPU).
 
-Differential oracles: dense take for the gather kernel, the sample-validity
-invariants (membership/counts/distinctness) for the windowed sampler — the
-same oracles the XLA paths are held to (SURVEY §4)."""
+Differential oracles: the sample-validity invariants
+(membership/counts/distinctness) for the windowed sampler — the same oracles
+the XLA paths are held to (SURVEY §4)."""
 
 import numpy as np
 import jax
@@ -10,24 +10,9 @@ import jax.numpy as jnp
 import pytest
 
 from quiver_tpu import CSRTopo
-from quiver_tpu.ops.pallas.gather import gather_rows
 from quiver_tpu.ops.pallas.sample import sample_layer_windowed
 from quiver_tpu.ops.sample import sample_layer, stratified_offsets
 from quiver_tpu.utils.graphgen import generate_pareto_graph
-
-
-def test_gather_rows_matches_dense():
-    t = np.random.default_rng(0).normal(size=(300, 128)).astype(np.float32)
-    ids = np.random.default_rng(1).integers(0, 300, 77)  # non-multiple of tile
-    out = np.asarray(gather_rows(jnp.asarray(t), jnp.asarray(ids, jnp.int32)))
-    assert np.allclose(out, t[ids])
-
-
-def test_gather_rows_narrow_features():
-    t = np.random.default_rng(2).normal(size=(100, 32)).astype(np.float32)
-    ids = np.arange(100)
-    out = np.asarray(gather_rows(jnp.asarray(t), jnp.asarray(ids, jnp.int32), tile=8))
-    assert np.allclose(out, t)
 
 
 def test_stratified_offsets_distinct_and_bounded():
@@ -94,41 +79,15 @@ def test_windowed_sampler_small_graph_rejected():
         )
 
 
-# -- jitted-lowering smoke (the QUIVER_GATHER_KERNEL election contract) -------
+# -- jitted-lowering smoke ---------------------------------------------------
 #
-# The election (feature._hot_gather_fn / resolve_gather_kernel) can route
-# EVERY hot-tier gather through the Pallas kernels inside jitted trainer
-# and serving programs — where the kernels run under jax.jit tracing, not
-# eagerly. These smokes pin that lowering path: sample_layer_windowed once
-# indexed a host-numpy indptr with a tracer and broke ONLY under jit,
-# which no eager test could see. graftaudit's pallas_* targets keep the
-# trace/lower half checked statically; these keep interpret-mode execution
-# bitwise-equal to eager.
-
-
-def test_gather_rows_jitted_matches_eager():
-    t = np.random.default_rng(5).normal(size=(120, 16)).astype(np.float32)
-    ids = np.random.default_rng(6).integers(0, 120, 33).astype(np.int32)
-    fn = lambda tbl, i: gather_rows(tbl, i, interpret=True)  # noqa: E731
-    eager = np.asarray(fn(jnp.asarray(t), jnp.asarray(ids)))
-    jitted = np.asarray(jax.jit(fn)(jnp.asarray(t), jnp.asarray(ids)))
-    assert np.array_equal(eager, jitted)
-    assert np.array_equal(eager, t[ids])
-
-
-def test_hot_gather_election_int8_jitted():
-    # the int8 tier stores codes; the elected pallas gather must move them
-    # un-upcast under jit exactly as the xla take does
-    from quiver_tpu.feature.feature import _hot_gather_fn
-
-    codes = np.random.default_rng(7).integers(
-        -128, 128, size=(90, 8)).astype(np.int8)
-    ids = np.random.default_rng(8).integers(0, 90, 40).astype(np.int32)
-    tbl = jnp.asarray(codes)
-    for kernel in ("pallas", "xla"):
-        out = jax.jit(_hot_gather_fn(tbl, kernel))(jnp.asarray(ids))
-        assert out.dtype == jnp.int8, kernel
-        assert np.array_equal(np.asarray(out), codes[ids]), kernel
+# kernel="pallas" runs the kernel inside jitted trainer and serving
+# programs — under jax.jit tracing, not eagerly. This smoke pins that
+# lowering path: sample_layer_windowed once indexed a host-numpy indptr
+# with a tracer and broke ONLY under jit, which no eager test could see.
+# graftaudit's pallas_fused_interp target keeps the trace/lower half
+# checked statically; this keeps interpret-mode execution bitwise-equal
+# to eager.
 
 
 def test_windowed_sampler_jitted_matches_eager():

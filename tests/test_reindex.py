@@ -1,7 +1,8 @@
 """Differential + property tests for order-preserving masked unique / reindex.
 
-Oracle: the hash-map reference in ops/cpu_ref.py (parity with the reference's
-reindex_group, quiver.cpp:39-84).
+Oracles, neither of them JAX: the plain-Python first-occurrence contract
+below and the hash-map reference in ops/cpu_ref.py (parity with the
+reference's reindex_group, quiver.cpp:39-84).
 """
 
 import pytest
@@ -11,15 +12,6 @@ import jax.numpy as jnp
 from quiver_tpu.ops.reindex import (
     _spread_bits, masked_unique, reindex_layer)
 from quiver_tpu.ops.cpu_ref import reindex_layer_ref
-
-
-def _first_occurrence_unique(xs):
-    seen, out = set(), []
-    for x in xs:
-        if x not in seen:
-            seen.add(x)
-            out.append(x)
-    return out
 
 
 def test_masked_unique_basic():
@@ -58,64 +50,14 @@ def test_masked_unique_overflow():
     assert list(local) == [0, 1, 2, -1, -1]  # overflowed get -1
 
 
-def test_masked_unique_random_vs_python():
-    rng = np.random.default_rng(0)
-    for trial in range(10):
-        t = int(rng.integers(1, 200))
-        ids = rng.integers(0, 50, t)
-        valid = rng.random(t) < 0.8
-        uniq, n, local = masked_unique(jnp.asarray(ids), jnp.asarray(valid), size=t)
-        expect = _first_occurrence_unique(ids[valid].tolist())
-        assert int(n) == len(expect)
-        assert list(np.asarray(uniq[: len(expect)])) == expect
-        # local ids consistent: uniq[local[p]] == ids[p] for valid p
-        la = np.asarray(local)
-        ua = np.asarray(uniq)
-        for p in range(t):
-            if valid[p]:
-                assert ua[la[p]] == ids[p]
-            else:
-                assert la[p] == -1
-
-
-@pytest.mark.slow  # 37s 3-way differential; map/scan spot checks stay fast
-def test_masked_unique_alternatives_match_sort():
-    """The sort-free dense-map dedup (node_bound) AND the zero-scatter scan
-    dedup must be bit-identical to the sort path on every output, across
-    duplicates, invalid lanes, forced (duplicated) seed lanes, and capacity
-    overflow."""
-    rng = np.random.default_rng(7)
-    for trial in range(20):
-        t = int(rng.integers(1, 300))
-        bound = int(rng.integers(5, 80))
-        ids = rng.integers(0, bound, t)
-        valid = rng.random(t) < 0.8
-        forced = int(rng.integers(0, min(t, 10)))
-        size = int(rng.integers(1, t + 5))
-        got = masked_unique(
-            jnp.asarray(ids), jnp.asarray(valid), size=size,
-            num_forced=forced,
-        )
-        for kw in ({"node_bound": bound}, {"scatter_free": True}):
-            alt = masked_unique(
-                jnp.asarray(ids), jnp.asarray(valid), size=size,
-                num_forced=forced, **kw,
-            )
-            for a, b, name in zip(got, alt, ("uniq", "n", "local")):
-                assert np.array_equal(np.asarray(a), np.asarray(b)), (
-                    trial, kw, name, np.asarray(a), np.asarray(b)
-                )
-
-
-def test_masked_unique_scan_all_invalid_and_oversize():
-    """Scan-strategy edge cases: every lane invalid, and size > T."""
+def test_masked_unique_all_invalid_and_oversize():
+    """Every lane invalid, and size > T."""
     ids = jnp.asarray([5, 5, 2])
     none = jnp.zeros(3, bool)
-    uniq, n, local = masked_unique(ids, none, size=6, scatter_free=True)
+    uniq, n, local = masked_unique(ids, none, size=6)
     assert int(n) == 0
     assert np.all(np.asarray(uniq) == -1) and np.all(np.asarray(local) == -1)
-    uniq, n, local = masked_unique(ids, jnp.ones(3, bool), size=6,
-                                   scatter_free=True)
+    uniq, n, local = masked_unique(ids, jnp.ones(3, bool), size=6)
     assert list(np.asarray(uniq)) == [5, 2, -1, -1, -1, -1]
     assert int(n) == 2 and list(np.asarray(local)) == [0, 0, 1]
 
@@ -137,6 +79,46 @@ def _masked_unique_oracle(ids, valid, size, forced):
     return (uniq + [-1] * size)[:size], len(uniq), local
 
 
+def _contract_case(t, bound, seed, p_valid=0.8, forced=None, size=None):
+    """Random lanes over ``bound`` ids; the forced prefix is drawn like the
+    rest, so it holds duplicates and invalid lanes as a batch may."""
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, bound, t)
+    valid = rng.random(t) < p_valid
+    forced = int(rng.integers(0, min(t, 10) + 1)) if forced is None else forced
+    size = int(rng.integers(1, t + 5)) if size is None else size
+    return ids, valid, size, forced
+
+
+# few duplicates: four ids a lane; many: a lane in eight is a new id
+_CONTRACT_CASES = {
+    **{f"T{t}_few_duplicates": (t, 4 * t, 100 + t)
+       for t in (1, 2, 63, 64, 65, 300, 1024)},
+    **{f"T{t}_many_duplicates": (t, max(2, t // 8), 200 + t)
+       for t in (1, 2, 63, 64, 65, 300, 1024)},
+    "forced_duplicates": (200, 6, 1, 1.0, 32, 200),
+    "overflow": (400, 300, 2, 0.9, 8, 17),
+    "mostly_invalid": (512, 40, 3, 0.05),
+}
+
+
+@pytest.mark.parametrize("case", list(_CONTRACT_CASES))
+def test_masked_unique_matches_the_plain_contract(case):
+    """The one path against the plain-Python contract, ``num_forced`` and
+    ``size`` included: what the differentials between three JAX strategies
+    used to hold, against an oracle that is not JAX."""
+    ids, valid, size, forced = _contract_case(*_CONTRACT_CASES[case])
+    want = _masked_unique_oracle(ids.tolist(), valid.tolist(), size, forced)
+    got = masked_unique(jnp.asarray(ids, jnp.int32), jnp.asarray(valid),
+                        size=size, num_forced=forced)
+    for g, w, name in zip(got, want, ("uniq", "num_unique", "local")):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), (case, name)
+    # and the contract's own reading: a valid lane's label finds its id
+    uniq, local = np.asarray(got[0]), np.asarray(got[2])
+    kept = valid & (local >= 0)
+    assert np.array_equal(uniq[local[kept]], ids[kept])
+
+
 def _random_case(t, forced, size, bound, seed):
     """Random lanes over ``bound`` ids, a fifth invalid past the forced
     prefix (whose lanes are distinct: ops/cpu_ref.py then speaks too)."""
@@ -151,8 +133,8 @@ def _random_case(t, forced, size, bound, seed):
 _TOP = np.iinfo(np.int32).max - 1
 
 # (ids, valid, size, forced), or a function that makes them; the number of
-# broadcast passes of dedup="scan" follows from (T, size) alone, so the
-# shapes below force 1, 2 and 3 of them
+# broadcast passes follows from (T, size) alone, so the shapes below force
+# 1, 2 and 3 of them
 _COMPACT_CASES = {
     "all_invalid": ([5, 5, 2, 9], [0, 0, 0, 0], 3, 2),
     "size_over_T": ([5, 3, 5, 7], [1, 1, 1, 1], 9, 1),
@@ -179,8 +161,8 @@ _PASSES = {"one_pass": 1, "two_passes": 2, "three_passes": 3}
 @pytest.mark.parametrize("fn", ["masked_unique", "reindex_layer"])
 @pytest.mark.parametrize("case", list(_COMPACT_CASES))
 def test_scan_compaction_edge_cases(case, fn):
-    """The payload sorts of dedup="scan" against dedup="sort", dedup="map",
-    the plain-Python contract and ops/cpu_ref.py."""
+    """The payload sorts against the plain-Python contract and
+    ops/cpu_ref.py."""
     made = _COMPACT_CASES[case]
     ids, valid, size, forced = made() if callable(made) else made
     ids = np.asarray(ids, np.int32)
@@ -201,14 +183,9 @@ def test_scan_compaction_edge_cases(case, fn):
         assert ref_frontier.tolist()[:size] == want_uniq[:want_n][:size]
         ref_col = np.where(ref_col < size, ref_col, -1)
         assert ref_col[0].tolist() == want_local[forced:]
-    # the dense map of dedup="map" is as long as its id space
-    bound = int(ids.max()) + 1
-    others = [{}] + ([{"node_bound": bound}] if bound < 1 << 24 else [])
     if fn == "masked_unique":
-        args = (jnp.asarray(ids), jnp.asarray(valid), size)
-        scan = masked_unique(*args, num_forced=forced, scatter_free=True)
-        others = [masked_unique(*args, num_forced=forced, **kw)
-                  for kw in others]
+        got = masked_unique(jnp.asarray(ids), jnp.asarray(valid), size,
+                            num_forced=forced)
         want = (want_uniq, want_n, want_local)
     else:
         # seeds = the forced lanes (a valid prefix), one neighbour row each
@@ -217,17 +194,13 @@ def test_scan_compaction_edge_cases(case, fn):
         nbr[:len(ids) - forced] = lanes[forced:]
         args = (jnp.asarray(lanes[:forced]), jnp.int32(valid[:forced].sum()),
                 jnp.asarray(nbr.reshape(forced, k)), size)
-        scan = reindex_layer(*args, scatter_free=True)
-        others = [reindex_layer(*args, **kw) for kw in others]
+        got = reindex_layer(*args)
         col = np.full(forced * k, -1)
         col[:len(ids) - forced] = want_local[forced:]
         want = (want_uniq, min(want_n, size), col.reshape(forced, k),
                 max(want_n - size, 0))
-    for other in others:
-        for got, theirs in zip(scan, other):
-            assert np.array_equal(np.asarray(got), np.asarray(theirs)), case
-    for got, expect in zip(scan, want):
-        assert np.array_equal(np.asarray(got), np.asarray(expect)), case
+    for out, expect in zip(got, want):
+        assert np.array_equal(np.asarray(out), np.asarray(expect)), case
 
 
 @pytest.mark.parametrize("T", [1, 2, 3, 63, 64, 65, 16_384, 178_816, 852_480,
@@ -252,29 +225,6 @@ def test_the_cells_hops_take_one_or_two_passes():
     hops = {(16_384, 16_256): 1, (178_816, 142_080): 2, (852_480, 672_384): 2,
             (26_624, 30_208): 1, (332_288, 195_328): 2}
     assert {k: _spread_bits(*k)[1] for k in hops} == hops
-
-
-def test_sampler_dedup_alternatives_match_sort():
-    """End-to-end: GraphSageSampler(dedup='map'|'scan') reproduces
-    dedup='sort' exactly (same seed, same key path)."""
-    from quiver_tpu import CSRTopo, GraphSageSampler
-
-    rng = np.random.default_rng(3)
-    ei = np.stack([rng.integers(0, 500, 4000), rng.integers(0, 500, 4000)])
-    topo = CSRTopo(edge_index=ei)
-    seeds = rng.integers(0, topo.node_count, 64)
-    outs = {}
-    for dedup in ("sort", "map", "scan"):
-        s = GraphSageSampler(topo, [5, 3], seed=11, dedup=dedup)
-        outs[dedup] = s.sample(seeds)
-    a = outs["sort"]
-    for other in ("map", "scan"):
-        b = outs[other]
-        assert np.array_equal(np.asarray(a.n_id), np.asarray(b.n_id)), other
-        for adj_a, adj_b in zip(a.adjs, b.adjs):
-            assert np.array_equal(
-                np.asarray(adj_a.edge_index), np.asarray(adj_b.edge_index)
-            ), other
 
 
 def test_sampler_device_topo_reuse():
@@ -366,51 +316,13 @@ def test_complete_permutation_rejects_overlong():
         complete_permutation(jnp.arange(10, dtype=jnp.int32), 5)
 
 
-def test_resolve_dedup_platform_and_env(monkeypatch):
-    """'auto' -> platform default (cpu->map here; tpu->scan by policy),
-    QUIVER_DEDUP overrides, explicit names pass through untouched. The
-    resolution is pinned ONCE per process (env-before-first-use — the
-    resolver runs inside traced sampler bodies, graftlint env-at-trace);
-    flipping the env mid-process requires a cache reset, which is exactly
-    what a live model can NOT do."""
-    from quiver_tpu.ops import reindex as R
-
-    def reset():
-        monkeypatch.setattr(R, "_forced_dedup", None)
-        monkeypatch.setattr(R, "_auto_dedup", None)
-
-    reset()
-    monkeypatch.delenv("QUIVER_DEDUP", raising=False)
-    assert R.resolve_dedup("sort") == "sort"  # explicit passthrough
-    assert R.resolve_dedup("auto") == "map"  # tests pin JAX_PLATFORMS=cpu
-    monkeypatch.setenv("QUIVER_DEDUP", "scan")
-    # without a reset the pinned resolution stays — env after first use is
-    # inert by contract
-    assert R.resolve_dedup("auto") == "map"
-    reset()
-    assert R.resolve_dedup("auto") == "scan"
-    import pytest
-
-    reset()
-    monkeypatch.setenv("QUIVER_DEDUP", "bogus")  # a typo'd FORCE must raise
-    with pytest.raises(ValueError, match="QUIVER_DEDUP"):
-        R.resolve_dedup("auto")
-    with pytest.raises(ValueError, match="dedup"):
-        R.resolve_dedup("hash")  # unknown explicit name rejected too
-    reset()  # leave no pin for other tests
-
-
-def test_sampler_dedup_auto_resolves(monkeypatch):
+def test_sampler_dedup_auto_samples():
     from quiver_tpu import CSRTopo, GraphSageSampler
 
-    monkeypatch.delenv("QUIVER_DEDUP", raising=False)
     rng = np.random.default_rng(0)
     topo = CSRTopo(edge_index=rng.integers(0, 50, (2, 400)).astype(np.int64))
     s = GraphSageSampler(topo, [3], seed_capacity=16)
-    assert s.dedup == "map"  # resolved, never the literal "auto"
     out = s.sample(np.arange(16))
     assert int(out.n_count) >= 16
-    import pytest
-
     with pytest.raises(ValueError, match="dedup"):
         GraphSageSampler(topo, [3], dedup="hash")

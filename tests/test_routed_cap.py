@@ -31,7 +31,7 @@ def test_capped_bit_identical_to_uncapped_no_overflow():
     zero overflow, and capped output must equal uncapped BIT-FOR-BIT."""
     mesh = make_mesh(data=2, feature=4)
     t = _table()
-    st = ShardedTensor(mesh, kernel="xla").from_cpu_tensor(t)
+    st = ShardedTensor(mesh).from_cpu_tensor(t)
     rng = np.random.default_rng(1)
     for n in (64, 301, 777):
         ids = rng.integers(0, t.shape[0], n).astype(np.int32)
@@ -47,7 +47,7 @@ def test_capped_explicit_cap_and_invalid_lanes():
     return zero rows and never eat bucket capacity."""
     mesh = make_mesh(data=2, feature=4)
     t = _table()
-    st = ShardedTensor(mesh, kernel="xla").from_cpu_tensor(t)
+    st = ShardedTensor(mesh).from_cpu_tensor(t)
     ids = np.concatenate([
         np.random.default_rng(2).integers(0, t.shape[0], 90),
         [-1] * 6,
@@ -63,7 +63,7 @@ def test_forced_overflow_served_by_fallback():
     exactly, and the count is observable as batch metadata."""
     mesh = make_mesh(data=2, feature=4)
     t = _table()
-    st = ShardedTensor(mesh, kernel="xla").from_cpu_tensor(t)
+    st = ShardedTensor(mesh).from_cpu_tensor(t)
     rng = np.random.default_rng(3)
     # rows_per_shard = 200: ids < 200 all live on shard 0
     ids = rng.integers(0, st.rows_per_shard, 256).astype(np.int32)
@@ -77,7 +77,7 @@ def test_forced_overflow_served_by_fallback():
 def test_no_overflow_on_clean_batch_metadata_zero():
     mesh = make_mesh(data=2, feature=4)
     t = _table()
-    st = ShardedTensor(mesh, kernel="xla").from_cpu_tensor(t)
+    st = ShardedTensor(mesh).from_cpu_tensor(t)
     # round-robin over the 4 owning shards: every device's 32-lane slice
     # sends 8 requests per bucket, well under cap=ceil(2*32/4)=16
     lanes = np.arange(256)
@@ -93,7 +93,7 @@ def test_auto_tuner_grows_alpha_until_overflow_stops():
     overflowed batch, saturating at alpha=F (the uncapped program)."""
     mesh = make_mesh(data=2, feature=4)
     t = _table()
-    st = ShardedTensor(mesh, kernel="xla").from_cpu_tensor(t)
+    st = ShardedTensor(mesh).from_cpu_tensor(t)
     st.routed_alpha = 1.0
     ids = np.random.default_rng(4).integers(
         0, st.rows_per_shard, 256).astype(np.int32)  # all on shard 0

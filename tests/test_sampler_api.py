@@ -1,9 +1,12 @@
 """End-to-end GraphSageSampler contract tests (PyG-compat output)."""
 
 import numpy as np
+import pytest
+import jax
 import jax.numpy as jnp
 
 from quiver_tpu import CSRTopo, GraphSageSampler
+from quiver_tpu.sampling.saint import SAINTEdgeSampler, SAINTNodeSampler
 from quiver_tpu.utils.graphgen import generate_pareto_graph
 
 
@@ -112,8 +115,6 @@ def test_duplicate_seeds_keep_positions():
 
 
 def test_out_of_range_seeds_rejected():
-    import pytest
-
     topo, sampler = _sampler(n=100)
     with pytest.raises(ValueError, match="seed ids"):
         sampler.sample(np.array([5, 100]))
@@ -172,3 +173,217 @@ def test_eid_with_pallas_kernel():
         src = np.asarray(adj.edge_index)[0]
         eids = np.asarray(adj.e_id)
         assert np.array_equal(eids >= 0, src >= 0)
+
+
+# -- every caller of masked_unique, on the one reindex ----------------------
+#
+# What the reindex owes each sampler, read off the sampler's own output at
+# its own lane count T: the forced prefix verbatim (``n_id[:batch] ==
+# seeds``, duplicates included), every other kept id distinct, and every
+# edge's local ids naming a CSR neighbour. The oracle is the host CSR.
+
+def _dup_seeds(n_nodes, batch, seed):
+    """``batch`` seeds with repeats among them."""
+    rng = np.random.default_rng(seed)
+    seeds = rng.integers(0, n_nodes, batch)
+    seeds[batch // 2:] = seeds[: batch - batch // 2]
+    return rng.permutation(seeds)
+
+
+def _assert_frontier(n_id, seeds):
+    n_id = np.asarray(n_id)
+    batch = len(seeds)
+    assert np.array_equal(n_id[:batch], seeds)
+    rest = n_id[batch:]
+    rest = rest[rest >= 0]
+    assert len(np.unique(rest)) == len(rest)
+    assert not np.isin(rest, seeds).any()
+
+
+def _assert_edges(edge_index, src_ids, dst_ids, indptr, indices):
+    """Each valid (src, dst) lane: ``src_ids[src]`` is in the CSR row of
+    ``dst_ids[dst]``. Returns the number of lanes checked."""
+    src, dst = np.asarray(edge_index)
+    keep = src >= 0
+    assert np.array_equal(keep, dst >= 0)
+    u, v = np.asarray(src_ids)[src[keep]], np.asarray(dst_ids)[dst[keep]]
+    assert (u >= 0).all() and (v >= 0).all()
+    for a, b in zip(u.tolist(), v.tolist()):
+        assert a in indices[indptr[b]:indptr[b + 1]]
+    return int(keep.sum())
+
+
+def _homogeneous(sizes, batch=64, n=600, avg_deg=12.0, mesh=None, **kw):
+    ei = generate_pareto_graph(n, avg_deg, seed=4)
+    topo = CSRTopo(edge_index=ei)
+    if kw.get("weighted"):
+        topo.set_edge_weight(
+            np.random.default_rng(5).random(topo.edge_count) + 0.1)
+    if "time_window" in kw:
+        topo.set_edge_time(np.random.default_rng(6).random(topo.edge_count))
+    seeds = _dup_seeds(n, batch, seed=7)
+    if mesh is not None:
+        sampler = GraphSageSampler(topo, sizes, seed=3, topo_sharding="mesh",
+                                   mesh=mesh, **kw)
+        outs = sampler.sample_per_worker(seeds, key=jax.random.PRNGKey(1))
+        blocks = np.array_split(seeds, sampler.workers)
+    else:
+        sampler = GraphSageSampler(topo, sizes, seed=3, **kw)
+        outs, blocks = [sampler.sample(seeds)], [seeds]
+    checked = 0
+    for out, blk in zip(outs, blocks):
+        _assert_frontier(out.n_id, blk)
+        for adj in out.adjs:
+            checked += _assert_edges(adj.edge_index, out.n_id, out.n_id,
+                                     topo.indptr, topo.indices)
+    return checked
+
+
+def _hetero(mesh=None):
+    from quiver_tpu import HeteroCSRTopo, HeteroGraphSampler
+    from quiver_tpu.sampling.dist_hetero import DistHeteroSampler
+
+    rng = np.random.default_rng(8)
+    nodes = {"paper": 120, "author": 60}
+    topo = HeteroCSRTopo(nodes, {
+        ("paper", "cites", "paper"): rng.integers(0, 120, (2, 500)),
+        ("author", "writes", "paper"): np.stack(
+            [rng.integers(0, 60, 400), rng.integers(0, 120, 400)]),
+        ("paper", "written_by", "author"): np.stack(
+            [rng.integers(0, 120, 400), rng.integers(0, 60, 400)]),
+    })
+    seeds = _dup_seeds(120, 32, seed=9)
+    if mesh is not None:
+        sampler = DistHeteroSampler(topo, [3, 2], input_type="paper", seed=2,
+                                    mesh=mesh)
+        outs = sampler.sample_per_worker(seeds, key=jax.random.PRNGKey(1))
+        blocks = np.array_split(seeds, len(outs))
+    else:
+        sampler = HeteroGraphSampler(topo, [3, 2], input_type="paper", seed=2)
+        outs, blocks = [sampler.sample(seeds)], [seeds]
+    checked = 0
+    for out, blk in zip(outs, blocks):
+        _assert_frontier(out.n_id["paper"], blk)
+        _assert_frontier(out.n_id["author"], blk[:0])
+        for layer in out.adjs:
+            for (s_t, _, d_t), adj in layer.adjs.items():
+                rel = topo.relations[(s_t, _, d_t)]
+                checked += _assert_edges(
+                    adj.edge_index, out.n_id[s_t], out.n_id[d_t],
+                    rel.indptr, rel.indices)
+    return checked
+
+
+def _saint(cls):
+    ei = generate_pareto_graph(500, 8.0, seed=10)
+    topo = CSRTopo(edge_index=ei)
+    sub = cls(topo, 96, deg_cap=topo.max_degree, seed=1).sample()
+    _assert_frontier(sub.node_id, np.zeros(0, np.int64))
+    # an induced edge (u, v): v is in u's row
+    src, dst = np.asarray(sub.edge_index)
+    return _assert_edges(np.stack([dst, src]), sub.node_id, sub.node_id,
+                         topo.indptr, topo.indices)
+
+
+def _serve_ladder():
+    from quiver_tpu.models.sage import GraphSAGE
+    from quiver_tpu.serving.ladder import ServeLadder
+
+    ei = generate_pareto_graph(400, 10.0, seed=11)
+    topo = CSRTopo(edge_index=ei)
+    sampler = GraphSageSampler(topo, [5, 5], seed=0)
+    ladder = ServeLadder(
+        sampler, GraphSAGE(hidden=8, num_classes=4, num_layers=2), 8)
+    checked = 0
+    for seed in (3, 77, 250):
+        n_id, edge_indices, overflow = ladder._lane_sample(
+            sampler.topo, jnp.int32(seed), jnp.int32(1), jnp.int32(seed),
+            jax.random.PRNGKey(0))
+        assert int(overflow) == 0
+        _assert_frontier(n_id, np.array([seed]))
+        for ei_l in edge_indices:
+            checked += _assert_edges(ei_l, n_id, n_id, topo.indptr,
+                                     topo.indices)
+    return checked
+
+
+def _mesh2():
+    from quiver_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(n_devices=2, data=1, feature=2)
+
+
+# the first two are the benchmark's hop shapes, the batch cut to 64: T is
+# 64 * 16, then (cap + cap * 10), (cap + cap * 5) of the planned frontiers
+_REINDEX_CALLERS = {
+    "products_hops": lambda: _homogeneous([15, 10, 5], frontier_caps="auto"),
+    "reddit_hops": lambda: _homogeneous(
+        [25, 10], avg_deg=40.0, frontier_caps="auto"),
+    "weighted": lambda: _homogeneous([5, 3], weighted=True),
+    "with_eid": lambda: _homogeneous([5, 3], with_eid=True),
+    "temporal": lambda: _homogeneous([5, 3], time_window=(0.2, 0.9)),
+    "hetero": _hetero,
+    "dist_mesh2": lambda: _homogeneous([4, 3], mesh=_mesh2()),
+    "dist_hetero_mesh2": lambda: _hetero(mesh=_mesh2()),
+    "saint_node": lambda: _saint(SAINTNodeSampler),
+    "saint_edge": lambda: _saint(SAINTEdgeSampler),
+    "serve_ladder": _serve_ladder,
+}
+
+
+@pytest.mark.parametrize("caller", list(_REINDEX_CALLERS))
+def test_every_sampler_reindexes_by_the_contract(caller):
+    assert _REINDEX_CALLERS[caller]() > 20
+
+
+# -- the two keywords that take one value ------------------------------------
+
+@pytest.mark.parametrize("dedup", ["sort", "map"])
+def test_dedup_takes_auto_and_scan_only(dedup):
+    topo, _ = _sampler(n=50)
+    with pytest.raises(ValueError, match="removed"):
+        GraphSageSampler(topo, [3], dedup=dedup)
+    for ok in ("auto", "scan"):
+        assert not hasattr(GraphSageSampler(topo, [3], dedup=ok), "dedup")
+
+
+def test_kernel_auto_is_xla_whatever_the_backend(monkeypatch, tmp_path):
+    """On a TPU the default constructors settle their kernel from the
+    argument alone: nothing compiled, nothing timed, nothing written."""
+    import time
+
+    import jax.monitoring
+
+    from quiver_tpu.feature.feature import Feature
+    from quiver_tpu.feature.shard import ShardedFeature
+    from quiver_tpu.utils import backend
+
+    ei = generate_pareto_graph(200, 6.0, seed=0)
+    topo = CSRTopo(edge_index=ei)
+    mesh = _mesh2()
+    monkeypatch.setattr(backend, "CHECKOUT", str(tmp_path))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiles = []
+
+    def on_compile(name, *_a, **_k):
+        if "compil" in name:
+            compiles.append(name)
+
+    def no_clock(*_a, **_k):
+        raise AssertionError("a constructor read the clock")
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        with monkeypatch.context() as m:
+            for fn in ("time", "perf_counter", "monotonic"):
+                m.setattr(time, fn, no_clock)
+            sampler = GraphSageSampler(topo, [3])
+            Feature()
+            ShardedFeature(mesh)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert sampler.kernel == "xla"
+    assert GraphSageSampler(topo, [3], kernel="auto").kernel == "xla"
+    assert GraphSageSampler(topo, [3], kernel="pallas").kernel == "pallas"
+    assert compiles == []
+    assert not list(tmp_path.rglob("*"))

@@ -29,6 +29,30 @@ from quiver_tpu.serving.aot import program_fingerprint
 from test_serving import FakeClock, _graph, _stack
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _fresh_compiles_only():
+    """Every executable this module publishes is compiled here, by this
+    module: JAX's persistent compilation cache is off while it runs.
+
+    XLA:CPU cannot re-serialize an executable that the persistent cache
+    served (``serialize`` drops its compiled functions; the entry loads,
+    then fails at the first run with ``NOT_FOUND: ... Function
+    <name>_fusion not found``). So if an earlier test of the same worker
+    left that cache on, the republish in ``test_corrupt_aot_entry_recovers``
+    published such an entry and the tests after it failed: whether this
+    module passed depended on what the process had done before.
+    """
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
 @pytest.fixture(scope="module")
 def warm_stack(tmp_path_factory):
     """One shared graph/model stack + one disk AOT cache populated by a
@@ -98,7 +122,7 @@ def test_warm_replica_zero_compiles_bitwise(warm_stack):
 
 def test_corrupt_aot_entry_recovers(warm_stack, caplog):
     """A truncated cache entry degrades to compile-and-republish with a
-    single WARNING (the election cache's tolerant loader); the republish
+    single WARNING (``aot.tolerant_cache_read``); the republish
     heals the entry so the NEXT replica is compile-free again."""
     import pathlib
 

@@ -141,7 +141,7 @@ def test_sharded_tensor_routed_standalone_matches_psum_and_dense():
     mesh = make_mesh(data=2, feature=4)
     rng = np.random.default_rng(3)
     table = rng.normal(size=(777, 12)).astype(np.float32)
-    st = ShardedTensor(mesh, kernel="xla").from_cpu_tensor(table)
+    st = ShardedTensor(mesh).from_cpu_tensor(table)
     for n in (8, 301, 777):
         ids = rng.integers(0, 777, n).astype(np.int32)
         a = np.asarray(st.gather(jnp.asarray(ids)))

@@ -105,10 +105,9 @@ def test_dist_parity_mesh8():
     topo = _graph(n=500)
     mesh = make_mesh(data=1, feature=8)
     dist = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                            dedup="sort", topo_sharding="mesh", mesh=mesh)
+                            topo_sharding="mesh", mesh=mesh)
     assert isinstance(dist, DistGraphSageSampler)
-    rep = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                           dedup="sort")
+    rep = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32)
     seeds = np.random.default_rng(1).integers(
         0, topo.node_count, 32 * dist.workers - 5
     )
@@ -127,10 +126,10 @@ def test_dist_parity_weighted_mesh2():
     )
     mesh = make_mesh(n_devices=2, data=1, feature=2)
     dist = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                            dedup="sort", topo_sharding="mesh", mesh=mesh,
+                            topo_sharding="mesh", mesh=mesh,
                             weighted=True)
     rep = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                           dedup="sort", weighted=True)
+                           weighted=True)
     seeds = np.random.default_rng(6).integers(0, topo.node_count, 61)
     _assert_worker_parity(dist, rep, seeds, jax.random.PRNGKey(11))
 
@@ -144,10 +143,10 @@ def test_dist_parity_temporal_mesh2():
     mesh = make_mesh(n_devices=2, data=1, feature=2)
     win = (0.2, 0.8)
     dist = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                            dedup="sort", topo_sharding="mesh", mesh=mesh,
+                            topo_sharding="mesh", mesh=mesh,
                             time_window=win)
     rep = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                           dedup="sort", time_window=win)
+                           time_window=win)
     seeds = np.random.default_rng(9).integers(0, topo.node_count, 61)
     _assert_worker_parity(dist, rep, seeds, jax.random.PRNGKey(13))
 
@@ -171,10 +170,10 @@ def test_dist_parity_attr_widths(kind, F):
         kw["time_window"] = (0.2, 0.8)
     mesh = make_mesh(n_devices=F, data=1, feature=F)
     dist = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                            dedup="sort", topo_sharding="mesh", mesh=mesh,
+                            topo_sharding="mesh", mesh=mesh,
                             routed_alpha=0.25, **kw)
     rep = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                           dedup="sort", **kw)
+                           **kw)
     seeds = np.random.default_rng(F).integers(0, topo.node_count,
                                               32 * F - 3)
     _assert_worker_parity(dist, rep, seeds, jax.random.PRNGKey(F))
@@ -188,9 +187,8 @@ def test_dist_parity_other_mesh_widths(F):
     topo = _graph(n=500)
     mesh = make_mesh(n_devices=F, data=1, feature=F)
     dist = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                            dedup="sort", topo_sharding="mesh", mesh=mesh)
-    rep = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                           dedup="sort")
+                            topo_sharding="mesh", mesh=mesh)
+    rep = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32)
     seeds = np.random.default_rng(F).integers(
         0, topo.node_count, 32 * F - 3
     )
@@ -205,10 +203,9 @@ def test_forced_overflow_exact():
     topo = _graph(n=500)
     mesh = make_mesh(n_devices=4, data=1, feature=4)
     dist = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                            dedup="sort", topo_sharding="mesh", mesh=mesh,
+                            topo_sharding="mesh", mesh=mesh,
                             routed_alpha=0.01)
-    rep = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32,
-                           dedup="sort")
+    rep = GraphSageSampler(topo, [4, 3], seed=7, seed_capacity=32)
     # all seeds on shard 0's row range
     seeds = np.random.default_rng(2).integers(
         0, dist.topo.rows_per_shard, 32 * 4

@@ -85,12 +85,12 @@ def _tracing_disabled():
     trace._enabled = None
 
 
-def build(fanout, caps, data=1, feature=1, dedup="scan", **kwargs):
+def build(fanout, caps, data=1, feature=1, **kwargs):
     ei = generate_pareto_graph(300, 6.0, seed=0)
     topo = quiver.CSRTopo(edge_index=ei)
     sampler = quiver.GraphSageSampler(
         topo, list(fanout), frontier_caps=list(caps), kernel="xla",
-        dedup=dedup)
+        dedup="scan")
     mesh = make_mesh(data=data, feature=feature,
                      devices=jax.devices()[:data * feature])
     rows = np.random.default_rng(0).normal(
@@ -260,14 +260,10 @@ def test_the_overflow_fallback_has_its_scope():
     assert any(rx.search(p) for p in paths if "route_fallback" in p)
 
 
-@pytest.mark.parametrize("dedup", ["sort", "map", "scan"])
-def test_every_dedup_strategy_has_the_three_phases(dedup):
+def test_the_reindex_has_the_three_phases():
     def run(seeds, nbr):
         with trace.trace_scope("reindex_layer_0"):
-            return reindex_layer(
-                seeds, jnp.int32(6), nbr, 16,
-                node_bound=40 if dedup == "map" else None,
-                scatter_free=dedup == "scan")
+            return reindex_layer(seeds, jnp.int32(6), nbr, 16)
 
     text = jax.jit(run).lower(
         jnp.arange(8, dtype=jnp.int32),
@@ -275,7 +271,7 @@ def test_every_dedup_strategy_has_the_three_phases(dedup):
     ).compile().as_text()
     paths = _OP_NAME.findall(text)
     for phase in ("dedup", "compact", "relabel"):
-        assert has(paths, rf"reindex_layer_0/{phase}/"), (dedup, phase)
+        assert has(paths, rf"reindex_layer_0/{phase}/"), phase
 
 
 # -- the step keeps the sampler's counts --------------------------------------
