@@ -197,7 +197,10 @@ class ShardedTensor:
         a full data worker over its own seed block while the table stays
         sharded (see docs/Introduction.md "Cost of redundant sampling").
 
-        Comm model (L = per-device request length, F = feature-axis size):
+        Comm model (L = per-device request length, F = feature-axis size;
+        beside it each device pays the plan — F running counts and one
+        sort of L lanes — and two row gathers, the owner's over F x cap
+        slots and its own over L lanes):
 
         * ``cap=None`` — exact-safe full-length buckets: every destination
           bucket is padded to L (worst case all ids on one shard), so each
@@ -242,8 +245,8 @@ class ShardedTensor:
 
         # one audited code path for both comm modes and both consumers
         # (feature gather here, neighbor sampling in sampling/dist.py):
-        # parallel.routing.BucketRoute owns the sort-by-owner bucketing,
-        # the two all_to_all hops, and the cond-gated psum fallback
+        # parallel.routing.BucketRoute owns the owner bucketing, the two
+        # all_to_all hops, and the cond-gated psum fallback
         my = jax.lax.axis_index(self.axis)
         rps = self.rows_per_shard
 

@@ -23,7 +23,6 @@ __all__ = [
     "masked_unique",
     "reindex_layer",
     "inverse_permutation",
-    "inverse_permutation_gather",
     "complete_permutation",
 ]
 
@@ -34,13 +33,6 @@ def inverse_permutation(p):
     for_each."""
     n = p.shape[0]
     return jnp.zeros(n, p.dtype).at[p].set(jnp.arange(n, dtype=p.dtype))
-
-
-def inverse_permutation_gather(p):
-    """The zero-scatter sibling of :func:`inverse_permutation`: argsort of
-    a permutation IS its inverse. Costs a sort instead of a scatter (the
-    routed feature gather un-buckets through it)."""
-    return jnp.argsort(p).astype(jnp.int32)
 
 
 def complete_permutation(p, n: int):

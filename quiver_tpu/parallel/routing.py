@@ -4,13 +4,17 @@ Extracted from the PR 1 capped-bucket routed gather (feature/shard.py) so
 the two per-hop consumers — the sharded-feature gather and the distributed
 neighbor sampler (sampling/dist.py) — drive ONE audited code path:
 
-1. sort my per-device requests by owning shard (stable, so results can be
-   unsorted with a gather through the inverse permutation — no scatter);
-2. pack destination buckets CAPPED at ``cap`` lanes each and exchange them
-   with one ``all_to_all`` over the mesh axis (``F x cap`` lanes per hop
-   instead of the exact-safe worst case ``F x L``);
-3. serve the received requests locally (the caller's ``serve`` closure) and
-   return the answers with a second ``all_to_all``;
+1. give every request its slot in its owner's bucket — its running count
+   among my lanes of the same owner, in lane order — and group the ids by
+   owner with one sort that carries them as its payload (no gather through
+   a sort order, no ``searchsorted``, no scatter);
+2. cut destination buckets CAPPED at ``cap`` lanes each out of that sorted
+   array as contiguous slices and exchange them with one ``all_to_all``
+   over the mesh axis (``F x cap`` lanes per hop instead of the exact-safe
+   worst case ``F x L``);
+3. serve the received requests locally (the caller's ``serve`` closure),
+   return the answers with a second ``all_to_all``, and read them once, in
+   lane order, at ``owner * cap + slot``;
 4. lanes past their bucket's capacity are DETECTED in-program, never
    silent: they are served exactly through a psum fallback (all_gather the
    <= L-cap overflow requests over the axis, every shard contributes the
@@ -33,8 +37,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-from ..ops.reindex import inverse_permutation_gather
 from ..utils.trace import trace_scope
 
 __all__ = ["BucketRoute"]
@@ -43,11 +47,22 @@ __all__ = ["BucketRoute"]
 class BucketRoute:
     """One planned owner-routing of a per-device request vector.
 
-    Call inside ``shard_map``. The plan (owner sort, bucket bounds, overflow
-    mask) is computed once; :meth:`exchange` can then route any number of
-    request/payload exchanges through the same buckets — the distributed
-    sampler uses this to route ids, then per-id sample offsets, without
-    re-sorting.
+    Call inside ``shard_map``. The plan (slots, bucket bounds, the ids
+    grouped by owner, overflow mask) is computed once; :meth:`exchange` can
+    then route any number of request/payload exchanges through the same
+    buckets — the distributed sampler uses this to route ids, then per-id
+    sample offsets, without planning again.
+
+    What the plan costs: ``F`` running counts over the ``L`` lanes (a lane's
+    slot is its rank among the lanes of its owner; ``F`` is static, the
+    mesh axis's size, so the counts are one ``(F, L)`` ``cumsum``) and one
+    sort of ``L`` lanes, keyed by ``owner * L + lane``, that carries the
+    ids. Nothing is gathered through the sort order: bucket ``f`` is the
+    slice of the sorted ids that starts at the number of lanes owned below
+    ``f``, and only an exchange that carries a payload reads the order. In
+    the four-chip step on v5e, at 672,384 lanes and ``F`` 2, ``route_plan``
+    reads 0.77 ms where the argsort, ``searchsorted`` and five gathers it
+    replaced read 18.79 (one traced pair, PERF.md, PR 31).
 
     Args:
       ids: (L,) int request keys; invalid lanes may hold anything (they are
@@ -79,28 +94,37 @@ class BucketRoute:
         cap = int(cap)
         if cap < 1:
             raise ValueError(f"cap must be >= 1, got {cap}")
+        if (F + 1) * L > jnp.iinfo(jnp.int32).max:
+            raise ValueError(
+                f"BucketRoute: {L} lanes over {F} shards leave the sort key "
+                "no room in 32 bits")
         self.axis = axis
         self.num_shards = F
-        self.length = L
         self.cap = cap
 
         self._valid = valid
-        safe = jnp.where(valid, ids, 0)
+        self._ids = jnp.where(valid, ids, 0)
         # invalid lanes go to a sentinel bucket F past the real ones: they
         # are never routed, eat no bucket capacity, and cannot fake overflow
-        owner = jnp.where(valid, jnp.clip(owner, 0, F - 1), F)
-        order = jnp.argsort(owner, stable=True)
-        self._order = order
-        self._sorted_ids = safe[order]
-        sorted_owner = owner[order]
-        sorted_valid = valid[order]
-        bounds = jnp.searchsorted(
-            sorted_owner, jnp.arange(F + 1, dtype=sorted_owner.dtype)
+        key = jnp.where(valid, jnp.clip(owner, 0, F - 1), F).astype(jnp.int32)
+        # a lane's slot in its bucket: how many lanes of its owner precede
+        # it (0 on invalid lanes, which are masked wherever slots are read)
+        mine = key[None, :] == jnp.arange(F, dtype=jnp.int32)[:, None]
+        running = lax.cumsum(mine.astype(jnp.int32), axis=1)
+        self._counts = running[:, -1]
+        self._start = jnp.cumsum(self._counts) - self._counts
+        slot = jnp.sum(jnp.where(mine, running - 1, 0), axis=0)
+        # the ids grouped by owner, lane order kept inside a group: bucket f
+        # is sorted[start[f] : start[f] + count[f]]. The sort's one key
+        # packs the lane under the owner, so it needs no stability and its
+        # sorted key gives the order back (read by payload exchanges only)
+        word, self._sorted_ids = lax.sort(
+            (key * L + jnp.arange(L, dtype=jnp.int32), self._ids),
+            num_keys=1, is_stable=False,
         )
-        self._start, ends = bounds[:F], bounds[1:]
-        self._counts = ends - self._start
-        self._owner_c = jnp.clip(sorted_owner, 0, F - 1)
-        self._slot = jnp.arange(L, dtype=jnp.int32) - self._start[self._owner_c]
+        self._order = word % L
+        # where each lane's answer lands in the returned (F * cap) buffer
+        self._back = jnp.where(valid & (slot < cap), key * cap + slot, 0)
 
         # overflow bookkeeping (statically absent when cap == L)
         self.ov_budget = L - cap
@@ -108,14 +132,14 @@ class BucketRoute:
             self._ov_mask = None
             self.overflow = jnp.zeros((), jnp.int32)
         else:
-            self._ov_mask = sorted_valid & (self._slot >= cap)
+            self._ov_mask = valid & (slot >= cap)
             ov_local = jnp.sum(self._ov_mask.astype(jnp.int32))
             self._ov_local = ov_local
             # axis-psum'd: uniform across the axis group — the fallback
             # cond's deadlock-free predicate, and the count callers surface
             self.overflow = jax.lax.psum(ov_local, axis)
             # compact my overflow lanes to the static budget (overflow lanes
-            # first in sorted order: False < True, stable)
+            # first, in lane order: False < True, stable)
             self._ov_take = jnp.argsort(~self._ov_mask, stable=True)[
                 : self.ov_budget
             ]
@@ -137,14 +161,21 @@ class BucketRoute:
 
     @trace_scope("route_plan")
     def _bucketize(self, sorted_vals, fill):
-        """(L, ...) sorted per-lane values -> (F, cap, ...) send buckets:
-        the first ``cap`` lanes per destination, ``fill`` elsewhere."""
-        F, cap, L = self.num_shards, self.cap, self.length
-        j = jnp.arange(cap, dtype=jnp.int32)[None, :]
-        pos = jnp.clip(self._start[:, None] + j, 0, L - 1)
-        live = j < jnp.minimum(self._counts, cap)[:, None]
-        vals = sorted_vals[pos]  # (F, cap, ...)
-        live = live.reshape(live.shape + (1,) * (vals.ndim - 2))
+        """(L, ...) per-lane values grouped by owner -> (F, cap, ...) send
+        buckets: the first ``cap`` lanes per destination, ``fill``
+        elsewhere. A bucket is a contiguous slice; the padding keeps the
+        last one's ``cap`` lanes inside the array."""
+        F, cap = self.num_shards, self.cap
+        trailing = sorted_vals.shape[1:]
+        padded = jnp.pad(
+            sorted_vals, ((0, cap),) + ((0, 0),) * len(trailing))
+        vals = jnp.stack([
+            lax.dynamic_slice_in_dim(padded, self._start[f], cap)
+            for f in range(F)
+        ])
+        live = (jnp.arange(cap, dtype=jnp.int32)[None, :]
+                < jnp.minimum(self._counts, cap)[:, None])
+        live = live.reshape(live.shape + (1,) * len(trailing))
         return jnp.where(live, vals, fill)
 
     @trace_scope("route_exchange")
@@ -156,24 +187,24 @@ class BucketRoute:
         )
         return out.reshape(x.shape)
 
-    def _compact_overflow(self, sorted_vals, fill):
-        """(L, ...) sorted values -> (ov_budget, ...) overflow lanes first,
-        ``fill`` past the live count."""
-        take = sorted_vals[self._ov_take]
+    def _compact_overflow(self, vals, fill):
+        """(L, ...) per-lane values -> (ov_budget, ...) overflow lanes
+        first, ``fill`` past the live count."""
+        take = vals[self._ov_take]
         live = jnp.arange(self.ov_budget, dtype=jnp.int32) < self._ov_local
         live = live.reshape(live.shape + (1,) * (take.ndim - 1))
         return jnp.where(live, take, fill)
 
     @trace_scope("route_fallback")
-    def _answer_overflow(self, serve, sorted_payload, main):
+    def _answer_overflow(self, serve, payload, main):
         """``main`` with the lanes past their bucket's capacity answered
         through the cond-gated psum fallback."""
         F = self.num_shards
         L_ov = self.ov_budget
-        ov_ids = self._compact_overflow(self._sorted_ids, fill=-1)
+        ov_ids = self._compact_overflow(self._ids, fill=-1)
         ov_payload = (
-            None if sorted_payload is None
-            else self._compact_overflow(sorted_payload, fill=0)
+            None if payload is None
+            else self._compact_overflow(payload, fill=0)
         )
         trailing = main.shape[1:]
         dtype = main.dtype
@@ -229,16 +260,15 @@ class BucketRoute:
         fallback exact, and it is harmless on the main hop (routing already
         guarantees ownership there).
         """
-        F, cap, L = self.num_shards, self.cap, self.length
+        F, cap = self.num_shards, self.cap
         if self._recv_ids is None:
             self._recv_ids = self._a2a(
                 self._bucketize(self._sorted_ids, fill=-1)
             )
         recv_ids = self._recv_ids
-        sorted_payload = None
         if payload is not None:
-            sorted_payload = payload[self._order]
-            recv_payload = self._a2a(self._bucketize(sorted_payload, fill=0))
+            recv_payload = self._a2a(
+                self._bucketize(payload[self._order], fill=0))
             served = serve(
                 recv_ids.reshape(-1),
                 recv_payload.reshape((F * cap,) + recv_payload.shape[2:]),
@@ -247,13 +277,10 @@ class BucketRoute:
             served = serve(recv_ids.reshape(-1))
         served = served.reshape((F, cap) + served.shape[1:])
         back = self._a2a(served)
-        main = back[self._owner_c, jnp.clip(self._slot, 0, cap - 1)]
-
-        if self.ov_budget == 0:
-            answered = main
-        else:
-            answered = self._answer_overflow(serve, sorted_payload, main)
-
-        out = answered[inverse_permutation_gather(self._order)]
+        # the one pass over the rows on this side: each lane reads its slot
+        # of its owner's answers (lanes with none read row 0, masked below)
+        out = back.reshape((F * cap,) + back.shape[2:])[self._back]
+        if self.ov_budget != 0:
+            out = self._answer_overflow(serve, payload, out)
         vmask = self._valid.reshape(self._valid.shape + (1,) * (out.ndim - 1))
         return jnp.where(vmask, out, 0)

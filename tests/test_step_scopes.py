@@ -249,11 +249,49 @@ def test_accepted_patterns_still_claim_the_gather_and_the_optimizer(programs):
     assert exchanged and all(gather.search(p) for p in exchanged)
 
 
-def test_the_overflow_fallback_has_its_scope():
-    """``route_fallback`` exists only where a bucket can overflow: with
-    two shards the default budget (alpha 2) makes full-length buckets."""
-    paths = paths_of(step_instructions(*build(
-        **CELLS["products-sage.clique2x2"], routed_alpha=1.0)))
+@pytest.fixture(scope="module")
+def capped_clique():
+    """The clique's stand-in with buckets that can overflow: with two
+    shards the default budget (alpha 2) makes full-length buckets."""
+    trace.disable_trace()
+    return step_instructions(*build(
+        **CELLS["products-sage.clique2x2"], routed_alpha=1.0))
+
+
+@pytest.mark.parametrize("buckets", ["full", "capped"])
+def test_the_route_plan_sorts_and_never_gathers(
+        programs, capped_clique, buckets):
+    """The plan carries the ids as a sort's payload and cuts its buckets
+    out as slices: no gather, no ``while`` (``searchsorted``'s) and at most
+    two sorts (the plan's, and the overflow compaction's where a bucket can
+    overflow) under ``route_plan``; the rows are gathered twice under
+    ``tier_hot``, by their owner and by the requester, and un-bucketing
+    sorts nothing."""
+    program = (programs["products-sage.clique2x2"] if buckets == "full"
+               else capped_clique)
+    hot = [(op, path) for op, path in program
+           if path.startswith("feature_gather/tier_hot/")]
+    plan = [(op, path) for op, path in hot
+            if path.startswith("feature_gather/tier_hot/route_plan/")]
+    assert plan
+    gathers = [path for op, path in plan
+               if op == "gather" or path.endswith("/gather")]
+    assert not gathers, gathers[:5]
+    loops = [path for op, path in plan
+             if op == "while" or "searchsorted" in path or "while" in path]
+    assert not loops, loops[:5]
+    sorts = [path for op, path in plan if op == "sort"]
+    assert 1 <= len(sorts) <= (1 if buckets == "full" else 2), sorts
+    rows = [path for op, path in hot
+            if op == "gather" and path.endswith("/gather")
+            and "route_fallback" not in path]
+    assert 1 <= len(rows) <= 2, rows
+    assert [path for op, path in hot if op == "sort"] == sorts
+
+
+def test_the_overflow_fallback_has_its_scope(capped_clique):
+    """``route_fallback`` exists only where a bucket can overflow."""
+    paths = paths_of(capped_clique)
     assert has(paths, r"^feature_gather/tier_hot/route_fallback/")
     rx = re.compile(
         spec.load_metric("gather_route_device_ms")["args"]["pattern"])
