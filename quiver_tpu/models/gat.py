@@ -1,17 +1,37 @@
-"""Graph Attention Network over padded Adj blocks.
+"""Graph Attention Network over padded Adj blocks: ogbn-products GAT as
+PyTorch Geometric's own example trains it.
 
 The reference delegates GAT to PyG (its ogbn-products GAT config is plain
 ``torch_geometric.nn.GATConv`` fed by quiver's sampler/feature — BASELINE
-config 4 "attention aggregation, exercises segment-softmax"). quiver-tpu
-ships a TPU-native GATConv: multi-head additive attention with a
-segment-softmax over the padded edge list (-1 sentinel lanes excluded), all
-dense matmuls batched over heads so the MXU sees (E, H*F)-shaped work.
+config 4 "attention aggregation, exercises segment-softmax"). The recipe is
+that of
+https://github.com/pyg-team/pytorch_geometric/blob/master/examples/ogbn_products_gat.py:
+``GATConv`` layers (v1, Velickovic et al.) with a linear skip added to every
+layer's output, heads concatenated in the hidden layers and averaged in the
+output layer. For a layer with source rows ``h_j`` (the frontier), targets
+``i < num_dst`` (the first ``num_dst`` sources), ``H`` heads of width ``F``:
 
-Semantics follow PyG's GATConv (v1, Velickovic et al.):
-  e_ij  = LeakyReLU(a_l . (W h_j) + a_r . (W h_i))
-  alpha = softmax_i(e_ij)   (over j in N(i), per head)
-  h_i'  = concat_heads( sum_j alpha_ij W h_j )   [+ mean over heads if
-          ``concat=False``, as PyG does for the output layer]
+  z_j    = W h_j                                  (no bias)
+  s_j    = <a_src, z_j>,  d_i = <a_dst, z_i>      per head
+  L(i)   = {valid sampled lanes (j -> i) with j != i} + {i}
+  e_ij   = LeakyReLU_0.2(s_j + d_i)
+  alpha  = softmax over j in L(i), per head
+  o_i    = sum_{j in L(i)} alpha_ij z_j
+  hidden : y_i = concat_heads(o_i) + b
+  output : y_i = mean_heads(o_i) + b              (H heads, not one)
+  h'_i   = y_i + W_skip h_i + b_skip              then ELU (+ dropout) unless last
+
+**The self lane.** ``GATConv``'s self loop (PyG's ``add_self_loops=True``:
+existing self loops are removed, then one is added per node) is, in a
+sampled bipartite block, a lane that the block does not hold: a sampled lane
+whose source is its own target is dropped, and every target attends to
+itself through one self term. On the dense fanout path that term is a
+separate operand of the softmax's max and denominator and of the fanout sum
+(``layers.fanout_softmax``), so nothing is scattered and no
+``(num_dst, fanout + 1, H, F)`` copy is made to append it; the segment path
+appends the self edges to the edge list and is the differential oracle.
+
+Dense matmuls are batched over heads so the MXU sees (N, H*F)-shaped work.
 """
 
 from __future__ import annotations
@@ -21,6 +41,7 @@ from typing import Sequence
 import jax.numpy as jnp
 import flax.linen as nn
 
+from ..utils.trace import trace_scope
 from .layers import fanout_softmax, fanout_sum_aggregate, segment_softmax
 
 __all__ = ["GATConv", "GAT"]
@@ -28,6 +49,11 @@ __all__ = ["GATConv", "GAT"]
 
 class GATConv(nn.Module):
     """Multi-head graph attention over a padded edge block.
+
+    Every target also attends to itself, through the self lane of the
+    module docstring, and a linear projection (with bias) of the targets'
+    own input rows is added to the output, as the ogbn-products example's
+    ``skips`` do.
 
     Args:
       features: per-head output width F.
@@ -46,7 +72,7 @@ class GATConv(nn.Module):
         # setup-style (attribute/param names keep the original compact
         # module's tree: lin/att_l/att_r/bias) so full-graph layer-wise
         # inference (models/inference.py) can reuse trained weights through
-        # the project/finish methods
+        # the project/finish/add_skip methods
         H, F = self.heads, self.features
         self.lin = nn.Dense(H * F, use_bias=False, dtype=self.dtype,
                             name="lin")
@@ -60,6 +86,8 @@ class GATConv(nn.Module):
             "bias", nn.initializers.zeros,
             (H * F,) if self.concat else (F,),
         )
+        self.lin_skip = nn.Dense(
+            H * F if self.concat else F, dtype=self.dtype, name="skip")
 
     def project(self, x):
         """Node-level halves of the attention: per-head projections plus the
@@ -79,43 +107,76 @@ class GATConv(nn.Module):
             return out.reshape(num_dst, self.heads * self.features) + self.bias
         return out.mean(axis=1) + self.bias
 
+    def add_skip(self, y, x_dst):
+        """The layer output plus the skip projection of the targets' own
+        input rows ``x_dst``."""
+        return y + self.lin_skip(x_dst)
+
     def __call__(self, x, edge_index, num_dst: int, fanout: int | None = None):
         src, dst = edge_index[0], edge_index[1]
-        valid = (src >= 0) & (dst >= 0)
+        # a sampled lane from a target to itself is the self loop that the
+        # self lane replaces
+        valid = (src >= 0) & (dst >= 0) & (src != dst)
         src_safe = jnp.clip(src, 0)
         dense = fanout is not None and src.shape[0] == num_dst * fanout
-
-        h_all, alpha_src, alpha_dst = self.project(x)
-        alpha_dst = alpha_dst[:num_dst]
-
-        logits = alpha_src[src_safe] + alpha_dst[jnp.clip(dst, 0, num_dst - 1)]
-        logits = nn.leaky_relu(logits, self.negative_slope)  # (E, H)
-        # softmax over each destination's edges, all heads at once
-        # (computed in f32 via the att-param promotion for stability, then
-        # downcast so the big (E, H, F) message traffic runs at the compute
-        # dtype rather than silently promoting back to f32)
-        if dense:
-            alpha = fanout_softmax(logits, valid, num_dst, fanout)  # (E, H)
-        else:
+        if not dense:
             dst_safe = jnp.where(valid, dst, num_dst)  # overflow segment
-            alpha = segment_softmax(logits, dst_safe, valid, num_dst)
-        alpha = alpha.astype(h_all.dtype)
 
-        msgs = h_all[src_safe] * alpha[:, :, None]  # (E, H, F)
-        msgs = jnp.where(valid[:, None, None], msgs, 0.0)
-        H, F = self.heads, self.features
-        if dense:
-            return self.finish(fanout_sum_aggregate(msgs, valid, num_dst, fanout))
-        out = jnp.zeros((num_dst + 1, H, F), msgs.dtype).at[dst_safe].add(msgs)
-        return self.finish(out[:num_dst])
+        # the scopes are entered here and not in the methods, which flax
+        # names for itself (``conv0.project``): paths read
+        # ``conv{i}/<scope>/...`` (docs/Introduction.md)
+        with trace_scope("attn_project"):
+            h_all, alpha_src, alpha_dst = self.project(x)
+            h_dst, alpha_dst = h_all[:num_dst], alpha_dst[:num_dst]
+
+        with trace_scope("attn_logits"):
+            logits = nn.leaky_relu(
+                alpha_src[src_safe] + alpha_dst[jnp.clip(dst, 0, num_dst - 1)],
+                self.negative_slope)                             # (E, H)
+            self_logits = nn.leaky_relu(
+                alpha_src[:num_dst] + alpha_dst, self.negative_slope)
+        # softmax over each destination's lanes and its self lane, all heads
+        # at once (computed in f32 via the att-param promotion for
+        # stability, then downcast so the big (E, H, F) message traffic runs
+        # at the compute dtype rather than silently promoting back to f32)
+        with trace_scope("attn_softmax"):
+            if dense:
+                alpha, alpha_self = fanout_softmax(
+                    logits, self_logits, valid, num_dst, fanout)
+            else:
+                # the self edges appended to the edge list
+                alpha_all = segment_softmax(
+                    jnp.concatenate([logits, self_logits]),
+                    jnp.concatenate(
+                        [dst_safe, jnp.arange(num_dst, dtype=dst.dtype)]),
+                    jnp.concatenate([valid, jnp.ones((num_dst,), bool)]),
+                    num_dst)
+                alpha, alpha_self = (alpha_all[:src.shape[0]],
+                                     alpha_all[src.shape[0]:])
+            alpha = alpha.astype(h_all.dtype)
+            alpha_self = alpha_self.astype(h_all.dtype)
+
+        with trace_scope("attn_aggregate"):
+            msgs = h_all[src_safe] * alpha[:, :, None]  # (E, H, F)
+            if dense:
+                out = fanout_sum_aggregate(msgs, valid, num_dst, fanout)
+            else:
+                msgs = jnp.where(valid[:, None, None], msgs, 0.0)
+                out = jnp.zeros((num_dst + 1,) + msgs.shape[1:], msgs.dtype)
+                out = out.at[dst_safe].add(msgs)[:num_dst]
+            out = out + h_dst * alpha_self[:, :, None]
+            y = self.finish(out)
+        with trace_scope("skip"):
+            return self.add_skip(y, x[:num_dst])
 
 
 class GAT(nn.Module):
     """Multi-layer GAT consuming sampler output (adjs deepest-first).
 
-    Mirrors the PyG mini-batch GAT recipe: hidden layers concat heads + ELU;
-    the output layer averages heads (concat=False) into ``num_classes``.
-    """
+    The ogbn-products recipe of the module docstring: every layer has
+    ``heads`` heads and a linear skip; hidden layers concatenate the heads
+    (width ``heads * hidden``) and are followed by ELU and dropout; the
+    output layer averages its ``heads`` heads into ``num_classes``."""
 
     hidden: int
     num_classes: int
@@ -138,7 +199,7 @@ class GAT(nn.Module):
             last = i == self.num_layers - 1
             x = GATConv(
                 features=self.num_classes if last else self.hidden,
-                heads=1 if last else self.heads,
+                heads=self.heads,
                 concat=not last,
                 dtype=self.dtype,
                 name=f"conv{i}",

@@ -207,6 +207,14 @@ def full_neighbor_mean(topo, x_all, chunk: int = 1 << 21,
                               host, span=span)
 
 
+def _gat_edge_chunk(indptr, indices, e0, chunk: int, n: int, host: bool):
+    """(src, dst) of ``_edge_chunk`` with the graph's own self loops sent to
+    the bucket row ``n`` with the tail lanes: GATConv's self lane replaces
+    them (models/gat.py)."""
+    src, dst, _ = _edge_chunk(indptr, indices, e0, chunk, n, host)
+    return src, jnp.where(src == dst, n, dst)
+
+
 def _edge_logits(alpha_src, alpha_dst, src, dst, negative_slope):
     logit = alpha_src[src] + alpha_dst[jnp.clip(dst, 0, alpha_dst.shape[0] - 1)]
     return jax.nn.leaky_relu(logit, negative_slope)
@@ -218,7 +226,7 @@ def _edge_logits(alpha_src, alpha_dst, src, dst, negative_slope):
 def _gat_max_chunk(seg_max, a_s, a_d, indptr, indices, e0, chunk, host,
                    slope):
     n = seg_max.shape[0] - 1
-    src, dst, _ = _edge_chunk(indptr, indices, e0, chunk, n, host)
+    src, dst = _gat_edge_chunk(indptr, indices, e0, chunk, n, host)
     return seg_max.at[dst].max(_edge_logits(a_s, a_d, src, dst, slope))
 
 
@@ -233,7 +241,7 @@ def _gat_denom_accum_chunk(num, denom, h_all, seg_max, a_s, a_d, indptr,
     searchsorted, logits, exp) is identical, so splitting them would sweep
     the (possibly pinned-host multi-GB) edge array twice for nothing."""
     n = num.shape[0] - 1
-    src, dst, _ = _edge_chunk(indptr, indices, e0, chunk, n, host)
+    src, dst = _gat_edge_chunk(indptr, indices, e0, chunk, n, host)
     logit = _edge_logits(a_s, a_d, src, dst, slope)
     w = jnp.exp(logit - seg_max[dst])  # (chunk, H)
     return (
@@ -251,21 +259,24 @@ def gat_layerwise_inference(model, params, topo, x_all,
     only for SAGE): per layer, two chunked edge passes realize an exact
     whole-graph segment softmax — (1) per-destination logit max, (2) a
     fused pass accumulating both the shifted-exp denominator and the
-    weighted-message numerator — then the trained head combine/bias applies
-    via GATConv.finish. Matches the sampled model at full fanout (tested).
-    Zero-in-degree nodes output bias-only rows, the sampled path's
-    convention.
+    weighted-message numerator — then the trained head combine, bias and
+    skip apply via GATConv.finish and add_skip. The recipe is
+    ``models/gat.py``'s: every node attends to itself through the self lane,
+    which starts each node's max, denominator and numerator, and the graph's
+    own self loops are left out of the passes; every layer has
+    ``model.heads`` heads (averaged in the output layer) and a skip. Matches
+    the sampled model at full fanout (tested). Zero-in-degree nodes attend
+    to themselves alone.
     """
     x = jnp.asarray(x_all)
     indptr, indices, host = _place(topo, mode)
     n = topo.node_count
     E = int(topo.edge_count)
-    slope = None
     for i in range(model.num_layers):
         last = i == model.num_layers - 1
         conv = GATConv(
             features=model.num_classes if last else model.hidden,
-            heads=1 if last else model.heads,
+            heads=model.heads,
             concat=not last,
         )
         slope = conv.negative_slope
@@ -275,23 +286,25 @@ def gat_layerwise_inference(model, params, topo, x_all,
 
         e0s = [jnp.asarray(e0, indptr.dtype)
                for e0 in range(0, max(E, 1), chunk)]
-        seg_max = jnp.full((n + 1, H), -jnp.inf, h_all.dtype)
+        self_logit = jax.nn.leaky_relu(a_s + a_d, slope)  # (n, H)
+        # the bucket row's shift stays finite (what lands there is cut off)
+        seg_max = jnp.concatenate(
+            [self_logit, jnp.zeros((1, H), h_all.dtype)])
         for e0 in e0s:
             seg_max = _gat_max_chunk(seg_max, a_s, a_d, indptr, indices, e0,
                                      chunk, host, slope)
-        # empty destinations: keep the shift finite (their denom stays 0)
-        seg_max = jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)
-        denom = jnp.zeros((n + 1, H), h_all.dtype)
-        num = jnp.zeros((n + 1, H, h_all.shape[2]), h_all.dtype)
+        w_self = jnp.exp(self_logit - seg_max[:n])
+        pad = ((0, 1), (0, 0))
+        denom = jnp.pad(w_self, pad)
+        num = jnp.pad(w_self[:, :, None] * h_all, pad + ((0, 0),))
         for e0 in e0s:
             num, denom = _gat_denom_accum_chunk(
                 num, denom, h_all, seg_max, a_s, a_d, indptr, indices, e0,
                 chunk, host, slope,
             )
-        out = num[:n] / jnp.maximum(
-            denom[:n], jnp.finfo(h_all.dtype).tiny
-        )[:, :, None]
-        x = conv.apply(p_i, out, method=GATConv.finish)
+        out = num[:n] / denom[:n, :, None]
+        y = conv.apply(p_i, out, method=GATConv.finish)
+        x = conv.apply(p_i, y, x, method=GATConv.add_skip)
         if not last:
             x = jax.nn.elu(x)
     return jax.nn.log_softmax(x, axis=-1)

@@ -183,21 +183,27 @@ def segment_mean_aggregate(messages, dst, valid, num_dst: int,
     return total / jnp.maximum(cnt, 1.0)[:, None]
 
 
-def fanout_softmax(logits, valid, num_dst: int, fanout: int):
-    """Dense counterpart of ``segment_softmax`` for the regular layout:
-    per-edge softmax weights over each target's ``fanout`` lanes, no
-    scatters. ``logits`` (E, ...) -> weights (E, ...)."""
+def fanout_softmax(logits, self_logits, valid, num_dst: int, fanout: int):
+    """Dense counterpart of ``segment_softmax`` with a self term, no scatters.
+
+    The softmax runs over ``fanout + 1`` entries per target: its ``fanout``
+    sampled lanes and its own (``self_logits`` (num_dst, ...), the self loop
+    of an attention layer), which is no lane of the block, in the regular
+    layout (lane ``t * fanout + k`` targets ``t``). The self term is an
+    operand of its own of the max and of the denominator, so nothing is
+    scattered and no ``(num_dst, fanout + 1, ...)`` copy is made to append
+    it; it also keeps every denominator at 1 or more, all-invalid targets
+    included.
+    ``logits`` (E, ...) -> weights (E, ...) and self weights (num_dst, ...)."""
     shape = logits.shape
     validb = valid.reshape(valid.shape + (1,) * (logits.ndim - 1))
     neg = jnp.finfo(logits.dtype).min
     g = jnp.where(validb, logits, neg).reshape((num_dst, fanout) + shape[1:])
-    gmax = g.max(axis=1, keepdims=True)  # finite even for all-invalid rows
-    # all-invalid rows are handled by the g > neg mask (their exp(0) lanes
-    # are zeroed), not by the max
-    expv = jnp.where(g > neg, jnp.exp(g - gmax), 0.0)
-    denom = jnp.maximum(expv.sum(axis=1, keepdims=True),
-                        jnp.finfo(logits.dtype).tiny)
-    return (expv / denom).reshape(shape)
+    gmax = jnp.maximum(g.max(axis=1), self_logits)
+    expv = jnp.where(g > neg, jnp.exp(g - gmax[:, None]), 0.0)
+    exp_self = jnp.exp(self_logits - gmax)
+    denom = expv.sum(axis=1) + exp_self
+    return (expv / denom[:, None]).reshape(shape), exp_self / denom
 
 
 def segment_softmax(logits, seg, valid, num_seg: int):

@@ -69,26 +69,38 @@ def test_dense_matches_segment(sampled, family):
 
 
 def test_fanout_softmax_matches_segment_softmax():
+    """The dense softmax over each target's ``fanout`` lanes and its self
+    term against the segment softmax over the same lanes with one self
+    edge per target appended."""
     from quiver_tpu.models.layers import fanout_softmax, segment_softmax
 
     rng = np.random.default_rng(0)
     S, K, H = 12, 6, 3
     logits = jnp.asarray(rng.normal(size=(S * K, H)).astype(np.float32))
-    valid = jnp.asarray(rng.random(S * K) < 0.7)
+    own = jnp.asarray(rng.normal(size=(S, H)).astype(np.float32))
+    valid = np.asarray(rng.random(S * K) < 0.7)
+    valid[2 * K:3 * K] = False  # a target with no valid lane
+    valid = jnp.asarray(valid)
     dst = jnp.repeat(jnp.arange(S), K)
-    seg = jnp.where(valid, dst, S)
-    a_seg = segment_softmax(logits, seg, valid, S)
-    a_dense = fanout_softmax(logits, valid, S, K)
+    seg = jnp.concatenate([jnp.where(valid, dst, S), jnp.arange(S)])
+    a_seg = segment_softmax(
+        jnp.concatenate([logits, own]), seg,
+        jnp.concatenate([valid, jnp.ones(S, bool)]), S)
+    a_dense, a_own = fanout_softmax(logits, own, valid, S, K)
     # compare on valid lanes only (invalid lanes: dense gives 0, segment
     # gives exp(min)/tiny garbage that callers mask anyway)
     m = np.asarray(valid)
     np.testing.assert_allclose(
-        np.asarray(a_dense)[m], np.asarray(a_seg)[m], rtol=1e-5, atol=1e-6
-    )
-    # each target's valid weights sum to 1 (or 0 for all-invalid rows)
-    sums = np.zeros(S)
-    np.add.at(sums, np.asarray(dst)[m], np.asarray(a_dense)[m].sum(-1)[...] / H)
-    assert np.all((np.abs(sums - 1) < 1e-5) | (sums == 0))
+        np.asarray(a_dense)[m], np.asarray(a_seg)[:S * K][m],
+        rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(a_own), np.asarray(a_seg)[S * K:], rtol=1e-5, atol=1e-6)
+    assert np.all(np.asarray(a_dense)[~m] == 0)
+    # each target's weights, its own among them, sum to 1; alone it is 1
+    sums = np.asarray(a_own).copy()
+    np.add.at(sums, np.asarray(dst)[m], np.asarray(a_dense)[m])
+    np.testing.assert_allclose(sums, 1.0, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(a_own)[2], 1.0, atol=1e-6)
 
 
 def test_zero_scatter_counts_matches_bincount():

@@ -20,7 +20,6 @@ import optax
 
 import quiver_tpu as quiver
 from chipbench import spec
-from quiver_tpu.models.sage import GraphSAGE
 from quiver_tpu.ops.reindex import reindex_layer
 from quiver_tpu.parallel.mesh import make_mesh
 from quiver_tpu.parallel.trainer import DistributedTrainer
@@ -31,13 +30,28 @@ BATCH = 8
 BENCH = spec.load_benchmark()
 
 # the tiny programs stand for the benchmark's cells: same hops, same mesh,
-# same feature store and seed sharding
+# same feature store and seed sharding, the model of the cell's configuration
 CELLS = {
     "reddit-sage.hbm": dict(fanout=[3, 2], caps=[32, 64]),
     "products-sage.hbm": dict(fanout=[3, 2, 2], caps=[32, 64, 128]),
     "products-sage.clique2x2": dict(
         fanout=[3, 2, 2], caps=[32, 64, 128], data=2, feature=2),
+    "products-gat.hbm": dict(fanout=[3, 2, 2], caps=[32, 64, 128]),
 }
+
+
+def model_file(cell):
+    """The program's side of the model of the cell's configuration: what
+    builds it, and the ``SCOPE`` its ops carry in the step."""
+    cfg = spec.load_config(spec.cell(BENCH, cell)["config"])
+    return spec.load_model(cfg["model"], "models")
+
+
+def scope_of(cell) -> str:
+    return re.escape(model_file(cell).SCOPE)
+
+
+SCOPES = "|".join(sorted({scope_of(cell) for cell in CELLS}))
 
 # (b): what may sit outside every scope. Parameters, tuples, copies and
 # constants (a broadcast or iota of one included) are data movement the
@@ -52,11 +66,18 @@ PARTITIONER_CONSTANT = re.compile(r"^broadcast\.\d+$")
 
 TOP_LEVEL = re.compile(
     r"^(sample_layer_\d+|reindex_layer_\d+|feature_gather"
-    r"|jvp\(GraphSAGE\)|transpose\(jvp\(GraphSAGE\)\)"
+    rf"|jvp\((?:{SCOPES})\)|transpose\(jvp\((?:{SCOPES})\)\)"
     r"|seed_loss|jvp\(seed_loss\)|transpose\(jvp\(seed_loss\)\)"
     r"|step_keys|grad_allreduce|optax_update|step_metrics)$")
 
-# (c): the top-level scope each new metric's pattern may reach into
+# the scopes ``GATConv`` writes under ``conv{i}`` (models/gat.py), forward
+# and transposed
+ATTENTION_SCOPES = ("attn_project", "attn_logits", "attn_softmax",
+                    "attn_aggregate", "skip")
+EITHER_WAY = r"jvp\({model_scope}\)|transpose\(jvp\({model_scope}\)\)"
+
+# (c): the top-level scope each new metric's pattern may reach into;
+# ``{model_scope}`` is the scope of the cell's model
 NEW_METRICS = {
     "reindex_dedup_device_ms": r"reindex_layer_\d+",
     "reindex_compact_device_ms": r"reindex_layer_\d+",
@@ -68,9 +89,22 @@ NEW_METRICS = {
     "gather_route_device_ms": r"feature_gather",
     "gather_exchange_device_ms": r"feature_gather",
     "allreduce_device_ms": r"grad_allreduce",
-    "forward_device_ms": r"jvp\(GraphSAGE\)",
-    "backward_device_ms": r"transpose\(jvp\(GraphSAGE\)\)",
+    "forward_device_ms": r"jvp\({model_scope}\)",
+    "backward_device_ms": r"transpose\(jvp\({model_scope}\)\)",
     "optimizer_device_ms": r"optax_update",
+    "attn_project_device_ms": EITHER_WAY,
+    "attn_softmax_device_ms": EITHER_WAY,
+    "attn_aggregate_device_ms": EITHER_WAY,
+    "attn_skip_device_ms": EITHER_WAY,
+    "attn_roofline": EITHER_WAY,
+}
+# the scopes under ``conv{i}`` that each metric of the attention may read
+ATTENTION_METRICS = {
+    "attn_project_device_ms": {"attn_project"},
+    "attn_softmax_device_ms": {"attn_logits", "attn_softmax"},
+    "attn_aggregate_device_ms": {"attn_aggregate"},
+    "attn_skip_device_ms": {"skip"},
+    "attn_roofline": {"attn_logits", "attn_softmax", "attn_aggregate"},
 }
 
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%\S+ = .*?\s?([a-z][a-z0-9\-]*)\(")
@@ -85,7 +119,8 @@ def _tracing_disabled():
     trace._enabled = None
 
 
-def build(fanout, caps, data=1, feature=1, **kwargs):
+def build(fanout, caps, data=1, feature=1, cell="products-sage.hbm",
+          **kwargs):
     ei = generate_pareto_graph(300, 6.0, seed=0)
     topo = quiver.CSRTopo(edge_index=ei)
     sampler = quiver.GraphSageSampler(
@@ -104,8 +139,8 @@ def build(fanout, caps, data=1, feature=1, **kwargs):
             device_cache_size=rows.nbytes, csr_topo=topo, kernel="xla")
     trainer = DistributedTrainer(
         mesh, sampler, store.from_cpu_tensor(rows),
-        GraphSAGE(hidden=8, num_classes=4, num_layers=len(fanout),
-                  dropout=0.0),
+        model_file(cell).build({"hidden": 8, "classes": 4, "heads": 2,
+                                "layers": len(fanout), "dropout": 0.0}),
         optax.adam(1e-2), local_batch=BATCH,
         seed_sharding="all" if feature > 1 else "data", **kwargs)
     params, opt_state = trainer.init(jax.random.PRNGKey(0))
@@ -135,7 +170,7 @@ def step_instructions(trainer, params, opt_state, labels):
 def programs():
     """The compiled step of each cell's tiny stand-in, lowered once."""
     trace.disable_trace()
-    return {cell: step_instructions(*build(**shape))
+    return {cell: step_instructions(*build(**shape, cell=cell))
             for cell, shape in CELLS.items()}
 
 
@@ -157,10 +192,15 @@ def test_every_scope_of_the_tree_is_in_the_untraced_program(programs, cell):
     for l in range(hops):
         wanted += [f"^reindex_layer_{l}/(.*/)?{phase}(/|$)"
                    for phase in ("dedup", "compact", "relabel", "assemble")]
+    scope = scope_of(cell)
     wanted += [r"^feature_gather/", r"^feature_gather/tier_hot/",
-               r"^jvp\(GraphSAGE\)/", r"^transpose\(jvp\(GraphSAGE\)\)/",
+               rf"^jvp\({scope}\)/", rf"^transpose\(jvp\({scope}\)\)/",
                r"^(jvp\()?seed_loss", r"^step_keys/", r"^grad_allreduce/",
                r"^optax_update/", r"^step_metrics/"]
+    if scope == "GAT":
+        wanted += [rf"^{way}/conv{l}/{name}/"
+                   for way in (r"jvp\(GAT\)", r"transpose\(jvp\(GAT\)\)")
+                   for l in range(hops) for name in ATTENTION_SCOPES]
     if cell.endswith("clique2x2"):
         wanted += [r"^feature_gather/tier_hot/route_plan/",
                    r"^feature_gather/tier_hot/route_exchange/"]
@@ -217,25 +257,36 @@ def test_every_instruction_lies_under_one_top_level_scope(programs, cell):
 @pytest.mark.parametrize("metric", list(NEW_METRICS))
 def test_new_metric_patterns_read_their_scope_and_nothing_else(
         programs, metric):
-    file = spec.load_metric(metric)
-    assert file["reader"] == "device_time_by_scope"
-    rx = re.compile(file["args"]["pattern"])
-    inside = re.compile("^(" + NEW_METRICS[metric] + ")$")
-    cells = file.get("workloads") or [w["name"] for w in BENCH["workloads"]]
+    cells = spec.load_metric(metric).get("workloads") or [
+        w["name"] for w in BENCH["workloads"]]
     for cell in cells:
+        # as a traced run of the cell loads it: the cell's model in the fill
+        file = spec.load_metric(metric, model_file(cell).SCOPE)
+        assert file["reader"] in ("device_time_by_scope", "roofline")
+        rx = re.compile(file["args"]["pattern"])
+        inside = re.compile("^(" + NEW_METRICS[metric].replace(
+            "{model_scope}", scope_of(cell)) + ")$")
         # the reader searches the whole path, prefix included
         matched = [p for p in paths_of(programs[cell])
                    if rx.search("jit(body)/" + p)]
         assert matched, (metric, cell)
         outside = [p for p in matched if not inside.match(p.split("/")[0])]
         assert not outside, (metric, cell, outside[:5])
+        if metric in ATTENTION_METRICS:
+            # forward and transpose both, every layer, its own scopes alone
+            read = {tuple(p.split("/")[:3]) for p in matched}
+            assert {r[2] for r in read} == ATTENTION_METRICS[metric], read
+            assert {r[:2] for r in read} == {
+                (way, f"conv{l}") for l in range(len(CELLS[cell]["fanout"]))
+                for way in ("jvp(GAT)", "transpose(jvp(GAT))")}, read
 
 
 def test_accepted_patterns_still_claim_the_gather_and_the_optimizer(programs):
     gather = re.compile(spec.load_metric("gather_device_ms")["args"]["pattern"])
-    model = re.compile(spec.load_metric("model_device_ms")["args"]["pattern"])
     clique = programs["products-sage.clique2x2"]
     for cell, instructions in programs.items():
+        model = re.compile(spec.load_metric(
+            "model_device_ms", model_file(cell).SCOPE)["args"]["pattern"])
         rows = [p for op, p in instructions
                 if op in ("gather", "fusion") and "tier_hot" in p
                 and p.endswith("/gather")]
@@ -287,6 +338,17 @@ def test_the_route_plan_sorts_and_never_gathers(
             and "route_fallback" not in path]
     assert 1 <= len(rows) <= 2, rows
     assert [path for op, path in hot if op == "sort"] == sorts
+
+
+def test_the_attention_scatters_only_in_its_backward(programs):
+    """The dense fanout path's forward pass scatters nothing: the self lane
+    is an operand of the max, the denominator and the sum, not a lane
+    scattered in. The backward's one kind of scatter is the transposed lane
+    gather (ROADMAP S5)."""
+    scatters = [path for op, path in programs["products-gat.hbm"]
+                if op == "scatter"]
+    assert not [p for p in scatters if p.startswith("jvp(GAT)")]
+    assert any("attn_aggregate" in p for p in scatters)
 
 
 def test_the_overflow_fallback_has_its_scope(capped_clique):
