@@ -31,6 +31,20 @@ separate operand of the softmax's max and denominator and of the fanout sum
 ``(num_dst, fanout + 1, H, F)`` copy is made to append it; the segment path
 appends the self edges to the edge list and is the differential oracle.
 
+**The logits on the dense fanout path** are built from operands that are
+in lane order already. The layer gathers its ``z`` rows once, by lane, into
+``(targets, fanout, H, F)`` (scope ``attn_aggregate``, entered a first time
+for the gather); the source half ``s_j`` of a lane's logit is the sum over
+``F`` of that row times ``a_src``, the target half ``d_i`` is formed for the
+targets' rows alone and broadcast over the fanout axis (a lane's target is
+``lane // fanout``); the same gathered rows are then weighted and summed
+(``attn_aggregate`` again). So a layer has one gather, of rows, and its
+backward one scatter-add, of rows: no ``(lanes, H)`` array is gathered or
+scattered (on a v5e the transposed gather of those 16-byte lanes cost 29 ms
+of a 158 ms step: PERF.md, PR 33), and the backward's broadcast over the
+fanout axis fuses into its users. The segment path keeps the per-node halves
+``project`` returns and gathers them by lane.
+
 Dense matmuls are batched over heads so the MXU sees (N, H*F)-shaped work.
 """
 
@@ -38,6 +52,7 @@ from __future__ import annotations
 
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
@@ -125,16 +140,42 @@ class GATConv(nn.Module):
         # the scopes are entered here and not in the methods, which flax
         # names for itself (``conv0.project``): paths read
         # ``conv{i}/<scope>/...`` (docs/Introduction.md)
-        with trace_scope("attn_project"):
-            h_all, alpha_src, alpha_dst = self.project(x)
-            h_dst, alpha_dst = h_all[:num_dst], alpha_dst[:num_dst]
-
-        with trace_scope("attn_logits"):
-            logits = nn.leaky_relu(
-                alpha_src[src_safe] + alpha_dst[jnp.clip(dst, 0, num_dst - 1)],
-                self.negative_slope)                             # (E, H)
-            self_logits = nn.leaky_relu(
-                alpha_src[:num_dst] + alpha_dst, self.negative_slope)
+        if dense:
+            # the logits from operands in lane order (module docstring): no
+            # per-row s / d, no (lanes, H) gather
+            with trace_scope("attn_project"):
+                h_all = self.lin(x).reshape(
+                    x.shape[0], self.heads, self.features)
+                # the targets' rows as an array of their own: every use of
+                # z but the gather reads these alone, so the whole
+                # projection is free once the lanes' rows are gathered and
+                # does not stay live through the backward for its head
+                h_dst = jax.lax.optimization_barrier(h_all[:num_dst])
+                s_dst = (h_dst * self.att_l).sum(-1)             # (num_dst, H)
+                d_dst = (h_dst * self.att_r).sum(-1)
+            with trace_scope("attn_aggregate"):
+                zg = h_all[src_safe.reshape(num_dst, fanout)]    # (T, K, H, F)
+                # the product is taken in the gather's scope: its transpose
+                # is the rows' second cotangent, and the sum of the two is
+                # then this scope's op, as the transposed gather it feeds is
+                zl = zg * self.att_l
+            with trace_scope("attn_logits"):
+                logits = nn.leaky_relu(
+                    zl.sum(-1) + d_dst[:, None, :],              # (T, K, H)
+                    self.negative_slope).reshape(-1, self.heads)
+                self_logits = nn.leaky_relu(
+                    s_dst + d_dst, self.negative_slope)
+        else:
+            with trace_scope("attn_project"):
+                h_all, alpha_src, alpha_dst = self.project(x)
+                h_dst, alpha_dst = h_all[:num_dst], alpha_dst[:num_dst]
+            with trace_scope("attn_logits"):
+                logits = nn.leaky_relu(
+                    alpha_src[src_safe]
+                    + alpha_dst[jnp.clip(dst, 0, num_dst - 1)],
+                    self.negative_slope)                         # (E, H)
+                self_logits = nn.leaky_relu(
+                    alpha_src[:num_dst] + alpha_dst, self.negative_slope)
         # softmax over each destination's lanes and its self lane, all heads
         # at once (computed in f32 via the att-param promotion for
         # stability, then downcast so the big (E, H, F) message traffic runs
@@ -157,10 +198,13 @@ class GATConv(nn.Module):
             alpha_self = alpha_self.astype(h_all.dtype)
 
         with trace_scope("attn_aggregate"):
-            msgs = h_all[src_safe] * alpha[:, :, None]  # (E, H, F)
             if dense:
-                out = fanout_sum_aggregate(msgs, valid, num_dst, fanout)
+                msgs = zg * alpha.reshape(num_dst, fanout, self.heads, 1)
+                out = fanout_sum_aggregate(
+                    msgs.reshape((-1,) + msgs.shape[2:]), valid, num_dst,
+                    fanout)
             else:
+                msgs = h_all[src_safe] * alpha[:, :, None]       # (E, H, F)
                 msgs = jnp.where(valid[:, None, None], msgs, 0.0)
                 out = jnp.zeros((num_dst + 1,) + msgs.shape[1:], msgs.dtype)
                 out = out.at[dst_safe].add(msgs)[:num_dst]

@@ -214,23 +214,59 @@ def test_gat_is_the_published_recipe(case, path):
                 err_msg=f"layer{i}.{name}")
 
 
+# the module's dtype, and how closely its dense path follows its segment
+# path: float32 at ``highest`` to round-off; in bfloat16 both paths read the
+# same rounded rows and weights and sum the messages in another order (read:
+# one ulp of a bfloat16 output, 7.8e-3, and 7.9e-3 of the largest gradient)
+DTYPES = {None: dict(rtol=1e-5, atol=1e-6),
+          "bfloat16": dict(rtol=2e-2, atol=2e-2)}
+
+
+@pytest.mark.parametrize("dtype", list(DTYPES), ids=lambda d: d or "float32")
 @pytest.mark.parametrize("case", list(BLOCKS))
-def test_gatconv_dense_path_is_the_segment_path(case):
+def test_gatconv_dense_path_is_the_segment_path(case, dtype):
     """One ``GATConv`` on the same block with its fanout and without: the
     self term as a separate operand of the max, the denominator and the sum
-    gives what the self edges appended to the edge list give."""
+    gives what the self edges appended to the edge list give, and a lane's
+    logit read off the gathered ``z`` row and broadcast over the fanout
+    axis is the one gathered from the per-node halves. Values, and every
+    parameter's gradient (``att_l`` and ``att_r`` among them, which the two
+    paths sum over lanes and over rows)."""
     rng = np.random.default_rng(3)
     k = BLOCKS[case]["fanouts"][0]
     src, dst = _fanout_block(rng, 10, 24, k, BLOCKS[case].get("edit"))
     ei = jnp.asarray(np.stack([src, dst]))
     x = jnp.asarray(rng.normal(size=(24, 7)).astype(np.float32))
-    conv = GATConv(features=5, heads=3)
+    conv = GATConv(features=5, heads=3, dtype=dtype)
     params = conv.init(jax.random.PRNGKey(0), x, ei, 10)
-    y_dense = conv.apply(params, x, ei, 10, k)
-    y_segment = conv.apply(params, x, ei, 10)
+    weight = jnp.asarray(rng.normal(size=(10, 15)).astype(np.float32))
+
+    def value_and_grads(fanout):
+        def scalar(p):
+            y = conv.apply(p, x, ei, 10, fanout)
+            return (y.astype(jnp.float32) * weight).sum(), y
+
+        with jax.default_matmul_precision("highest"):
+            (_, y), grads = jax.value_and_grad(scalar, has_aux=True)(params)
+        return y, grads["params"]
+
+    y_dense, g_dense = value_and_grads(k)
+    y_segment, g_segment = value_and_grads(None)
     assert y_dense.shape == (10, 15)
-    np.testing.assert_allclose(np.asarray(y_dense), np.asarray(y_segment),
-                               rtol=1e-5, atol=1e-6)
+    tol = DTYPES[dtype]
+    np.testing.assert_allclose(
+        np.asarray(y_dense, np.float32), np.asarray(y_segment, np.float32),
+        **tol)
+    flat = jax.tree_util.tree_leaves_with_path(g_segment)
+    assert {jax.tree_util.keystr(path) for path, _ in flat} >= {
+        "['att_l']", "['att_r']"}
+    for (path, want), got in zip(flat, jax.tree_util.tree_leaves(g_dense)):
+        want = np.asarray(want, np.float32)
+        assert np.abs(want).max() > 0, path
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), want, rtol=tol["rtol"],
+            atol=tol["rtol"] * np.abs(want).max(),
+            err_msg=jax.tree_util.keystr(path))
 
 
 def test_a_self_lane_is_not_counted_twice():

@@ -351,6 +351,28 @@ def test_the_attention_scatters_only_in_its_backward(programs):
     assert any("attn_aggregate" in p for p in scatters)
 
 
+def test_the_attention_moves_rows_once_a_layer_and_logits_never(programs):
+    """On the dense fanout path a lane's logit is built from operands that
+    are in lane order already (the source half read off the gathered ``z``
+    row, the target half broadcast over the fanout axis): nothing is
+    gathered or scattered under ``attn_logits``, forward or transposed,
+    and a layer's one gather and one scatter are those of its rows, under
+    ``attn_aggregate``."""
+    program = programs["products-gat.hbm"]
+    logits = re.compile(r"^(transpose\()?jvp\(GAT\)\)?/conv\d+/attn_logits(/|$)")
+    moved = [(op, path) for op, path in program if logits.match(path)
+             and (op in ("gather", "scatter")
+                  or path.endswith(("/gather", "/scatter-add")))]
+    assert not moved, moved[:5]
+    for l in range(len(CELLS["products-gat.hbm"]["fanout"])):
+        for op, way in (("gather", "jvp(GAT)"),
+                        ("scatter", "transpose(jvp(GAT))")):
+            under = [path for o, path in program
+                     if o == op and f"/conv{l}/" in path]
+            assert len(under) == 1, (l, op, under)
+            assert under[0].startswith(f"{way}/conv{l}/attn_aggregate/"), under
+
+
 def test_the_overflow_fallback_has_its_scope(capped_clique):
     """``route_fallback`` exists only where a bucket can overflow."""
     paths = paths_of(capped_clique)
