@@ -38,12 +38,25 @@ for the gather); the source half ``s_j`` of a lane's logit is the sum over
 ``F`` of that row times ``a_src``, the target half ``d_i`` is formed for the
 targets' rows alone and broadcast over the fanout axis (a lane's target is
 ``lane // fanout``); the same gathered rows are then weighted and summed
-(``attn_aggregate`` again). So a layer has one gather, of rows, and its
-backward one scatter-add, of rows: no ``(lanes, H)`` array is gathered or
-scattered (on a v5e the transposed gather of those 16-byte lanes cost 29 ms
-of a 158 ms step: PERF.md, PR 33), and the backward's broadcast over the
-fanout axis fuses into its users. The segment path keeps the per-node halves
-``project`` returns and gathers them by lane.
+(``attn_aggregate`` again). So a layer has one gather, of rows: no
+``(lanes, H)`` array is gathered or scattered (on a v5e the transposed
+gather of those 16-byte lanes cost 29 ms of a 158 ms step: PERF.md, PR 33),
+and the backward's broadcast over the fanout axis fuses into its users. The
+segment path keeps the per-node halves ``project`` returns and gathers them
+by lane.
+
+**The row gather's transpose on the dense fanout path** is a gather as well
+(``layers.gather_lane_rows``; as one scatter-add of every lane's 2 KB
+cotangent row it was 40 ms of a 107 ms step, padded lanes and all: PERF.md,
+PR 35): two sorts give every source row one of its lanes and the list of
+the lanes that repeat a row, one row gather takes each row's cotangent from
+its lane, and only the repeats are scatter-added, in chunks, by a loop of as
+many trips as they need; the masked lanes add nothing. Its ops keep the
+path of the forward call (``transpose(jvp(GAT))/conv{i}/attn_aggregate``).
+The function returns the targets' rows ``z_i`` beside the lanes', so that
+their cotangent is added to its rows of the gathered one in place and the
+whole projection's cotangent is that rule's result as it stands. The segment
+path keeps ``h[src]`` and its plain transposed scatter-add.
 
 Dense matmuls are batched over heads so the MXU sees (N, H*F)-shaped work.
 """
@@ -57,7 +70,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from ..utils.trace import trace_scope
-from .layers import fanout_softmax, fanout_sum_aggregate, segment_softmax
+from .layers import (
+    fanout_softmax, fanout_sum_aggregate, gather_lane_rows, segment_softmax)
 
 __all__ = ["GATConv", "GAT"]
 
@@ -146,19 +160,26 @@ class GATConv(nn.Module):
             with trace_scope("attn_project"):
                 h_all = self.lin(x).reshape(
                     x.shape[0], self.heads, self.features)
+            with trace_scope("attn_aggregate"):
+                # (T, K, H, F) and the targets' rows; the masked lanes read
+                # row 0 and their cotangent is dropped, the targets'
+                # cotangent joins the lanes' inside the transposed gather
+                # (layers.gather_lane_rows)
+                zg, h_dst = gather_lane_rows(
+                    h_all, jnp.where(valid, src, -1).reshape(num_dst, fanout),
+                    num_dst)
                 # the targets' rows as an array of their own: every use of
                 # z but the gather reads these alone, so the whole
                 # projection is free once the lanes' rows are gathered and
                 # does not stay live through the backward for its head
-                h_dst = jax.lax.optimization_barrier(h_all[:num_dst])
-                s_dst = (h_dst * self.att_l).sum(-1)             # (num_dst, H)
-                d_dst = (h_dst * self.att_r).sum(-1)
-            with trace_scope("attn_aggregate"):
-                zg = h_all[src_safe.reshape(num_dst, fanout)]    # (T, K, H, F)
+                h_dst = jax.lax.optimization_barrier(h_dst)
                 # the product is taken in the gather's scope: its transpose
                 # is the rows' second cotangent, and the sum of the two is
                 # then this scope's op, as the transposed gather it feeds is
                 zl = zg * self.att_l
+            with trace_scope("attn_project"):
+                s_dst = (h_dst * self.att_l).sum(-1)             # (num_dst, H)
+                d_dst = (h_dst * self.att_r).sum(-1)
             with trace_scope("attn_logits"):
                 logits = nn.leaky_relu(
                     zl.sum(-1) + d_dst[:, None, :],              # (T, K, H)

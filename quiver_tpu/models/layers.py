@@ -18,14 +18,23 @@ Two aggregation paths, identical results:
 * **segment** (``fanout=None``): ``jax.ops.segment_sum`` with an overflow
   bucket for invalid lanes — kept for hand-built/irregular Adjs and as the
   differential-test oracle.
+
+A dense path that needs its sources' rows by lane and differentiates through
+them (GAT's attention) takes them with :func:`gather_lane_rows`, whose
+transpose is a row gather and a scatter-add of the repeated lanes alone: a
+scatter-add of 2 KB rows walks every lane at 73 ns on a v5e, dropped or not,
+a row gather at 10 (PERF.md, PR 35). :func:`gather_src` is the plain gather
+with the plain transpose.
 """
 
 from __future__ import annotations
 
 import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 __all__ = [
     "segment_mean_aggregate",
@@ -33,6 +42,7 @@ __all__ = [
     "fanout_softmax",
     "fanout_sum_aggregate",
     "gather_src",
+    "gather_lane_rows",
     "zero_scatter_counts",
     "occurrence_counts",
     "resolve_counts_strategy",
@@ -111,6 +121,125 @@ def gather_src(x, src):
     valid = src >= 0
     h = x[jnp.clip(src, 0)]
     return jnp.where(valid[:, None], h, 0.0), valid
+
+
+# lanes a trip of the repeats' loop adds (a chunk of 2 KB rows is 2 MiB; the
+# loop's time follows the lanes, not the chunk: PERF.md, PR 35's probe)
+_REPEAT_CHUNK = 1024
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def gather_lane_rows(h, idx, num_head: int):
+    """Rows of ``h`` by lane and its head rows, transposed as a gather.
+
+    ``h`` is (rows, ...) and ``idx`` of any shape: ``h[clip(idx, 0)]`` and
+    ``h[:num_head]`` (a block's sources by lane, and its targets).
+
+    A lane with ``idx < 0`` is padding: it reads row 0, as the clip makes it,
+    and **its cotangent is dropped**, so the caller masks those lanes out of
+    whatever it computes from the result (every user of a padded block does).
+
+    The plain transpose is one scatter-add of every lane's cotangent row. On
+    a v5e that op walks its lanes at 73 ns each whatever they do (544,000
+    lanes of 2 KB rows: 40 ms; a lane sent out of range and dropped still
+    costs 71 ns), six times a gathered row (PERF.md, PR 35). Most rows of a
+    sampled block are named by one lane and need no read-modify-write, so the
+    rule here turns the transpose round:
+
+    * two sorts, over the lanes and one query per row, give every row one of
+      its lanes (none for a row no lane names) and the other lanes of the
+      rows named more than once, compacted (:func:`_lane_plan`);
+    * ONE row gather takes each row's cotangent from its lane, zero where it
+      has none;
+    * the repeats alone are scatter-added, ``_REPEAT_CHUNK`` lanes a trip of
+      a loop whose trip count follows their number;
+    * the head's cotangent is added to its rows in place (which is why the
+      head is this function's to return: sliced off ``h`` by the caller, its
+      cotangent would be padded to all rows and added in a pass over all).
+
+    The same sums in another order (a row's lanes in the sort's order). The
+    rule keeps ``idx`` of the forward and nothing else, and reads the
+    cotangent in place: no lane-sized copy of it is made."""
+    return h[jnp.clip(idx, 0)], h[:num_head]
+
+
+def _gather_lane_rows_fwd(h, idx, num_head):
+    return gather_lane_rows(h, idx, num_head), (idx, h.shape[0])
+
+
+def _lane_plan(flat, rows: int, chunk: int):
+    """For lanes ``flat`` (L,) naming rows in [0, rows) (negative: padding):
+    ``lane_of`` (rows,), one lane of each row or -1; the remaining valid
+    lanes, compacted and padded to whole chunks, as ``(rep_rows, rep_lanes)``
+    (behind them: row ``rows``, out of range) and their number. Two sorts
+    and no scatter: a row's query sorts right behind the row's lanes, so its
+    left neighbour is one of them and every lane whose right neighbour is
+    another lane is a repeat; the second sort puts the queries first, in row
+    order, and the repeats behind them."""
+    L = flat.shape[0]
+    big = jnp.iinfo(jnp.int32).max - 1          # even, behind every key
+    if 2 * rows + L >= big:
+        raise ValueError(
+            f"{L} lanes over {rows} rows do not fit the packed int32 keys")
+    one = partial(jnp.full, (1,), dtype=jnp.int32)
+    key = jnp.concatenate([
+        jnp.where(flat >= 0, flat.astype(jnp.int32) * 2, big),
+        jnp.arange(rows, dtype=jnp.int32) * 2 + 1])
+    lane = jnp.concatenate([
+        jnp.arange(L, dtype=jnp.int32), jnp.full((rows,), -1, jnp.int32)])
+    key, lane = lax.sort((key, lane), num_keys=1, is_stable=False)
+    query = (key & 1) == 1
+    left_key = jnp.concatenate([one(-1), key[:-1]])
+    left_lane = jnp.concatenate([one(-1), lane[:-1]])
+    right_key = jnp.concatenate([key[1:], one(big)])
+    repeat = ~query & (key != big) & (right_key == key)
+    pos = jnp.arange(L + rows, dtype=jnp.int32)
+    order = jnp.where(query, key >> 1, jnp.where(repeat, rows + pos, big))
+    _, lane, row = lax.sort(
+        (order, jnp.where(query, jnp.where(left_key == key - 1, left_lane, -1),
+                          lane), jnp.where(repeat, key >> 1, rows)),
+        num_keys=1, is_stable=False)
+    pad = (-L) % chunk + chunk      # a slice of the loop never runs off the end
+    return (lane[:rows], jnp.pad(row[rows:], (0, pad), constant_values=rows),
+            jnp.pad(lane[rows:], (0, pad)), repeat.sum(dtype=jnp.int32))
+
+
+def _gather_lane_rows_bwd(num_head, res, cots):
+    idx, rows = res
+    cot, cot_head = cots
+    # one barrier over the two: the lanes' plan needs ``idx`` alone and
+    # would be free to run, and to keep its arrays, while the cotangent's
+    # producers still hold theirs (the step's peak); and the reshape below
+    # stays a view of the cotangent as its producer wrote it (moved up into
+    # that producer it turns a broadcast over the lanes into an array of
+    # its own, 1.11 GB at GAT's layer 0: PERF.md, PR 35)
+    cot, idx = lax.optimization_barrier((cot, idx))
+    L, chunk = idx.size, _REPEAT_CHUNK
+    cot = cot.reshape((L,) + cot.shape[idx.ndim:])
+    lane_of, rep_rows, rep_lanes, num_rep = _lane_plan(
+        idx.reshape(L), rows, chunk)
+    named = (lane_of >= 0).reshape((rows,) + (1,) * (cot.ndim - 1))
+    d_h = jnp.where(named, cot[jnp.clip(lane_of, 0)], 0)
+
+    def add_chunk(i, d_h):
+        at = lax.dynamic_slice_in_dim(rep_rows, i * chunk, chunk)
+        lanes = lax.dynamic_slice_in_dim(rep_lanes, i * chunk, chunk)
+        return d_h.at[at].add(cot[lanes], mode="drop")
+
+    d_h = lax.fori_loop(0, (num_rep + chunk - 1) // chunk, add_chunk, d_h)
+    # the head's cotangent goes into its rows in place, behind the loop. The
+    # rows are read through a 2-D view behind a barrier: the compiler gives
+    # a plain slice the layout of the head's cotangent, which costs a copy of
+    # ALL rows, before the loop or behind it (2.8 ms at GAT's layer 0:
+    # PERF.md, PR 35); the reshape is a boundary that layout does not cross
+    head = lax.optimization_barrier(
+        lax.slice_in_dim(d_h, 0, num_head).reshape(num_head, -1))
+    d_h = lax.dynamic_update_slice_in_dim(
+        d_h, head.reshape(cot_head.shape) + cot_head, 0, axis=0)
+    return d_h, None
+
+
+gather_lane_rows.defvjp(_gather_lane_rows_fwd, _gather_lane_rows_bwd)
 
 
 def zero_scatter_counts(ids, valid, n: int, dtype=jnp.float32):

@@ -103,6 +103,114 @@ def test_fanout_softmax_matches_segment_softmax():
     np.testing.assert_allclose(np.asarray(a_own)[2], 1.0, atol=1e-6)
 
 
+# index blocks (targets, fanout) over ROWS rows for ``gather_lane_rows``,
+# NUM_DST of them targets; -1 is a padded lane
+ROWS, NUM_DST = 20, 6
+
+
+def _lanes_random(rng):
+    idx = rng.integers(0, ROWS - 2, (NUM_DST, 5)).astype(np.int32)
+    idx[rng.random(idx.shape) < 0.35] = -1
+    return idx
+
+
+def _lanes_of(*rows):
+    return lambda rng: np.asarray(rows, np.int32)
+
+
+LANE_BLOCKS = {
+    "padded lanes and repeats": _lanes_random,
+    "one source twice within a target": _lanes_of(
+        [7, 7, -1], [8, 9, -1], [10, -1, -1]),
+    "one source across targets": _lanes_of(
+        [7, 8, -1], [7, 9, -1], [-1, 7, 8]),
+    "a source below num_dst": _lanes_of(
+        [2, 7, -1], [0, 2, 2], [5, -1, 0]),
+    "a row no lane names": _lanes_of([7, 9], [9, 11]),
+    "every lane padded": _lanes_of([-1, -1, -1], [-1, -1, -1]),
+    "no lane padded": _lanes_of(
+        [7, 8, 9], [7, 10, 11], [12, 7, 19]),
+    # 7 repeats, chunks of 4 and of 3: the last trip is part empty
+    "repeats not a multiple of the chunk": _lanes_of(
+        [7, 7, 7, 7], [7, 8, 8, 8], [9, 9, -1, 10]),
+}
+
+
+@pytest.mark.parametrize("chunk", [3, 4, 1024])
+@pytest.mark.parametrize("case", list(LANE_BLOCKS))
+def test_gather_lane_rows_transposes_as_the_plain_gather(
+        case, chunk, monkeypatch):
+    """``gather_lane_rows`` is ``h[clip(idx, 0)]`` and ``h[:num_head]``
+    forward, and its rule (one lane a row by a row gather, the head's
+    cotangent added in the same pass, the repeats scatter-added in chunks,
+    padded lanes dropped) gives the gradient ``jax.grad`` of the plain
+    gather and slice gives once the padded lanes are masked, to 1e-6:
+    whatever the repeats' number is to the chunk, compiled or not."""
+    from quiver_tpu.models import layers
+
+    monkeypatch.setattr(layers, "_REPEAT_CHUNK", chunk)
+    rng = np.random.default_rng(5)
+    idx = jnp.asarray(LANE_BLOCKS[case](rng))
+    h = jnp.asarray(rng.normal(size=(ROWS, 2, 3)).astype(np.float32))
+    weight = jnp.asarray(
+        rng.normal(size=idx.shape + (2, 3)).astype(np.float32))
+    head_weight = jnp.asarray(
+        rng.normal(size=(NUM_DST, 2, 3)).astype(np.float32))
+    live = (idx >= 0)[..., None, None]
+
+    def through(gather):
+        def scalar(h):
+            rows, head = gather(h)
+            return ((jnp.where(live, rows, 0.0) * weight).sum()
+                    + (head * head_weight).sum()), (rows, head)
+        return scalar
+
+    new = through(lambda h: layers.gather_lane_rows(h, idx, NUM_DST))
+    plain = through(lambda h: (h[jnp.clip(idx, 0)], h[:NUM_DST]))
+    with jax.default_matmul_precision("highest"):
+        (_, want_out), want = jax.value_and_grad(plain, has_aux=True)(h)
+        (_, out), got = jax.value_and_grad(new, has_aux=True)(h)
+        _, jitted = jax.jit(jax.value_and_grad(new, has_aux=True))(h)
+    for a, b in zip(out, want_out):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for grad in (got, jitted):
+        np.testing.assert_allclose(
+            np.asarray(grad), np.asarray(want), rtol=1e-6, atol=1e-6)
+    # a row that no lane names and that is no target gets exactly nothing
+    unnamed = np.setdiff1d(
+        np.arange(NUM_DST, ROWS), np.asarray(idx).reshape(-1))
+    assert unnamed.size and not np.asarray(got)[unnamed].any()
+
+
+def test_gather_lane_rows_plan_names_every_row_once():
+    """The two sorts' plan: each named row gets one of its own lanes, the
+    other valid lanes are the repeats, each once, and the padding of the
+    compacted arrays points out of range."""
+    from quiver_tpu.models.layers import _lane_plan
+
+    rng = np.random.default_rng(6)
+    flat = rng.integers(-1, 9, 40).astype(np.int32)
+    lane_of, rep_rows, rep_lanes, num_rep = map(
+        np.asarray, _lane_plan(jnp.asarray(flat), 12, 8))
+    named = np.unique(flat[flat >= 0])
+    assert set(np.flatnonzero(lane_of >= 0)) == set(named)
+    assert all(flat[lane_of[r]] == r for r in named)
+    n = int(num_rep)
+    assert n == (flat >= 0).sum() - named.size
+    assert rep_rows.size == rep_lanes.size == 48 and rep_rows.size % 8 == 0
+    assert (flat[rep_lanes[:n]] == rep_rows[:n]).all()
+    assert (rep_rows[n:] == 12).all()
+    lanes = np.concatenate([lane_of[lane_of >= 0], rep_lanes[:n]])
+    assert sorted(lanes) == sorted(np.flatnonzero(flat >= 0))
+
+
+def test_gather_lane_rows_refuses_lanes_its_keys_cannot_hold():
+    from quiver_tpu.models.layers import _lane_plan
+
+    with pytest.raises(ValueError, match="packed int32 keys"):
+        _lane_plan(jnp.zeros(4, jnp.int32), 2 ** 30, 4)
+
+
 def test_zero_scatter_counts_matches_bincount():
     from quiver_tpu.models.layers import zero_scatter_counts
 

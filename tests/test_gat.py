@@ -269,6 +269,51 @@ def test_gatconv_dense_path_is_the_segment_path(case, dtype):
             err_msg=jax.tree_util.keystr(path))
 
 
+@pytest.mark.parametrize("chunk", [2, 1024])
+@pytest.mark.parametrize("dtype", list(DTYPES), ids=lambda d: d or "float32")
+@pytest.mark.parametrize("case", list(BLOCKS))
+def test_gatconv_dense_backward_is_the_segment_paths(
+        case, dtype, chunk, monkeypatch):
+    """The dense path's backward goes through ``layers.gather_lane_rows``'s
+    rule (a row's cotangent gathered from one of its lanes, the repeats
+    added in chunks of ``chunk``, the masked lanes dropped) and projects the
+    targets' rows on their own; the segment path keeps the plain transposed
+    gather. Both give the same gradient of every parameter and of the
+    input rows ``x``: sources below ``num_dst``, sources that several lanes
+    name (30 lanes over 24 rows), rows that none does."""
+    from quiver_tpu.models import layers
+
+    monkeypatch.setattr(layers, "_REPEAT_CHUNK", chunk)
+    rng = np.random.default_rng(7)
+    k = BLOCKS[case]["fanouts"][0]
+    src, dst = _fanout_block(rng, 10, 24, k, BLOCKS[case].get("edit"))
+    ei = jnp.asarray(np.stack([src, dst]))
+    x = jnp.asarray(rng.normal(size=(24, 7)).astype(np.float32))
+    conv = GATConv(features=5, heads=3, dtype=dtype)
+    params = conv.init(jax.random.PRNGKey(0), x, ei, 10)
+    weight = jnp.asarray(rng.normal(size=(10, 15)).astype(np.float32))
+
+    def grads(fanout):
+        def scalar(p, x):
+            y = conv.apply(p, x, ei, 10, fanout)
+            return (y.astype(jnp.float32) * weight).sum()
+
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.grad(scalar, argnums=(0, 1)))(params, x)
+
+    tol = DTYPES[dtype]["rtol"]
+    dense, segment = grads(k), grads(None)
+    flat = jax.tree_util.tree_leaves_with_path(segment)
+    assert len(flat) == 7        # six parameters and x
+    for (path, want), got in zip(flat, jax.tree_util.tree_leaves(dense)):
+        want = np.asarray(want, np.float32)
+        assert np.abs(want).max() > 0, path
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), want, rtol=tol,
+            atol=tol * np.abs(want).max(),
+            err_msg=jax.tree_util.keystr(path))
+
+
 def test_a_self_lane_is_not_counted_twice():
     """A target whose every sampled lane is itself attends to itself once:
     its output is its own projected row, as if it had no lane at all."""

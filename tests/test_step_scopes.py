@@ -343,12 +343,16 @@ def test_the_route_plan_sorts_and_never_gathers(
 def test_the_attention_scatters_only_in_its_backward(programs):
     """The dense fanout path's forward pass scatters nothing: the self lane
     is an operand of the max, the denominator and the sum, not a lane
-    scattered in. The backward's one kind of scatter is the transposed lane
-    gather (ROADMAP S5)."""
+    scattered in. The backward's one kind of scatter is that of the rows
+    named by more than one lane, inside the loop of the transposed lane
+    gather (``layers.gather_lane_rows``, ROADMAP S5)."""
     scatters = [path for op, path in programs["products-gat.hbm"]
                 if op == "scatter"]
     assert not [p for p in scatters if p.startswith("jvp(GAT)")]
     assert any("attn_aggregate" in p for p in scatters)
+
+
+GAT_LAYERS = range(len(CELLS["products-gat.hbm"]["fanout"]))
 
 
 def test_the_attention_moves_rows_once_a_layer_and_logits_never(programs):
@@ -356,7 +360,7 @@ def test_the_attention_moves_rows_once_a_layer_and_logits_never(programs):
     are in lane order already (the source half read off the gathered ``z``
     row, the target half broadcast over the fanout axis): nothing is
     gathered or scattered under ``attn_logits``, forward or transposed,
-    and a layer's one gather and one scatter are those of its rows, under
+    and a layer's one forward gather is that of its rows, under
     ``attn_aggregate``."""
     program = programs["products-gat.hbm"]
     logits = re.compile(r"^(transpose\()?jvp\(GAT\)\)?/conv\d+/attn_logits(/|$)")
@@ -364,13 +368,42 @@ def test_the_attention_moves_rows_once_a_layer_and_logits_never(programs):
              and (op in ("gather", "scatter")
                   or path.endswith(("/gather", "/scatter-add")))]
     assert not moved, moved[:5]
-    for l in range(len(CELLS["products-gat.hbm"]["fanout"])):
-        for op, way in (("gather", "jvp(GAT)"),
-                        ("scatter", "transpose(jvp(GAT))")):
-            under = [path for o, path in program
-                     if o == op and f"/conv{l}/" in path]
-            assert len(under) == 1, (l, op, under)
-            assert under[0].startswith(f"{way}/conv{l}/attn_aggregate/"), under
+    for l in GAT_LAYERS:
+        under = [path for o, path in program
+                 if o == "gather" and path.startswith(f"jvp(GAT)/conv{l}/")]
+        assert under == [f"jvp(GAT)/conv{l}/attn_aggregate/gather"], under
+
+
+@pytest.mark.parametrize("layer", list(GAT_LAYERS))
+def test_the_transposed_row_gather_is_a_gather(programs, layer):
+    """The transpose of a layer's row gather (``layers.gather_lane_rows``'s
+    rule, which enters no scope of its own) keeps the path of the forward
+    call, so ``attn_aggregate_device_ms``, ``backward_device_ms`` and
+    ``attn_roofline``'s time go on counting it: every gather, scatter, sort
+    and loop of the layer's backward lies under
+    ``transpose(jvp(GAT))/conv{l}/attn_aggregate/``. There it is the two
+    sorts of the lanes' plan (the probe chose the plan of two sorts over
+    lanes and one query a row, which makes a row's lane without the 4-byte
+    scatter ISSUE 35 sketched: PERF.md, PR 35), ONE gather of ``(rows, H,
+    F)`` outside the loop, and one ``while`` whose body gathers and
+    scatter-adds the repeats' chunk: no scatter of rows stands outside a
+    ``while`` body, so what is scattered is the lanes that repeat a row and
+    not the lanes of the block."""
+    program = programs["products-gat.hbm"]
+    home = f"transpose(jvp(GAT))/conv{layer}/attn_aggregate/"
+    moving = [(op, path) for op, path in program
+              if op in ("gather", "scatter", "sort", "while")
+              and f"jvp(GAT))/conv{layer}/" in path]
+    assert moving and all(path.startswith(home) for _, path in moving), moving
+    kinds = lambda op: [path[len(home):] for o, path in moving if o == op]
+    assert kinds("sort") == ["sort", "sort"]
+    assert kinds("while") == ["while"]
+    assert sorted(kinds("gather")) == ["gather", "while/body/gather"]
+    assert kinds("scatter") == ["while/body/scatter-add"]
+    # nothing of the rule outside the five scopes' paths
+    assert not [path for _, path in program
+                if "GAT" in path and "/while" in path
+                and "/attn_aggregate/while" not in path]
 
 
 def test_the_overflow_fallback_has_its_scope(capped_clique):
