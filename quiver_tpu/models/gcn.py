@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from .layers import fanout_sum_aggregate, occurrence_counts
+from .layers import fanout_gather_sum, occurrence_counts
 
 __all__ = ["GCNConv", "GCN"]
 
@@ -52,8 +52,6 @@ class GCNConv(nn.Module):
         N = x.shape[0]
         src, dst = edge_index[0], edge_index[1]
         valid = (src >= 0) & (dst >= 0)
-        one = valid.astype(x.dtype)
-        dense = fanout is not None and src.shape[0] == num_dst * fanout
 
         # in-block degrees of the self-loop-augmented graph: every dst gets
         # +1 (its loop), and a src that is also a dst carries that same loop
@@ -62,23 +60,22 @@ class GCNConv(nn.Module):
         # platform-resolved histogram either way.
         deg_src = occurrence_counts(src, valid, N, dtype=x.dtype)
         deg_src = deg_src.at[:num_dst].add(1.0)
-        if dense:
-            deg_dst = one.reshape(num_dst, fanout).sum(axis=1) + 1.0
+        inv_s_src = jax.lax.rsqrt(jnp.maximum(deg_src, 1.0))
+
+        h = x * inv_s_src[:, None]  # pre-scale once per node, not per edge
+        if fanout is not None and src.shape[0] == num_dst * fanout:
+            agg, cnt = fanout_gather_sum(
+                h, jnp.where(valid, src, -1), num_dst, fanout)
+            deg_dst = cnt.astype(x.dtype) + 1.0
         else:
             dst_safe = jnp.where(valid, dst, num_dst)
             deg_dst = jax.ops.segment_sum(
-                one, dst_safe, num_segments=num_dst + 1)[:num_dst] + 1.0
-
-        inv_s_src = jax.lax.rsqrt(jnp.maximum(deg_src, 1.0))
-        inv_s_dst = jax.lax.rsqrt(deg_dst)  # >= 1 by the self loop
-
-        h = x * inv_s_src[:, None]  # pre-scale once per node, not per edge
-        msgs = jnp.where(valid[:, None], h[jnp.clip(src, 0)], 0.0)
-        if dense:
-            agg = fanout_sum_aggregate(msgs, valid, num_dst, fanout)
-        else:
+                valid.astype(x.dtype), dst_safe,
+                num_segments=num_dst + 1)[:num_dst] + 1.0
+            msgs = jnp.where(valid[:, None], h[jnp.clip(src, 0)], 0.0)
             agg = jax.ops.segment_sum(
                 msgs, dst_safe, num_segments=num_dst + 1)[:num_dst]
+        inv_s_dst = jax.lax.rsqrt(deg_dst)  # >= 1 by the self loop
         agg = agg + h[:num_dst]  # the self loop, already src-scaled
         agg = agg * inv_s_dst[:, None]
         return self.combine(agg)

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from .layers import fanout_sum_aggregate
+from .layers import fanout_gather_sum
 
 __all__ = ["GINConv", "GIN"]
 
@@ -58,11 +58,12 @@ class GINConv(nn.Module):
         src, dst = edge_index[0], edge_index[1]
         valid = (src >= 0) & (dst >= 0)
 
-        msgs = jnp.where(valid[:, None], x[jnp.clip(src, 0)], 0.0)
-        if fanout is not None and msgs.shape[0] == num_dst * fanout:
+        if fanout is not None and src.shape[0] == num_dst * fanout:
             # regular sampler layout: dense reduction, zero scatters
-            agg = fanout_sum_aggregate(msgs, valid, num_dst, fanout)
+            agg, _ = fanout_gather_sum(
+                x, jnp.where(valid, src, -1), num_dst, fanout)
         else:
+            msgs = jnp.where(valid[:, None], x[jnp.clip(src, 0)], 0.0)
             dst_safe = jnp.where(valid, dst, num_dst)  # padding -> overflow
             agg = jax.ops.segment_sum(
                 msgs, dst_safe, num_segments=num_dst + 1)[:num_dst]

@@ -9,22 +9,32 @@ id, target = seed-local id).
 Two aggregation paths, identical results:
 
 * **dense** (``fanout`` set — every sampler-built Adj): the sampler's edge
-  layout is regular (lane ``s*fanout + k`` targets seed ``s``), so
-  aggregation is a masked ``(num_dst, fanout, F)`` reshape + axis-1
-  reduction — zero scatters. A scatter is not serialized on a v5e but
-  costs 4.6 ns a lane, 4.4x a payload sort of the same lanes (one
-  unique-index scatter of 852,480 lanes: PERF.md, PR 26); the segment
-  path's own cost has not been measured on the chip.
+  layout is regular (lane ``s*fanout + k`` targets seed ``s``), so a
+  target's sum is a sum of its ``fanout`` lanes — zero scatters. A scatter
+  is not serialized on a v5e but costs 4.6 ns a lane, 4.4x a payload sort
+  of the same lanes (one unique-index scatter of 852,480 lanes: PERF.md,
+  PR 26); the segment path's own cost has not been measured on the chip.
+  A family that sums its sources' rows as they are (SAGE, GCN, GIN, RGCN)
+  gathers and sums in ONE function, :func:`fanout_gather_sum`, which takes
+  the rows **fanout-major**: the indices transposed to ``(fanout,
+  num_dst)``, the gathered rows ``(fanout, num_dst, F)``, the sum over
+  axis 0. With ``num_dst`` a multiple of 8 that view is the gather's own
+  ``(E, F)`` output tile for tile and the sum adds ``fanout`` aligned
+  slabs. Gathered in lane order and viewed ``(num_dst, fanout, F)``, a v5e
+  wants the 8 x 128 tiles over ``(fanout, F)``: the view was a copy of
+  every gathered row with the fanout padded to 8 or 16 sublanes, and the
+  sum read the padded copy (PERF.md, PR 36).
 * **segment** (``fanout=None``): ``jax.ops.segment_sum`` with an overflow
   bucket for invalid lanes — kept for hand-built/irregular Adjs and as the
   differential-test oracle.
 
 A dense path that needs its sources' rows by lane and differentiates through
-them (GAT's attention) takes them with :func:`gather_lane_rows`, whose
-transpose is a row gather and a scatter-add of the repeated lanes alone: a
-scatter-add of 2 KB rows walks every lane at 73 ns on a v5e, dropped or not,
-a row gather at 10 (PERF.md, PR 35). :func:`gather_src` is the plain gather
-with the plain transpose.
+them (GAT's attention) takes them in lane order with
+:func:`gather_lane_rows` and sums the messages it forms from them with
+:func:`fanout_sum_aggregate`; the gather's transpose is a row gather and a
+scatter-add of the repeated lanes alone: a scatter-add of 2 KB rows walks
+every lane at 73 ns on a v5e, dropped or not, a row gather at 10 (PERF.md,
+PR 35). :func:`gather_src` is the segment path's plain gather in lane order.
 """
 
 from __future__ import annotations
@@ -41,6 +51,8 @@ __all__ = [
     "segment_softmax",
     "fanout_softmax",
     "fanout_sum_aggregate",
+    "fanout_gather_sum",
+    "gather_mean_aggregate",
     "gather_src",
     "gather_lane_rows",
     "zero_scatter_counts",
@@ -271,28 +283,57 @@ def occurrence_counts(ids, valid, n: int, dtype=jnp.float32):
 
 def fanout_sum_aggregate(messages, valid, num_dst: int, fanout: int):
     """Masked dense sum over the regular sampler layout: ``messages``
-    (num_dst*fanout, ...) -> (num_dst, ...), zero scatters. The shared
-    reduction behind every conv family's dense path."""
+    (num_dst*fanout, ...) in lane order -> (num_dst, ...), zero scatters.
+    For a caller that has formed its messages by lane already (GAT's
+    weighted rows); one that sums its sources' rows as they are gathers and
+    sums with :func:`fanout_gather_sum`, which never holds them by lane."""
     validb = valid.reshape(valid.shape + (1,) * (messages.ndim - 1))
     m = jnp.where(validb, messages, 0)
     return m.reshape((num_dst, fanout) + messages.shape[1:]).sum(axis=1)
 
 
-def segment_mean_aggregate(messages, dst, valid, num_dst: int,
-                           fanout: int | None = None):
-    """Mean-aggregate edge messages into target nodes.
+def fanout_gather_sum(x, src, num_dst: int, fanout: int):
+    """Each target's sum of its sources' rows, gathered fanout-major.
+
+    ``x`` (rows, F); ``src`` (num_dst*fanout,) in the regular sampler
+    layout (lane ``t * fanout + k`` is target ``t``'s k-th source, -1 a
+    padded lane) -> the sums (num_dst, F) and the number of sources of each
+    target (num_dst,) int32. The gather and the reduction of every family
+    that sums plain rows on the dense path.
+
+    The rows are gathered **fanout-major**: the indices go in transposed,
+    so slab ``k`` of the ``(fanout, num_dst, F)`` result holds every
+    target's k-th source and the sum over axis 0 adds ``fanout`` slabs
+    elementwise. With ``num_dst`` a multiple of 8 that 3-D array is the
+    gather's ``(fanout * num_dst, F)`` output in the same 8 x 128 tiles (no
+    copy), each row is read once, and the transpose broadcasts the
+    cotangent over the leading axis straight into the scatter-add's
+    update. The lane-order view ``(num_dst, fanout, F)`` tiles over
+    ``(fanout, F)`` instead: a copy of every gathered row with the fanout
+    padded to whole sublanes (5 -> 8, 10 -> 16), 3.1 + 1.6 ms of
+    reddit-sage's 25.2 ms step (PERF.md, PR 36). Any other ``num_dst``
+    gives the same sums; the view may then cost its copy again. The same
+    terms as the segment path, added in another order."""
+    idx = src.reshape(num_dst, fanout).T
+    valid = idx >= 0
+    rows = jnp.where(valid[..., None], x[jnp.clip(idx, 0)], 0)
+    return rows.sum(axis=0), valid.sum(axis=0, dtype=jnp.int32)
+
+
+def gather_mean_aggregate(x, src, dst, num_dst: int,
+                          fanout: int | None = None):
+    """Mean of each target's sources' rows of ``x``, 0 where it has none.
+
+    ``src`` / ``dst`` (E,) hold -1 on padded lanes.
 
     With ``fanout`` (regular sampler layout, ``E == num_dst * fanout``) the
-    aggregate is a dense masked reduction; otherwise invalid lanes are
-    routed to an overflow segment (index num_dst) and sliced off — the
-    padded-shape analogue of skipping masked edges.
-    """
-    if fanout is not None and messages.shape[0] == num_dst * fanout:
+    dense :func:`fanout_gather_sum`; otherwise the rows in lane order
+    through :func:`segment_mean_aggregate`."""
+    if fanout is not None and src.shape[0] == num_dst * fanout:
         if _check_enabled():
-            _check_regular_layout(dst, valid, num_dst, fanout)
-        total = fanout_sum_aggregate(messages, valid, num_dst, fanout)
-        cnt = valid.reshape(num_dst, fanout).sum(1).astype(messages.dtype)
-        return total / jnp.maximum(cnt, 1.0)[:, None]
+            _check_regular_layout(dst, src >= 0, num_dst, fanout)
+        total, cnt = fanout_gather_sum(x, src, num_dst, fanout)
+        return total / jnp.maximum(cnt, 1).astype(total.dtype)[:, None]
     if fanout is not None:
         from ..utils.trace import info_once
 
@@ -301,11 +342,21 @@ def segment_mean_aggregate(messages, dst, valid, num_dst: int,
         # segment-scatter path — make the perf regression visible (ADVICE
         # layers.py:93)
         info_once(
-            f"dense-gate-fallback-{messages.shape[0]}-{num_dst}-{fanout}",
+            f"dense-gate-fallback-{src.shape[0]}-{num_dst}-{fanout}",
             "Adj.fanout=%d set but E=%d != num_dst*fanout=%d; falling back "
             "to the segment-scatter aggregation path (slow on TPU)",
-            fanout, messages.shape[0], num_dst * fanout,
+            fanout, src.shape[0], num_dst * fanout,
         )
+    messages, valid = gather_src(x, src)
+    return segment_mean_aggregate(messages, jnp.clip(dst, 0), valid, num_dst)
+
+
+def segment_mean_aggregate(messages, dst, valid, num_dst: int):
+    """Mean-aggregate edge messages (E, F) into target nodes by segment.
+
+    Invalid lanes are routed to an overflow segment (index num_dst) and
+    sliced off — the padded-shape analogue of skipping masked edges. Any
+    edge order; the oracle of the dense path."""
     seg = jnp.where(valid, dst, num_dst)
     total = jax.ops.segment_sum(messages, seg, num_segments=num_dst + 1)[:num_dst]
     cnt = jax.ops.segment_sum(valid.astype(messages.dtype), seg, num_segments=num_dst + 1)[:num_dst]

@@ -23,7 +23,7 @@ from typing import Sequence
 import jax.numpy as jnp
 import flax.linen as nn
 
-from .layers import gather_src, segment_mean_aggregate
+from .layers import gather_mean_aggregate
 
 __all__ = ["RGCNLayer", "RGCN"]
 
@@ -83,9 +83,8 @@ class RGCNLayer(nn.Module):
                     name=f"rel_{_rel_name(et)}",
                 )(x_dict[s_t])
             src, dst = adj.edge_index
-            msgs, valid = gather_src(h, src)
-            agg = segment_mean_aggregate(
-                msgs, jnp.clip(dst, 0), valid, layer.dst_caps[d_t],
+            agg = gather_mean_aggregate(
+                h, src, dst, layer.dst_caps[d_t],
                 fanout=getattr(adj, "fanout", None),
             )
             out[d_t] = out[d_t] + agg

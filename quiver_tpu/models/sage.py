@@ -15,7 +15,7 @@ from typing import Sequence
 import jax.numpy as jnp
 import flax.linen as nn
 
-from .layers import gather_src, segment_mean_aggregate
+from .layers import gather_mean_aggregate
 
 __all__ = ["SAGEConv", "GraphSAGE"]
 
@@ -43,9 +43,7 @@ class SAGEConv(nn.Module):
 
     def __call__(self, x, edge_index, num_dst: int, fanout: int | None = None):
         src, dst = edge_index[0], edge_index[1]
-        msgs, valid = gather_src(x, src)
-        agg = segment_mean_aggregate(msgs, jnp.clip(dst, 0), valid, num_dst,
-                                     fanout=fanout)
+        agg = gather_mean_aggregate(x, src, dst, num_dst, fanout=fanout)
         return self.combine(agg, x[:num_dst])
 
 

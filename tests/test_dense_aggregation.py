@@ -1,8 +1,9 @@
 """Dense (fanout) vs segment aggregation parity.
 
 Every sampler-built Adj now carries its static ``fanout``, switching the
-model convs to dense masked (num_dst, fanout) reductions — zero scatters
-(on a v5e a scatter costs 4.4x a sort of the same lanes: PERF.md, PR 26).
+model convs to dense masked reductions over each target's ``fanout`` lanes —
+zero scatters (on a v5e a scatter costs 4.4x a sort of the same lanes:
+PERF.md, PR 26).
 These tests pin the invariant that the dense path is numerically the
 segment path: same Adj, same params, fanout set vs stripped, outputs must
 agree to float tolerance for all four homogeneous conv families plus the
@@ -66,6 +67,207 @@ def test_dense_matches_segment(sampled, family):
     np.testing.assert_allclose(
         np.asarray(y_dense), np.asarray(y_seg), rtol=2e-4, atol=2e-5
     )
+
+
+# (num_dst, fanout) of a hand-built regular block: 37 is no multiple of 8
+# (the fanout-major view is then no view of whole tiles: same sums)
+FANOUT_BLOCKS = [(64, 15), (64, 10), (40, 5), (37, 5), (8, 25)]
+
+
+def _regular_block(num_dst, fanout, rows, seed=0):
+    """``edge_index`` (2, num_dst * fanout) in the sampler's layout with
+    padded lanes, a target with none but padded lanes, one with none
+    padded, a source repeated within a target and one across targets."""
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, rows, (num_dst, fanout)).astype(np.int32)
+    src[rng.random(src.shape) < 0.3] = -1
+    src[1] = -1
+    src[2] = rng.integers(0, rows, fanout)
+    src[3, :2] = 7
+    src[4:7, 0] = 9
+    dst = np.repeat(np.arange(num_dst, dtype=np.int32), fanout)
+    src = src.reshape(-1)
+    return jnp.asarray(np.stack([src, np.where(src >= 0, dst, -1)]))
+
+
+@pytest.mark.parametrize("num_dst,fanout", FANOUT_BLOCKS)
+def test_fanout_gather_sum_is_the_segment_sum(num_dst, fanout):
+    """The fanout-major gather and sum against ``segment_sum`` over the
+    same lanes: totals, counts, and the gradient of the rows."""
+    from quiver_tpu.models.layers import fanout_gather_sum
+
+    rows, width = 3 * num_dst, 12
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.normal(size=(rows, width)).astype(np.float32))
+    weight = jnp.asarray(rng.normal(size=(num_dst, width)).astype(np.float32))
+    src, dst = _regular_block(num_dst, fanout, rows)
+    valid = src >= 0
+    seg = jnp.where(valid, dst, num_dst)
+
+    def dense(x):
+        total, cnt = fanout_gather_sum(x, src, num_dst, fanout)
+        return (total * weight).sum(), (total, cnt)
+
+    def segment(x):
+        msgs = jnp.where(valid[:, None], x[jnp.clip(src, 0)], 0.0)
+        total = jax.ops.segment_sum(msgs, seg, num_dst + 1)[:num_dst]
+        cnt = jax.ops.segment_sum(valid.astype(jnp.int32), seg, num_dst + 1)
+        return (total * weight).sum(), (total, cnt[:num_dst])
+
+    (_, (total, cnt)), grad = jax.value_and_grad(dense, has_aux=True)(x)
+    (_, (want, want_cnt)), want_grad = jax.value_and_grad(
+        segment, has_aux=True)(x)
+    _, jitted = jax.jit(jax.value_and_grad(dense, has_aux=True))(x)
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(want_cnt))
+    assert cnt.dtype == jnp.int32 and int(cnt[1]) == 0 and int(cnt[2]) == fanout
+    assert not np.asarray(total)[1].any()
+    np.testing.assert_allclose(
+        np.asarray(total), np.asarray(want), rtol=1e-5, atol=1e-5)
+    for g in (grad, jitted):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(want_grad), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("num_dst,fanout", FANOUT_BLOCKS)
+def test_sageconv_dense_path_is_the_segment_path(num_dst, fanout):
+    """``SAGEConv`` with the block's fanout against the same block with
+    none: the layer's values and its gradients with respect to the rows
+    and every weight."""
+    from quiver_tpu.models.sage import SAGEConv
+
+    rows, width = 3 * num_dst, 12
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(rows, width)).astype(np.float32))
+    edge_index = _regular_block(num_dst, fanout, rows, seed=3)
+    conv = SAGEConv(8)
+    params = conv.init(jax.random.PRNGKey(0), x, edge_index, num_dst, fanout)
+    weight = jnp.asarray(rng.normal(size=(num_dst, 8)).astype(np.float32))
+
+    def loss(params, x, fanout):
+        y = conv.apply(params, x, edge_index, num_dst, fanout)
+        return (y * weight).sum(), y
+
+    with jax.default_matmul_precision("highest"):
+        (_, y), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(params, x, fanout)
+        (_, want), want_grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(params, x, None)
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(want), rtol=1e-5, atol=1e-5)
+    for g, w in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-5)
+
+
+def test_sageconv_dense_forward_gathers_fanout_major():
+    """The traced dense forward of ``SAGEConv`` holds ONE gather, straight
+    into ``(fanout, num_dst, F)``, and a sum over axis 0 of that array;
+    nothing is reshaped to ``(num_dst, fanout, F)``, the view a v5e can
+    only have as a padded copy of every gathered row (PERF.md, PR 36). The
+    segment path keeps its ``(E, F)`` gather."""
+    from quiver_tpu.models.sage import SAGEConv
+    from quiver_tpu.tools.audit.ir import iter_eqns
+
+    num_dst, fanout, width = 16, 5, 12
+    x = jnp.zeros((3 * num_dst, width), jnp.float32)
+    edge_index = _regular_block(num_dst, fanout, x.shape[0])
+    conv = SAGEConv(8)
+    params = conv.init(jax.random.PRNGKey(0), x, edge_index, num_dst, fanout)
+
+    def traced(fanout):
+        jaxpr = jax.make_jaxpr(
+            lambda p, x: conv.apply(p, x, edge_index, num_dst, fanout))(
+                params, x)
+        return [eqn for eqn, _ in iter_eqns(jaxpr)]
+
+    slabs = (fanout, num_dst, width)
+    eqns = traced(fanout)
+    gathers = [e for e in eqns if e.primitive.name == "gather"]
+    assert [e.outvars[0].aval.shape for e in gathers] == [slabs]
+    sums = [e for e in eqns if e.primitive.name == "reduce_sum"
+            and e.invars[0].aval.shape == slabs]
+    assert [e.params["axes"] for e in sums] == [(0,)]
+    by_lane = {(num_dst, fanout, width), (num_dst * fanout, width)}
+    for e in eqns:
+        shapes = [v.aval.shape for v in list(e.invars) + list(e.outvars)
+                  if hasattr(v.aval, "shape")]
+        assert not by_lane & set(shapes), (e.primitive.name, shapes)
+    assert not any(e.primitive.name.startswith("scatter") for e in eqns)
+    segment = [e.outvars[0].aval.shape for e in traced(None)
+               if e.primitive.name == "gather"]
+    assert segment == [(num_dst * fanout, width)]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip's sharding: the TPU's compiler
+    runs where there is no TPU. Described inside the fixture, by the worker
+    that runs this file alone."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _relayouts(compiled, elements):
+    """The ENTRY computation's ``copy`` / ``reshape`` / ``transpose``
+    instructions of ``elements`` elements or more: the ops that move an
+    array into another layout (a view is a ``bitcast``)."""
+    import re
+
+    text = compiled.as_text()
+    found = []
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(
+            r"\s+(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([a-z\-]+)\(", line)
+        if m and m.group(2) in ("copy", "reshape", "transpose"):
+            if np.prod([int(d) for d in m.group(1).split(",") if d]) >= elements:
+                found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize(
+    "num_dst,fanout,width", [(1024, 10, 256), (1024, 5, 100), (1000, 25, 602)])
+def test_a_v5e_gets_the_fanout_major_rows_without_a_copy(
+        one_chip, num_dst, fanout, width):
+    """Compiled for a described v5e, value and gradient: behind the
+    fanout-major gather no instruction re-lays out an array of the
+    gathered rows' size, forward or transposed; the same rows gathered in
+    lane order and summed by ``fanout_sum_aggregate`` cost ``reshape``s of
+    that size both ways (which also shows that the check can see one).
+    Nothing runs; this says nothing of results or times."""
+    from quiver_tpu.models.layers import (
+        fanout_gather_sum, fanout_sum_aggregate, gather_src)
+
+    def fanout_major(x, src, weight):
+        total, _ = fanout_gather_sum(x, src, num_dst, fanout)
+        return (total * weight).sum()
+
+    def lane_order(x, src, weight):
+        msgs, valid = gather_src(x, src)
+        return (fanout_sum_aggregate(msgs, valid, num_dst, fanout)
+                * weight).sum()
+
+    def relayouts(fn):
+        shapes = [((4 * num_dst, width), jnp.float32),
+                  ((num_dst * fanout,), jnp.int32),
+                  ((num_dst, width), jnp.float32)]
+        compiled = jax.jit(jax.value_and_grad(fn)).lower(*[
+            jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]).compile()
+        return _relayouts(compiled, num_dst * fanout * width)
+
+    assert relayouts(fanout_major) == []
+    assert len(relayouts(lane_order)) >= 2
 
 
 def test_fanout_softmax_matches_segment_softmax():

@@ -11,7 +11,7 @@ import pytest
 
 from quiver_tpu import CSRTopo, GraphSageSampler
 from quiver_tpu.feature.feature import Feature
-from quiver_tpu.models.layers import segment_mean_aggregate
+from quiver_tpu.models.layers import gather_mean_aggregate
 from quiver_tpu.parallel.mesh import make_mesh
 from quiver_tpu.utils import trace as trace_mod
 
@@ -29,11 +29,12 @@ def _fresh_once_keys():
 # -- QUIVER_CHECK dense-layout assertion (ADVICE layers.py:93) -------------
 
 def _regular_adj(num_dst=4, fanout=3, dim=2):
-    msgs = np.arange(num_dst * fanout * dim, dtype=np.float32).reshape(
+    """Source rows, and a block whose lane ``i`` reads row ``i``."""
+    x = np.arange(num_dst * fanout * dim, dtype=np.float32).reshape(
         num_dst * fanout, dim)
+    src = np.arange(num_dst * fanout)
     dst = np.repeat(np.arange(num_dst), fanout)
-    valid = np.ones(num_dst * fanout, bool)
-    return jnp.asarray(msgs), jnp.asarray(dst), jnp.asarray(valid)
+    return jnp.asarray(x), jnp.asarray(src), jnp.asarray(dst)
 
 
 def _reset_check_cache(monkeypatch):
@@ -47,8 +48,8 @@ def _reset_check_cache(monkeypatch):
 def test_quiver_check_passes_on_regular_layout(monkeypatch):
     _reset_check_cache(monkeypatch)
     monkeypatch.setenv("QUIVER_CHECK", "1")
-    msgs, dst, valid = _regular_adj()
-    out = segment_mean_aggregate(msgs, dst, valid, 4, fanout=3)
+    x, src, dst = _regular_adj()
+    out = gather_mean_aggregate(x, src, dst, 4, fanout=3)
     assert out.shape == (4, 2)
 
 
@@ -57,28 +58,28 @@ def test_quiver_check_catches_layout_violation(monkeypatch):
     QUIVER_CHECK instead of silently mis-aggregating."""
     _reset_check_cache(monkeypatch)
     monkeypatch.setenv("QUIVER_CHECK", "1")
-    msgs, dst, valid = _regular_adj()
+    x, src, dst = _regular_adj()
     bad_dst = jnp.asarray(np.roll(np.asarray(dst), 1))  # breaks regularity
     with pytest.raises(Exception, match="QUIVER_CHECK"):
-        np.asarray(segment_mean_aggregate(msgs, bad_dst, valid, 4, fanout=3))
+        np.asarray(gather_mean_aggregate(x, src, bad_dst, 4, fanout=3))
 
 
 def test_quiver_check_off_by_default(monkeypatch):
     _reset_check_cache(monkeypatch)
     monkeypatch.delenv("QUIVER_CHECK", raising=False)
-    msgs, dst, valid = _regular_adj()
+    x, src, dst = _regular_adj()
     bad_dst = jnp.asarray(np.roll(np.asarray(dst), 1))
     # dense path trusts the claim (documented); no error without the flag
-    out = segment_mean_aggregate(msgs, bad_dst, valid, 4, fanout=3)
+    out = gather_mean_aggregate(x, src, bad_dst, 4, fanout=3)
     assert out.shape == (4, 2)
 
 
 def test_dense_gate_shape_fallback_logged(caplog):
     """fanout set but E != num_dst*fanout: the silent revert to the
     segment-scatter path now logs once."""
-    msgs, dst, valid = _regular_adj(num_dst=4, fanout=3)
+    x, src, dst = _regular_adj(num_dst=4, fanout=3)
     with caplog.at_level(logging.INFO, logger="quiver_tpu"):
-        out = segment_mean_aggregate(msgs, dst, valid, 4, fanout=5)  # wrong
+        out = gather_mean_aggregate(x, src, dst, 4, fanout=5)  # wrong
     assert out.shape == (4, 2)
     assert any("segment-scatter" in r.message for r in caplog.records)
 
