@@ -38,7 +38,6 @@ from .obs import (
     StepTimeline,
     TelemetryEndpoint,
     Tracer,
-    profile_epoch,
 )
 from .ooc import (
     AsyncStager,
@@ -129,7 +128,6 @@ __all__ = [
     "MetricsRegistry",
     "MetricSnapshot",
     "StepTimeline",
-    "profile_epoch",
     "Tracer",
     "FlightRecorder",
     "TelemetryEndpoint",

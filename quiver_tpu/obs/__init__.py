@@ -10,11 +10,14 @@ pieces:
   cond-gated fallbacks, psum'd once per step, landing as typed
   :class:`MetricSnapshot` objects (``registry.py``);
 * :class:`StepTimeline` — host-side per-stage wall clock with streaming
-  p50/p95/p99 (``timeline.py``);
+  p50/p95/p99; each stage is also a ``quiver.<name>`` slice on the host
+  thread of a ``jax.profiler`` capture (``timeline.py``,
+  ``utils.trace.host_span``);
 * JSONL + Prometheus-style exporters, both parse-back round-trippable
   (``export.py``);
-* :func:`profile_epoch` — ``jax.profiler`` capture bracketing with the
-  same stage names on the device timeline (``profile.py``).
+* :func:`compile_watch` — what XLA compiled in this process, from JAX's
+  monitoring events; the trainer puts each compile down to the step that
+  paid it (``compile_watch.py``).
 
 ``DistributedTrainer.metrics_report()`` is the one-call summary over all
 of it.
@@ -31,6 +34,7 @@ grafttrace extends the layer with causal chains and crash forensics:
   ``/metrics``, ``/traces``, ``/healthz`` (``endpoint.py``).
 """
 
+from .compile_watch import compile_watch
 from .endpoint import TelemetryEndpoint
 from .export import (
     from_prometheus,
@@ -41,7 +45,6 @@ from .export import (
     to_prometheus,
     write_jsonl,
 )
-from .profile import profile_epoch
 from .recorder import (
     FlightRecorder,
     TornBundle,
@@ -85,7 +88,7 @@ __all__ = [
     "to_prometheus",
     "from_prometheus",
     "prometheus_name",
-    "profile_epoch",
+    "compile_watch",
     "Span",
     "Tracer",
     "TRACE_SPANS",

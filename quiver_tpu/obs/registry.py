@@ -82,6 +82,9 @@ __all__ = [
     "TRACE_SPANS",
     "RECORDER_BUNDLES",
     "RECORDER_EVENTS",
+    "XLA_COMPILES",
+    "XLA_COMPILE_SECONDS",
+    "XLA_CACHE_HITS",
 ]
 
 # well-known metric names — the three streams the registry was distilled
@@ -184,6 +187,13 @@ CTRL_OOC_PROMOTIONS = "ctrl.ooc_promotions"
 TRACE_SPANS = "trace.spans"
 RECORDER_BUNDLES = "recorder.bundles"
 RECORDER_EVENTS = "recorder.events"
+# what the launches of DistributedTrainer.step() compiled (host-side, from
+# obs/compile_watch.py): backend compilations they paid (cache-served ones
+# included), those compilations' wall seconds, and how many the persistent
+# compilation cache served; compiles - cache_hits were compiled anew
+XLA_COMPILES = "xla.compiles"
+XLA_COMPILE_SECONDS = "xla.compile_seconds"
+XLA_CACHE_HITS = "xla.cache_hits"
 
 _KINDS = ("counter", "gauge")
 

@@ -5,10 +5,13 @@ but the *host* loop still has stages worth attributing: eager tuners, seed
 packing, H2D, dispatch, readbacks, prefetch waits. :class:`StepTimeline`
 times named stages (``with timeline.stage("sample", sync=out.n_id):``),
 keeps streaming p50/p95/p99 per stage via the P² algorithm (O(1) memory —
-a long run never stores every sample), and each stage also enters
-``trace_scope(name)`` so a ``jax.profiler`` capture (see
-``obs.profile_epoch``) carries the SAME stage names on the device timeline
-as the host report.
+a long run never stores every sample), and each stage is also a
+``utils.trace.host_span(name)``: with tracing enabled a ``jax.profiler``
+capture (``utils.trace.start_trace`` / ``stop_trace``) shows the stage as
+a ``quiver.<name>`` slice on the HOST thread, on the clock of the device's
+ops. A stage puts no name on the device timeline: a scope entered around
+a jitted call does not reach the program's op names (those are
+``trace_scope``'s, inside the traced function).
 
 ``sync=`` takes any array/pytree to ``block_until_ready`` before the clock
 stops — without it a stage measures dispatch latency, not work (the same
@@ -24,7 +27,7 @@ import time
 
 import jax
 
-from ..utils.trace import trace_scope
+from ..utils.trace import host_span
 
 __all__ = ["P2Quantile", "StageStats", "StepTimeline"]
 
@@ -160,9 +163,10 @@ class StepTimeline:
     @contextlib.contextmanager
     def stage(self, name: str, sync=None):
         """Time a stage; ``sync`` blocks on the given array/pytree before
-        the clock stops. Also a ``trace_scope`` — under a profiler capture
-        the device timeline shows the same stage name."""
-        with trace_scope(name):
+        the clock stops. Also a ``host_span(name)`` — under a profiler
+        capture with tracing enabled the host thread shows
+        ``quiver.<name>`` over the same lines."""
+        with host_span(name):
             t0 = time.perf_counter()
             try:
                 yield

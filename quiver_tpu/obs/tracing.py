@@ -261,18 +261,35 @@ class Tracer:
         return _SpanScope(self, self._make(name, trace, parent,
                                            subsystem, attrs))
 
+    def begin_span(self, name: str, trace: str | None = None, parent=None,
+                   subsystem: str | None = None, **attrs) -> Span | None:
+        """Open a span whose clock readings the CALLER takes (it already
+        reads the clock for another sink): the span has its id now, so
+        children can name it as ``parent`` while it is open, and is timed
+        and kept by :meth:`finish_span`. None when disabled."""
+        if not self.enabled:
+            return None
+        return self._make(name, trace, parent, subsystem, attrs)
+
+    def finish_span(self, span: Span | None, t0: float, dur: float) -> None:
+        """Time and keep a span from :meth:`begin_span`: ``t0`` on the
+        tracer's relative clock (see :meth:`now`), ``dur`` in seconds.
+        The disabled tracer's None is accepted and dropped."""
+        if span is None:
+            return
+        span.t0 = float(t0)
+        span.dur = float(dur)
+        self._record(span)
+
     def record(self, name: str, t0: float, dur: float,
                trace: str | None = None, parent=None,
                subsystem: str | None = None, **attrs) -> Span | None:
         """Record an already-measured span: ``t0`` on the tracer's
         relative clock (see :meth:`now`), ``dur`` in seconds. Returns the
         span (None when disabled) so callers can parent children on it."""
-        if not self.enabled:
-            return None
-        s = self._make(name, trace, parent, subsystem, attrs)
-        s.t0 = float(t0)
-        s.dur = float(dur)
-        self._record(s)
+        s = self.begin_span(name, trace=trace, parent=parent,
+                            subsystem=subsystem, **attrs)
+        self.finish_span(s, t0, dur)
         return s
 
     def observe(self, name: str, seconds: float, trace: str | None = None,

@@ -42,6 +42,7 @@ from ..core.config import parse_size_bytes
 from ..feature.feature import Feature
 from ..feature.shard import ShardedFeature
 from ..control.freq import heat_num_bins, row_heat_histogram
+from ..obs.compile_watch import CompileTotals, compile_watch
 from ..obs.registry import (
     FEATURE_ROW_HEAT,
     GUARD_NONFINITE,
@@ -54,6 +55,9 @@ from ..obs.registry import (
     SAMPLE_OVERFLOW,
     TIER_HITS,
     TRAIN_OVERLAP_EFFICIENCY,
+    XLA_CACHE_HITS,
+    XLA_COMPILE_SECONDS,
+    XLA_COMPILES,
     MetricsRegistry,
 )
 from ..obs.timeline import StepTimeline
@@ -61,7 +65,7 @@ from ..obs.tracing import Tracer
 from ..resilience.elastic import validate_resume_meta, worker_ordered_mean
 from ..resilience.faults import Preemption
 from ..resilience.guard import guard_verdict, guarded_update
-from ..utils.trace import info_once, trace_scope
+from ..utils.trace import get_logger, host_span, info_once, trace_scope
 from ..parallel.mesh import DATA_AXIS, FEATURE_AXIS, shard_map
 from ..parallel.pipeline import PipelinedBatch, Prefetcher
 from ..parallel.train import cross_entropy_on_seeds
@@ -99,6 +103,57 @@ def _metrics_report(metrics: MetricsRegistry, timeline: StepTimeline,
     lines.append("timeline:")
     lines.extend("  " + ln for ln in timeline.report().splitlines())
     return "\n".join(lines)
+
+
+class _Phase:
+    """One host phase of ``DistributedTrainer.step``, told to every sink
+    from ONE pair of clock readings: the slice ``quiver.<stage>`` on the
+    profiler's timeline (``utils.trace.host_span``; ``attrs`` are its
+    stats), an observation of stage ``<stage>`` of the trainer's
+    ``StepTimeline``, and, with an enabled ``Tracer``, the span
+    ``train.<stage>`` of ``trace`` under ``parent``'s span. A phase that
+    raises is still told, the span tagged with the error's name."""
+
+    __slots__ = ("_timeline", "_tracer", "_stage", "_trace", "_parent",
+                 "_attrs", "_note", "_t0", "span")
+
+    def __init__(self, trainer: "DistributedTrainer", stage: str,
+                 trace: str, parent: "_Phase | None" = None, **attrs):
+        self._timeline = trainer.timeline
+        self._tracer = trainer.tracer
+        self._stage = stage
+        self._trace = trace
+        self._parent = parent
+        self._attrs = attrs
+
+    def set(self, **attrs) -> None:
+        """Attach ``attrs`` to the open phase: stats of its profiler
+        slice, attributes of its tracer span."""
+        self._note.set_metadata(**attrs)
+        if self.span is not None:
+            self.span.attrs.update(attrs)
+
+    def __enter__(self) -> "_Phase":
+        # the slice opens first and closes last: it holds the phase's own
+        # bookkeeping, so the leaves of a step cover it
+        self._note = host_span(self._stage, **self._attrs)
+        self._note.__enter__()
+        self.span = self._tracer.begin_span(
+            "train." + self._stage, trace=self._trace,
+            parent=None if self._parent is None else self._parent.span,
+            subsystem="trainer", **self._attrs,
+        )
+        self._t0 = self._tracer.now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dur = self._tracer.now() - self._t0
+        self._timeline.observe(self._stage, dur)
+        if self.span is not None:
+            if exc_type is not None:
+                self.span.attrs["error"] = exc_type.__name__
+            self._tracer.finish_span(self.span, self._t0, dur)
+        return self._note.__exit__(exc_type, exc, tb)
 
 
 class DistributedTrainer:
@@ -408,6 +463,28 @@ class DistributedTrainer:
         # epoch_scan() time their eager dispatch, callers can add their own
         # stages (or feed it via Timer(registry=trainer.timeline))
         self.timeline = StepTimeline()
+        # what step()'s launches compiled (obs/compile_watch.py): a re-keyed
+        # step program (eager resplit, refresh(), grown routed_alpha) is
+        # put down to the step that paid for it, in the registry, the
+        # timeline (stage step.compile), health() and the log
+        self._xla = compile_watch()
+        self._xla_paid = CompileTotals()
+        self._last_compile_step: int | None = None
+        self.metrics.counter(
+            XLA_COMPILES, unit="programs",
+            doc="backend compilations step()'s launches paid, those the "
+                "persistent compilation cache served included (host-side)",
+        )
+        self.metrics.gauge(
+            XLA_COMPILE_SECONDS, dtype=jnp.float32, unit="s",
+            doc="wall seconds of those compilations (a cache hit: of its "
+                "retrieval), total (host-side)",
+        )
+        self.metrics.counter(
+            XLA_CACHE_HITS, unit="programs",
+            doc="of xla.compiles, how many the persistent compilation "
+                "cache served (host-side)",
+        )
         # replicate_budget: L0 super-hot tier override. A value re-splits a
         # ShardedFeature's replicated/sharded boundary BEFORE the program
         # is built (needs the store's retained host region); on a plain
@@ -587,7 +664,9 @@ class DistributedTrainer:
 
     def health(self) -> dict:
         """The ``/healthz`` summary: worker geometry, bound streaming
-        versions, checkpoint progress, guard-trip count."""
+        versions, checkpoint progress, guard-trip count, and the index of
+        the last eager ``step()`` whose launch compiled (None: none has;
+        past the first step it names a re-keyed program)."""
         topo_v, feat_v = self._current_versions()
         return {
             "workers": int(self.workers),
@@ -596,6 +675,7 @@ class DistributedTrainer:
             "feature_version": feat_v,
             "checkpoint_seq": int(self._ckpt_seq),
             "guard_trips": int(self._guard_trips_seen),
+            "last_compile_step": self._last_compile_step,
         }
 
     def serve_telemetry(self, host: str = "127.0.0.1",
@@ -1242,6 +1322,33 @@ class DistributedTrainer:
                 "nonfinite_guard", stage="train", skipped_total=total,
             )
 
+    def _note_compile(self, step_idx: int, launch: _Phase,
+                      before: CompileTotals) -> None:
+        """Put what compiled during ``launch`` down to step ``step_idx``:
+        ``compiles=<n>`` on the launch's span, the seconds as timeline
+        stage ``step.compile``, the trainer's running totals in the
+        registry, the index in :meth:`health`, one line in the log."""
+        after = self._xla.totals
+        n = after.compiles - before.compiles
+        seconds = after.seconds - before.seconds
+        hits = after.cache_hits - before.cache_hits
+        launch.set(compiles=n)
+        self.timeline.observe("step.compile", seconds)
+        paid = self._xla_paid = CompileTotals(
+            self._xla_paid.compiles + n,
+            self._xla_paid.seconds + seconds,
+            self._xla_paid.cache_hits + hits,
+        )
+        self.metrics.set(XLA_COMPILES, np.int32(paid.compiles))
+        self.metrics.set(XLA_COMPILE_SECONDS, np.float32(paid.seconds))
+        self.metrics.set(XLA_CACHE_HITS, np.int32(paid.cache_hits))
+        self._last_compile_step = step_idx
+        get_logger().info(
+            "step %d compiled: %d program(s) in %.3f s, %d served by the "
+            "persistent compilation cache",
+            step_idx, n, seconds, hits,
+        )
+
     def step(self, params, opt_state, seeds, labels, key):
         """One fused step. ``seeds``: global seed array (host). ``labels``:
         full (N,) label array (replicated).
@@ -1270,52 +1377,80 @@ class DistributedTrainer:
         vector here: the eager tuner moves its replicated/sharded boundary
         before the next step's dispatch (the changed tier shapes re-key
         the jit cache, so the program retraces on the new split).
+
+        The host side of the call is five phases, each one ``with``:
+        ``tune`` (version check, eager tuners), ``pack`` (seed blocks),
+        ``place`` (the operands' placements), ``launch`` (the jitted call
+        alone) and ``record`` (registry, guard check, the tuners' feeds).
+        Each is stage ``step.<phase>`` of ``self.timeline`` inside stage
+        ``step``, and with tracing enabled the slice
+        ``quiver.step.<phase>`` inside ``quiver.step`` (stat ``step``: the
+        count of eager calls) on the profiler's timeline; with an enabled
+        tracer, span ``train.step.<phase>`` under ``train.step``. The
+        names are an interface (``docs/Introduction.md``, "Reading the
+        host side of a step"). A launch that compiled carries
+        ``compiles=<n>`` and is put down to its step (:meth:`health`,
+        ``xla.*`` in the registry, stage ``step.compile``).
         """
-        self._check_versions()
         feature = self.feature
         plan = self.fault_plan
         step_idx = self._fault_step
-        self._fault_step += 1
-        with self.tracer.span("train.step", trace=f"train.step.{step_idx}",
-                              subsystem="trainer", step=step_idx), \
-                self.timeline.stage("step"):
-            if isinstance(feature, ShardedFeature) and (
-                feature.auto_split
-                or getattr(feature, "_controller", None) is not None
-            ):
-                feature._maybe_auto_split()
-            self._maybe_grow_routed_alpha()
-            packed = self.shard_seeds(seeds)
-            if self.controller is not None:
-                # seeds are the host-visible slice of the step's gather
-                # traffic — feed the controller's heavy-hitter set (the
-                # in-program histogram covers the full id stream, but
-                # only host-visible ids can NAME rows for a repin)
-                self.controller.observe_ids(packed)
-            # every operand reaches the mesh by an explicit placement:
-            # nothing is left for jit to move between devices
-            replicated = NamedSharding(self.mesh, P())
-            packed = jax.device_put(
-                packed, NamedSharding(self.mesh, self._seed_spec())
-            )
-            key, inject = jax.device_put(
-                (key, np.asarray(plan is not None and plan.nan_at(step_idx))),
-                replicated,
-            )
-            params, opt_state, loss, mtree = self._step(
-                params, opt_state, self.topo, self._feature_parts(), packed,
-                self._mesh_wide("labels", labels), key, inject
-            )
-        self.metrics.record(mtree)
-        self._check_guard_trip()
-        if mtree and isinstance(feature, ShardedFeature):
-            # hand the batch totals to the store so its eager split tuner
-            # sees the fused path's traffic too
-            feature.last_tier_hits = mtree[TIER_HITS]
-        if mtree and self.controller is not None:
-            # fold the step's heat histogram into the controller's sketch
-            # (no-op when the heat feed is off)
-            self.controller.observe_histogram(mtree.get(FEATURE_ROW_HEAT))
+        trace = f"train.step.{step_idx}"
+        with _Phase(self, "step", trace, step=step_idx) as whole:
+            with _Phase(self, "step.tune", trace, whole):
+                self._check_versions()
+                # a step the version check refuses takes no index
+                self._fault_step += 1
+                if isinstance(feature, ShardedFeature) and (
+                    feature.auto_split
+                    or getattr(feature, "_controller", None) is not None
+                ):
+                    feature._maybe_auto_split()
+                self._maybe_grow_routed_alpha()
+            with _Phase(self, "step.pack", trace, whole):
+                packed = self.shard_seeds(seeds)
+                if self.controller is not None:
+                    # seeds are the host-visible slice of the step's gather
+                    # traffic — feed the controller's heavy-hitter set (the
+                    # in-program histogram covers the full id stream, but
+                    # only host-visible ids can NAME rows for a repin)
+                    self.controller.observe_ids(packed)
+            with _Phase(self, "step.place", trace, whole):
+                # every operand reaches the mesh by an explicit placement:
+                # nothing is left for jit to move between devices
+                replicated = NamedSharding(self.mesh, P())
+                packed = jax.device_put(
+                    packed, NamedSharding(self.mesh, self._seed_spec())
+                )
+                key, inject = jax.device_put(
+                    (key, np.asarray(
+                        plan is not None and plan.nan_at(step_idx))),
+                    replicated,
+                )
+                parts = self._feature_parts()
+                labels = self._mesh_wide("labels", labels)
+            with _Phase(self, "step.launch", trace, whole) as launch:
+                # the jitted call alone: trace, lowering and compile (or
+                # cache load) on the first call of a program, dispatch after
+                compiled = self._xla.totals
+                params, opt_state, loss, mtree = self._step(
+                    params, opt_state, self.topo, parts, packed, labels,
+                    key, inject
+                )
+                if self._xla.totals is not compiled:
+                    self._note_compile(step_idx, launch, compiled)
+            with _Phase(self, "step.record", trace, whole):
+                self.metrics.record(mtree)
+                self._check_guard_trip()
+                if mtree and isinstance(feature, ShardedFeature):
+                    # hand the batch totals to the store so its eager split
+                    # tuner sees the fused path's traffic too
+                    feature.last_tier_hits = mtree[TIER_HITS]
+                if mtree and self.controller is not None:
+                    # fold the step's heat histogram into the controller's
+                    # sketch (no-op when the heat feed is off)
+                    self.controller.observe_histogram(
+                        mtree.get(FEATURE_ROW_HEAT))
         if (plan is not None and not self._preempt_fired
                 and plan.preempts_in(step_idx, step_idx + 1)):
             # the step ran but its results are lost with the raise — the

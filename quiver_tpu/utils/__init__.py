@@ -6,6 +6,7 @@ __all__ = [
     "reorder_by_degree",
     "Timer",
     "trace_scope",
+    "host_span",
     "enable_trace",
     "disable_trace",
     "trace_enabled",
@@ -23,6 +24,7 @@ _LAZY = {
     "reorder_by_degree": "reorder",
     "Timer": "trace",
     "trace_scope": "trace",
+    "host_span": "trace",
     "enable_trace": "trace",
     "disable_trace": "trace",
     "trace_enabled": "trace",

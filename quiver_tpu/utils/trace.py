@@ -7,10 +7,16 @@ Capability parity with the reference's observability layer (SURVEY §5):
   (``jax.named_scope``), is ALWAYS written: names are op metadata, cost
   nothing on the device, and JAX's persistent compile cache leaves metadata
   out of its key, so names behind a switch would be lost whenever a process
-  with the switch off filled the cache first. Its host half, the slice on the host
-  profiler timeline (``jax.profiler.TraceAnnotation``), is the part the
-  reference's ``QUIVER_ENABLE_TRACE`` switch (or :func:`enable_trace`)
-  turns on. ``docs/Introduction.md`` has the scope tree of the fused step.
+  with the switch off filled the cache first. Its host half is
+  :func:`host_span`, the part the reference's ``QUIVER_ENABLE_TRACE``
+  switch (or :func:`enable_trace`) turns on. ``docs/Introduction.md`` has
+  the scope tree of the fused step.
+- :func:`host_span` is the package's ONE way to put eager host code on the
+  profiler's timeline: a ``jax.profiler.TraceAnnotation`` named
+  ``quiver.<name>`` with its keyword arguments as the event's stats, so a
+  capture shows the program's host side on the clock of the device's ops.
+  ``obs.StepTimeline.stage`` and ``DistributedTrainer.step``'s phases go
+  through it (``docs/Introduction.md``, "Reading the host side of a step").
 - the RAII wall-clock ``timer`` (timer.hpp:7-28) becomes :class:`Timer`.
 - the ad-hoc ``"LOG>>>"`` prints (feature.py:109-111, shard_tensor.py:69-71)
   become a real structured logger under the ``quiver_tpu`` namespace.
@@ -34,6 +40,7 @@ __all__ = [
     "disable_trace",
     "trace_enabled",
     "trace_scope",
+    "host_span",
     "Timer",
     "get_logger",
     "info_once",
@@ -58,7 +65,7 @@ def trace_enabled() -> bool:
 
 
 def enable_trace() -> None:
-    """Turn on :func:`trace_scope`'s host annotations (overrides the env).
+    """Turn on :func:`host_span`'s annotations (overrides the env).
 
     Scope names in compiled programs do not depend on it."""
     global _enabled
@@ -70,6 +77,43 @@ def disable_trace() -> None:
     _enabled = False
 
 
+class _NoSpan:
+    """What :func:`host_span` hands out with tracing disabled: it enters,
+    exits and takes metadata as a ``TraceAnnotation`` does, and does
+    nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **attrs) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def host_span(name: str, **attrs):
+    """A slice of eager host code on the profiler's timeline.
+
+    With tracing enabled (:func:`trace_enabled`) this is a
+    ``jax.profiler.TraceAnnotation`` named ``"quiver." + name`` whose
+    keyword arguments become the event's stats (``host_span("step",
+    step=7)``; ``set_metadata(**more)`` adds to them while it is open);
+    disabled, it is a shared object that does nothing with either. Host
+    code has no ops, so no ``jax.named_scope`` is entered: nothing of a
+    compiled program depends on it. The ``quiver.`` names are what a
+    reader of a capture selects by.
+    """
+    if not trace_enabled():
+        return _NO_SPAN
+    return jax.profiler.TraceAnnotation("quiver." + name, **attrs)
+
+
 @contextlib.contextmanager
 def trace_scope(name: str):
     """Name a region in the XLA program, and on the host profiler timeline.
@@ -77,14 +121,11 @@ def trace_scope(name: str):
     The ``jax.named_scope`` is entered unconditionally: every op traced
     inside carries ``name`` in its scope path, whether or not tracing is
     enabled, so a device trace of any process reads the same names. Only
-    the host half (a ``TraceAnnotation`` slice around eager host code) is
-    behind :func:`trace_enabled`, mirroring the reference's compile-time-
-    gated TRACE_SCOPE.
+    the host half (:func:`host_span`, a ``quiver.<name>`` slice around
+    eager host code) is behind :func:`trace_enabled`, mirroring the
+    reference's compile-time-gated TRACE_SCOPE.
     """
-    with contextlib.ExitStack() as stack:
-        stack.enter_context(jax.named_scope(name))
-        if trace_enabled():
-            stack.enter_context(jax.profiler.TraceAnnotation(name))
+    with jax.named_scope(name), host_span(name):
         yield
 
 

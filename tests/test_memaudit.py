@@ -152,6 +152,7 @@ _SOURCES_EXEMPT = frozenset({
     "quiver_tpu/core/config.py",
     "quiver_tpu/core/memory.py",
     "quiver_tpu/core/sharded_topology.py",
+    "quiver_tpu/obs/compile_watch.py",
     "quiver_tpu/obs/endpoint.py",
     "quiver_tpu/obs/export.py",
     "quiver_tpu/obs/timeline.py",

@@ -5,7 +5,7 @@ feeding through shard_map with per-metric psum placement, the
 enabled/disabled program-level switch), the StepTimeline's streaming P²
 percentiles and stage timing, Timer's registry hookup, both exporters'
 round trips (JSONL and Prometheus exposition, including epoch_scan-shaped
-``(steps, k)`` metrics), profile_epoch bracketing, and the acceptance
+``(steps, k)`` metrics), and the acceptance
 differential: metrics collection disabled vs enabled yields a bit-identical
 loss trajectory over an ``epoch_scan`` epoch.
 """
@@ -25,7 +25,6 @@ from quiver_tpu.obs import (
     P2Quantile,
     StepTimeline,
     from_prometheus,
-    profile_epoch,
     read_jsonl,
     to_prometheus,
     write_jsonl,
@@ -338,20 +337,6 @@ def test_ledger_metrics_artifact(tmp_path, monkeypatch):
     assert len(back) == 3
     monkeypatch.setenv("QUIVER_METRICS_JSONL", "")
     assert ledger.append_metrics(_sample_snapshots()) == 0  # disabled
-
-
-# -- profiler bracketing ------------------------------------------------------
-
-
-@pytest.mark.slow  # 15s profiled epoch
-def test_profile_epoch_brackets_and_restores(tmp_path):
-    prev = trace._enabled
-    trace.disable_trace()
-    with profile_epoch(str(tmp_path / "prof")):
-        assert trace.trace_enabled()  # stage scopes annotate the capture
-        jnp.arange(4).block_until_ready()
-    assert not trace.trace_enabled()  # prior state restored
-    trace._enabled = prev
 
 
 # -- acceptance differential --------------------------------------------------
