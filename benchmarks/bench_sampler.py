@@ -152,7 +152,7 @@ def _stage_profile(args, sampler, topo, reps: int = 30):
         )
 
         # same trace-time fallback rules the fused program applies
-        E = int(sampler.topo.indices.shape[0])
+        E = int(sampler.topo.edge_count)
         md = getattr(sampler.topo, "max_degree", None)
         use_pallas = (
             E >= DEFAULT_WINDOW

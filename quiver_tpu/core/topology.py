@@ -14,6 +14,7 @@ reference's UVA zero-copy registration, quiver_sample.cu:400-408).
 
 from __future__ import annotations
 
+import mmap
 import os
 
 import numpy as np
@@ -647,6 +648,31 @@ class CSRTopo:
         )
 
 
+EDGE_BLOCK = 128  # words of the edge array the sampler reads a lane
+
+
+def _whole_blocks(indices) -> np.ndarray:
+    """``indices`` zero-padded on the host to a whole number of blocks, in
+    the dtype the device will hold.
+
+    The padded copy is written into an anonymous mapping populated in one
+    call (its tail is zero as mapped): a fresh half gigabyte touched page
+    by page is seconds of page faults on a virtual machine, and placing a
+    topology compiles nothing, so the padding is not done on the device."""
+    indices = np.asarray(indices)
+    words = indices.shape[0]
+    pad = -words % EDGE_BLOCK
+    if pad == 0:
+        return indices
+    dtype = np.dtype(jax.dtypes.canonicalize_dtype(indices.dtype))
+    buf = mmap.mmap(-1, (words + pad) * dtype.itemsize,
+                    flags=mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS
+                    | getattr(mmap, "MAP_POPULATE", 0))
+    out = np.frombuffer(buf, dtype)
+    out[:words] = indices
+    return out
+
+
 def place_csr_arrays(indptr, indices, eid, cum_weights, max_degree: int,
                      mode: SampleMode | str,
                      edge_time=None) -> "DeviceTopology":
@@ -658,9 +684,18 @@ def place_csr_arrays(indptr, indices, eid, cum_weights, max_degree: int,
     them (``edge_time`` is HBM-only, enforced by the ``to_device`` callers);
     the weighted/temporal binary searches' static iteration bound derives
     from ``max_degree``.
+
+    In HBM mode ``indices`` is placed as whole 128-word blocks: padded with
+    zeros (which no valid lane names) up to a multiple of ``EDGE_BLOCK``
+    words, so that the sampler can read it through its ``(E'/128, 128)``
+    view, 512 bytes a lane (``ops.sample.sample_layer``). The array stays
+    1-D and is held once; ``DeviceTopology.edge_count`` stays the CSR's
+    edge count. HOST-mode arrays are not padded: a staged host gather does
+    not cost by the tile.
     """
     mode = SampleMode.parse(mode)
     indptr = jnp.asarray(indptr)
+    edge_count = int(np.shape(indices)[0])
     host = False
     if mode == SampleMode.HOST:
         indices, host = to_pinned_host(indices)
@@ -672,7 +707,7 @@ def place_csr_arrays(indptr, indices, eid, cum_weights, max_degree: int,
                 else jnp.asarray(cum_weights)
             )
     else:
-        indices = jnp.asarray(indices)
+        indices = jnp.asarray(_whole_blocks(indices))
         if eid is not None:
             eid = jnp.asarray(eid)
         if cum_weights is not None:
@@ -687,7 +722,7 @@ def place_csr_arrays(indptr, indices, eid, cum_weights, max_degree: int,
     return DeviceTopology(indptr=indptr, indices=indices, eid=eid,
                           cum_weights=cum_weights, edge_time=edge_time,
                           host_indices=host, search_iters=iters,
-                          max_degree=int(max_degree))
+                          max_degree=int(max_degree), edge_count=edge_count)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -699,11 +734,20 @@ class DeviceTopology:
     ``max_degree`` is static host metadata (None when unknown, e.g. a
     hand-built topology); the fused Pallas sampler uses it for trace-time
     window-coverage decisions.
+
+    ``indices`` is 1-D. A placement made by ``place_csr_arrays`` in HBM
+    mode holds it zero-padded to a whole number of 128-word blocks, so
+    ``indices.shape[0]`` can exceed the number of edges: ``edge_count``
+    (static metadata, the CSR's ``indptr[-1]``) is what means "edges". A
+    hand-built topology may pass any ``indices``; without ``edge_count``
+    its length is taken, and if that is not a whole number of blocks the
+    sampler reads it a word a lane.
     """
 
     def __init__(self, indptr, indices, eid=None, cum_weights=None,
                  edge_time=None, host_indices: bool = False,
-                 search_iters: int = 0, max_degree: int | None = None):
+                 search_iters: int = 0, max_degree: int | None = None,
+                 edge_count: int | None = None):
         self.indptr = indptr
         self.indices = indices
         self.eid = eid
@@ -712,24 +756,23 @@ class DeviceTopology:
         self.host_indices = host_indices
         self.search_iters = search_iters
         self.max_degree = max_degree
+        self.edge_count = (
+            int(indices.shape[0]) if edge_count is None else int(edge_count)
+        )
 
     @property
     def node_count(self) -> int:
         return self.indptr.shape[0] - 1
 
-    @property
-    def edge_count(self) -> int:
-        return self.indices.shape[0]
-
     def tree_flatten(self):
         children = (self.indptr, self.indices, self.eid, self.cum_weights,
                     self.edge_time)
         return children, (self.host_indices, self.search_iters,
-                          self.max_degree)
+                          self.max_degree, self.edge_count)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         indptr, indices, eid, cum_weights, edge_time = children
         return cls(indptr, indices, eid, cum_weights, edge_time,
                    host_indices=aux[0], search_iters=aux[1],
-                   max_degree=aux[2])
+                   max_degree=aux[2], edge_count=aux[3])

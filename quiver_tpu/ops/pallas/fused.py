@@ -400,7 +400,7 @@ def fused_sample_layer(topo, seeds, num_seeds, k: int, key, *,
     if k > 46340:
         raise ValueError(f"fanout k must be <= 46340, got {k}")
     interpret = resolve_interpret(interpret)
-    E = topo.indices.shape[0]
+    E = topo.edge_count
     if E < window + _LANE:
         raise ValueError(
             f"edge_count {E} < window {window} + {_LANE}; use the XLA path"

@@ -28,6 +28,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..core.topology import EDGE_BLOCK
+
 __all__ = [
     "sample_layer",
     "stratified_offsets",
@@ -192,8 +194,13 @@ def sample_layer(topo, seeds, num_seeds, k: int, key, with_eid: bool = False,
                  weighted: bool = False, time_window=None):
     """Sample up to ``k`` neighbors for each valid seed.
 
+    The neighbour ids at the drawn positions are read as 512-byte blocks
+    of the edge array (``_gather_indices``); the draw itself, and so every
+    returned value, does not depend on how they are read.
+
     Args:
-      topo: DeviceTopology (indptr (N+1,), indices (E,)).
+      topo: DeviceTopology (indptr (N+1,), indices (E,), or (E',) padded
+        to whole 128-word blocks by ``place_csr_arrays``).
       seeds: (S,) node ids, -1 padded; valid entries occupy a prefix.
       num_seeds: scalar count of valid seeds.
       k: static fanout. Must be >= 1 (use max_degree for full neighborhood,
@@ -263,14 +270,14 @@ def sample_layer(topo, seeds, num_seeds, k: int, key, with_eid: bool = False,
     mask = valid[:, None] & mask_sel
 
     epos = base[:, None] + off.astype(base.dtype)
-    safe_epos = jnp.where(mask, epos, 0)
-    nbr = _gather_indices(topo, safe_epos)
+    nbr = _gather_indices(topo, epos, mask)
     nbr = jnp.where(mask, nbr, -1).astype(jnp.int32)
     counts = jnp.where(valid, jnp.minimum(deg, k), 0)
 
     if with_eid:
         eids = jnp.where(mask, epos, -1)
         if topo.eid is not None:
+            safe_epos = jnp.where(mask, epos, 0)
             eids = jnp.where(
                 mask, staged_gather(topo.eid, safe_epos, topo.host_indices), -1
             )
@@ -278,8 +285,56 @@ def sample_layer(topo, seeds, num_seeds, k: int, key, with_eid: bool = False,
     return nbr, counts
 
 
-def _gather_indices(topo, epos):
-    return staged_gather(topo.indices, epos, getattr(topo, "host_indices", False))
+_BLOCK_SHIFT = EDGE_BLOCK.bit_length() - 1  # a block is 128 words, 512 bytes
+
+
+def _gather_indices(topo, epos, mask):
+    """``topo.indices[epos]`` where ``mask``; masked lanes return anything.
+
+    An edge array held as whole 128-word blocks (``place_csr_arrays`` pads
+    it so in HBM mode; decided from the shape, at trace time) is read
+    through its ``(E'/128, 128)`` view, a bitcast: one row gather fetches
+    each lane's block, then the word inside it is selected by comparing the
+    minor axis with the lane's column and summing (one term is non-zero, so
+    the sum is exact). A v5e moves a 512-byte tile row in a third of the
+    time it takes to fetch one word of a 1-D array, and a second 4-byte
+    gather (``take_along_axis``) would give that back. Three things about
+    the row gather are the chip's, each measured (PERF.md section 6,
+    PR 38):
+
+    * lanes are gathered flat and fanout-major, so that the blocks are the
+      gather's own 2-D output: viewed ``(S, k, 128)`` they would be copied
+      into tiles with ``k`` padded to 8;
+    * a masked lane reads a block of its own (its lane number modulo the
+      block count), not block 0: rows that all name one block are served
+      one after the other, 13 ns each against 4;
+    * the lanes are padded to 512 past a multiple of 1,024: XLA's TPU row
+      gather keeps 256 rows in flight when it pads its index vector to
+      whole 1,024-word tiles itself and 128 when the vector already fills
+      them, 4 ns a row against 10 (``tests/test_dense_aggregation.py``
+      holds the compiled gather to that).
+
+    A host-resident array (HOST mode) keeps ``staged_gather`` and a ragged
+    hand-built one the plain gather, a word a lane.
+    """
+    indices = topo.indices
+    if getattr(topo, "host_indices", False):
+        return staged_gather(indices, jnp.where(mask, epos, 0), True)
+    if indices.shape[0] == 0 or indices.shape[0] % EDGE_BLOCK:
+        return indices[jnp.where(mask, epos, 0)]
+    blocks = indices.reshape(-1, EDGE_BLOCK)
+    S, k = epos.shape
+    lanes = S * k
+    pad = (512 - lanes) % 1024
+    pos = jnp.pad(epos.T.reshape(-1), (0, pad))
+    live = jnp.pad(mask.T.reshape(-1), (0, pad))
+    own = jnp.arange(lanes + pad, dtype=pos.dtype) % blocks.shape[0]
+    # (lanes + pad, 128)
+    rows = blocks[jnp.where(live, pos >> _BLOCK_SHIFT, own)]
+    col = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+    hit = col == (pos & (EDGE_BLOCK - 1)).astype(jnp.int32)[:, None]
+    word = jnp.sum(jnp.where(hit, rows, 0), axis=-1)
+    return word[:lanes].reshape(k, S).T
 
 
 def staged_host_call(fn, static_argnums=()):

@@ -143,7 +143,7 @@ def _degree_proportional_nodes(topo, key, budget: int):
     draws (the degree law is undefined), matching the host path's p=None
     fallback; E is a static shape, so the branch resolves at trace time.
     """
-    E = topo.indices.shape[0]
+    E = topo.edge_count
     if E == 0:
         n = topo.indptr.shape[0] - 1
         src = jax.random.randint(key, (budget,), 0, max(n, 1), dtype=jnp.int32)
@@ -158,7 +158,7 @@ def _degree_proportional_nodes(topo, key, budget: int):
 
 def _uniform_edge_endpoints(topo, key, budget: int):
     """Device-side uniform edge draw -> dedup'd endpoint set (cap 2*budget)."""
-    E = topo.indices.shape[0]
+    E = topo.edge_count
     eids = _uniform_edge_positions(key, budget, E, topo.indptr.dtype)
     dst = staged_gather(topo.indices, eids, topo.host_indices).astype(jnp.int32)
     src = (

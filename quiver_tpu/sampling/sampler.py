@@ -120,7 +120,7 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
         # trace-time eligibility for the fused kernel; every degrade is a
         # one-shot INFO (same info_once discipline as the other silent
         # fallback paths) and lands on the bitwise-identical XLA oracle
-        E = int(topo.indices.shape[0])
+        E = topo.edge_count
         md = getattr(topo, "max_degree", None)
         if getattr(topo, "host_indices", False):
             info_once(

@@ -270,6 +270,61 @@ def test_a_v5e_gets_the_fanout_major_rows_without_a_copy(
     assert len(relayouts(lane_order)) >= 2
 
 
+@pytest.mark.parametrize("nodes,edges,targets,fanout", [
+    (232_965, 114_615_892, 30_208, 10),    # reddit-sage, hop 1
+    (2_449_029, 123_718_280, 142_080, 5),  # products-sage, hop 2
+])
+def test_a_v5e_reads_the_neighbour_ids_as_rows_256_in_flight(
+        one_chip, nodes, edges, targets, fanout):
+    """``sample_layer`` at a cell's widest hop, compiled for a described
+    v5e: the edge array's 2-D view is a bitcast of the argument, the one
+    gather from it is a row gather that keeps 256 rows in flight, and the
+    hop's temporaries are the gathered blocks once, not a padded copy of
+    them. ``ops/sample.py::_gather_indices`` pads its lanes to 512 past a
+    multiple of 1,024 for that: the same row gather over exactly
+    ``targets * fanout`` lanes (reddit's fill whole 1,024-word tiles)
+    keeps 128 in flight, 10 ns a row against 4 on the chip (PERF.md
+    section 6, PR 38), which also shows that the check can see it.
+    Nothing runs; this says nothing of results or times."""
+    import re
+
+    from quiver_tpu.core.topology import DeviceTopology
+    from quiver_tpu.ops.sample import sample_layer
+
+    def shape(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    words = -(-edges // 128) * 128
+    topo = DeviceTopology(shape((nodes + 1,)), shape((words,)),
+                          edge_count=edges)
+    compiled = jax.jit(
+        lambda t, s, n, key: sample_layer(t, s, n, fanout, key)
+    ).lower(topo, shape((targets,)), shape(()), shape((2,), jnp.uint32)
+            ).compile()
+
+    def row_gathers(text):
+        """rows in flight of every gather fusion over the 2-D view"""
+        return [int(re.search(r'"integer":"(\d+)"', line).group(1))
+                for line in text.splitlines()
+                if "kind=kCustom" in line and "/gather" in line
+                and re.search(r"= s32\[\d+,128\]", line)]
+
+    text = compiled.as_text()
+    assert f"s32[{words // 128},128]" in text
+    assert not re.search(
+        rf"= s32\[{words}\]\S* (copy|pad|slice|concatenate)\(", text)
+    assert not re.search(
+        rf"= s32\[{words // 128},128\]\S* (copy|pad|slice|reshape)\(", text)
+    assert row_gathers(text) == [256]
+    lanes = targets * fanout
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * lanes * 512
+
+    plain = jax.jit(lambda blocks, blk: blocks[blk]).lower(
+        shape((words // 128, 128)), shape((lanes,))).compile()
+    expected = 128 if lanes % 1024 == 0 else 256
+    assert row_gathers(plain.as_text()) == [expected]
+
+
 def test_fanout_softmax_matches_segment_softmax():
     """The dense softmax over each target's ``fanout`` lanes and its self
     term against the segment softmax over the same lanes with one self

@@ -147,3 +147,189 @@ def test_duplicate_seeds_exceeding_node_count_keep_capacity():
     assert nid.shape[0] >= 50
     assert (nid[:50] == 0).all()
     assert int(out.overflow) == 0
+
+
+# -- the edge array read as 128-word blocks (PR 38) --------------------------
+# A placement pads `indices` to whole blocks and `sample_layer` reads a lane's
+# id as a row of the (E'/128, 128) view plus a select inside the row. The draw
+# is untouched, so against a hand-built topology over the ragged array (which
+# keeps the plain `indices[p]`) every output is equal bit for bit.
+
+_HUB = 128 * 25 + 77  # more blocks than any fanout here has lanes
+
+
+def _blocky_graph():
+    """Rows of degree 0 and 1, one that straddles a block boundary, a hub
+    of `_HUB` edges, a tail of small rows; E is not a multiple of 128 and
+    the last row's edges lie in the padded last block."""
+    rng = np.random.default_rng(38)
+    deg = np.concatenate([[0, 1, 120, 130, _HUB, 0],
+                          rng.integers(0, 40, 200), [3]]).astype(np.int64)
+    indptr = np.zeros(deg.shape[0] + 1, np.int64)
+    np.cumsum(deg, out=indptr[1:])
+    E = int(indptr[-1])
+    assert E % 128 not in (0, 1, 2), E
+    assert indptr[3] // 128 != (indptr[4] - 1) // 128  # row 3 straddles
+    indices = rng.integers(0, deg.shape[0], E).astype(np.int32)
+    return CSRTopo(indptr=indptr, indices=indices)
+
+
+def _ragged(topo, with_eid=False):
+    """Today's path: a hand-built topology over the unpadded array."""
+    from quiver_tpu.core.topology import DeviceTopology
+
+    return DeviceTopology(
+        jnp.asarray(topo.indptr), jnp.asarray(topo.indices),
+        eid=jnp.asarray(topo.eid) if with_eid and topo.eid is not None
+        else None)
+
+
+def _seed_block(topo, S=64, num=50):
+    seeds = np.full(S, -1, np.int32)
+    # the special rows first, then random ones, a padded -1 inside the
+    # valid prefix, and `num < S`
+    head = [0, 1, 2, 3, 4, 5, topo.node_count - 1, 4]
+    seeds[:num] = np.random.default_rng(1).integers(0, topo.node_count, num)
+    seeds[: len(head)] = head
+    seeds[20] = -1
+    return jnp.asarray(seeds), jnp.int32(num)
+
+
+def _run(mode, fn, *args):
+    if mode == "eager":
+        return fn(*args)
+    if mode == "jit":
+        return jax.jit(fn)(*args)
+    from jax.sharding import PartitionSpec as P
+
+    from quiver_tpu.parallel.mesh import make_mesh, shard_map
+
+    mesh = make_mesh(n_devices=2, data=2, feature=1)
+    return jax.jit(shard_map(
+        fn, mesh=mesh, in_specs=tuple(P() for _ in args), out_specs=P(),
+        check_vma=False))(*args)
+
+
+def test_placement_pads_the_edge_array_to_whole_blocks():
+    topo = _blocky_graph()
+    dev = topo.to_device()
+    assert dev.indices.ndim == 1 and dev.indices.shape[0] % 128 == 0
+    assert 0 < dev.indices.shape[0] - topo.edge_count < 128
+    assert dev.edge_count == topo.edge_count
+    got = np.asarray(dev.indices)
+    np.testing.assert_array_equal(got[: topo.edge_count], topo.indices)
+    assert not got[topo.edge_count:].any()
+
+
+@pytest.mark.parametrize("words,dtype", [
+    (5003, np.int32), (5003, np.int64), (1, np.int32), (127, np.int64),
+    (1280, np.int32)])
+def test_the_padded_host_copy_is_whole_blocks_in_the_device_dtype(
+        words, dtype):
+    """The host-side padding behind a placement: zeros after the edges,
+    the dtype the device will hold (no second conversion copy), and the
+    array itself, untouched, where it needs no padding."""
+    from quiver_tpu.core.topology import _whole_blocks
+
+    indices = np.random.default_rng(words).integers(
+        0, 1 << 30, words).astype(dtype)
+    got = _whole_blocks(indices)
+    if words % 128 == 0:
+        assert got is indices
+        return
+    assert got.shape[0] == -(-words // 128) * 128
+    assert got.dtype == jnp.asarray(indices[:1]).dtype
+    np.testing.assert_array_equal(got[:words], indices)
+    assert not got[words:].any()
+    np.testing.assert_array_equal(np.asarray(jnp.asarray(got)), got)
+
+
+@pytest.mark.parametrize("mode", ["eager", "jit", "shard_map"])
+@pytest.mark.parametrize("k", [5, 10, 15, 25])
+def test_block_read_equals_plain_gather_bitwise(k, mode):
+    topo = _blocky_graph()
+    seeds, num = _seed_block(topo)
+    key = jax.random.PRNGKey(k)
+
+    def draw(dev, seeds, num, key):
+        return sample_layer(dev, seeds, num, k, key)
+
+    nbr, cnt = _run(mode, draw, topo.to_device(), seeds, num, key)
+    want_nbr, want_cnt = draw(_ragged(topo), seeds, num, key)
+    np.testing.assert_array_equal(np.asarray(nbr), np.asarray(want_nbr))
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(want_cnt))
+    nbr = np.asarray(nbr)
+    # the hub takes k distinct positions over more than k blocks; rows of
+    # degree 0 and 1, the padded seed and the seeds past `num` as ever
+    assert (nbr[4] >= 0).all() and (nbr[0] == -1).all()
+    assert nbr[1, 0] == topo.indices[0] and (nbr[1, 1:] == -1).all()
+    assert (nbr[20] == -1).all() and (nbr[int(num):] == -1).all()
+    # the last row's three edges sit in the padded last block
+    np.testing.assert_array_equal(nbr[6, :3], topo.indices[-3:])
+
+
+@pytest.mark.parametrize("mode", ["eager", "jit"])
+def test_block_read_with_eid_equals_plain_gather_bitwise(mode):
+    rng = np.random.default_rng(2)
+    ei = generate_pareto_graph(300, 9.0, seed=4)
+    ei = ei[:, rng.permutation(ei.shape[1])]  # eid is a real permutation
+    topo = CSRTopo(edge_index=ei)
+    assert topo.edge_count % 128
+    seeds, num = _seed_block(topo)
+    key = jax.random.PRNGKey(9)
+
+    def draw(dev, seeds, num, key):
+        return sample_layer(dev, seeds, num, 10, key, with_eid=True)
+
+    got = _run(mode, draw, topo.to_device(with_eid=True), seeds, num, key)
+    want = draw(_ragged(topo, with_eid=True), seeds, num, key)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    nbr, _, eids = (np.asarray(x) for x in got)
+    ok = nbr >= 0
+    np.testing.assert_array_equal(ei[1][eids[ok]], nbr[ok])
+
+
+@pytest.mark.parametrize("edges", [127, 128, 129, 256, 1000])
+def test_gather_indices_reads_every_position(edges):
+    """`_gather_indices` against numpy's `indices[p]` at every position of
+    the array, masked lanes anywhere (out of range too): whole blocks or a
+    ragged hand-built array alike."""
+    from quiver_tpu.core.topology import DeviceTopology, place_csr_arrays
+    from quiver_tpu.ops.sample import _gather_indices
+
+    rng = np.random.default_rng(edges)
+    indices = rng.integers(0, 1 << 30, edges).astype(np.int32)
+    indptr = np.asarray([0, edges], np.int64)
+    placed = place_csr_arrays(indptr, indices, None, None, edges, "HBM")
+    ragged = DeviceTopology(jnp.asarray(indptr), jnp.asarray(indices))
+    assert placed.indices.shape[0] == -(-edges // 128) * 128
+    assert ragged.indices.shape[0] == edges and ragged.edge_count == edges
+    pos = np.concatenate([np.arange(edges), rng.integers(0, edges, 24)])
+    pos = pos[: pos.shape[0] // 8 * 8].reshape(-1, 8)
+    mask = rng.random(pos.shape) < 0.8
+    wild = np.where(mask, pos, rng.integers(-5, edges + 500, pos.shape))
+    for dev in (placed, ragged):
+        got = jax.jit(_gather_indices)(dev, jnp.asarray(wild, jnp.int32),
+                                       jnp.asarray(mask))
+        np.testing.assert_array_equal(np.asarray(got)[mask],
+                                      indices[pos][mask])
+
+
+def test_block_read_takes_rows_not_words():
+    """The mechanism, in the jaxpr: on a padded placement the only gather
+    from the edge array takes (1, 128) slices of its 2-D view; on a ragged
+    array it is the one-word gather it was."""
+    topo = _blocky_graph()
+    seeds, num = _seed_block(topo)
+
+    def gathers(dev):
+        jaxpr = jax.make_jaxpr(
+            lambda d: sample_layer(d, seeds, num, 5, jax.random.PRNGKey(0))
+        )(dev)
+        return [tuple(e.params["slice_sizes"]) for e in jaxpr.jaxpr.eqns
+                if e.primitive.name == "gather"
+                and e.invars[0].aval.size >= topo.edge_count]
+
+    assert gathers(topo.to_device()) == [(1, 128)]
+    assert gathers(_ragged(topo)) == [(1,)]

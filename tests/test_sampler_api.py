@@ -96,6 +96,53 @@ def test_multilayer_frontier_growth_and_reuse():
     assert len(np.unique(vals)) == len(vals)
 
 
+@pytest.mark.parametrize("mode,extras", [
+    ("HBM", {}), ("HBM", {"with_eid": True}), ("HBM", {"with_weights": True}),
+    ("HBM", {"with_times": True}), ("HOST", {}), ("HOST", {"with_eid": True}),
+])
+def test_edge_count_is_the_csrs_on_a_padded_placement(mode, extras):
+    """A placement may hold `indices` padded to whole 128-word blocks;
+    `edge_count` stays the CSR's, through a pytree round trip and as a
+    static value inside jit."""
+    ei = generate_pareto_graph(300, 9.0, seed=3)
+    topo = CSRTopo(edge_index=ei)
+    assert topo.edge_count % 128
+    if extras.get("with_weights"):
+        topo.set_edge_weight(np.ones(topo.edge_count))
+    if extras.get("with_times"):
+        topo.set_edge_time(np.arange(topo.edge_count, dtype=np.float64))
+    dev = topo.to_device(mode, **extras)
+    assert dev.edge_count == topo.edge_count
+    assert dev.indices.shape[0] >= topo.edge_count
+    if mode == "HBM":
+        assert dev.indices.shape[0] == -(-topo.edge_count // 128) * 128
+    for name in ("eid", "cum_weights", "edge_time"):
+        arr = getattr(dev, name)
+        assert arr is None or arr.shape[0] == topo.edge_count  # not padded
+    leaves, treedef = jax.tree_util.tree_flatten(dev)
+    back = treedef.unflatten(leaves)
+    assert back.edge_count == topo.edge_count
+    assert back.max_degree == dev.max_degree
+    assert jax.jit(lambda d: d.edge_count + 0 * d.indptr[0])(dev) \
+        == topo.edge_count
+
+
+def test_saint_edge_draw_never_names_a_padded_word():
+    """The uniform edge draw ranges over the CSR's edges, not over the
+    padded array: node 0 has no edge at either end here, and the padding
+    words are zeros, so a draw from the padding would name it."""
+    from quiver_tpu.sampling.saint import _uniform_edge_endpoints
+
+    rng = np.random.default_rng(0)
+    ei = rng.integers(1, 40, (2, 130))
+    topo = CSRTopo(edge_index=ei)
+    dev = topo.to_device()
+    assert dev.indices.shape[0] == 256 and dev.edge_count == 130
+    nodes, num = _uniform_edge_endpoints(dev, jax.random.PRNGKey(0), 4096)
+    got = np.asarray(nodes)[: int(num)]
+    assert got.size and 0 not in got
+
+
 def test_share_ipc_roundtrip():
     topo, sampler = _sampler()
     rebuilt = GraphSageSampler.lazy_from_ipc_handle(sampler.share_ipc())

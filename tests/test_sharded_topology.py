@@ -87,6 +87,21 @@ def test_partition_plan_covers_csr_and_shrinks_bytes():
     assert plan["shrink_factor"] >= F / 2
 
 
+@pytest.mark.parametrize("F", [2, 8])
+def test_edge_count_agrees_with_the_padded_replicated_placement(F):
+    """The replicated placement pads its edge array to whole 128-word
+    blocks; both placements still say the CSR's edge count, and the shards
+    hold exactly the CSR's edges."""
+    topo = _graph(n=500)
+    assert topo.edge_count % 128
+    mesh = make_mesh(n_devices=F, data=1, feature=F)
+    st = ShardedTopology(mesh, topo)
+    dev = topo.to_device()
+    assert dev.indices.shape[0] > topo.edge_count
+    assert st.edge_count == dev.edge_count == topo.edge_count
+    assert sum(st.plan["shard_edges"]) == dev.edge_count
+
+
 def test_routed_sample_cap_schedule():
     assert routed_sample_cap(128, 8, 2.0) == 32  # ceil(2*128/8)
     assert routed_sample_cap(128, 8, None) is None  # uncapped

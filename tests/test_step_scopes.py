@@ -149,14 +149,18 @@ def build(fanout, caps, data=1, feature=1, cell="products-sage.hbm",
     return trainer, params, opt_state, labels
 
 
-def step_instructions(trainer, params, opt_state, labels):
-    """(opcode, scope path below ``jit(body)/[shard_map/]``) of every
-    instruction of the compiled step that carries a ``jit(...)`` name."""
+def step_text(trainer, params, opt_state, labels) -> str:
+    """The compiled step, as text."""
     seeds = jnp.asarray(trainer.shard_seeds(np.arange(trainer.global_batch)))
-    text = trainer._step.lower(
+    return trainer._step.lower(
         params, opt_state, trainer.topo, trainer._feature_parts(), seeds,
         labels, jax.random.PRNGKey(1), np.asarray(False),
     ).compile().as_text()
+
+
+def instructions_of(text):
+    """(opcode, scope path below ``jit(body)/[shard_map/]``) of every
+    instruction of the compiled step that carries a ``jit(...)`` name."""
     out = []
     for line in text.splitlines():
         inst, name = _INSTRUCTION.match(line), _OP_NAME.search(line)
@@ -166,12 +170,25 @@ def step_instructions(trainer, params, opt_state, labels):
     return out
 
 
+def step_instructions(trainer, params, opt_state, labels):
+    return instructions_of(step_text(trainer, params, opt_state, labels))
+
+
 @pytest.fixture(scope="module")
-def programs():
-    """The compiled step of each cell's tiny stand-in, lowered once."""
+def texts():
+    """The compiled step of each cell's tiny stand-in, lowered once, with
+    the length of the edge array as the step holds it."""
     trace.disable_trace()
-    return {cell: step_instructions(*build(**shape, cell=cell))
-            for cell, shape in CELLS.items()}
+    out = {}
+    for cell, shape in CELLS.items():
+        built = build(**shape, cell=cell)
+        out[cell] = (step_text(*built), int(built[0].topo.indices.shape[0]))
+    return out
+
+
+@pytest.fixture(scope="module")
+def programs(texts):
+    return {cell: instructions_of(text) for cell, (text, _) in texts.items()}
 
 
 def paths_of(instructions):
@@ -252,6 +269,51 @@ def test_every_instruction_lies_under_one_top_level_scope(programs, cell):
                     and not PARTITIONER_CONSTANT.match(path)
                     and not TOP_LEVEL.match(path.split("/")[0])})
     assert not stray, stray[:20]
+
+
+_DEFINITION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\w+\[[\d,]*\])\S* ([a-z][a-z0-9\-]*)\((.*)$")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_SLICE_SIZES = re.compile(r"slice_sizes=\{([\d,]*)\}")
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_the_sampler_reads_the_edge_array_as_rows_of_128_words(texts, cell):
+    """The edge array is placed as whole 128-word blocks and every hop
+    reads it through its ``(E'/128, 128)`` view: each gather from it takes
+    ``(1, 128)`` slices (none takes one word from an operand of E words),
+    nothing in the step copies, pads or slices an array of its size (the
+    view is a bitcast of the step's argument), and every op that reads it
+    lies under ``sample_layer_{l}``, where ``sample_device_ms`` reads."""
+    text, words = texts[cell]
+    assert words % 128 == 0
+    whole = {f"s32[{words}]", f"s32[{words // 128},128]"}
+    shape_of, reads = {}, []
+    for line in text.splitlines():
+        m = _DEFINITION.match(line)
+        if not m:
+            continue
+        name, shape, op, rest = m.groups()
+        shape_of[name] = shape
+        reads.append((op, [o for o in _OPERAND.findall(rest.split(
+            ", metadata=")[0].split(", calls=")[0])], line))
+        # the array itself, its view, and nothing made from it at its size
+        assert shape not in whole or op in ("parameter", "bitcast"), line[:200]
+    hops = set()
+    for op, operands, line in reads:
+        if not any(shape_of.get(o) in whole for o in operands):
+            continue
+        if op in ("bitcast", "tuple", "get-tuple-element"):
+            continue
+        name = _OP_NAME.search(line)
+        path = _PREFIX.sub("", name.group(1)) if name else ""
+        hop = re.match(r"^sample_layer_(\d+)/", path)
+        assert hop, line[:300]
+        if op == "gather":
+            assert shape_of[operands[0]] == f"s32[{words // 128},128]"
+            assert _SLICE_SIZES.search(line).group(1) == "1,128", line[:300]
+            hops.add(int(hop.group(1)))
+    assert hops == set(range(len(CELLS[cell]["fanout"])))
 
 
 @pytest.mark.parametrize("metric", list(NEW_METRICS))
