@@ -68,17 +68,22 @@ def numbers(model, obs_losses, obs_grads, obs_params, ref_losses, ref_grads,
 
 
 def compare(cfg: dict, data, weights0, obs: Observed, seed: int) -> dict:
-    """Check ``obs.blocks`` against the graph, run the reference over them
-    and return the compared numbers."""
+    """Check ``obs.blocks`` against the graph (and, where the graph's file
+    has a ``lane_faults``, what their lanes carry against what its edges
+    do), run the reference over them and return the compared numbers."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng([int(seed), 5])
+    lane_faults = getattr(spec.load_graph(cfg["graph"]["generator"]),
+                          "lane_faults", None)
     faults = {}
     for step_blocks, step_seeds in zip(obs.blocks, obs.seeds):
         for block, seeds in zip(step_blocks, step_seeds):
-            for k, v in graph.block_faults(
-                    data.indptr, data.indices, seeds, block, cfg["fanout"],
-                    rng).items():
+            found = graph.block_faults(
+                data.indptr, data.indices, seeds, block, cfg["fanout"], rng)
+            if lane_faults is not None:
+                found.update(lane_faults(data, seeds, block))
+            for k, v in found.items():
                 faults[k] = faults.get(k, 0) + v
     features = jnp.asarray(data.features)
     labels = jnp.asarray(data.labels)

@@ -1,159 +1,44 @@
-"""Graph, features, labels, weights and the step feed, all from ``--seed``.
+"""What a run is fed, all from ``--seed``: the graph with its rows and labels
+(drawn by the graph file the configuration names, ``graphs/<name>.py``), the
+weights (drawn by the plain side of its model) and the step feed.
 
 The seed decides values and never a shape: ``nodes``, ``edges`` and the
 largest degree come from the configuration's file, so every seed drives the
-programs that the checkout's first run compiled. Everything is made on the
-host by one ``numpy.random.Generator`` in one thread: no BLAS, no sort over
-the edges, so the stage takes the same time in every run.
+programs that the checkout's first run compiled.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import mmap
 
 import numpy as np
 
 from . import spec
 
-_CHUNK = 1 << 23  # edges drawn at a time into a buffer that is reused
-
-__all__ = ["Inputs", "degree_sequence", "draw_endpoints", "make_inputs",
-           "make_weights", "Feed"]
+__all__ = ["Inputs", "make_inputs", "make_weights", "Feed"]
 
 
 @dataclasses.dataclass
 class Inputs:
+    """What a graph file makes. Node ids are flat: typed nodes are
+    contiguous id ranges of one CSR."""
+
     indptr: np.ndarray    # (nodes + 1,) int64
     indices: np.ndarray   # (edges,) int32
     features: np.ndarray  # (nodes, feature_dim), the configuration's dtype
     labels: np.ndarray    # (nodes,) int32
-
-
-def allocate(shape, dtype) -> np.ndarray:
-    """A host array whose pages are mapped before it is written.
-
-    A fresh gigabyte touched page by page costs seconds of page faults on a
-    virtual machine, and a different number of them from run to run;
-    ``MAP_POPULATE`` maps the whole array in one call."""
-    dtype = np.dtype(dtype)
-    size = int(np.prod(shape)) * dtype.itemsize
-    buf = mmap.mmap(-1, max(size, 1), flags=mmap.MAP_PRIVATE
-                    | mmap.MAP_ANONYMOUS | getattr(mmap, "MAP_POPULATE", 0))
-    return np.frombuffer(buf, dtype=dtype, count=int(np.prod(shape))).reshape(shape)
-
-
-def degree_sequence(rng, nodes: int, edges: int, alpha: float,
-                    max_degree: int) -> np.ndarray:
-    """Truncated power-law degrees that sum to ``edges`` exactly and whose
-    largest is ``max_degree`` exactly.
-
-    The draw is the one ``generate_pareto_graph`` makes (numpy's ``pareto``,
-    a Lomax tail of index ``alpha``, every node at least one edge); the
-    scale is then found by bisection so that the truncated, floored degrees
-    reach the published edge count, and the remainder of a few edges goes
-    one each to the first nodes that have room.
-    """
-    if not nodes <= edges <= nodes * max_degree:
-        raise ValueError(
-            f"{edges} edges cannot be spread over {nodes} nodes with degrees "
-            f"in [1, {max_degree}]"
-        )
-    tail = rng.pareto(alpha, nodes).astype(np.float32)
-    top = np.float32(max_degree - 1)
-
-    def degrees(scale):
-        return np.minimum(tail * np.float32(scale), top).astype(np.int32) + 1
-
-    def total(scale):
-        return int(degrees(scale).sum(dtype=np.int64))
-
-    lo, hi = 0.0, float(max_degree)
-    while total(hi) < edges:
-        hi *= 2.0
-    for _ in range(40):
-        mid = 0.5 * (lo + hi)
-        if total(mid) <= edges:
-            lo = mid
-        else:
-            hi = mid
-    deg = degrees(lo).astype(np.int64)
-    deg[np.argmax(tail)] = max_degree
-    rest = edges - int(deg.sum())
-    if rest > 0:
-        room = np.flatnonzero(deg < max_degree)[:rest]
-        deg[room] += 1
-    elif rest < 0:
-        room = np.flatnonzero((deg > 1) & (deg < max_degree))[:-rest]
-        deg[room] -= 1
-    if int(deg.sum()) != edges or int(deg.max()) != max_degree:
-        raise ValueError("degree sequence did not reach the configured sizes")
-    return deg
-
-
-def draw_endpoints(rng, law: str, indptr: np.ndarray,
-                   indices: np.ndarray) -> None:
-    """Fill ``indices`` with every edge's endpoint, by the configuration's
-    ``graph.endpoints``.
-
-    ``"uniform"``: any node, with equal chance. A node is then reached
-    with chance 1/N whatever its degree, so ordering rows by degree orders
-    them by nothing the traffic follows, and a cache of any share of the
-    rows hits that share.
-
-    ``"degree"``: node ``v`` with chance ``deg(v) / edges``, the owner of a
-    uniformly drawn edge slot, ``deg`` being the out-degrees just drawn.
-    In-degree then follows out-degree as on a symmetrised graph, and hubs
-    are sampled in proportion to their degree. The owners of all slots are
-    one more ``int32[edges]`` on the host while the edges are drawn (472
-    MiB at ogbn-products' 123.7 M edges), and the draw is a random read of
-    that table for every edge: there the inputs stage takes 6.7 s against
-    3.0 s under ``"uniform"`` on the chip's host (PERF.md section 6, PR 27).
-    """
-    nodes, edges = indptr.shape[0] - 1, indices.shape[0]
-    if law == "uniform":
-        high, owner = nodes, None
-    elif law == "degree":
-        # every node has an edge, so its first slot is its own: mark the
-        # first slots of nodes 1.. and sum
-        high, owner = edges, np.zeros(edges, np.int32)
-        owner[indptr[1:-1]] = 1
-        np.cumsum(owner, dtype=np.int32, out=owner)
-    else:
-        raise ValueError(
-            f"`graph.endpoints` is {law!r}: \"uniform\" or \"degree\"")
-    for lo in range(0, edges, _CHUNK):
-        hi = min(lo + _CHUNK, edges)
-        drawn = rng.integers(0, high, size=hi - lo, dtype=np.int32)
-        indices[lo:hi] = drawn if owner is None else owner[drawn]
+    # the ids a batch may be drawn from (the labelled nodes of one type,
+    # say); None: every node
+    seed_nodes: np.ndarray | None = None
+    # what an edge carries beside its endpoint: name -> array aligned with
+    # `indices` (an int8 relation, say)
+    edge_data: dict = dataclasses.field(default_factory=dict)
 
 
 def make_inputs(cfg: dict, seed: int) -> Inputs:
-    """The configuration's graph as a CSR, its feature table, and labels
-    that the model can learn, so that the loss falls."""
-    g = cfg["graph"]
-    nodes, edges = int(g["nodes"]), int(g["edges"])
-    rng = np.random.default_rng([int(seed), 1])
-    deg = degree_sequence(rng, nodes, edges, float(g["degree_alpha"]),
-                          int(g["max_degree"]))
-    indptr = np.zeros(nodes + 1, np.int64)
-    np.cumsum(deg, out=indptr[1:])
-    indices = allocate((edges,), np.int32)
-    draw_endpoints(rng, g["endpoints"], indptr, indices)
-    if np.dtype(cfg["feature_dtype"]) != np.float32:
-        raise ValueError("the generator makes float32 features")
-    classes, width = int(cfg["classes"]), int(cfg["feature_dim"])
-    # unit-variance uniform features, and on each node's label column a
-    # bump that a model can learn from the node's own row; where there are
-    # more classes than columns, classes `width` apart share a column
-    feat = allocate((nodes, width), np.float32)
-    rng.random(out=feat, dtype=np.float32)
-    feat -= np.float32(0.5)
-    feat *= np.float32(12 ** 0.5)
-    labels = rng.integers(0, classes, size=nodes, dtype=np.int32)
-    column = labels if classes <= width else labels % width
-    feat[np.arange(nodes), column] += np.float32(3.0)
-    return Inputs(indptr, indices, feat, labels)
+    """The configuration's graph, feature table and labels, drawn from the
+    seed by the graph file that its ``graph.generator`` names."""
+    return spec.load_graph(cfg["graph"]["generator"]).make(cfg, seed)
 
 
 def make_weights(cfg: dict, seed: int) -> list[dict]:
@@ -167,21 +52,30 @@ def make_weights(cfg: dict, seed: int) -> list[dict]:
 
 
 class Feed:
-    """Step ``i``'s seed nodes and PRNG key: a permutation of the nodes cut
-    into global batches (an epoch that wraps), and raw ``uint32[2]`` keys.
-    Every step's rows differ from the step before."""
+    """Step ``i``'s seed nodes and PRNG key: a permutation of the nodes that
+    may be seeds, cut into global batches (an epoch that wraps), and raw
+    ``uint32[2]`` keys. Every step's rows differ from the step before."""
 
     KEYS = 1 << 14
 
-    def __init__(self, nodes: int, global_batch: int, seed: int):
+    def __init__(self, nodes: int, global_batch: int, seed: int,
+                 seed_nodes: np.ndarray | None = None):
         rng = np.random.default_rng([int(seed), 3])
-        self.order = rng.permutation(nodes).astype(np.int32)
+        self.order = rng.permutation(
+            nodes if seed_nodes is None else np.asarray(seed_nodes)
+        ).astype(np.int32)
         self.global_batch = int(global_batch)
-        if self.global_batch > nodes:
-            raise ValueError("a global batch larger than the graph")
-        self.steps_per_epoch = nodes // self.global_batch
+        if self.global_batch > self.order.shape[0]:
+            raise ValueError("a global batch larger than the graph's seeds")
+        self.steps_per_epoch = self.order.shape[0] // self.global_batch
         self.keys = rng.integers(0, 1 << 32, size=(self.KEYS, 2),
                                  dtype=np.uint32)
+
+    @classmethod
+    def of(cls, data: Inputs, global_batch: int, seed: int) -> "Feed":
+        """The feed over ``data``: its ``seed_nodes``, or every node."""
+        return cls(data.indptr.shape[0] - 1, global_batch, seed,
+                   data.seed_nodes)
 
     def seeds(self, i: int) -> np.ndarray:
         j = i % self.steps_per_epoch

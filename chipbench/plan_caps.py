@@ -58,7 +58,7 @@ def main() -> None:
     worst = [0] * len(cfg["fanout"])
     for seed in range(args.seeds):
         data = inputs.make_inputs(cfg, seed)
-        feed = inputs.Feed(cfg["graph"]["nodes"], cfg["batch"], seed)
+        feed = inputs.Feed.of(data, cfg["batch"], seed)
         rng = np.random.default_rng([seed, 4])
         for b in range(args.batches):
             got = frontier_sizes(data.indptr, data.indices, feed.seeds(b),

@@ -50,8 +50,7 @@ def main() -> int:
         data = inputs.make_inputs(cfg, seed)
         weights0 = inputs.make_weights(cfg, seed)
         program = Program(cfg, one_chip, data, weights0, jax.devices()[:1])
-        feed = inputs.Feed(cfg["graph"]["nodes"],
-                           workers * int(cfg["batch"]), seed)
+        feed = inputs.Feed.of(data, workers * int(cfg["batch"]), seed)
         blocks = [program.blocks(feed.seeds(i), feed.key(i), workers)
                   for i in range(int(traffic["followed_steps"]))]
         program.close()

@@ -22,12 +22,18 @@ class Block:
     """One sampled mini-batch of one worker: ``n_id`` maps local ids to
     nodes (-1 pads), seeds first; ``layers`` holds, input layer first,
     ``(src, dst, n_dst)``: an edge list in local ids (``src`` -1 on a lane
-    that holds no edge) and how many local ids are targets."""
+    that holds no edge) and how many local ids are targets. ``lane_data``
+    holds what the lanes carry from the sampler to the model (a relation,
+    a weight, a timestamp): per layer, in ``layers``' order, a dict of
+    arrays aligned with that layer's ``src``; empty where lanes carry
+    nothing. The graph's file checks it against the graph
+    (``lane_faults``), a model's plain side reads it."""
 
     n_id: np.ndarray
     layers: list
     num_seeds: int
     overflow: int = 0  # lanes the sampler clipped at a frontier cap
+    lane_data: list = dataclasses.field(default_factory=list)
 
 
 def block_faults(indptr, indices, seeds, block, fanout, rng) -> dict:

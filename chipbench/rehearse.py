@@ -25,23 +25,6 @@ import numpy as np  # noqa: E402
 from . import inputs, spec  # noqa: E402
 
 
-def described(cfg: dict) -> inputs.Inputs:
-    """Inputs of the configured shapes with no values: the degree sequence
-    is flat but for one row of ``max_degree``, which the topology records."""
-    g = cfg["graph"]
-    nodes, edges = int(g["nodes"]), int(g["edges"])
-    deg = np.full(nodes, (edges - int(g["max_degree"])) // (nodes - 1),
-                  np.int64)
-    deg[0] = int(g["max_degree"])
-    deg[1] += edges - int(deg.sum())
-    indptr = np.zeros(nodes + 1, np.int64)
-    np.cumsum(deg, out=indptr[1:])
-    return inputs.Inputs(
-        indptr, np.zeros(edges, np.int32),
-        np.zeros((nodes, int(cfg["feature_dim"])), cfg["feature_dtype"]),
-        np.zeros(nodes, np.int32))
-
-
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -71,10 +54,11 @@ def main() -> None:
 
     jax.device_put = put
     try:
-        program = Program(cfg, traffic, described(cfg),
-                          inputs.make_weights(cfg, 0), devices)
+        data = spec.load_graph(cfg["graph"]["generator"]).describe(cfg)
+        program = Program(cfg, traffic, data, inputs.make_weights(cfg, 0),
+                          devices)
         trainer = program.trainer
-        feed = inputs.Feed(cfg["graph"]["nodes"], program.global_batch, 0)
+        feed = inputs.Feed.of(data, program.global_batch, 0)
         captured = {}
         real_step = trainer._step
 

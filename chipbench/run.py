@@ -227,7 +227,7 @@ def run(args, devices, compiled: CompileMeter, setup_start: float,
 
     t = time.perf_counter()
     program = Program(cfg, traffic, data, weights0, devices)
-    feed = inputs.Feed(cfg["graph"]["nodes"], program.global_batch, args.seed)
+    feed = inputs.Feed.of(data, program.global_batch, args.seed)
     stages["place"] = time.perf_counter() - t
 
     # the warm-up steps go through the window's own call and feed; the

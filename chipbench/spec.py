@@ -1,10 +1,11 @@
 """The benchmark's data files, found by the names in ``BENCHMARK.json``.
 
 A cell names a configuration and a traffic mix; a per-layer metric names
-itself; a configuration names its model. Each is one file under this
-directory (a model two: the program's side and the plain reference), so a
-later PR adds a cell, a mix, a metric or a model by adding files and entries
-and edits nothing that is here.
+itself; a configuration names its model, its graph's generator and the
+program's assembly. Each is one file under this directory (a model two: the
+program's side and the plain reference), so a later PR adds a cell, a mix, a
+metric, a model, a graph or an assembly by adding files and entries and
+edits nothing that is here.
 """
 
 from __future__ import annotations
@@ -36,9 +37,10 @@ def load_benchmark() -> dict:
 
 
 def load_config(name: str) -> dict:
-    """The configuration's file. Its model and its graph's endpoint law
-    have no default: a file that lacks either key, or names a model that
-    has no files, is an error here, before anything is built."""
+    """The configuration's file. Its model, its graph's generator and
+    endpoint law and the program's assembly have no default: a file that
+    lacks one of the keys, or names a model, a generator or an assembly
+    that has no file, is an error here, before anything is built."""
     cfg = _load("configs", name)
     if "model" not in cfg:
         raise KeyError(
@@ -47,16 +49,32 @@ def load_config(name: str) -> dict:
     if "endpoints" not in cfg.get("graph", {}):
         raise KeyError(
             f"configuration {name!r} has no `graph.endpoints`; give the law "
-            "its edges' endpoints follow (chipbench/inputs.py), there is no "
-            "default")
-    model = cfg["model"]
-    for side in MODEL_SIDES:
-        if not (NAME.match(model) and os.path.isfile(
-                os.path.join(HERE, side, model + ".py"))):
+            "its edges' endpoints follow (the graph's file reads it), there "
+            "is no default")
+    if "generator" not in cfg["graph"]:
+        raise KeyError(
+            f"configuration {name!r} has no `graph.generator`; name a file "
+            "of chipbench/graphs/, there is no default")
+    if "assembly" not in cfg:
+        raise KeyError(
+            f"configuration {name!r} has no `assembly`; name a file of "
+            "chipbench/assemblies/, there is no default")
+    files = [("model", side, cfg["model"]) for side in MODEL_SIDES] + [
+        ("graph.generator", "graphs", cfg["graph"]["generator"]),
+        ("assembly", "assemblies", cfg["assembly"])]
+    for key, kind, named in files:
+        if not (isinstance(named, str) and NAME.match(named)
+                and os.path.isfile(os.path.join(HERE, kind, named + ".py"))):
             raise FileNotFoundError(
-                f"`model` {model!r} of configuration {name!r} has no file "
-                f"chipbench/{side}/{model}.py")
+                f"`{key}` {named!r} of configuration {name!r} has no file "
+                f"chipbench/{kind}/{named}.py")
     return cfg
+
+
+def _module(kind: str, name: str):
+    if not NAME.match(name):
+        raise ValueError(f"not a name the benchmark allows: {kind}/{name!r}")
+    return importlib.import_module(f"{__package__}.{kind}.{name}")
 
 
 def load_model(name: str, side: str):
@@ -66,9 +84,27 @@ def load_model(name: str, side: str):
     ``step_flops``, and any count of the bytes of its own mechanisms that a
     roofline's ``work`` names: ``work.counting`` looks there for a name
     that ``work.WORK`` does not have)."""
-    if not NAME.match(name) or side not in MODEL_SIDES:
+    if side not in MODEL_SIDES:
         raise ValueError(f"not a model the benchmark allows: {side}/{name!r}")
-    return importlib.import_module(f"{__package__}.{side}.{name}")
+    return _module(side, name)
+
+
+def load_graph(name: str):
+    """The graph file of that name (``graphs/<name>.py``): ``make(cfg,
+    seed)`` draws the configuration's ``inputs.Inputs`` from the seed,
+    ``describe(cfg)`` gives them at the same shapes without values, and a
+    file *may* have ``lane_faults(data, seeds, block)``, whose counts
+    ``check.compare`` adds to ``block_faults``."""
+    return _module("graphs", name)
+
+
+def load_assembly(name: str):
+    """The assembly file of that name (``assemblies/<name>.py``), which
+    calls the program's constructors: ``build(cfg, traffic, data, mesh)``
+    returns the parts that ``DistributedTrainer`` takes (``.sampler``,
+    ``.feature``), ``blocks(parts, cfg, seeds, key, workers)`` draws again
+    the blocks that a step trains on."""
+    return _module("assemblies", name)
 
 
 def load_traffic(name: str) -> dict:

@@ -35,6 +35,8 @@ BENCH = spec.load_benchmark()
 # -- the data files ----------------------------------------------------------
 
 ADDED = "reddit-sage-wide.hbm-long-warm"
+# a second graph, a second assembly and the configuration that names both
+TYPED = "products-typed.hbm"
 
 # what the copy's second model says otherwise than the first: the scope its
 # ops carry (a flax class of another name) and the names of its weights'
@@ -70,7 +72,12 @@ def added(tmp_path, monkeypatch):
     """What the README's worked examples add, added in a copy: nothing
     that is there is edited, and the harness finds each by its name. The
     second model is not the first: ``sage2``'s files declare a scope, a
-    weights' tree and a byte count of their own."""
+    weights' tree and a byte count of their own; the second graph
+    (``data/typed_graph.py``) has two node types, a relation on every edge
+    and seeds of the first type alone, and the second assembly
+    (``data/typed_assembly.py``) hands each lane's relation on."""
+    import chipbench.assemblies
+    import chipbench.graphs
     import chipbench.models
     import chipbench.reference
 
@@ -83,6 +90,14 @@ def added(tmp_path, monkeypatch):
         here / "models" / "sage.py", SECOND_PROGRAM + SECOND_LEAVES))
     (here / "reference" / "sage2.py").write_text(rewritten(
         here / "reference" / "sage.py", SECOND_LEAVES) + SECOND_COUNT)
+    for kind in ("graph", "assembly"):
+        shutil.copy(os.path.join(DATA, f"typed_{kind}.py"),
+                    here / {"graph": "graphs", "assembly": "assemblies"}[kind]
+                    / "typed.py")
+    typed = spec.load_config("products-sage")
+    typed["name"], typed["assembly"] = "products-typed", "typed"
+    typed["graph"].update(generator="typed", type_shares=[0.25, 0.75],
+                          relations=4)
     cfg = spec.load_config("reddit-sage")
     cfg["name"], cfg["fanout"] = "reddit-sage-wide", [25, 15]
     cfg["model"], cfg["graph"]["endpoints"] = "sage2", "degree"
@@ -96,7 +111,8 @@ def added(tmp_path, monkeypatch):
         layer="model + optimizer (models/sage.py, optax)", reader="roofline",
         args={"pattern": r"/jvp\({model_scope}\)/conv\d+/",
               "work": "sage2_aggregate_bytes", "peak": "hbm_gbps"})
-    for kind, item in (("configs", cfg), ("traffic", traffic),
+    for kind, item in (("configs", cfg), ("configs", typed),
+                       ("traffic", traffic),
                        ("metrics", metric), ("metrics", roofline)):
         with open(here / kind / f"{item['name']}.json", "w") as f:
             json.dump(item, f)
@@ -104,11 +120,16 @@ def added(tmp_path, monkeypatch):
         "configs": [{
             "name": "reddit-sage-wide", "source": cfg["source"],
             "file": "chipbench/configs/reddit-sage-wide.json",
-            "reduced": cfg["reduced"], "why": "an example"}],
+            "reduced": cfg["reduced"], "why": "an example"}, {
+            "name": "products-typed", "source": typed["source"],
+            "file": "chipbench/configs/products-typed.json",
+            "reduced": typed["reduced"], "why": "an example"}],
         "workloads": [{
             "name": ADDED, "config": "reddit-sage-wide",
             "traffic": "train-hbm-long-warm", "chips": 1,
-            "why": "an example"}],
+            "why": "an example"}, {
+            "name": TYPED, "config": "products-typed",
+            "traffic": "train-hbm", "chips": 1, "why": "an example"}],
         "per_layer": [
             {k: m[k] for k in ("name", "unit", "better", "source", "layer",
                                "moves", "workloads")}
@@ -124,14 +145,17 @@ def added(tmp_path, monkeypatch):
         json.dump(bench, f)
     monkeypatch.setattr(spec, "HERE", str(here))
     monkeypatch.setattr(spec, "ROOT", str(root))
-    # the copy's model files are found as a checkout's own are
-    for package in (chipbench.models, chipbench.reference):
+    # the copy's model, graph and assembly files are found as a checkout's
+    # own are
+    for package in (chipbench.models, chipbench.reference, chipbench.graphs,
+                    chipbench.assemblies):
         side = package.__name__.rsplit(".", 1)[1]
         monkeypatch.setattr(package, "__path__",
                             list(package.__path__) + [str(here / side)])
     yield spec.load_benchmark()
-    for side in spec.MODEL_SIDES:
-        sys.modules.pop(f"chipbench.{side}.sage2", None)
+    for module in ("models.sage2", "reference.sage2", "graphs.typed",
+                   "assemblies.typed"):
+        sys.modules.pop(f"chipbench.{module}", None)
 
 
 @pytest.fixture(params=["as it is", "with a second model"])
@@ -286,6 +310,120 @@ def test_a_model_is_two_files_and_a_configuration_names_it(added, monkeypatch):
     leaves = [set(layer) for layer in inputs.make_weights(cfg, 11)]
     assert leaves == [{"neigh", "b", "root"}] * 2
     assert not hasattr(spec.load_model("sage", "reference"), "sage2_aggregate_bytes")
+
+
+def typed_run(monkeypatch, seed: int = 13) -> tuple:
+    """The ``added`` copy's typed cell through the whole run, the chip left
+    out: the result, the seeds every step was fed, and the blocks that went
+    to the comparison."""
+    import jax
+
+    from chipbench.adapter import Program
+
+    fed, compared = [], []
+    real_step, real_compare = Program.step, check.compare
+
+    def step(self, seeds, key):
+        fed.append(np.asarray(seeds))
+        return real_step(self, seeds, key)
+
+    def compare(cfg, data, weights0, obs, seed):
+        compared.extend(b for blocks in obs.blocks for b in blocks)
+        return real_compare(cfg, data, weights0, obs, seed)
+
+    monkeypatch.setattr(Program, "step", step)
+    monkeypatch.setattr(check, "compare", compare)
+    monkeypatch.setattr(spec, "load_config", tiny.tiny_config)
+    args = argparse.Namespace(workload=TYPED, seed=seed, seconds=0.5, trace=0)
+    result = harness.run(args, jax.devices()[:1], harness.CompileMeter(),
+                         time.perf_counter(), {})
+    return result, fed, compared
+
+
+def test_a_graph_and_an_assembly_are_files_and_a_configuration_names_them(
+        added, monkeypatch):
+    """``graphs/typed.py``, ``assemblies/typed.py`` and a configuration that
+    names both: two node types of which the first alone is fed as seeds, an
+    ``int8`` relation on every edge, and every sampled lane carrying its
+    edge's relation to the comparison; the whole run, the chip left out,
+    comes out correct through those files."""
+    result, fed, compared = typed_run(monkeypatch)
+    assert result["correct"] is True, result["compared"]
+    for kind in ("graphs", "assemblies"):
+        module = sys.modules[f"chipbench.{kind}.typed"]
+        assert module.__file__.startswith(spec.HERE)
+    cfg = spec.load_config("products-typed")
+    assert (cfg["graph"]["generator"], cfg["assembly"]) == ("typed", "typed")
+    data = inputs.make_inputs(cfg, 13)
+    first = 3000 // 4
+    assert np.array_equal(data.seed_nodes, np.arange(first))
+    relation = data.edge_data["relation"]
+    assert relation.dtype == np.int8 and relation.shape == data.indices.shape
+    assert set(np.unique(relation)) == {0, 1, 2, 3}
+    assert len(fed) > 3 and all(
+        seeds.shape == (64,) and seeds.max() < first for seeds in fed)
+    assert len(compared) == 3
+    for block in compared:
+        assert len(block.lane_data) == len(block.layers)
+        for (src, _, _), carried in zip(block.layers, block.lane_data):
+            assert carried["relation"].shape == src.shape
+            assert (carried["relation"][src >= 0] >= 0).all()
+    # the first graph says nothing more than it did
+    plain = inputs.make_inputs(tiny.tiny_config("products-sage"), 13)
+    assert plain.seed_nodes is None and plain.edge_data == {}
+    assert not hasattr(spec.load_graph("lomax"), "lane_faults")
+    assert np.array_equal(plain.indices, data.indices)
+
+
+@pytest.mark.parametrize("bent", ["one lane's relation", "no lane carries any"])
+def test_a_bent_relation_comes_out_not_correct(added, monkeypatch, bent):
+    """A lane's payload altered where it is produced: the graph file's
+    ``lane_faults`` finds a relation that no edge between the lane's two
+    nodes has, ``block_faults`` counts it and the run is not correct."""
+    from chipbench.adapter import Program
+
+    real = Program.blocks
+
+    def altered(self, seeds, key):
+        blocks = real(self, seeds, key)
+        if bent == "no lane carries any":
+            blocks[0].lane_data = []
+            return blocks
+        carried = blocks[0].lane_data[0]["relation"]
+        lane = int(np.flatnonzero(blocks[0].layers[0][0] >= 0)[0])
+        carried[lane] = 4  # the graph has relations 0..3
+        return blocks
+
+    monkeypatch.setattr(Program, "blocks", altered)
+    result, _, _ = typed_run(monkeypatch)
+    assert result["correct"] is False
+    assert result["compared"]["block_faults"]["value"] > 0
+    failed = {n for n, r in result["compared"].items()
+              if r["value"] > r["limit"]}
+    assert failed == {"block_faults"}
+
+
+@pytest.mark.parametrize("broken, named", [
+    (lambda cfg: cfg["graph"].pop("generator"), "`graph.generator`"),
+    (lambda cfg: cfg.pop("assembly"), "`assembly`"),
+    (lambda cfg: cfg["graph"].update(generator="no_such_graph"),
+     "`graph.generator` 'no_such_graph'"),
+    (lambda cfg: cfg.update(assembly="no_such_assembly"),
+     "`assembly` 'no_such_assembly'"),
+])
+def test_a_configuration_names_its_graph_and_its_assembly(
+        added, broken, named):
+    """Neither key has a default: a file without one, or one that names a
+    graph or an assembly that has no file, fails in ``spec.load_config``
+    with the key's name, before anything is built."""
+    cfg = spec.load_config("products-typed")
+    broken(cfg)
+    with open(os.path.join(spec.HERE, "configs", "products-typed.json"),
+              "w") as f:
+        json.dump(cfg, f)
+    with pytest.raises((KeyError, FileNotFoundError)) as raised:
+        spec.load_config("products-typed")
+    assert named in str(raised.value)
 
 
 def test_a_models_plain_side_counts_its_own_bytes(added):
@@ -477,6 +615,29 @@ def test_inputs_are_the_bytes_they_were(config, seed):
     assert got == GOLDEN[config, seed]
 
 
+def test_a_feed_over_every_node_is_the_golden_feed_and_over_seed_nodes_draws_only_those():
+    """``Feed.of`` over inputs that name no ``seed_nodes`` is the feed the
+    golden digests hold, to the byte; over a subset it cuts a permutation
+    of the subset and nothing else."""
+    cfg = tiny.tiny_config("products-sage")
+    data = inputs.make_inputs(cfg, 3)
+    assert data.seed_nodes is None
+    feed = inputs.Feed.of(data, cfg["batch"], 3)
+    assert sha256(feed.seeds(0), feed.seeds(1), feed.key(0), feed.key(1)) \
+        == GOLDEN["products-sage", 3]["feed"]
+    assert feed.steps_per_epoch == 3000 // 64
+    data.seed_nodes = np.arange(5, 3000, 7, dtype=np.int32)  # 428 nodes
+    some = inputs.Feed.of(data, cfg["batch"], 3)
+    assert some.steps_per_epoch == 428 // 64
+    epoch = np.concatenate([some.seeds(i) for i in range(6)])
+    assert epoch.dtype == np.int32 and np.unique(epoch).shape == (6 * 64,)
+    assert np.isin(epoch, data.seed_nodes).all()
+    assert np.array_equal(some.seeds(6), some.seeds(0))  # the epoch wraps
+    assert not np.array_equal(epoch, np.sort(epoch))
+    with pytest.raises(ValueError):
+        inputs.Feed.of(data, 429, 3)
+
+
 def test_more_classes_than_feature_columns_share_the_bumps():
     """ogbn-papers100M has 172 classes and 128-d rows: the label's bump
     goes to column ``label % width``, and every label is still drawn."""
@@ -537,8 +698,8 @@ def test_a_cache_of_a_fifth_of_the_rows_hits_by_the_law(law_graph):
     nodes, width = data.features.shape
     topo = quiver_tpu.CSRTopo(indptr=data.indptr, indices=data.indices)
     feature = quiver_tpu.Feature(
-        device_cache_size=nodes // 5 * width * 4, csr_topo=topo,
-        kernel="xla").from_cpu_tensor(data.features)
+        device_cache_size=nodes // 5 * width * 4,
+        csr_topo=topo).from_cpu_tensor(data.features)
     assert feature.hot_rows == nodes // 5
     feed = inputs.Feed(nodes, 256, 7)
     n_id = plan_caps.frontiers(data.indptr, data.indices, feed.seeds(0),
