@@ -129,7 +129,8 @@ class CSRTopo:
     """
 
     def __init__(self, edge_index=None, indptr=None, indices=None, eid=None,
-                 edge_weight=None, edge_time=None, use_native: bool = True):
+                 edge_weight=None, edge_time=None, edge_relation=None,
+                 use_native: bool = True):
         if edge_index is not None:
             if indptr is not None or indices is not None:
                 raise ValueError("pass either edge_index or indptr/indices, not both")
@@ -186,6 +187,7 @@ class CSRTopo:
         self._edge_weight = None
         self._cum_weights = None
         self._edge_time = None
+        self._edge_relation = None
         self._max_degree = None  # lazy cache (manifest-seeded on raw loads)
         # streaming-mutation version: bumped ONCE per committed transaction
         # (quiver_tpu.streaming); device placements capture the version they
@@ -196,6 +198,9 @@ class CSRTopo:
             self.set_edge_weight(edge_weight, coo_order=edge_index is not None)
         if edge_time is not None:
             self.set_edge_time(edge_time, coo_order=edge_index is not None)
+        if edge_relation is not None:
+            self.set_edge_relation(edge_relation,
+                                   coo_order=edge_index is not None)
 
     # -- properties (parity with reference utils.py:150-210) ---------------
 
@@ -300,6 +305,8 @@ class CSRTopo:
             self._cum_weights = _row_prefix_weights(
                 self._edge_weight, self._indptr
             )
+        if self._edge_relation is not None:
+            self._edge_relation = self._edge_relation[order]
         return self
 
     @property
@@ -307,6 +314,40 @@ class CSRTopo:
         """Per-edge timestamps in CSR slot order (float32, rows sorted
         time-nondecreasing), or None if untimestamped."""
         return self._edge_time
+
+    # -- edge relations (typed graphs) ---------------------------------------
+
+    def set_edge_relation(self, edge_relation,
+                          coo_order: bool = True) -> "CSRTopo":
+        """Attach each edge's relation, a small non-negative integer: the
+        edge type of a heterogeneous graph whose node types are contiguous
+        id ranges of this one CSR. A sampler over the topology hands every
+        sampled lane its edge's relation (``Adj.relation``).
+
+        ``coo_order=True`` means the relations align with the COO edge
+        order this topology was built from (translated through ``eid``);
+        otherwise they are taken in CSR slot order. Stored ``int8``.
+        """
+        rel = _as_numpy(edge_relation).reshape(-1)
+        if rel.shape[0] != self.edge_count:
+            raise ValueError(
+                f"edge_relation must have {self.edge_count} entries, got "
+                f"{rel.shape[0]}"
+            )
+        if rel.dtype.kind not in "iu":
+            raise ValueError(
+                f"edge_relation must be integers, got dtype {rel.dtype}")
+        if rel.size and not 0 <= int(rel.min()) <= int(rel.max()) <= 127:
+            raise ValueError("edge relations must lie in [0, 127]")
+        if coo_order and self._eid is not None:
+            rel = rel[self._eid]
+        self._edge_relation = rel.astype(np.int8)
+        return self
+
+    @property
+    def edge_relation(self) -> np.ndarray | None:
+        """Per-edge relations in CSR slot order (int8), or None."""
+        return self._edge_relation
 
     @property
     def version(self) -> int:
@@ -344,6 +385,10 @@ class CSRTopo:
                 "topology is timestamped (the streaming admission layer "
                 "rejects mismatched deltas)"
             )
+        if self._edge_relation is not None:
+            raise ValueError(
+                "a topology with edge relations cannot be mutated: a delta "
+                "carries no relation for the edges it adds")
         edge_count = int(indptr[-1])
         node_count = int(indptr.shape[0] - 1)
         indptr = indptr.astype(_index_dtype(edge_count), copy=False)
@@ -403,7 +448,7 @@ class CSRTopo:
         recompute; the raw format's mmap loads depend on that."""
         arrays = {"indptr": self._indptr, "indices": self._indices}
         for name in ("eid", "edge_weight", "cum_weights", "edge_time",
-                     "feature_order"):
+                     "edge_relation", "feature_order"):
             v = getattr(self, f"_{name}")
             if v is not None:
                 arrays[name] = v
@@ -489,6 +534,7 @@ class CSRTopo:
         topo._edge_weight = arrays.get("edge_weight")
         topo._cum_weights = arrays.get("cum_weights")
         topo._edge_time = arrays.get("edge_time")
+        topo._edge_relation = arrays.get("edge_relation")
         topo._max_degree = (
             int(meta["max_degree"]) if "max_degree" in meta else None
         )
@@ -562,6 +608,8 @@ class CSRTopo:
             if "edge_time" in z.files:
                 # stored post-sort, so the re-sort inside is the identity
                 topo.set_edge_time(z["edge_time"], coo_order=False)
+            if "edge_relation" in z.files:
+                topo.set_edge_relation(z["edge_relation"], coo_order=False)
             if "feature_order" in z.files:
                 topo.feature_order = z["feature_order"]
         return topo
@@ -609,7 +657,8 @@ class CSRTopo:
 
     def to_device(self, mode: SampleMode | str = SampleMode.HBM,
                   with_eid: bool = False, with_weights: bool = False,
-                  with_times: bool = False) -> "DeviceTopology":
+                  with_times: bool = False,
+                  with_relations: bool = False) -> "DeviceTopology":
         """Place the topology for sampling.
 
         HBM mode puts everything in device memory. HOST mode keeps the large
@@ -620,12 +669,19 @@ class CSRTopo:
         sampling (requires ``set_edge_weight`` first); ``with_times`` ships
         the timestamp array for temporal windows (requires ``set_edge_time``
         first, HBM mode only — the window search gathers timestamps inside
-        the draw loop, which HOST staging cannot serve).
+        the draw loop, which HOST staging cannot serve). ``with_relations``
+        packs each edge's relation into the edge array's free high bits
+        (requires ``set_edge_relation`` first; see ``place_csr_arrays``).
         """
         if with_weights and self._cum_weights is None:
             raise ValueError(
                 "weighted sampling requires edge weights; call "
                 "set_edge_weight() or pass edge_weight= to CSRTopo"
+            )
+        if with_relations and self._edge_relation is None:
+            raise ValueError(
+                "with_relations requires edge relations; call "
+                "set_edge_relation() or pass edge_relation= to CSRTopo"
             )
         if with_times:
             if self._edge_time is None:
@@ -645,15 +701,17 @@ class CSRTopo:
             self._cum_weights if with_weights else None,
             self.max_degree, mode,
             edge_time=self._edge_time if with_times else None,
+            edge_relation=self._edge_relation if with_relations else None,
         )
 
 
 EDGE_BLOCK = 128  # words of the edge array the sampler reads a lane
 
 
-def _whole_blocks(indices) -> np.ndarray:
+def _whole_blocks(indices, relation=None, shift: int = 0) -> np.ndarray:
     """``indices`` zero-padded on the host to a whole number of blocks, in
-    the dtype the device will hold.
+    the dtype the device will hold; with ``relation``, each word carries
+    its edge's relation above bit ``shift`` (``place_csr_arrays``).
 
     The padded copy is written into an anonymous mapping populated in one
     call (its tail is zero as mapped): a fresh half gigabyte touched page
@@ -662,20 +720,39 @@ def _whole_blocks(indices) -> np.ndarray:
     indices = np.asarray(indices)
     words = indices.shape[0]
     pad = -words % EDGE_BLOCK
-    if pad == 0:
+    if pad == 0 and relation is None:
         return indices
     dtype = np.dtype(jax.dtypes.canonicalize_dtype(indices.dtype))
-    buf = mmap.mmap(-1, (words + pad) * dtype.itemsize,
+    buf = mmap.mmap(-1, max((words + pad) * dtype.itemsize, 1),
                     flags=mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS
                     | getattr(mmap, "MAP_POPULATE", 0))
     out = np.frombuffer(buf, dtype)
-    out[:words] = indices
+    if relation is None:
+        out[:words] = indices
+        return out
+    head = out[:words]
+    head[:] = relation
+    head <<= shift
+    head |= indices
     return out
+
+
+def relation_shift(node_count: int, num_relations: int) -> int:
+    """The bit at which an edge's relation sits in its packed word: above
+    the bits of the largest node id. Raises where the two do not fit the
+    31 bits of a non-negative ``int32``."""
+    shift = max(int(node_count - 1).bit_length(), 1)
+    bits = max(int(num_relations - 1).bit_length(), 1)
+    if shift + bits > 31:
+        raise ValueError(
+            f"{node_count} nodes and {num_relations} relations do not fit "
+            "one int32 word per edge")
+    return shift
 
 
 def place_csr_arrays(indptr, indices, eid, cum_weights, max_degree: int,
                      mode: SampleMode | str,
-                     edge_time=None) -> "DeviceTopology":
+                     edge_time=None, edge_relation=None) -> "DeviceTopology":
     """Shared CSR placement for CSRTopo and hetero RelCSR.
 
     HBM mode puts everything in device memory; HOST mode keeps the large
@@ -692,11 +769,26 @@ def place_csr_arrays(indptr, indices, eid, cum_weights, max_degree: int,
     1-D and is held once; ``DeviceTopology.edge_count`` stays the CSR's
     edge count. HOST-mode arrays are not padded: a staged host gather does
     not cost by the tile.
+
+    ``edge_relation`` (int8 per edge, CSR order) is packed into the edge
+    array itself: each word holds its node id in the low
+    ``relation_shift(nodes, relations)`` bits and its edge's relation
+    above them, so the sampler reads an edge's endpoint and relation in
+    the one block it fetches anyway (``DeviceTopology.relation_shift``,
+    ``num_relations``). Where node ids and relations do not fit 31 bits
+    this raises.
     """
     mode = SampleMode.parse(mode)
     indptr = jnp.asarray(indptr)
     edge_count = int(np.shape(indices)[0])
     host = False
+    shift = num_relations = 0
+    if edge_relation is not None:
+        edge_relation = np.asarray(edge_relation)
+        num_relations = int(edge_relation.max(initial=-1)) + 1
+        shift = relation_shift(int(np.shape(indptr)[0]) - 1, num_relations)
+        if mode == SampleMode.HOST:
+            indices = _whole_blocks(indices, edge_relation, shift)[:edge_count]
     if mode == SampleMode.HOST:
         indices, host = to_pinned_host(indices)
         if eid is not None:
@@ -707,7 +799,7 @@ def place_csr_arrays(indptr, indices, eid, cum_weights, max_degree: int,
                 else jnp.asarray(cum_weights)
             )
     else:
-        indices = jnp.asarray(_whole_blocks(indices))
+        indices = jnp.asarray(_whole_blocks(indices, edge_relation, shift))
         if eid is not None:
             eid = jnp.asarray(eid)
         if cum_weights is not None:
@@ -722,7 +814,8 @@ def place_csr_arrays(indptr, indices, eid, cum_weights, max_degree: int,
     return DeviceTopology(indptr=indptr, indices=indices, eid=eid,
                           cum_weights=cum_weights, edge_time=edge_time,
                           host_indices=host, search_iters=iters,
-                          max_degree=int(max_degree), edge_count=edge_count)
+                          max_degree=int(max_degree), edge_count=edge_count,
+                          relation_shift=shift, num_relations=num_relations)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -742,12 +835,16 @@ class DeviceTopology:
     hand-built topology may pass any ``indices``; without ``edge_count``
     its length is taken, and if that is not a whole number of blocks the
     sampler reads it a word a lane.
+
+    ``num_relations`` (static, 0 for none) says that each word of
+    ``indices`` holds its edge's relation above bit ``relation_shift``.
     """
 
     def __init__(self, indptr, indices, eid=None, cum_weights=None,
                  edge_time=None, host_indices: bool = False,
                  search_iters: int = 0, max_degree: int | None = None,
-                 edge_count: int | None = None):
+                 edge_count: int | None = None, relation_shift: int = 0,
+                 num_relations: int = 0):
         self.indptr = indptr
         self.indices = indices
         self.eid = eid
@@ -759,6 +856,8 @@ class DeviceTopology:
         self.edge_count = (
             int(indices.shape[0]) if edge_count is None else int(edge_count)
         )
+        self.relation_shift = int(relation_shift)
+        self.num_relations = int(num_relations)
 
     @property
     def node_count(self) -> int:
@@ -768,11 +867,13 @@ class DeviceTopology:
         children = (self.indptr, self.indices, self.eid, self.cum_weights,
                     self.edge_time)
         return children, (self.host_indices, self.search_iters,
-                          self.max_degree, self.edge_count)
+                          self.max_degree, self.edge_count,
+                          self.relation_shift, self.num_relations)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         indptr, indices, eid, cum_weights, edge_time = children
         return cls(indptr, indices, eid, cum_weights, edge_time,
                    host_indices=aux[0], search_iters=aux[1],
-                   max_degree=aux[2], edge_count=aux[3])
+                   max_degree=aux[2], edge_count=aux[3],
+                   relation_shift=aux[4], num_relations=aux[5])

@@ -52,6 +52,8 @@ __all__ = [
     "fanout_softmax",
     "fanout_sum_aggregate",
     "fanout_gather_sum",
+    "fanout_relation_sums",
+    "masked_batch_norm",
     "gather_mean_aggregate",
     "gather_src",
     "gather_lane_rows",
@@ -318,6 +320,46 @@ def fanout_gather_sum(x, src, num_dst: int, fanout: int):
     valid = idx >= 0
     rows = jnp.where(valid[..., None], x[jnp.clip(idx, 0)], 0)
     return rows.sum(axis=0), valid.sum(axis=0, dtype=jnp.int32)
+
+
+def fanout_relation_sums(x, src, relation, num_dst: int, fanout: int,
+                         num_relations: int):
+    """Each target's sum of its sources' rows per relation, gathered
+    fanout-major as :func:`fanout_gather_sum` gathers them.
+
+    ``relation`` is ``(fanout, num_dst)``, the sampler's ``Adj.relation``
+    (lane ``t * fanout + k`` at ``[k, t]``; -1 on padded lanes). Returns
+    one ``(num_dst, F)`` float32 sum per relation (a tuple: each is its
+    relation's operand as it is, no stacked copy of the five) and each
+    target's number of lanes of each relation ``(num_relations, num_dst)``
+    int32.
+
+    The rows are gathered once, in the dtype ``x`` holds (float16 rows
+    stay two bytes until they are summed), and each relation's sum adds
+    the ``fanout`` slabs that its lanes select, widened to float32 inside
+    the sum: one pass over the gathered rows a relation."""
+    idx = src.reshape(num_dst, fanout).T
+    rows = x[jnp.clip(idx, 0)]
+    kinds = jnp.arange(num_relations, dtype=relation.dtype)
+    picked = (relation[None] == kinds[:, None, None]) & (idx >= 0)[None]
+    sums = tuple(
+        jnp.sum(jnp.where(picked[r][..., None], rows, 0), axis=0,
+                dtype=jnp.float32)
+        for r in range(num_relations))
+    return sums, picked.sum(axis=1, dtype=jnp.int32)
+
+
+def masked_batch_norm(x, valid, scale, bias, eps: float = 1e-5):
+    """Batch normalisation in training mode over the rows where ``valid``:
+    the mean and the biased variance of those rows alone (a padded block's
+    empty slots would otherwise move both), then ``scale`` and ``bias``.
+    Rows that are not valid come out zero."""
+    v = valid[:, None]
+    n = jnp.maximum(valid.sum(dtype=x.dtype), 1)
+    mean = jnp.where(v, x, 0).sum(axis=0) / n
+    centred = jnp.where(v, x - mean, 0)
+    var = (centred * centred).sum(axis=0) / n
+    return centred * (lax.rsqrt(var + eps) * scale) + jnp.where(v, bias, 0)
 
 
 def gather_mean_aggregate(x, src, dst, num_dst: int,

@@ -51,6 +51,7 @@ __all__ = [
     "SAMPLE_EDGES",
     "SAMPLE_FRONTIER",
     "SAMPLE_FRONTIER_OVERFLOW",
+    "SAMPLE_RELATION_LANES",
     "HETERO_SAMPLE_OVERFLOW",
     "GUARD_SKIPPED",
     "GUARD_NONFINITE",
@@ -101,6 +102,9 @@ SAMPLE_OVERFLOW = "sample.hop_overflow"
 SAMPLE_EDGES = "sample.edges"
 SAMPLE_FRONTIER = "sample.frontier"
 SAMPLE_FRONTIER_OVERFLOW = "sample.frontier_overflow"
+# over a topology with edge relations: valid sampled lanes per hop
+# (seeds-outward) and relation, mesh totals per step
+SAMPLE_RELATION_LANES = "sample.relation_lanes"
 # per-(hop, edge-type) routed-overflow lanes of the distributed hetero
 # sampler (flat vector in the sampler's static slot order; relations
 # sharing a destination type share that hop's route plan, so they report

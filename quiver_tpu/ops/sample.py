@@ -217,6 +217,10 @@ def sample_layer(topo, seeds, num_seeds, k: int, key, with_eid: bool = False,
       counts: (S,) number of valid samples per row (min(deg, k), 0 for
         invalid seeds) — the padded analogue of the reference's counts output.
       eids: (S, K) CSR edge slots or -1, only if ``with_eid``.
+      relations: (S, K) int8 edge relations or -1, last, only over a
+        topology placed with ``to_device(with_relations=True)``: its edge
+        words carry the relation above ``topo.relation_shift``, read from
+        the word each lane fetches, no second gather.
     """
     if k < 1:
         raise ValueError(f"fanout k must be >= 1, got {k}")
@@ -271,9 +275,15 @@ def sample_layer(topo, seeds, num_seeds, k: int, key, with_eid: bool = False,
 
     epos = base[:, None] + off.astype(base.dtype)
     nbr = _gather_indices(topo, epos, mask)
+    relations = None
+    if getattr(topo, "num_relations", 0):
+        shift = topo.relation_shift
+        relations = jnp.where(mask, nbr >> shift, -1).astype(jnp.int8)
+        nbr = nbr & ((1 << shift) - 1)
     nbr = jnp.where(mask, nbr, -1).astype(jnp.int32)
     counts = jnp.where(valid, jnp.minimum(deg, k), 0)
 
+    out = (nbr, counts)
     if with_eid:
         eids = jnp.where(mask, epos, -1)
         if topo.eid is not None:
@@ -281,8 +291,10 @@ def sample_layer(topo, seeds, num_seeds, k: int, key, with_eid: bool = False,
             eids = jnp.where(
                 mask, staged_gather(topo.eid, safe_epos, topo.host_indices), -1
             )
-        return nbr, counts, eids
-    return nbr, counts
+        out += (eids,)
+    if relations is not None:
+        out += (relations,)
+    return out
 
 
 _BLOCK_SHIFT = EDGE_BLOCK.bit_length() - 1  # a block is 128 words, 512 bytes

@@ -53,6 +53,7 @@ from ..obs.registry import (
     SAMPLE_FRONTIER,
     SAMPLE_FRONTIER_OVERFLOW,
     SAMPLE_OVERFLOW,
+    SAMPLE_RELATION_LANES,
     TIER_HITS,
     TRAIN_OVERLAP_EFFICIENCY,
     XLA_CACHE_HITS,
@@ -316,7 +317,9 @@ class DistributedTrainer:
         # The sampler's own counts have no ``last_*`` view; read them with
         # ``trainer.metrics.value(name)``: sample.edges, sample.frontier
         # (int32 (num_layers,), seeds-outward) and
-        # sample.frontier_overflow (scalar), on every topology.
+        # sample.frontier_overflow (scalar), on every topology;
+        # sample.relation_lanes (int32 (num_layers, relations)) over a
+        # topology with edge relations.
         # collect_metrics=False disables collection at the PROGRAM level:
         # the compiled step carries zero metric values/collectives and the
         # loss trajectory is bit-identical (tests/test_obs.py differential).
@@ -354,6 +357,17 @@ class DistributedTrainer:
             doc="mesh-total uniques dropped for exceeding frontier_caps, "
                 "all hops; > 0 means the step trained on truncated blocks",
         )
+        # over a topology with edge relations, the lanes of each relation
+        # per hop: registered (and compiled in) only there, so a program
+        # over a plain topology is the one it was
+        relations = getattr(sampler.topo, "num_relations", 0)
+        if relations:
+            self.metrics.counter(
+                SAMPLE_RELATION_LANES, shape=hops + (relations,),
+                unit="lanes",
+                doc="mesh-total valid sampled lanes per hop (seeds-outward) "
+                    "and edge relation",
+            )
         # resilience (resilience/): nonfinite_guard=True compiles the
         # non-finite step guard into the step body — a NaN/Inf loss or
         # gradient cond-skips the optimizer update (params/opt_state pass
@@ -828,6 +842,7 @@ class DistributedTrainer:
             else 0
         )
         node_count = sampler.csr_topo.node_count
+        relations = getattr(sampler.topo, "num_relations", 0)
         rows_per_shard = (
             sampler.topo.rows_per_shard if topo_sharded else 0
         )
@@ -955,6 +970,14 @@ class DistributedTrainer:
                     frontier=jnp.stack(frontier[::-1]),
                     frontier_ov=frontier_ov,
                 )
+                if relations:
+                    # the lanes' relations as the model gets them (-1 on
+                    # invalid lanes), counted per hop, seeds-outward
+                    kinds = jnp.arange(relations, dtype=jnp.int8)
+                    tele["relation_lanes"] = jnp.stack([
+                        (a.relation[..., None] == kinds).sum(
+                            axis=(0, 1), dtype=jnp.int32)
+                        for a in adjs[::-1]])
             return n_id, x, adjs, num_seeds, tele
 
         def train_block(params, n_id, x, adjs, num_seeds, labels, key,
@@ -1016,7 +1039,8 @@ class DistributedTrainer:
         issue_names = (
             ROUTED_OVERFLOW, TIER_HITS, SAMPLE_OVERFLOW, SAMPLE_EDGES,
             SAMPLE_FRONTIER, SAMPLE_FRONTIER_OVERFLOW,
-        ) + ((FEATURE_ROW_HEAT,) if heat_on else ())
+        ) + ((FEATURE_ROW_HEAT,) if heat_on else ()) + (
+            (SAMPLE_RELATION_LANES,) if relations else ())
         train_names = (GUARD_SKIPPED, GUARD_NONFINITE) if guard else ()
         program_names = issue_names + train_names
         axes = (DATA_AXIS, FEATURE_AXIS)
@@ -1062,6 +1086,9 @@ class DistributedTrainer:
             tape.add(SAMPLE_FRONTIER, tele["frontier"], psum=lane_axes)
             tape.add(SAMPLE_FRONTIER_OVERFLOW, tele["frontier_ov"],
                      psum=lane_axes)
+            if relations:
+                tape.add(SAMPLE_RELATION_LANES, tele["relation_lanes"],
+                         psum=lane_axes)
 
         def allreduce_update(params, opt_state, blocks):
             """The shared tail of the serial body and the train half:

@@ -438,6 +438,12 @@ class DistGraphSageSampler(GraphSageSampler):
                 "sharded CSR slices do not carry eid — use the replicated "
                 "GraphSageSampler"
             )
+        if getattr(csr_topo, "edge_relation", None) is not None:
+            raise ValueError(
+                "edge relations over a sharded topology are not supported; "
+                "the sharded CSR slices do not carry them — use the "
+                "replicated GraphSageSampler"
+            )
         if SampleMode.parse(mode) is not SampleMode.HBM:
             raise ValueError(
                 "topo_sharding='mesh' requires mode='HBM': each shard's CSR "

@@ -47,14 +47,24 @@ class Adj:
     ``s`` (or is invalid), so ``E_cap == size[1] * fanout``. Models use it
     to aggregate with dense (num_dst, fanout) reductions instead of
     segment scatters.
+
+    Over a topology with edge relations the sampler also sets
+    ``relation``, each lane's edge relation (int8, -1 on invalid lanes)
+    **fanout-major**, ``(fanout, size[1])``: entry ``[k, s]`` is lane
+    ``s*fanout + k``, the layout in which the dense aggregation gathers
+    its rows (``models.layers.fanout_gather_sum``); and ``dst_count``, the
+    number of valid targets (a prefix of the ``size[1]`` slots), which
+    PyG's unpadded ``size`` would give. Both are None otherwise.
     """
 
     def __init__(self, edge_index, e_id, size: tuple[int, int],
-                 fanout: int | None = None):
+                 fanout: int | None = None, relation=None, dst_count=None):
         self.edge_index = edge_index
         self.e_id = e_id
         self.size = tuple(size)
         self.fanout = fanout
+        self.relation = relation
+        self.dst_count = dst_count
 
     def __iter__(self):
         return iter((self.edge_index, self.e_id, self.size))
@@ -63,19 +73,21 @@ class Adj:
         return f"Adj(edge_index={self.edge_index.shape}, size={self.size})"
 
     def to(self, device):
+        put = lambda a: None if a is None else jax.device_put(a, device)
         return Adj(
-            jax.device_put(self.edge_index, device),
-            None if self.e_id is None else jax.device_put(self.e_id, device),
-            self.size,
-            self.fanout,
+            jax.device_put(self.edge_index, device), put(self.e_id),
+            self.size, self.fanout, put(self.relation), put(self.dst_count),
         )
 
     def tree_flatten(self):
-        return (self.edge_index, self.e_id), (self.size, self.fanout)
+        return ((self.edge_index, self.e_id, self.relation, self.dst_count),
+                (self.size, self.fanout))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(children[0], children[1], *aux)
+        edge_index, e_id, relation, dst_count = children
+        return cls(edge_index, e_id, *aux, relation=relation,
+                   dst_count=dst_count)
 
 
 class SampleOutput(NamedTuple):
@@ -104,7 +116,9 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
     its edge_index columns (-1 on invalid lanes) — the reference's per-hop
     ``e_id`` output (sage_sampler.py:100-109, reindex_single eid plumbing).
     Ids are original COO edge positions when the topology tracks ``eid``,
-    raw CSR slots otherwise.
+    raw CSR slots otherwise. A topology placed with its edge relations
+    (``topo.num_relations``) gives every Adj its lanes' ``relation`` and
+    its ``dst_count``.
 
     Returns (n_id, n_count, adjs deepest-first, overflow, per-layer edge
     counts, per-layer unclipped frontier counts).
@@ -158,13 +172,18 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
                 "%s); falling back to the XLA draw", DEFAULT_WINDOW, md,
             )
             use_pallas = False
+    with_relation = getattr(topo, "num_relations", 0) > 0
+    if with_relation and use_pallas:
+        raise ValueError(
+            "the fused Pallas sampler does not read edge relations; use "
+            "kernel='xla'")
     adjs = []
     edge_counts = []
     frontier_counts = []
     cur, cur_n = seeds, num_seeds
     total_overflow = jnp.zeros((), jnp.int32)
     for l, k in enumerate(sizes):
-        eids = None
+        eids = relation = None
         if use_pallas and k > DEFAULT_WINDOW:
             info_once(
                 "sample-pallas-fanout",
@@ -182,14 +201,15 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
                     nbr, counts = fused_sample_layer(
                         topo, cur, cur_n, k, sub, weighted=weighted,
                         time_window=time_window)
-            elif with_eid:
-                nbr, counts, eids = sample_layer(topo, cur, cur_n, k, sub,
-                                                 weighted=weighted, with_eid=True,
-                                                 time_window=time_window)
             else:
-                nbr, counts = sample_layer(topo, cur, cur_n, k, sub,
-                                           weighted=weighted,
-                                           time_window=time_window)
+                out = sample_layer(topo, cur, cur_n, k, sub,
+                                   weighted=weighted, with_eid=with_eid,
+                                   time_window=time_window)
+                nbr, counts = out[:2]
+                if with_eid:
+                    eids = out[2]
+                if with_relation:
+                    relation = out[-1]
         with trace_scope(f"reindex_layer_{l}"):
             frontier, n_frontier, col, overflow = reindex_layer(
                 cur, cur_n, nbr, caps[l]
@@ -204,6 +224,8 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
                     # re-mask with col: neighbors dropped by frontier-cap
                     # overflow must not leak their edge ids
                     eids = jnp.where(col >= 0, eids, -1).reshape(-1)
+                if relation is not None:
+                    relation = jnp.where(col >= 0, relation, -1).T
                 # per-layer tallies in-program: the fused step's counters,
                 # benchmarks and the auto-cap planner read scalars instead
                 # of reducing (2, E_cap) arrays on the host path. Tallied
@@ -215,7 +237,9 @@ def multilayer_sample(topo, seeds, num_seeds, key, sizes, caps, weighted=False,
                 edge_counts.append(jnp.sum((col >= 0).astype(jnp.int32)))
                 frontier_counts.append(n_frontier + overflow)
                 total_overflow = total_overflow + overflow
-        adjs.append(Adj(edge_index, eids, (caps[l], S), fanout=k))
+        adjs.append(Adj(edge_index, eids, (caps[l], S), fanout=k,
+                        relation=relation,
+                        dst_count=cur_n if with_relation else None))
         cur, cur_n = frontier, n_frontier
     return (cur, cur_n, adjs[::-1], total_overflow, tuple(edge_counts[::-1]),
             tuple(frontier_counts[::-1]))
@@ -267,6 +291,9 @@ class GraphSageSampler:
       with_eid: populate ``Adj.e_id`` with per-edge global edge ids
         (reference sage_sampler.py:100-109) — COO positions when the
         topology tracks ``eid``, CSR slots otherwise.
+        A ``csr_topo`` with edge relations (``CSRTopo.set_edge_relation``)
+        needs no flag: its relations are placed with the edges and every
+        Adj carries ``relation`` and ``dst_count`` (kernel "xla" only).
       dedup: "auto" or "scan", one meaning: the reindex has one
         algorithm (ops.reindex.masked_unique). Kept because the benchmark
         passes it by name (ROADMAP D14); "sort" and "map" were removed
@@ -361,6 +388,10 @@ class GraphSageSampler:
             # are gone (ISSUE 16).
             if SampleMode.parse(mode) is not SampleMode.HBM:
                 raise ValueError("kernel='pallas' requires mode='HBM' (GPU) topology")
+            if csr_topo.edge_relation is not None:
+                raise ValueError(
+                    "kernel='pallas' does not read edge relations; use "
+                    "kernel='xla'")
         if self.weighted and csr_topo.cum_weights is None:
             raise ValueError(
                 "weighted=True requires edge weights; call "
@@ -446,6 +477,7 @@ class GraphSageSampler:
         return self.csr_topo.to_device(
             self.mode, with_eid=self.with_eid, with_weights=self.weighted,
             with_times=self.time_window is not None,
+            with_relations=self.csr_topo.edge_relation is not None,
         )
 
     # -- streaming-mutation versioning --------------------------------------

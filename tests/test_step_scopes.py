@@ -37,6 +37,7 @@ CELLS = {
     "products-sage.clique2x2": dict(
         fanout=[3, 2, 2], caps=[32, 64, 128], data=2, feature=2),
     "products-gat.hbm": dict(fanout=[3, 2, 2], caps=[32, 64, 128]),
+    "mag240m-rsage.hbm": dict(fanout=[3, 2], caps=[32, 64], relations=5),
 }
 
 
@@ -74,6 +75,8 @@ TOP_LEVEL = re.compile(
 # and transposed
 ATTENTION_SCOPES = ("attn_project", "attn_logits", "attn_softmax",
                     "attn_aggregate", "skip")
+# and those of ``RelSAGEConv`` (models/rsage.py)
+RELATIONAL_SCOPES = ("rel_aggregate", "rel_transform", "norm")
 EITHER_WAY = r"jvp\({model_scope}\)|transpose\(jvp\({model_scope}\)\)"
 
 # (c): the top-level scope each new metric's pattern may reach into;
@@ -97,6 +100,10 @@ NEW_METRICS = {
     "attn_aggregate_device_ms": EITHER_WAY,
     "attn_skip_device_ms": EITHER_WAY,
     "attn_roofline": EITHER_WAY,
+    "rel_aggregate_device_ms": EITHER_WAY,
+    "rel_transform_device_ms": EITHER_WAY,
+    "norm_device_ms": EITHER_WAY,
+    "rel_roofline": EITHER_WAY,
 }
 # the scopes under ``conv{i}`` that each metric of the attention may read
 ATTENTION_METRICS = {
@@ -105,6 +112,14 @@ ATTENTION_METRICS = {
     "attn_aggregate_device_ms": {"attn_aggregate"},
     "attn_skip_device_ms": {"skip"},
     "attn_roofline": {"attn_logits", "attn_softmax", "attn_aggregate"},
+}
+# the scopes that each metric of the relational layers may read, under
+# ``conv{i}`` (and, for the batch norms, the head's ``mlp``)
+RELATIONAL_METRICS = {
+    "rel_aggregate_device_ms": {"rel_aggregate"},
+    "rel_transform_device_ms": {"rel_transform"},
+    "norm_device_ms": {"norm"},
+    "rel_roofline": {"rel_aggregate"},
 }
 
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%\S+ = .*?\s?([a-z][a-z0-9\-]*)\(")
@@ -120,9 +135,12 @@ def _tracing_disabled():
 
 
 def build(fanout, caps, data=1, feature=1, cell="products-sage.hbm",
-          **kwargs):
+          relations=0, **kwargs):
     ei = generate_pareto_graph(300, 6.0, seed=0)
     topo = quiver.CSRTopo(edge_index=ei)
+    if relations:
+        topo.set_edge_relation(np.random.default_rng(2).integers(
+            0, relations, topo.edge_count), coo_order=False)
     sampler = quiver.GraphSageSampler(
         topo, list(fanout), frontier_caps=list(caps), kernel="xla",
         dedup="scan")
@@ -140,6 +158,7 @@ def build(fanout, caps, data=1, feature=1, cell="products-sage.hbm",
     trainer = DistributedTrainer(
         mesh, sampler, store.from_cpu_tensor(rows),
         model_file(cell).build({"hidden": 8, "classes": 4, "heads": 2,
+                                "relations": relations,
                                 "layers": len(fanout), "dropout": 0.0}),
         optax.adam(1e-2), local_batch=BATCH,
         seed_sharding="all" if feature > 1 else "data", **kwargs)
@@ -218,6 +237,13 @@ def test_every_scope_of_the_tree_is_in_the_untraced_program(programs, cell):
         wanted += [rf"^{way}/conv{l}/{name}/"
                    for way in (r"jvp\(GAT\)", r"transpose\(jvp\(GAT\)\)")
                    for l in range(hops) for name in ATTENTION_SCOPES]
+    if scope == "RGraphSAGE":
+        ways = (r"jvp\(RGraphSAGE\)", r"transpose\(jvp\(RGraphSAGE\)\)")
+        # the input layer's rows are data: its means have no transpose
+        wanted += [rf"^{way}/conv{l}/{name}/" for way in ways
+                   for l in range(hops) for name in RELATIONAL_SCOPES
+                   if (l, name, way) != (0, "rel_aggregate", ways[1])]
+        wanted += [rf"^{way}/mlp/(norm/)?" for way in ways]
     if cell.endswith("clique2x2"):
         wanted += [r"^feature_gather/tier_hot/route_plan/",
                    r"^feature_gather/tier_hot/route_exchange/"]
@@ -334,6 +360,22 @@ def test_new_metric_patterns_read_their_scope_and_nothing_else(
         assert matched, (metric, cell)
         outside = [p for p in matched if not inside.match(p.split("/")[0])]
         assert not outside, (metric, cell, outside[:5])
+        if metric in RELATIONAL_METRICS:
+            # its own scopes alone, under every layer (the batch norms'
+            # under the head's too), forward and transposed
+            read = {tuple(p.split("/")[:3]) for p in matched}
+            assert {r[2] for r in read} <= RELATIONAL_METRICS[metric] | {
+                "norm"} and {r[2] for r in read if r[1] != "mlp"} == \
+                RELATIONAL_METRICS[metric], read
+            layers = {f"conv{l}" for l in range(len(CELLS[cell]["fanout"]))}
+            if metric == "norm_device_ms":
+                layers.add("mlp")
+            assert {r[1] for r in read if r[0] == "jvp(RGraphSAGE)"} == \
+                layers, read
+            # the input layer's rows are data: its means have no transpose
+            transposed = {r[1] for r in read
+                          if r[0] == "transpose(jvp(RGraphSAGE))"}
+            assert layers - {"conv0"} <= transposed <= layers, read
         if metric in ATTENTION_METRICS:
             # forward and transpose both, every layer, its own scopes alone
             read = {tuple(p.split("/")[:3]) for p in matched}
