@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..utils.trace import info_once
+
 __all__ = [
     "segment_mean_aggregate",
     "segment_softmax",
@@ -294,6 +296,79 @@ def fanout_sum_aggregate(messages, valid, num_dst: int, fanout: int):
     return m.reshape((num_dst, fanout) + messages.shape[1:]).sum(axis=1)
 
 
+# XLA's TPU row gather pads its index vector to whole 1,024-word tiles
+# itself; where that pad comes to under 128 words (none included) it keeps
+# 128 rows in flight, and 256 otherwise (CPU compiles for a described v5e)
+_INDEX_TILE, _SHORT_PAD = 1024, 128
+
+
+def _target_pad(num_dst: int, fanout: int) -> int:
+    """Targets to add to a fanout-major row gather of ``num_dst * fanout``
+    lanes so that it keeps 256 rows in flight: the fewest, a multiple of 8
+    (a ``num_dst`` in whole sublanes stays so), that leave the lanes 128 or
+    more short of whole 1,024-word tiles. 0 where they already are, or
+    where no multiple of 8 targets gets them there."""
+    for pad in range(0, _INDEX_TILE + 1, 8):
+        if -(fanout * (num_dst + pad)) % _INDEX_TILE >= _SHORT_PAD:
+            return pad
+    return 0
+
+
+def _fanout_index(src, num_dst: int, fanout: int, rows: int):
+    """``src`` as the index of its fanout-major row gather, ``(fanout,
+    num_dst + pad)``: the ``pad`` targets of :func:`_target_pad` behind the
+    block's, whose lanes name rows of their own (a lane's number in the
+    padded gather modulo ``rows``): lanes that all name one row are served
+    one after the other (PERF.md section 6, the sampler's block gather).
+    The caller sums the block's targets alone (:func:`_block_sums`)."""
+    idx = src.reshape(num_dst, fanout).T
+    pad = _target_pad(num_dst, fanout)
+    if not pad:
+        return idx
+    info_once(f"fanout-gather-pad-{num_dst}-{fanout}",
+              "fanout-major row gather of %d x %d lanes: %d padded targets, "
+              "so that it keeps 256 rows in flight", fanout, num_dst, pad)
+    lane = (lax.broadcasted_iota(idx.dtype, (fanout, pad), 0)
+            * (num_dst + pad) + num_dst
+            + lax.broadcasted_iota(idx.dtype, (fanout, pad), 1))
+    return jnp.concatenate([idx, lane % rows], axis=1)
+
+
+def _block_sums(rows, num_dst: int, dtype=None):
+    """``rows[:, :num_dst].sum(axis=0, dtype=dtype)``: the ``(fanout,
+    num_dst + pad, F)`` rows of a gather from :func:`_fanout_index` summed
+    over the block's targets alone."""
+    if rows.shape[1] == num_dst:
+        return rows.sum(axis=0, dtype=dtype)
+    return _padded_block_sums(rows, num_dst, dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _padded_block_sums(rows, num_dst: int, dtype):
+    """The slice is read inside the reduction: sliced off the sums, the
+    ``(num_dst, F)`` result is a slice and a copy of its own in front of a
+    product (``compiled.as_text()`` for a described v5e). Transposed, the
+    sums' cotangent is padded to the pad targets and broadcast over the
+    fanout, as the transpose of a slice of the sums would be; the plain
+    transpose pads the broadcast, an array of the gathered rows' size."""
+    return rows[:, :num_dst].sum(axis=0, dtype=dtype)
+
+
+def _padded_block_sums_fwd(rows, num_dst, dtype):
+    # a zero-size residual: the rows' shape and dtype, and no bytes
+    return _padded_block_sums(rows, num_dst, dtype), rows[..., :0]
+
+
+def _padded_block_sums_bwd(num_dst, dtype, like, cot):
+    fanout, targets = like.shape[:2]
+    cot = lax.pad(cot.astype(like.dtype), jnp.zeros((), like.dtype),
+                  [(0, targets - num_dst, 0)] + [(0, 0, 0)] * (cot.ndim - 1))
+    return (jnp.broadcast_to(cot, (fanout,) + cot.shape),)
+
+
+_padded_block_sums.defvjp(_padded_block_sums_fwd, _padded_block_sums_bwd)
+
+
 def fanout_gather_sum(x, src, num_dst: int, fanout: int):
     """Each target's sum of its sources' rows, gathered fanout-major.
 
@@ -315,11 +390,25 @@ def fanout_gather_sum(x, src, num_dst: int, fanout: int):
     padded to whole sublanes (5 -> 8, 10 -> 16), 3.1 + 1.6 ms of
     reddit-sage's 25.2 ms step (PERF.md, PR 36). Any other ``num_dst``
     gives the same sums; the view may then cost its copy again. The same
-    terms as the segment path, added in another order."""
-    idx = src.reshape(num_dst, fanout).T
+    terms as the segment path, added in another order.
+
+    **The lanes are padded where they fill whole 1,024-word tiles** or
+    fall short of them by under 128: XLA's TPU row gather then keeps 128
+    rows in flight, not 256. On a v5e that is 20.9 against 13.2 ns a
+    1.5 KB float16 row, and 10 against 4 ns for the sampler's 512-byte
+    blocks, which are padded for the same reason (PERF.md section 6;
+    ``ops/sample.py::_gather_indices``). The pad is a few targets behind
+    ``num_dst`` (:func:`_target_pad`, decided from the lane count at trace
+    time), so the sum still adds aligned slabs, and it reads the block's
+    targets' part of them alone (:func:`_block_sums`): no copy of the
+    gathered rows is made. A pad lane reads a row of its own and enters no
+    sum and no count: they are those of the unpadded gather, bit for
+    bit."""
+    idx = _fanout_index(src, num_dst, fanout, x.shape[0])
     valid = idx >= 0
     rows = jnp.where(valid[..., None], x[jnp.clip(idx, 0)], 0)
-    return rows.sum(axis=0), valid.sum(axis=0, dtype=jnp.int32)
+    return (_block_sums(rows, num_dst),
+            valid[:, :num_dst].sum(axis=0, dtype=jnp.int32))
 
 
 def fanout_relation_sums(x, src, relation, num_dst: int, fanout: int,
@@ -337,16 +426,22 @@ def fanout_relation_sums(x, src, relation, num_dst: int, fanout: int,
     The rows are gathered once, in the dtype ``x`` holds (float16 rows
     stay two bytes until they are summed), and each relation's sum adds
     the ``fanout`` slabs that its lanes select, widened to float32 inside
-    the sum: one pass over the gathered rows a relation."""
-    idx = src.reshape(num_dst, fanout).T
+    the sum: one pass over the gathered rows a relation. The lanes are
+    padded as :func:`fanout_gather_sum` pads them (mag240m-rsage's 26,624
+    x 15 fill whole tiles: 8 targets more keep 256 rows in flight, PERF.md
+    section 6); a pad lane carries no relation and enters no sum."""
+    idx = _fanout_index(src, num_dst, fanout, x.shape[0])
     rows = x[jnp.clip(idx, 0)]
     kinds = jnp.arange(num_relations, dtype=relation.dtype)
+    if idx.shape[1] > num_dst:
+        relation = lax.pad(relation, jnp.asarray(-1, relation.dtype),
+                           ((0, 0, 0), (0, idx.shape[1] - num_dst, 0)))
     picked = (relation[None] == kinds[:, None, None]) & (idx >= 0)[None]
     sums = tuple(
-        jnp.sum(jnp.where(picked[r][..., None], rows, 0), axis=0,
-                dtype=jnp.float32)
+        _block_sums(jnp.where(picked[r][..., None], rows, 0), num_dst,
+                    jnp.float32)
         for r in range(num_relations))
-    return sums, picked.sum(axis=1, dtype=jnp.int32)
+    return sums, picked[..., :num_dst].sum(axis=1, dtype=jnp.int32)
 
 
 def masked_batch_norm(x, valid, scale, bias, eps: float = 1e-5):
@@ -377,8 +472,6 @@ def gather_mean_aggregate(x, src, dst, num_dst: int,
         total, cnt = fanout_gather_sum(x, src, num_dst, fanout)
         return total / jnp.maximum(cnt, 1).astype(total.dtype)[:, None]
     if fanout is not None:
-        from ..utils.trace import info_once
-
         # the gate failed on SHAPE: fanout promised the dense layout but
         # E != num_dst*fanout, so this aggregation silently reverts to the
         # segment-scatter path — make the perf regression visible (ADVICE

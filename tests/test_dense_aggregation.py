@@ -128,6 +128,54 @@ def test_fanout_gather_sum_is_the_segment_sum(num_dst, fanout):
             np.asarray(g), np.asarray(want_grad), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("relations,dtype", [
+    (0, np.float32), (5, np.float32), (5, np.float16)])
+def test_padded_lanes_give_the_unpadded_sums_bit_for_bit(
+        relations, dtype, monkeypatch):
+    """128 targets x 8 fill one whole 1,024-word tile, so the lanes are
+    padded (``layers._target_pad``): the sums and the counts are the
+    unpadded gather's bit for bit (a pad lane enters no sum, and each
+    target's terms are added in the same order), the gradient of the rows
+    the same to float32 round-off; masked lanes, a target with none but
+    masked lanes, every relation present."""
+    from quiver_tpu.models import layers
+
+    num_dst, fanout, rows, width = 128, 8, 300, 12
+    assert layers._target_pad(num_dst, fanout) > 0
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(size=(rows, width)).astype(dtype))
+    src = _regular_block(num_dst, fanout, rows, seed=5)[0]
+    relation = jnp.where(
+        src.reshape(num_dst, fanout).T >= 0,
+        jnp.asarray(rng.integers(0, max(relations, 1), (fanout, num_dst))),
+        -1).astype(jnp.int32)
+    weight = jnp.asarray(
+        rng.normal(size=(max(relations, 1), num_dst, width)).astype(np.float32))
+
+    def scalar(x):
+        if relations:
+            sums, counts = layers.fanout_relation_sums(
+                x, src, relation, num_dst, fanout, relations)
+        else:
+            total, count = layers.fanout_gather_sum(x, src, num_dst, fanout)
+            sums, counts = (total,), count[None]
+        return sum((t * w).sum() for t, w in zip(sums, weight)), (
+            sums, counts)
+
+    (_, (sums, counts)), grad = jax.value_and_grad(scalar, has_aux=True)(x)
+    monkeypatch.setattr(layers, "_target_pad", lambda num_dst, fanout: 0)
+    (_, (want, want_counts)), want_grad = jax.value_and_grad(
+        scalar, has_aux=True)(x)
+    assert (np.asarray(want_counts) > 0).any(axis=1).all()
+    assert int(np.asarray(want_counts)[:, 1].sum()) == 0
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
+    for a, b in zip(sums, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(np.asarray(grad, np.float32),
+                               np.asarray(want_grad, np.float32),
+                               rtol=1e-6, atol=1e-6)
+
+
 @pytest.mark.parametrize("num_dst,fanout", FANOUT_BLOCKS)
 def test_sageconv_dense_path_is_the_segment_path(num_dst, fanout):
     """``SAGEConv`` with the block's fanout against the same block with
@@ -219,9 +267,10 @@ def one_chip():
 
 
 def _relayouts(compiled, elements):
-    """The ENTRY computation's ``copy`` / ``reshape`` / ``transpose``
-    instructions of ``elements`` elements or more: the ops that move an
-    array into another layout (a view is a ``bitcast``)."""
+    """The ENTRY computation's ``copy`` / ``reshape`` / ``transpose`` /
+    ``slice`` instructions of ``elements`` elements or more: the ops that
+    move an array into another layout or a part of it into one of its own
+    (a view is a ``bitcast``)."""
     import re
 
     text = compiled.as_text()
@@ -229,10 +278,23 @@ def _relayouts(compiled, elements):
     for line in text[text.index("\nENTRY "):].splitlines():
         m = re.match(
             r"\s+(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([a-z\-]+)\(", line)
-        if m and m.group(2) in ("copy", "reshape", "transpose"):
+        if m and m.group(2) in ("copy", "reshape", "transpose", "slice"):
             if np.prod([int(d) for d in m.group(1).split(",") if d]) >= elements:
                 found.append(line.strip()[:120])
     return found
+
+
+def _row_gathers(text):
+    """(rows in flight, lanes) of every row-gather fusion of a compiled
+    program: the ``"integer"`` of its backend config, and its output's
+    leading dimension"""
+    import re
+
+    return [(int(re.search(r'"integer":"(\d+)"', line).group(1)),
+             int(re.search(r"= \w+\[(\d+)", line).group(1)))
+            for line in text.splitlines()
+            if "kind=kCustom" in line and "/gather" in line
+            and '"integer"' in line]
 
 
 @pytest.mark.parametrize(
@@ -268,6 +330,89 @@ def test_a_v5e_gets_the_fanout_major_rows_without_a_copy(
 
     assert relayouts(fanout_major) == []
     assert len(relayouts(lane_order)) >= 2
+
+
+def _parent_gather_sum(x, src, num_dst, fanout):
+    """``fanout_gather_sum`` as it was before its lanes were padded"""
+    idx = src.reshape(num_dst, fanout).T
+    valid = idx >= 0
+    rows = jnp.where(valid[..., None], x[jnp.clip(idx, 0)], 0)
+    return rows.sum(axis=0), valid.sum(axis=0, dtype=jnp.int32)
+
+
+# (table rows, targets, fanout, width, dtype, relations) of a cell's layer:
+# reddit-sage's and mag240m-rsage's conv0 fill whole 1,024-word tiles,
+# products-sage's does not; a conv2's 1,024 x 15 lanes over a hidden
+# layer's rows, differentiated (a table this size stays in HBM)
+V5E_GATHERS = {
+    "reddit conv0": (195_328, 30_208, 10, 602, jnp.float32, 0),
+    "mag240m conv0": (425_984, 26_624, 15, 768, jnp.float16, 5),
+    "products conv0": (672_384, 142_080, 5, 100, jnp.float32, 0),
+    "conv2": (163_840, 1_024, 15, 256, jnp.float32, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(V5E_GATHERS))
+def test_a_v5e_gathers_the_fanout_major_rows_256_in_flight(
+        one_chip, case, monkeypatch):
+    """``fanout_gather_sum`` / ``fanout_relation_sums`` at a cell's shapes,
+    value and gradient compiled for a described v5e: the one row gather
+    keeps 256 rows in flight, over the lanes padded by
+    ``layers._target_pad`` (which says so once), no instruction copies,
+    re-lays out or slices an array of the gathered rows' size, and the
+    temporaries are the unpadded program's to 2 % of the gathered rows'
+    bytes. Unpadded, the lanes that fill whole 1,024-word tiles keep 128
+    in flight (10 ns a row against 4 on the chip: PERF.md section 6), which
+    also shows that the check can see it; products' lanes are not padded
+    and lower as before. Nothing runs; this says nothing of results or
+    times."""
+    from quiver_tpu.models import layers
+
+    rows, num_dst, fanout, width, dtype, relations = V5E_GATHERS[case]
+    logged = []
+    monkeypatch.setattr(
+        layers, "info_once", lambda key, msg, *args: logged.append(args))
+
+    def loss(aggregate):
+        def scalar(x, src, kernel, relation):
+            if relations:
+                sums, counts = layers.fanout_relation_sums(
+                    x, src, relation, num_dst, fanout, relations)
+            else:
+                total, count = aggregate(x, src, num_dst, fanout)
+                sums, counts = (total,), count[None]
+            return sum(((t / jnp.maximum(c, 1)[:, None]) @ k).sum()
+                       for t, c, k in zip(sums, counts, kernel))
+        return scalar
+
+    shapes = [((rows, width), dtype), ((num_dst * fanout,), jnp.int32),
+              ((max(relations, 1), width, 16), jnp.float32),
+              ((fanout, num_dst), jnp.int32)]
+    # the input layer's rows are data; a hidden layer's are differentiated
+    argnums = (0, 2) if case == "conv2" else 2
+
+    def lowered(aggregate=layers.fanout_gather_sum):
+        return jax.jit(jax.value_and_grad(loss(aggregate), argnums)).lower(*[
+            jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+            for shape, dt in shapes])
+
+    compiled = lowered().compile()
+    traced = list(logged)
+    pad = layers._target_pad(num_dst, fanout)
+    lanes = fanout * (num_dst + pad)
+    assert traced == ([(fanout, num_dst, pad)] if pad else [])
+    assert _row_gathers(compiled.as_text()) == [(256, lanes)]
+    assert _relayouts(compiled, lanes * width) == []
+    if not pad:
+        assert lowered().as_text() == lowered(_parent_gather_sum).as_text()
+
+    monkeypatch.setattr(layers, "_target_pad", lambda num_dst, fanout: 0)
+    plain = lowered().compile()
+    gathered = lanes * width * jnp.dtype(dtype).itemsize
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            - plain.memory_analysis().temp_size_in_bytes) < 0.02 * gathered
+    if pad:
+        assert _row_gathers(plain.as_text()) == [(128, fanout * num_dst)]
 
 
 @pytest.mark.parametrize("nodes,edges,targets,fanout", [
