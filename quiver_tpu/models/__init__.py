@@ -16,6 +16,7 @@ from .inference import (
     rgcn_layerwise_inference,
     sage_layerwise_inference,
 )
+from .rgat import RGAT
 from .rgcn import RGCN
 from .sage import GraphSAGE, SAGEConv
 
@@ -26,6 +27,7 @@ __all__ = [
     "GIN",
     "GINConv",
     "GraphSAGE",
+    "RGAT",
     "RGCN",
     "SAGEConv",
     "full_neighbor_mean",

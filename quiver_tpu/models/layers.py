@@ -55,6 +55,7 @@ __all__ = [
     "fanout_sum_aggregate",
     "fanout_gather_sum",
     "fanout_relation_sums",
+    "fanout_relation_softmax",
     "masked_batch_norm",
     "gather_mean_aggregate",
     "gather_src",
@@ -442,6 +443,40 @@ def fanout_relation_sums(x, src, relation, num_dst: int, fanout: int,
                     jnp.float32)
         for r in range(num_relations))
     return sums, picked[..., :num_dst].sum(axis=1, dtype=jnp.int32)
+
+
+def fanout_relation_softmax(logits, relation, num_relations: int):
+    """Softmax over the fanout lanes of each target, one group per target,
+    relation and head, with no self term.
+
+    ``logits`` is ``(heads, fanout, targets)`` (lanes-minor: a heads-minor
+    ``(..., 4)`` array is padded to 128 lanes on a TPU) and
+    ``relation`` ``(fanout, targets)``, each lane's relation or -1 where
+    the lane is in no group (a padded lane). Returns the weights
+    ``(heads, fanout, targets)``: over the lanes of relation ``r`` of target
+    ``t`` they sum to 1 per head, and they are 0 on lanes in no group, so a
+    group with no lane gives a zero message and not NaN or a uniform weight.
+
+    Each group's max is taken over its own lanes and held out of the
+    gradient (the weights do not depend on it); a lane's max and
+    denominator are selected from the ``num_relations`` groups of its
+    target by its relation. No scatter, and no array of more than
+    ``relations x heads x lanes`` values."""
+    kinds = jnp.arange(num_relations, dtype=relation.dtype)
+    picked = relation[None] == kinds[:, None, None]      # (R, K, T)
+    valid = relation >= 0
+    neg = jnp.finfo(logits.dtype).min
+    group_max = lax.stop_gradient(jnp.where(
+        picked[:, None], logits[None], neg).max(axis=2))  # (R, H, T)
+
+    def by_lane(per_group):                              # (R, H, T) -> (H, K, T)
+        return sum(jnp.where(picked[r], per_group[r][:, None], 0)
+                   for r in range(num_relations))
+
+    expv = jnp.where(valid, jnp.exp(jnp.where(
+        valid, logits - by_lane(group_max), 0)), 0)
+    denom = jnp.where(picked[:, None], expv[None], 0).sum(axis=2)
+    return expv / jnp.where(valid, by_lane(denom), 1)
 
 
 def masked_batch_norm(x, valid, scale, bias, eps: float = 1e-5):

@@ -35,14 +35,16 @@ import flax.linen as nn
 from ..utils.trace import trace_scope
 from .layers import fanout_relation_sums, masked_batch_norm
 
-__all__ = ["RelSAGEConv", "RGraphSAGE"]
+__all__ = ["RelSAGEConv", "RGraphSAGE", "BatchNorm", "mlp_head",
+           "valid_targets"]
 
 
-def _valid_targets(adj, num_dst: int):
+def valid_targets(adj, num_dst: int):
+    """Which of a block's ``num_dst`` target slots hold a node."""
     if adj.dst_count is None:
         raise ValueError(
-            "RGraphSAGE needs Adj.dst_count: sample over a topology with "
-            "edge relations (CSRTopo.set_edge_relation)")
+            "a relational model needs Adj.dst_count: sample over a topology "
+            "with edge relations (CSRTopo.set_edge_relation)")
     return jnp.arange(num_dst) < adj.dst_count
 
 
@@ -93,7 +95,7 @@ class RelSAGEConv(nn.Module):
         # what the batch norm is given (a bias shared by every target is
         # cancelled by it): ``apply(..., mutable="intermediates")`` reads it
         self.sow("intermediates", "combined", out)
-        out = BatchNorm(name="norm")(out, _valid_targets(adj, num_dst))
+        out = BatchNorm(name="norm")(out, valid_targets(adj, num_dst))
         return nn.relu(out)
 
 
@@ -118,10 +120,19 @@ class RGraphSAGE(nn.Module):
             x = RelSAGEConv(self.hidden, self.num_relations,
                             name=f"conv{i}")(x, adj)
             x = nn.Dropout(self.dropout, deterministic=not train)(x)
-        seeds = _valid_targets(adjs[-1], x.shape[0])
-        with trace_scope("mlp"):
-            x = nn.Dense(self.hidden, name="lin0")(x)
-            x = nn.relu(BatchNorm(name="norm")(x, seeds))
-            x = nn.Dropout(self.dropout, deterministic=not train)(x)
-            x = nn.Dense(self.num_classes, name="lin1")(x)
-        return nn.log_softmax(x, axis=-1)
+        return mlp_head(x, valid_targets(adjs[-1], x.shape[0]), self.hidden,
+                        self.num_classes, self.dropout, train)
+
+
+def mlp_head(x, seeds, hidden: int, num_classes: int, dropout: float,
+             train: bool):
+    """The head of rgnn.py's models, called inside the model's compact
+    ``__call__`` (its layers are the model's ``lin0``, ``norm``, ``lin1``):
+    ``Linear -> BatchNorm over the seeds -> ReLU -> Linear``, then
+    log-probabilities, under the scope ``mlp``."""
+    with trace_scope("mlp"):
+        x = nn.Dense(hidden, name="lin0")(x)
+        x = nn.relu(BatchNorm(name="norm")(x, seeds))
+        x = nn.Dropout(dropout, deterministic=not train)(x)
+        x = nn.Dense(num_classes, name="lin1")(x)
+    return nn.log_softmax(x, axis=-1)

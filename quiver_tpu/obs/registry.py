@@ -52,6 +52,7 @@ __all__ = [
     "SAMPLE_FRONTIER",
     "SAMPLE_FRONTIER_OVERFLOW",
     "SAMPLE_RELATION_LANES",
+    "SAMPLE_RELATION_TARGETS",
     "HETERO_SAMPLE_OVERFLOW",
     "GUARD_SKIPPED",
     "GUARD_NONFINITE",
@@ -105,6 +106,9 @@ SAMPLE_FRONTIER_OVERFLOW = "sample.frontier_overflow"
 # over a topology with edge relations: valid sampled lanes per hop
 # (seeds-outward) and relation, mesh totals per step
 SAMPLE_RELATION_LANES = "sample.relation_lanes"
+# and the valid targets with at least one valid lane of each relation: the
+# groups a softmax per target and relation runs over that are not empty
+SAMPLE_RELATION_TARGETS = "sample.relation_targets"
 # per-(hop, edge-type) routed-overflow lanes of the distributed hetero
 # sampler (flat vector in the sampler's static slot order; relations
 # sharing a destination type share that hop's route plan, so they report

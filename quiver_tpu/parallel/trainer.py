@@ -54,6 +54,7 @@ from ..obs.registry import (
     SAMPLE_FRONTIER_OVERFLOW,
     SAMPLE_OVERFLOW,
     SAMPLE_RELATION_LANES,
+    SAMPLE_RELATION_TARGETS,
     TIER_HITS,
     TRAIN_OVERLAP_EFFICIENCY,
     XLA_CACHE_HITS,
@@ -318,8 +319,8 @@ class DistributedTrainer:
         # ``trainer.metrics.value(name)``: sample.edges, sample.frontier
         # (int32 (num_layers,), seeds-outward) and
         # sample.frontier_overflow (scalar), on every topology;
-        # sample.relation_lanes (int32 (num_layers, relations)) over a
-        # topology with edge relations.
+        # sample.relation_lanes and sample.relation_targets (int32
+        # (num_layers, relations)) over a topology with edge relations.
         # collect_metrics=False disables collection at the PROGRAM level:
         # the compiled step carries zero metric values/collectives and the
         # loss trajectory is bit-identical (tests/test_obs.py differential).
@@ -367,6 +368,12 @@ class DistributedTrainer:
                 unit="lanes",
                 doc="mesh-total valid sampled lanes per hop (seeds-outward) "
                     "and edge relation",
+            )
+            self.metrics.counter(
+                SAMPLE_RELATION_TARGETS, shape=hops + (relations,),
+                unit="targets",
+                doc="mesh-total valid targets per hop (seeds-outward) with "
+                    "at least one valid lane of each edge relation",
             )
         # resilience (resilience/): nonfinite_guard=True compiles the
         # non-finite step guard into the step body — a NaN/Inf loss or
@@ -974,10 +981,14 @@ class DistributedTrainer:
                     # the lanes' relations as the model gets them (-1 on
                     # invalid lanes), counted per hop, seeds-outward
                     kinds = jnp.arange(relations, dtype=jnp.int8)
+                    carried = [a.relation[..., None] == kinds
+                               for a in adjs[::-1]]
                     tele["relation_lanes"] = jnp.stack([
-                        (a.relation[..., None] == kinds).sum(
-                            axis=(0, 1), dtype=jnp.int32)
-                        for a in adjs[::-1]])
+                        c.sum(axis=(0, 1), dtype=jnp.int32) for c in carried])
+                    # a padded target's lanes carry none
+                    tele["relation_targets"] = jnp.stack([
+                        c.any(axis=0).sum(axis=0, dtype=jnp.int32)
+                        for c in carried])
             return n_id, x, adjs, num_seeds, tele
 
         def train_block(params, n_id, x, adjs, num_seeds, labels, key,
@@ -1040,7 +1051,8 @@ class DistributedTrainer:
             ROUTED_OVERFLOW, TIER_HITS, SAMPLE_OVERFLOW, SAMPLE_EDGES,
             SAMPLE_FRONTIER, SAMPLE_FRONTIER_OVERFLOW,
         ) + ((FEATURE_ROW_HEAT,) if heat_on else ()) + (
-            (SAMPLE_RELATION_LANES,) if relations else ())
+            (SAMPLE_RELATION_LANES, SAMPLE_RELATION_TARGETS)
+            if relations else ())
         train_names = (GUARD_SKIPPED, GUARD_NONFINITE) if guard else ()
         program_names = issue_names + train_names
         axes = (DATA_AXIS, FEATURE_AXIS)
@@ -1088,6 +1100,8 @@ class DistributedTrainer:
                      psum=lane_axes)
             if relations:
                 tape.add(SAMPLE_RELATION_LANES, tele["relation_lanes"],
+                         psum=lane_axes)
+                tape.add(SAMPLE_RELATION_TARGETS, tele["relation_targets"],
                          psum=lane_axes)
 
         def allreduce_update(params, opt_state, blocks):

@@ -237,9 +237,9 @@ def test_the_model_agrees_with_the_plain_reference(seed):
 
 # -- the benchmark's cell, by its files --------------------------------------
 
-def cell_run(monkeypatch, seed=11):
+def cell_run(monkeypatch, seed=11, cell=CELL):
     monkeypatch.setattr(spec, "load_config", tiny.tiny_config)
-    args = argparse.Namespace(workload=CELL, seed=seed, seconds=0.5, trace=0)
+    args = argparse.Namespace(workload=cell, seed=seed, seconds=0.5, trace=0)
     return harness.run(args, jax.devices()[:1], harness.CompileMeter(),
                        time.perf_counter(), {})
 

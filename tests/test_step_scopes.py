@@ -38,6 +38,7 @@ CELLS = {
         fanout=[3, 2, 2], caps=[32, 64, 128], data=2, feature=2),
     "products-gat.hbm": dict(fanout=[3, 2, 2], caps=[32, 64, 128]),
     "mag240m-rsage.hbm": dict(fanout=[3, 2], caps=[32, 64], relations=5),
+    "mag240m-rgat.hbm": dict(fanout=[3, 2], caps=[32, 64], relations=5),
 }
 
 
@@ -77,6 +78,9 @@ ATTENTION_SCOPES = ("attn_project", "attn_logits", "attn_softmax",
                     "attn_aggregate", "skip")
 # and those of ``RelSAGEConv`` (models/rsage.py)
 RELATIONAL_SCOPES = ("rel_aggregate", "rel_transform", "norm")
+# and those of ``RelGATConv`` (models/rgat.py)
+RGAT_SCOPES = ("rgat_aggregate", "rgat_logits", "rgat_softmax",
+               "rgat_transform", "norm")
 EITHER_WAY = r"jvp\({model_scope}\)|transpose\(jvp\({model_scope}\)\)"
 
 # (c): the top-level scope each new metric's pattern may reach into;
@@ -104,6 +108,11 @@ NEW_METRICS = {
     "rel_transform_device_ms": EITHER_WAY,
     "norm_device_ms": EITHER_WAY,
     "rel_roofline": EITHER_WAY,
+    "rgat_aggregate_device_ms": EITHER_WAY,
+    "rgat_logits_device_ms": EITHER_WAY,
+    "rgat_softmax_device_ms": EITHER_WAY,
+    "rgat_transform_device_ms": EITHER_WAY,
+    "rgat_roofline": EITHER_WAY,
 }
 # the scopes under ``conv{i}`` that each metric of the attention may read
 ATTENTION_METRICS = {
@@ -120,6 +129,14 @@ RELATIONAL_METRICS = {
     "rel_transform_device_ms": {"rel_transform"},
     "norm_device_ms": {"norm"},
     "rel_roofline": {"rel_aggregate"},
+}
+# and those of the relational attention, under ``conv{i}``
+RGAT_METRICS = {
+    "rgat_aggregate_device_ms": {"rgat_aggregate"},
+    "rgat_logits_device_ms": {"rgat_logits"},
+    "rgat_softmax_device_ms": {"rgat_softmax"},
+    "rgat_transform_device_ms": {"rgat_transform"},
+    "rgat_roofline": {"rgat_aggregate", "rgat_logits", "rgat_softmax"},
 }
 
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%\S+ = .*?\s?([a-z][a-z0-9\-]*)\(")
@@ -243,6 +260,11 @@ def test_every_scope_of_the_tree_is_in_the_untraced_program(programs, cell):
         wanted += [rf"^{way}/conv{l}/{name}/" for way in ways
                    for l in range(hops) for name in RELATIONAL_SCOPES
                    if (l, name, way) != (0, "rel_aggregate", ways[1])]
+        wanted += [rf"^{way}/mlp/(norm/)?" for way in ways]
+    if scope == "RGAT":
+        ways = (r"jvp\(RGAT\)", r"transpose\(jvp\(RGAT\)\)")
+        wanted += [rf"^{way}/conv{l}/{name}/" for way in ways
+                   for l in range(hops) for name in RGAT_SCOPES]
         wanted += [rf"^{way}/mlp/(norm/)?" for way in ways]
     if cell.endswith("clique2x2"):
         wanted += [r"^feature_gather/tier_hot/route_plan/",
@@ -376,6 +398,13 @@ def test_new_metric_patterns_read_their_scope_and_nothing_else(
             transposed = {r[1] for r in read
                           if r[0] == "transpose(jvp(RGraphSAGE))"}
             assert layers - {"conv0"} <= transposed <= layers, read
+        if metric in RGAT_METRICS:
+            # forward and transpose both, every layer, its own scopes alone
+            read = {tuple(p.split("/")[:3]) for p in matched}
+            assert {r[2] for r in read} == RGAT_METRICS[metric], read
+            assert {r[:2] for r in read} == {
+                (way, f"conv{l}") for l in range(len(CELLS[cell]["fanout"]))
+                for way in ("jvp(RGAT)", "transpose(jvp(RGAT))")}, read
         if metric in ATTENTION_METRICS:
             # forward and transpose both, every layer, its own scopes alone
             read = {tuple(p.split("/")[:3]) for p in matched}
