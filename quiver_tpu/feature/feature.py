@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from ..core.config import CachePolicy, parse_size_bytes
 from ..core.memory import to_pinned_host
 from ..core.topology import CSRTopo
+from ..ops.gather import padded_row_gather
 from ..ops.sample import staged_gather
 from ..utils.reorder import reorder_by_degree
 from ..utils.trace import get_logger, info_once, trace_scope
@@ -355,9 +356,14 @@ class Feature:
     def __getitem__(self, n_id):
         """Gather rows for (possibly padded, -1 sentinel) node ids.
 
-        Jit-composable; invalid lanes return zero rows.
+        Jit-composable; invalid lanes return zero rows. The hot tier's row
+        gather pads its lanes so that it keeps 256 rows in flight
+        (``ops.gather.padded_row_gather``; PERF.md section 7 (3)).
         """
-        hot_gather = None if self.hot is None else lambda ids: self.hot[ids]
+        hot_gather = (
+            None if self.hot is None
+            else lambda ids: padded_row_gather(self.hot, ids)
+        )
         cold_gather = (
             None
             if self.cold is None

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.gather import tile_pad
 from ..utils.trace import info_once
 
 __all__ = [
@@ -297,31 +298,20 @@ def fanout_sum_aggregate(messages, valid, num_dst: int, fanout: int):
     return m.reshape((num_dst, fanout) + messages.shape[1:]).sum(axis=1)
 
 
-# XLA's TPU row gather pads its index vector to whole 1,024-word tiles
-# itself; where that pad comes to under 128 words (none included) it keeps
-# 128 rows in flight, and 256 otherwise (CPU compiles for a described v5e)
-_INDEX_TILE, _SHORT_PAD = 1024, 128
-
-
-def _target_pad(num_dst: int, fanout: int) -> int:
-    """Targets to add to a fanout-major row gather of ``num_dst * fanout``
-    lanes so that it keeps 256 rows in flight: the fewest, a multiple of 8
-    (a ``num_dst`` in whole sublanes stays so), that leave the lanes 128 or
-    more short of whole 1,024-word tiles. 0 where they already are, or
-    where no multiple of 8 targets gets them there."""
-    for pad in range(0, _INDEX_TILE + 1, 8):
-        if -(fanout * (num_dst + pad)) % _INDEX_TILE >= _SHORT_PAD:
-            return pad
-    return 0
+# targets of ``fanout`` lanes to add to a fanout-major row gather so that it
+# keeps 256 rows in flight (PERF.md section 7 (3))
+_target_pad = tile_pad
 
 
 def _fanout_index(src, num_dst: int, fanout: int, rows: int):
     """``src`` as the index of its fanout-major row gather, ``(fanout,
-    num_dst + pad)``: the ``pad`` targets of :func:`_target_pad` behind the
-    block's, whose lanes name rows of their own (a lane's number in the
-    padded gather modulo ``rows``): lanes that all name one row are served
-    one after the other (PERF.md section 6, the sampler's block gather).
-    The caller sums the block's targets alone (:func:`_block_sums`)."""
+    num_dst + pad)``, padded so that the gather keeps 256 rows in flight
+    (PERF.md section 7 (3)): the ``pad`` targets of :func:`_target_pad`
+    behind the block's, whose lanes name rows of their own (a lane's number
+    in the padded gather modulo ``rows``): lanes that all name one row are
+    served one after the other (PERF.md section 6, the sampler's block
+    gather). The caller sums the block's targets alone
+    (:func:`_block_sums`)."""
     idx = src.reshape(num_dst, fanout).T
     pad = _target_pad(num_dst, fanout)
     if not pad:

@@ -87,6 +87,7 @@ __all__ = [
     "XLA_COMPILES",
     "XLA_COMPILE_SECONDS",
     "XLA_CACHE_HITS",
+    "GATHER_PAD_LANES",
 ]
 
 # well-known metric names — the three streams the registry was distilled
@@ -202,6 +203,10 @@ RECORDER_EVENTS = "recorder.events"
 XLA_COMPILES = "xla.compiles"
 XLA_COMPILE_SECONDS = "xla.compile_seconds"
 XLA_CACHE_HITS = "xla.cache_hits"
+# host-side, set when the fused step is traced: the lanes added to its plain
+# hot-tier row gather so that it keeps 256 rows in flight (ops/gather.py);
+# never an output of the step
+GATHER_PAD_LANES = "feature.gather_pad_lanes"
 
 _KINDS = ("counter", "gauge")
 

@@ -45,6 +45,7 @@ from ..control.freq import heat_num_bins, row_heat_histogram
 from ..obs.compile_watch import CompileTotals, compile_watch
 from ..obs.registry import (
     FEATURE_ROW_HEAT,
+    GATHER_PAD_LANES,
     GUARD_NONFINITE,
     GUARD_SKIPPED,
     PIPELINE_REISSUES,
@@ -330,6 +331,12 @@ class DistributedTrainer:
             ROUTED_OVERFLOW, unit="lanes",
             doc="capped-bucket fallback-served lanes of the step's sharded "
                 "feature gather",
+        )
+        self.metrics.counter(
+            GATHER_PAD_LANES, unit="lanes",
+            doc="lanes added to the step's plain hot-tier row gather so "
+                "that it keeps 256 rows in flight (host-side, set when the "
+                "step is traced)",
         )
         self.metrics.gauge(
             TIER_HITS, shape=(3,), unit="hits",
@@ -868,6 +875,7 @@ class DistributedTrainer:
             gathers), tier_hits the local int32 (3,) per-tier hit vector
             (the step body psums it mesh-wide)."""
             from ..feature.feature import tiered_lookup, wrap_dequant_gathers
+            from ..ops.gather import padded_row_gather, tile_pad
             from ..ops.sample import staged_gather
 
             rep_table, hot_table, cold_table, order, scale = parts
@@ -877,6 +885,7 @@ class DistributedTrainer:
             rep_rows = feature.rep_rows if sharded else 0
             hot_rows = feature.hot_rows
             ov_box = [jnp.zeros((), jnp.int32)]
+            pad_lanes = 0
             rep_g = (
                 None if rep_table is None
                 else lambda ids: rep_table[ids]
@@ -905,7 +914,11 @@ class DistributedTrainer:
                     feature.hot.local_gather(hot_table, ids), feature.hot.axis
                 )
             else:
-                hot_g = lambda ids: hot_table[ids]
+                # padded so that the gather keeps 256 rows in flight
+                # (PERF.md section 7 (3))
+                pad_lanes = tile_pad(n_id.shape[0])
+                hot_g = lambda ids: padded_row_gather(hot_table, ids)
+            metrics.set(GATHER_PAD_LANES, np.int32(pad_lanes))
             cold_g = (
                 None if cold_table is None
                 else lambda ids: staged_gather(cold_table, ids, cold_is_host)

@@ -247,7 +247,7 @@ def _routed_gather():
     "trainer step over an int8-quantized ShardedFeature: the three-tier "
     "lookup with int8 codes riding the routed all_to_all",
     sources=("quiver_tpu/feature/shard.py", "quiver_tpu/feature/feature.py",
-             "quiver_tpu/parallel/trainer.py"),
+             "quiver_tpu/parallel/trainer.py", "quiver_tpu/ops/gather.py"),
     meta={"int8_path": True, "hbm_budget": 40 * 1024},
 )
 def _tiered_lookup_int8():
@@ -342,7 +342,8 @@ def _epoch_donating():
     "serving-ladder forward program (largest bucket): AOT ladder rung the "
     "steady-state replay contract is staked on",
     sources=("quiver_tpu/serving/ladder.py", "quiver_tpu/models/sage.py",
-             "quiver_tpu/models/layers.py", "quiver_tpu/parallel/train.py"),
+             "quiver_tpu/models/layers.py", "quiver_tpu/ops/gather.py",
+             "quiver_tpu/parallel/train.py"),
     meta={"donation": "none", "hbm_budget": 24 * 1024},
 )
 def _serve_forward():

@@ -470,6 +470,62 @@ def test_a_v5e_reads_the_neighbour_ids_as_rows_256_in_flight(
     assert row_gathers(plain.as_text()) == [expected]
 
 
+# (table rows, width, dtype) of each one-chip cell's store, by the lanes of
+# its frontier: mag240m's fill whole 1,024-word tiles; products-sage's,
+# reddit-sage's and products-gat's fall short of them by 128 words or more
+V5E_STORES = {
+    425_984: (3_815_008, 768, jnp.float16),   # mag240m-rsage, mag240m-rgat
+    672_384: (2_449_029, 100, jnp.float32),   # products-sage
+    195_328: (232_965, 602, jnp.float32),     # reddit-sage
+    447_104: (2_449_029, 100, jnp.float32),   # products-gat
+}
+
+
+@pytest.mark.parametrize("lanes", list(V5E_STORES))
+def test_a_v5e_gathers_the_store_rows_256_in_flight(
+        one_chip, lanes, monkeypatch):
+    """``tiered_lookup`` over a plain hot tier, as ``Feature[...]`` and the
+    fused step call it, compiled for a described v5e at a cell's lanes: the
+    one ``tier_hot`` row gather keeps 256 rows in flight over the lanes
+    padded by ``ops.gather.tile_pad`` (which says so once), and no copy or
+    slice of the padded rows stands outside a fusion; the padded rows are
+    the one temporary of the lone lookup (in a cell's whole step they fit
+    under its peak: PERF.md section 6). Unpadded, mag's lanes keep 128 in flight (PERF.md
+    section 7 (3)), which also shows that the check can see it. Lanes that
+    take no pad lower to the unpadded gather's text. Nothing runs; this
+    says nothing of results or times."""
+    from quiver_tpu.feature.feature import tiered_lookup
+    from quiver_tpu.ops import gather
+
+    rows, width, dtype = V5E_STORES[lanes]
+    logged = []
+    monkeypatch.setattr(
+        gather, "info_once", lambda key, msg, *args: logged.append(args))
+
+    def lowered(hot_gather):
+        return jax.jit(lambda table, n_id: tiered_lookup(
+            n_id, None, rows, lambda ids: hot_gather(table, ids), None,
+        )).lower(jax.ShapeDtypeStruct((rows, width), dtype, sharding=one_chip),
+                 jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one_chip))
+
+    def plain(table, ids):
+        return table[ids]
+
+    padded = lowered(gather.padded_row_gather)
+    pad = gather.tile_pad(lanes)
+    if not pad:
+        assert logged == []
+        assert padded.as_text() == lowered(plain).as_text()
+        return
+    assert logged == [(lanes, pad)]
+    compiled = padded.compile()
+    assert _row_gathers(compiled.as_text()) == [(256, lanes + pad)]
+    assert _relayouts(compiled, lanes * width) == []
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 1.01 * (lanes + pad) * width * jnp.dtype(dtype).itemsize)
+    assert _row_gathers(lowered(plain).compile().as_text()) == [(128, lanes)]
+
+
 def test_fanout_softmax_matches_segment_softmax():
     """The dense softmax over each target's ``fanout`` lanes and its self
     term against the segment softmax over the same lanes with one self
