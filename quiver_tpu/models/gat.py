@@ -38,12 +38,25 @@ for the gather); the source half ``s_j`` of a lane's logit is the sum over
 ``F`` of that row times ``a_src``, the target half ``d_i`` is formed for the
 targets' rows alone and broadcast over the fanout axis (a lane's target is
 ``lane // fanout``); the same gathered rows are then weighted and summed
-(``attn_aggregate`` again). So a layer has one gather, of rows: no
-``(lanes, H)`` array is gathered or scattered (on a v5e the transposed
-gather of those 16-byte lanes cost 29 ms of a 158 ms step: PERF.md, PR 33),
-and the backward's broadcast over the fanout axis fuses into its users. The
-segment path keeps the per-node halves ``project`` returns and gathers them
-by lane.
+(``attn_aggregate`` again).
+
+**The per-lane scalars on the dense fanout path are lanes-minor**, for
+R-GAT's reason (``layers.fanout_relation_softmax``): the lanes' logits, the
+softmax's weights and, in the backward, their cotangents are ``(heads,
+fanout, targets)`` arrays and the targets' terms ``(heads, targets)``, since
+a heads-minor ``(targets, fanout, 4)`` array is padded to 128 lanes on a TPU
+(425 MiB a pass for 8.7 MB of values at ogbn-products' first layer, eighteen
+such passes 12.3 ms of a 68 ms step on a v5e: PERF.md). The lanes' logit
+sums over ``F`` are a product with ``a_src``'s block-diagonal at ``highest``
+(the MXU writes them lanes-minor; as a multiply and a reduce, XLA writes
+them heads-minor whatever their logical order), the targets' ``fanout``
+times fewer stay a multiply and a reduce, and the weights go into the rows
+as a broadcast of the rows' ``(H, K, T, F)`` transpose. So a layer has one
+gather, of rows: no ``(lanes, H)`` array is gathered or scattered (on a v5e
+the transposed gather of those 16-byte lanes cost 29 ms of a 158 ms step:
+PERF.md), and the backward's broadcast over the fanout axis fuses
+into its users. The segment path keeps the per-node halves ``project``
+returns and gathers them by lane.
 
 **The row gather's transpose on the dense fanout path** is a gather as well
 (``layers.gather_lane_rows``; as one scatter-add of every lane's 2 KB
@@ -74,6 +87,27 @@ from .layers import (
     fanout_softmax, fanout_sum_aggregate, gather_lane_rows, segment_softmax)
 
 __all__ = ["GATConv", "GAT"]
+
+
+def _lane_logits(rows, att):
+    """``<att[h], rows[t, k, h, :]>`` of ``(T, K, H, F)`` rows, lanes-minor:
+    ``(H, K, T)``. A product with ``att``'s block-diagonal ``(H, H, F)`` at
+    ``highest``, so that the MXU writes the sums lanes-minor and the passes
+    that use them keep that layout; as a multiply and a reduce over ``F``
+    XLA writes them heads-minor (module docstring). The same sums in
+    another order."""
+    H = att.shape[0]
+    return jnp.einsum("tkhf,ghf->gkt", rows,
+                      jnp.eye(H, dtype=att.dtype)[:, :, None] * att,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _weighted(rows, scalars):
+    """``(T, K, H, F)`` rows times ``(H, K, T)`` scalars, ``(T, H, F)`` rows
+    times ``(H, T)``: the scalars broadcast over ``F`` and transposed as 4-D
+    (or 3-D) arrays, so that no heads-minor array of them is formed."""
+    return rows * jnp.swapaxes(jnp.broadcast_to(
+        scalars[..., None], scalars.shape + rows.shape[-1:]), 0, -2)
 
 
 class GATConv(nn.Module):
@@ -173,17 +207,21 @@ class GATConv(nn.Module):
                 # projection is free once the lanes' rows are gathered and
                 # does not stay live through the backward for its head
                 h_dst = jax.lax.optimization_barrier(h_dst)
-                # the product is taken in the gather's scope: its transpose
-                # is the rows' second cotangent, and the sum of the two is
-                # then this scope's op, as the transposed gather it feeds is
-                zl = zg * self.att_l
+                # every per-lane and per-target scalar is lanes-minor from
+                # here on: (H, K, T) and (H, T) (module docstring). The
+                # lanes' product is taken in the gather's scope: its
+                # transpose is the rows' second cotangent, and the sum of
+                # the two is then this scope's op, as the transposed gather
+                # it feeds is
+                s_lane = _lane_logits(zg, self.att_l)
             with trace_scope("attn_project"):
-                s_dst = (h_dst * self.att_l).sum(-1)             # (num_dst, H)
-                d_dst = (h_dst * self.att_r).sum(-1)
+                # (H, T): fanout times fewer than the lanes' terms, and laid
+                # out targets-minor as they stand
+                s_dst = (h_dst * self.att_l).sum(-1).T
+                d_dst = (h_dst * self.att_r).sum(-1).T
             with trace_scope("attn_logits"):
-                logits = nn.leaky_relu(
-                    zl.sum(-1) + d_dst[:, None, :],              # (T, K, H)
-                    self.negative_slope).reshape(-1, self.heads)
+                logits = nn.leaky_relu(s_lane + d_dst[:, None],
+                                       self.negative_slope)
                 self_logits = nn.leaky_relu(
                     s_dst + d_dst, self.negative_slope)
         else:
@@ -204,7 +242,7 @@ class GATConv(nn.Module):
         with trace_scope("attn_softmax"):
             if dense:
                 alpha, alpha_self = fanout_softmax(
-                    logits, self_logits, valid, num_dst, fanout)
+                    logits, self_logits, valid.reshape(num_dst, fanout).T)
             else:
                 # the self edges appended to the edge list
                 alpha_all = segment_softmax(
@@ -220,16 +258,17 @@ class GATConv(nn.Module):
 
         with trace_scope("attn_aggregate"):
             if dense:
-                msgs = zg * alpha.reshape(num_dst, fanout, self.heads, 1)
+                msgs = _weighted(zg, alpha)
                 out = fanout_sum_aggregate(
                     msgs.reshape((-1,) + msgs.shape[2:]), valid, num_dst,
                     fanout)
+                out = out + _weighted(h_dst, alpha_self)
             else:
                 msgs = h_all[src_safe] * alpha[:, :, None]       # (E, H, F)
                 msgs = jnp.where(valid[:, None, None], msgs, 0.0)
                 out = jnp.zeros((num_dst + 1,) + msgs.shape[1:], msgs.dtype)
                 out = out.at[dst_safe].add(msgs)[:num_dst]
-            out = out + h_dst * alpha_self[:, :, None]
+                out = out + h_dst * alpha_self[:, :, None]
             y = self.finish(out)
         with trace_scope("skip"):
             return self.add_skip(y, x[:num_dst])

@@ -523,27 +523,28 @@ def segment_mean_aggregate(messages, dst, valid, num_dst: int):
     return total / jnp.maximum(cnt, 1.0)[:, None]
 
 
-def fanout_softmax(logits, self_logits, valid, num_dst: int, fanout: int):
+def fanout_softmax(logits, self_logits, valid):
     """Dense counterpart of ``segment_softmax`` with a self term, no scatters.
 
-    The softmax runs over ``fanout + 1`` entries per target: its ``fanout``
-    sampled lanes and its own (``self_logits`` (num_dst, ...), the self loop
-    of an attention layer), which is no lane of the block, in the regular
-    layout (lane ``t * fanout + k`` targets ``t``). The self term is an
-    operand of its own of the max and of the denominator, so nothing is
-    scattered and no ``(num_dst, fanout + 1, ...)`` copy is made to append
-    it; it also keeps every denominator at 1 or more, all-invalid targets
-    included.
-    ``logits`` (E, ...) -> weights (E, ...) and self weights (num_dst, ...)."""
-    shape = logits.shape
-    validb = valid.reshape(valid.shape + (1,) * (logits.ndim - 1))
+    ``logits`` is ``(heads, fanout, targets)``, lanes-minor for
+    :func:`fanout_relation_softmax`'s reason (a heads-minor ``(..., 4)``
+    array is padded to 128 lanes on a TPU), ``self_logits`` ``(heads,
+    targets)`` and ``valid`` ``(fanout, targets)``. The softmax runs over
+    ``fanout + 1`` entries per target and head: its valid lanes and its own
+    (the self loop of an attention layer), which is no lane of the block.
+    The self term is an operand of its own of the max and of the
+    denominator, so nothing is scattered and no ``(heads, fanout + 1,
+    targets)`` copy is made to append it; it also keeps every denominator
+    at 1 or more, all-invalid targets included. Returns the weights
+    ``(heads, fanout, targets)``, 0 on invalid lanes, and the self weights
+    ``(heads, targets)``."""
     neg = jnp.finfo(logits.dtype).min
-    g = jnp.where(validb, logits, neg).reshape((num_dst, fanout) + shape[1:])
+    g = jnp.where(valid, logits, neg)
     gmax = jnp.maximum(g.max(axis=1), self_logits)
     expv = jnp.where(g > neg, jnp.exp(g - gmax[:, None]), 0.0)
     exp_self = jnp.exp(self_logits - gmax)
     denom = expv.sum(axis=1) + exp_self
-    return (expv / denom[:, None]).reshape(shape), exp_self / denom
+    return expv / denom[:, None], exp_self / denom
 
 
 def segment_softmax(logits, seg, valid, num_seg: int):

@@ -526,39 +526,109 @@ def test_a_v5e_gathers_the_store_rows_256_in_flight(
     assert _row_gathers(lowered(plain).compile().as_text()) == [(128, lanes)]
 
 
+def _heads_minor(text, T, K, H):
+    """The float32 arrays of a compiled program that hold one scalar per
+    lane and head (dims ``(T, K, H)`` or ``(T * K, H)`` in any order) and
+    whose layout puts another dim than the targets or the lanes minor: the
+    heads (or the fanout) padded to 128."""
+    import re
+
+    found = set()
+    for dims, order in re.findall(r"f32\[([\d,]+)\]\{([\d,]+)", text):
+        dims = [int(d) for d in dims.split(",")]
+        if sorted(dims) in (sorted([T, K, H]), sorted([T * K, H])):
+            if dims[int(order.split(",")[0])] not in (T, T * K):
+                found.add(f"f32{dims}{{{order}}}")
+    return sorted(found)
+
+
+def test_a_v5e_keeps_the_gat_scalars_lanes_minor(one_chip):
+    """A dense ``GATConv``'s value and gradient compiled for a described
+    v5e: every array of per-lane scalars (the lanes' logits, the softmax's
+    weights, their cotangents) is laid out with the targets or the lanes
+    minor, in ``T(4,128)`` tiles that hold no padding (``models/gat.py``:
+    the lanes' logit terms come off the MXU that way and layout assignment
+    carries it through). The per-target ones, ``fanout`` times smaller,
+    are not held: alone, this layer's self weights' cotangent comes out
+    heads-minor (in the cell's whole step it does not: PERF.md).
+    The logical order alone
+    does not decide it: the same logits taken as a multiply and a reduce
+    over the width and softmaxed over the fanout come out heads-minor, in
+    tiles padded to 128 lanes (which also shows that the check can see
+    one). Nothing runs; this says nothing of results or times."""
+    import re
+
+    from quiver_tpu.models.gat import GATConv
+
+    T, K, H, F, N = 1024, 10, 4, 128, 3000
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    conv = GATConv(features=F, heads=H)
+    x, ei = shape((N, 100)), shape((2, T * K), jnp.int32)
+    params = jax.eval_shape(
+        lambda: conv.init(jax.random.PRNGKey(0), jnp.zeros((64, 100)),
+                          jnp.zeros((2, 64), jnp.int32), 8, 8))
+
+    def loss(p, x, ei):
+        return (conv.apply(p, x, ei, T, K) ** 2).sum()
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        jax.tree_util.tree_map(lambda a: shape(a.shape), params), x, ei
+    ).compile().as_text()
+    assert _heads_minor(text, T, K, H) == []
+    assert re.search(rf"f32\[{H},{K},{T}\]\{{2,\d,\d:T\(4,128\)", text)
+
+    def heads_minor(zg, att):
+        alpha = jax.nn.softmax((zg * att).sum(-1), axis=1)  # (T, K, H)
+        return (zg * alpha[..., None]).sum(1)
+
+    text = jax.jit(jax.grad(lambda zg, att: (heads_minor(zg, att) ** 2).sum())
+                   ).lower(shape((T, K, H, F)), shape((H, F))
+                           ).compile().as_text()
+    assert _heads_minor(text, T, K, H)
+
+
 def test_fanout_softmax_matches_segment_softmax():
     """The dense softmax over each target's ``fanout`` lanes and its self
-    term against the segment softmax over the same lanes with one self
-    edge per target appended."""
+    term, lanes-minor (``(heads, fanout, targets)`` in and out), against the
+    segment softmax over the same lanes in lane order with one self edge
+    per target appended: a target with no valid lane puts weight 1 on its
+    self lane, a fully valid one spreads it over all its lanes."""
     from quiver_tpu.models.layers import fanout_softmax, segment_softmax
 
     rng = np.random.default_rng(0)
     S, K, H = 12, 6, 3
-    logits = jnp.asarray(rng.normal(size=(S * K, H)).astype(np.float32))
-    own = jnp.asarray(rng.normal(size=(S, H)).astype(np.float32))
-    valid = np.asarray(rng.random(S * K) < 0.7)
+    logits = rng.normal(size=(S * K, H)).astype(np.float32)  # lane order
+    own = rng.normal(size=(S, H)).astype(np.float32)
+    valid = rng.random(S * K) < 0.7
     valid[2 * K:3 * K] = False  # a target with no valid lane
-    valid = jnp.asarray(valid)
-    dst = jnp.repeat(jnp.arange(S), K)
-    seg = jnp.concatenate([jnp.where(valid, dst, S), jnp.arange(S)])
-    a_seg = segment_softmax(
-        jnp.concatenate([logits, own]), seg,
-        jnp.concatenate([valid, jnp.ones(S, bool)]), S)
-    a_dense, a_own = fanout_softmax(logits, own, valid, S, K)
+    valid[5 * K:6 * K] = True   # a target whose every lane is valid
+    dst = np.repeat(np.arange(S), K)
+    seg = jnp.concatenate([jnp.asarray(np.where(valid, dst, S)),
+                           jnp.arange(S)])
+    a_seg = np.asarray(segment_softmax(
+        jnp.concatenate([jnp.asarray(logits), jnp.asarray(own)]), seg,
+        jnp.concatenate([jnp.asarray(valid), jnp.ones(S, bool)]), S))
+    a_dense, a_own = fanout_softmax(
+        jnp.asarray(logits.reshape(S, K, H).transpose(2, 1, 0)),
+        jnp.asarray(own.T), jnp.asarray(valid.reshape(S, K).T))
+    assert a_dense.shape == (H, K, S) and a_own.shape == (H, S)
+    a_dense = np.asarray(a_dense).transpose(2, 1, 0).reshape(S * K, H)
+    a_own = np.asarray(a_own).T
     # compare on valid lanes only (invalid lanes: dense gives 0, segment
     # gives exp(min)/tiny garbage that callers mask anyway)
-    m = np.asarray(valid)
-    np.testing.assert_allclose(
-        np.asarray(a_dense)[m], np.asarray(a_seg)[:S * K][m],
-        rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(
-        np.asarray(a_own), np.asarray(a_seg)[S * K:], rtol=1e-5, atol=1e-6)
-    assert np.all(np.asarray(a_dense)[~m] == 0)
+    np.testing.assert_allclose(a_dense[valid], a_seg[:S * K][valid],
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(a_own, a_seg[S * K:], rtol=1e-5, atol=1e-6)
+    assert np.all(a_dense[~valid] == 0)
     # each target's weights, its own among them, sum to 1; alone it is 1
-    sums = np.asarray(a_own).copy()
-    np.add.at(sums, np.asarray(dst)[m], np.asarray(a_dense)[m])
+    sums = a_own.copy()
+    np.add.at(sums, dst[valid], a_dense[valid])
     np.testing.assert_allclose(sums, 1.0, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(a_own)[2], 1.0, atol=1e-6)
+    np.testing.assert_allclose(a_own[2], 1.0, atol=1e-6)
+    assert np.all(a_dense[5 * K:6 * K] > 0) and np.all(a_own[5] < 1)
 
 
 # index blocks (targets, fanout) over ROWS rows for ``gather_lane_rows``,

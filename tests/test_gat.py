@@ -314,6 +314,40 @@ def test_gatconv_dense_backward_is_the_segment_paths(
             err_msg=jax.tree_util.keystr(path))
 
 
+def test_the_dense_path_keeps_its_per_lane_scalars_lanes_minor():
+    """The dense path's per-lane scalars (the lanes' logits, the softmax's
+    weights, and in the backward their cotangents) are ``(heads, fanout,
+    targets)`` arrays and its per-target ones enter them as ``(heads,
+    targets)``: no value of the lowered forward or gradient is ``(targets,
+    fanout, heads)`` or ``(targets * fanout, heads)``, whose heads a TPU
+    pads to 128 lanes. This holds the program's logical shapes only: XLA
+    chooses the layouts, and ``test_a_v5e_keeps_the_gat_scalars_lanes_minor``
+    (``tests/test_dense_aggregation.py``) holds what a v5e compiles."""
+    import re
+
+    T, K, H, F, N = 6, 5, 3, 4, 20
+    rng = np.random.default_rng(5)
+    src = rng.integers(0, N, T * K).astype(np.int32)
+    src[rng.random(T * K) < 0.3] = -1
+    ei = jnp.asarray(np.stack([src, np.repeat(np.arange(T, dtype=np.int32),
+                                              K)]))
+    x = jnp.asarray(rng.normal(size=(N, 7)).astype(np.float32))
+    conv = GATConv(features=F, heads=H)
+    params = conv.init(jax.random.PRNGKey(0), x, ei, T, K)
+
+    def forward(p):
+        return conv.apply(p, x, ei, T, K)
+
+    def gradient(p):
+        return jax.grad(lambda p: (forward(p) ** 2).sum())(p)
+
+    for fn in (forward, gradient):
+        shapes = set(re.findall(r"tensor<([0-9x]+)xf32>",
+                                jax.jit(fn).lower(params).as_text()))
+        assert not shapes & {f"{T}x{K}x{H}", f"{T * K}x{H}"}, fn.__name__
+        assert {f"{H}x{K}x{T}", f"{H}x{T}"} <= shapes, fn.__name__
+
+
 def test_a_self_lane_is_not_counted_twice():
     """A target whose every sampled lane is itself attends to itself once:
     its output is its own projected row, as if it had no lane at all."""
